@@ -28,8 +28,7 @@ RUN pip install --no-cache-dir aiohttp msgpack pyyaml jsonschema cryptography
 # the in-tree TPU worker (the TPU runtime/libtpu comes from the node image)
 ARG WITH_TPU=0
 RUN if [ "$WITH_TPU" = "1" ]; then \
-      pip install --no-cache-dir "jax[tpu]" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html || \
-      pip install --no-cache-dir jax; \
+      pip install --no-cache-dir "jax[tpu]" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html; \
     fi
 
 ENV PYTHONUNBUFFERED=1 \

@@ -1,6 +1,6 @@
 PY ?= python
 
-.PHONY: test test-fast smoke bench up init dryrun lint
+.PHONY: test test-fast smoke chip-smoke bench up init dryrun lint
 
 # Static analysis gate: cordumlint always (stdlib-only), ruff + mypy-strict
 # when installed (the CI lint job installs both; minimal TPU images may not).
@@ -25,6 +25,11 @@ test-fast:
 
 smoke:
 	$(PY) tools/platform_smoke.py
+
+# the main path on one TPU chip (exits non-zero without one); add
+# ARGS=--rehearse for the CPU rehearsal or ARGS="--chips 4" for the TP path
+chip-smoke:
+	$(PY) chip_smoke.py $(ARGS)
 
 bench:
 	$(PY) bench.py

@@ -13,11 +13,12 @@ Four benches, one JSON line:
   (reference analogue: 18,234/s, BENCHMARKS.md:131).
 * TPU compute: ``embeds_per_sec`` (context-engine embedder) and
   ``model_tokens_per_sec``+``mfu`` (Llama forward).  These run in a
-  SUBPROCESS with a hard watchdog: a wedged TPU grant or a crashed PJRT
+  SUBPROCESS with a hard watchdog, one child at a time, while this parent
+  stays off jax (a chip belongs to one process): a hung or crashed PJRT
   client must never take down the control-plane numbers, and any failure
-  is reported in ``embed_error``/``model_error`` — never swallowed.  A host
-  with no TPU skips cleanly (``{"skipped": "no tpu"}``) and the cpu
-  fallback carries the run without fabricating errors.
+  is reported in ``embed_error``/``model_error`` — never swallowed.  The
+  ``tpu`` child exits non-zero on a host with no chip; no CPU timing is
+  ever reported in its place.
 * Micro-batching: ``batched_embeds_per_sec`` vs ``single_job_embeds_per_sec``
   through the REAL worker path (bus → context fetch → batch queue →
   bucketed XLA flush → result publish); the acceptance bar is ≥3× the
@@ -48,14 +49,22 @@ SHARDED_JOBS = int(os.environ.get("BENCH_SHARDED_JOBS", "2000"))
 SHARDS = int(os.environ.get("BENCH_SHARDS", "4"))
 SB_PARTITIONS = int(os.environ.get("BENCH_STATEBUS_PARTITIONS", "2"))
 JAX_TIMEOUT_S = float(os.environ.get("BENCH_JAX_TIMEOUT_S", "420"))
-# TPU backend discovery gets its own short watchdog: a hung PJRT grant on a
-# TPU-less host must become a clean {"skipped": ...} exit 0, not a
-# faulthandler rc=1 crash polluting the JSON (BENCH_r04/r05)
-TPU_PROBE_TIMEOUT_S = float(os.environ.get("BENCH_TPU_PROBE_TIMEOUT_S", "45"))
 BASELINE_JOBS_PER_SEC = 1000.0  # BASELINE.json north-star target
 
-# bf16 peak FLOP/s per chip by TPU generation (public spec sheets)
-PEAK_FLOPS = {"v5e": 197e12, "v5p": 459e12, "v4": 275e12, "v6e": 918e12}
+# bf16 peak FLOP/s per chip by TPU generation (public spec sheets); a TPU
+# whose device_kind matches no key is an error, never a silent 0
+PEAK_FLOPS = {"v5e": 197e12, "v5lite": 197e12, "v5p": 459e12, "v4": 275e12,
+              "v6e": 918e12}
+
+
+def _peak_flops(device_kind: str) -> float:
+    kind = device_kind.lower().replace(" ", "")
+    for gen, flops in PEAK_FLOPS.items():
+        if gen in kind:
+            return flops
+    raise KeyError(
+        f"no peak FLOP/s known for device_kind {device_kind!r}; add it to "
+        "PEAK_FLOPS with its source")
 
 
 def _make_stack():
@@ -957,10 +966,10 @@ def _gang_child(smoke: bool) -> None:
     faulthandler.dump_traceback_later(max(60.0, JAX_TIMEOUT_S), exit=True)
     import jax
 
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:  # cordumlint: disable=CL002 -- older jax without the config key; env var governs
-        pass
+    jax.config.update("jax_platforms", "cpu")
+    from cordum_tpu.parallel.mesh import configure_compile_cache
+
+    configure_compile_cache()
     print(json.dumps(asyncio.run(_bench_gang(smoke))))
 
 
@@ -1210,10 +1219,10 @@ def _tp_child(smoke: bool) -> None:
     faulthandler.dump_traceback_later(max(60.0, JAX_TIMEOUT_S), exit=True)
     import jax
 
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:  # cordumlint: disable=CL002 -- older jax without the config key; env var governs
-        pass
+    jax.config.update("jax_platforms", "cpu")
+    from cordum_tpu.parallel.mesh import configure_compile_cache
+
+    configure_compile_cache()
     print(json.dumps(asyncio.run(_bench_tp(smoke))))
 
 
@@ -1304,103 +1313,37 @@ def bench_tp(*, smoke: bool = False) -> dict:
 
 # ---------------------------------------------------------------------------
 # TPU compute benches — run via `python bench.py --jax-child [tpu|cpu]` in a
-# subprocess so a wedged TPU grant / crashed PJRT client can't hang the
-# control-plane benches. The child prints ONE json line.
+# subprocess so a hung or crashed PJRT client can't hang the control-plane
+# benches. The child prints ONE json line.  `tpu` needs a chip and exits
+# non-zero with a one-line reason without one; `cpu` is the explicit child
+# `--smoke` asks for and names its device.
 # ---------------------------------------------------------------------------
 
 
 def _jax_child(device: str) -> None:
     import faulthandler
-    import threading
 
-    # watchdog: if the PJRT client wedges (e.g. TPU grant never arrives),
-    # die with a traceback instead of hanging the driver
+    # watchdog: if the PJRT client wedges, die with a traceback instead of
+    # hanging the driver
     faulthandler.dump_traceback_later(max(30.0, JAX_TIMEOUT_S - 30.0), exit=True)
     if device == "cpu":
         os.environ["JAX_PLATFORMS"] = "cpu"
     out: dict = {}
+    import jax
 
-    # Backend-discovery watchdog (the BENCH_r04/r05 `child rc=1` fix): on
-    # hosts where libtpu is installed but no TPU is grantable, jax.devices()
-    # HANGS instead of raising — and it hangs inside C init WITHOUT releasing
-    # the GIL, so an in-process watchdog thread (the original PR-5 fix) never
-    # gets to run.  The tpu probe therefore runs in a THROWAWAY GRANDCHILD
-    # process this child can kill from outside the GIL: a probe that doesn't
-    # finish inside TPU_PROBE_TIMEOUT_S, crashes, or reports a non-tpu
-    # backend is a clean skip (exit 0, {"skipped": ...}).  Only a probe that
-    # confirms a real TPU lets this process touch jax at all.
-    if device == "tpu":
-        try:
-            probe = subprocess.run(
-                [sys.executable, "-c",
-                 "import jax, json; print(json.dumps(jax.devices()[0].platform))"],
-                capture_output=True, text=True, timeout=TPU_PROBE_TIMEOUT_S,
-            )
-        except subprocess.TimeoutExpired:
-            print(json.dumps({"skipped": "no tpu",
-                              "detail": "backend probe timed out after "
-                                        f"{TPU_PROBE_TIMEOUT_S:.0f}s (TPU grant unavailable?)"}),
-                  flush=True)
-            return
-        platform = ""
-        if probe.returncode == 0:
-            lines = [ln for ln in probe.stdout.strip().splitlines() if ln]
-            try:
-                platform = json.loads(lines[-1]) if lines else ""
-            except ValueError:
-                platform = ""
-        if platform != "tpu":
-            detail = (f"jax backend is {platform!r}" if probe.returncode == 0
-                      else f"probe rc={probe.returncode}: {(probe.stderr or '')[-200:]}")
-            print(json.dumps({"skipped": "no tpu", "detail": detail}), flush=True)
-            return
-
-    # second line of defense: a probe-confirmed backend that still wedges in
-    # THIS process trips the event-based watchdog (kept for the case where
-    # the grant vanishes between probe and init — here the hang does release
-    # the GIL once real compilation work is underway)
-    probe_done = threading.Event()
-
-    def _probe_watchdog() -> None:
-        if probe_done.wait(TPU_PROBE_TIMEOUT_S):
-            return
-        if device == "tpu":
-            print(json.dumps({"skipped": "no tpu",
-                              "detail": "backend init timed out after "
-                                        f"{TPU_PROBE_TIMEOUT_S:.0f}s (TPU grant unavailable?)"}),
-                  flush=True)
-            os._exit(0)
-        faulthandler.dump_traceback()
-        os._exit(1)
-
-    threading.Thread(target=_probe_watchdog, daemon=True).start()
-    try:
-        import jax
-
-        if device == "cpu":
-            jax.config.update("jax_platforms", "cpu")
-        devs = jax.devices()
-    except Exception as ex:  # noqa: BLE001 - "no TPU" is an expected outcome
-        probe_done.set()
-        if device == "tpu":
-            # no TPU on this host is not a failure: exit cleanly so the
-            # driver falls back to the cpu child without an embed_error
-            print(json.dumps({"skipped": "no tpu",
-                              "detail": f"{type(ex).__name__}: {ex}"[:300]}),
-                  flush=True)
-            return
-        raise
-    probe_done.set()
-    dev = devs[0]
+    if device == "cpu":
+        jax.config.update("jax_platforms", "cpu")
+    dev = jax.devices()[0]
     if device == "tpu" and dev.platform != "tpu":
-        print(json.dumps({"skipped": "no tpu",
-                          "detail": f"jax backend is {dev.platform!r}"}), flush=True)
-        return
+        sys.stderr.write(
+            f"bench --jax-child tpu: no chip (jax backend is {dev.platform!r})\n")
+        sys.exit(2)
+    from cordum_tpu.parallel.mesh import configure_compile_cache
+
+    configure_compile_cache()
     out["device"] = dev.device_kind
-    peak = 0.0
-    for gen, flops in PEAK_FLOPS.items():
-        if gen in dev.device_kind.lower().replace(" ", ""):
-            peak = flops
+    # mfu is a device metric: only the tpu child computes it
+    peak = _peak_flops(dev.device_kind) if device == "tpu" else 0.0
 
     # --- embedder (context-engine path; headline embeds/sec) ---
     try:
@@ -2838,111 +2781,43 @@ async def bench_agents(smoke: bool = True) -> dict:
     }
 
 
-_CHILD_METRIC_KEYS = (
-    "embeds_per_sec", "model_tokens_per_sec", "model_achieved_tflops",
-    "model_params_m", "single_job_embeds_per_sec", "batched_embeds_per_sec",
-    "batched_speedup", "batch_flushes", "max_batch_rows",
-    "decode_tokens_per_sec", "sequential_decode_tokens_per_sec",
-    "prefill_tokens_per_sec", "serving_ttft_p50_ms",
-    "serving_speedup", "p50_inter_token_ms", "inter_token_p99_ms",
-    "serving_mean_occupancy", "serving_steps", "serving_sessions",
-    "serving_compile_count", "migration_pause_p50_ms", "migrations_done",
-    "disagg_ttft_p50_ms", "colocated_ttft_p50_ms", "disagg_ttft_gain",
-    "disagg_inter_token_p99_ms", "colocated_inter_token_p99_ms",
-    "disagg_inter_token_gain", "disagg_long_job_p50_ms",
-    "colocated_long_job_p50_ms", "disagg_migrations_done",
-    "disagg_decode_tokens_per_sec", "colocated_decode_tokens_per_sec",
-    "chat_ttft_cold_p50_ms", "chat_ttft_hit_p50_ms",
-    "chat_prefix_ttft_speedup", "chat_prefix_hit_rate",
-    "chat_token_identical", "chat_sessions", "chat_resident_sessions",
-    "chat_device_session_capacity", "chat_resident_over_capacity",
-    "chat_hibernated_pages", "chat_restored_pages",
-    "chat_restore_pause_p50_ms",
-    "spec_decode_speedup", "spec_token_identity", "spec_accept_rate",
-    "spec_decode_tokens_per_s", "spec_base_tokens_per_s", "spec_steps",
-    "spec_base_steps", "spec_drafted_tokens", "spec_accepted_tokens",
-    "spec_rolled_back_tokens", "spec_compile_count", "spec_sessions",
-)
-
-
 def bench_jax(*, smoke: bool = False) -> dict:
-    """Run the TPU bench child; fall back to a CPU child so the compute path
-    is still exercised when the TPU is unavailable (clearly labeled).
+    """Run the compute bench child: the ``tpu`` child, or the explicit
+    ``cpu`` child that ``--smoke`` asks for (it names its device).
 
-    A host without a TPU is NOT a failure: the tpu child exits cleanly with
-    ``{"skipped": "no tpu"}`` and the cpu fallback's success clears any
-    tpu-pass error (it survives as ``tpu_*_error`` context).  Real child
-    failures are never silently degraded into a partial metric: the full
-    child traceback rides along in ``child_traceback`` and main() flags the
-    run ``degraded`` with a loud stderr warning (CL002 applied to the bench
-    harness)."""
-    results: dict = {}
-    devices = ("cpu",) if smoke else ("tpu", "cpu")
-    for device in devices:
-        try:
-            proc = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "--jax-child", device],
-                capture_output=True, text=True, timeout=JAX_TIMEOUT_S,
-                cwd=os.path.dirname(os.path.abspath(__file__)),
-            )
-            line = (proc.stdout.strip().splitlines() or [""])[-1]
-            child = json.loads(line) if line.startswith("{") else {}
-            if not child:
-                tail = (proc.stderr or proc.stdout or "")[-300:]
-                child = {"embed_error": f"child rc={proc.returncode}: {tail}",
-                         "model_error": f"child rc={proc.returncode}"}
-            if ("embed_error" in child or "model_error" in child) and proc.stderr:
-                # full crash context, not just the one-line summary
-                child["child_traceback"] = proc.stderr[-8000:]
-        except subprocess.TimeoutExpired as te:
-            child = {"embed_error": f"{device} bench timed out after {JAX_TIMEOUT_S}s "
-                                    "(TPU grant unavailable?)",
-                     "model_error": "timeout"}
-            partial = te.stderr.decode(errors="replace") if isinstance(te.stderr, bytes) else (te.stderr or "")
-            if partial:
-                child["child_traceback"] = partial[-8000:]
-        except Exception as ex:  # noqa: BLE001
-            child = {"embed_error": f"{type(ex).__name__}: {ex}"[:300]}
-        if device == "tpu":
-            if child.get("skipped"):
-                # no TPU on this host: clean skip, cpu pass carries the run
-                results["tpu_skipped"] = str(child.get("detail") or child["skipped"])
-                continue
-            results = dict(child)
-            if all(k in child for k in
-                   ("embeds_per_sec", "model_tokens_per_sec",
-                    "batched_embeds_per_sec", "decode_tokens_per_sec")):
-                return results
-            # remember why the TPU pass failed, then try CPU for coverage;
-            # only backfill embed_error if the embed bench itself is missing
-            # (a model-only failure must not be misattributed)
-            if "embeds_per_sec" not in results and "embed_error" not in results:
-                results["embed_error"] = results.get("model_error", "unknown")
-        else:
-            # merge CPU numbers for whichever metric the TPU pass missed
-            for k in _CHILD_METRIC_KEYS:
-                if k not in results and k in child:
-                    results[k] = child[k]
-                    results["fallback_device"] = child.get("device", "cpu")
-            for k in ("embed_error", "model_error", "batched_error",
-                      "serving_error", "disagg_error", "chat_error",
-                      "spec_error", "child_traceback"):
-                if k not in results and k in child:
-                    results[k] = child[k]
-            if "device" not in results and "device" in child:
-                results["device"] = child["device"]
-    # the cpu fallback succeeded for a metric → the tpu-pass error is
-    # context, not a failure (the noisy BENCH_r05 embed_error fix)
-    for metric, err in (("embeds_per_sec", "embed_error"),
-                        ("model_tokens_per_sec", "model_error"),
-                        ("batched_embeds_per_sec", "batched_error"),
-                        ("decode_tokens_per_sec", "serving_error"),
-                        ("disagg_ttft_p50_ms", "disagg_error"),
-                        ("chat_prefix_ttft_speedup", "chat_error"),
-                        ("spec_decode_speedup", "spec_error")):
-        if metric in results and err in results and results.get("fallback_device"):
-            results[f"tpu_{err}"] = results.pop(err)
-    return results
+    The parent stays off jax while the child holds the chip.  A host with
+    no chip makes the ``tpu`` child exit non-zero, which surfaces here as
+    ``embed_error``/``model_error`` and flags the run ``degraded`` — a CPU
+    timing is never reported in a device metric's place.  The full child
+    traceback rides along in ``child_traceback`` (CL002 applied to the
+    bench harness)."""
+    device = "cpu" if smoke else "tpu"
+    # a chip belongs to one process: a parent that touched jax would hold it
+    assert device == "cpu" or "jax" not in sys.modules, "bench parent must stay off jax"
+    try:
+        proc = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--jax-child", device],
+            capture_output=True, text=True, timeout=JAX_TIMEOUT_S,
+            cwd=os.path.dirname(os.path.abspath(__file__)),
+        )
+        line = (proc.stdout.strip().splitlines() or [""])[-1]
+        child = json.loads(line) if line.startswith("{") else {}
+        if not child:
+            tail = (proc.stderr or proc.stdout or "")[-300:]
+            child = {"embed_error": f"child rc={proc.returncode}: {tail}",
+                     "model_error": f"child rc={proc.returncode}"}
+        if ("embed_error" in child or "model_error" in child) and proc.stderr:
+            # full crash context, not just the one-line summary
+            child["child_traceback"] = proc.stderr[-8000:]
+    except subprocess.TimeoutExpired as te:
+        child = {"embed_error": f"{device} bench timed out after {JAX_TIMEOUT_S}s",
+                 "model_error": "timeout"}
+        partial = te.stderr.decode(errors="replace") if isinstance(te.stderr, bytes) else (te.stderr or "")
+        if partial:
+            child["child_traceback"] = partial[-8000:]
+    except Exception as ex:  # noqa: BLE001
+        child = {"embed_error": f"{type(ex).__name__}: {ex}"[:300]}
+    return child
 
 
 def main() -> None:
@@ -3266,11 +3141,6 @@ def main() -> None:
     if prof is not None:
         # per-layer µs/op breakdown: routing / codec / selection / commit
         out["profile"] = prof
-    for k in ("fallback_device", "tpu_skipped", "tpu_embed_error",
-              "tpu_model_error", "tpu_batched_error", "tpu_serving_error",
-              "tpu_disagg_error", "tpu_chat_error", "tpu_spec_error"):
-        if k in jx:
-            out[k] = jx[k]
     degraded = bool(out["embed_error"] or out["model_error"]
                     or out["batched_error"] or out["serving_error"]
                     or out["disagg_error"] or out["chat_error"]

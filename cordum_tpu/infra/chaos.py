@@ -259,6 +259,9 @@ class ServerProc:
     async def start(self, *, timeout_s: float = 20.0) -> None:
         from .replication import probe_role
 
+        # the statebus never imports jax; the variable only keeps a chaos
+        # run's children uniform (several workers share the host, so all
+        # of them stay off the chip — see WorkerProc)
         env = {**os.environ, "JAX_PLATFORMS": "cpu",
                "STATEBUS_PORT": str(self.port), **self.env}
         self.proc = subprocess.Popen(
@@ -306,8 +309,9 @@ class WorkerProc:
     §Migration, drain, and failover).
 
     ``env`` carries the worker configuration (WORKER_ID,
-    CORDUM_STATEBUS_URL, WORKER_SERVING_*, ...); CPU is always forced so
-    chaos runs never claim a TPU grant.  ``kill()`` is SIGKILL (a crashed
+    CORDUM_STATEBUS_URL, WORKER_SERVING_*, ...).  A chaos run starts
+    several of these on one host and a chip belongs to one process at a
+    time, so every one of them is held to the CPU.  ``kill()`` is SIGKILL (a crashed
     worker: heartbeats just stop, sessions strand until the scheduler's
     WorkerFailover notices); ``terminate()`` is SIGTERM (graceful drain:
     sessions live-migrate to peers before exit).  Readiness is the

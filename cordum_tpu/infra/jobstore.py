@@ -254,7 +254,7 @@ class JobStore:
                 return False, snap
             # direct pipe_execute: _chain_ops only emits PIPELINE_OPS names
             # (re-validated store-side), so the Pipeline buffering/validation
-            # layer is pure overhead on this hot path (BENCH_r05 regression)
+            # layer is pure overhead on this hot path
             ok, versions = await self.kv.pipe_execute({key: snap.version}, ops)
             if ok:
                 merged = dict(snap.fields)

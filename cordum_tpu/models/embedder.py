@@ -149,7 +149,10 @@ class Embedder:
             self._data_sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
         else:
             self._data_sharding = None
-        self._fwd = jax.jit(lambda p, i, m: forward(p, i, m, self.cfg))
+        def embed_forward(p, i, m):  # named: compile logs and traces find it
+            return forward(p, i, m, self.cfg)
+
+        self._fwd = jax.jit(embed_forward)
 
     def embed(self, texts: Sequence[str]) -> np.ndarray:
         ids, mask = batch_tokenize(texts, self.cfg)

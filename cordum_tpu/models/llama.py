@@ -137,22 +137,6 @@ def shard_params(params: Params, cfg: LlamaConfig, mesh: Mesh) -> Params:
 KV_ARENA_SPEC = P(None, None, None, AXIS_TP, None)
 
 
-def shard_serving_state(
-    params: Params, k_pages: jax.Array, v_pages: jax.Array,
-    cfg: LlamaConfig, mesh: Mesh,
-) -> tuple[Params, jax.Array, jax.Array]:
-    """Place serving state onto a TP mesh: weights per :func:`param_specs`,
-    both page arenas split over ``kvh`` (:data:`KV_ARENA_SPEC`).  On a
-    size-1 mesh (the CPU-CI full-replica fallback) every spec degenerates
-    to a trivial placement and this is a no-op device_put."""
-    arena = NamedSharding(mesh, KV_ARENA_SPEC)
-    return (
-        shard_params(params, cfg, mesh),
-        jax.device_put(k_pages, arena),
-        jax.device_put(v_pages, arena),
-    )
-
-
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -521,10 +505,11 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer=None):
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    from ..parallel.compat import donated_train_step
-
-    jstep = donated_train_step(
-        step, mesh=mesh, param_shardings=param_shardings, batch_sharding=batch_sharding
+    jstep = jax.jit(
+        step,
+        in_shardings=(param_shardings, None, batch_sharding),
+        out_shardings=(param_shardings, None, None),
+        donate_argnums=(0, 1),
     )
 
     def init(key):

@@ -199,10 +199,11 @@ def make_train_step(cfg: MoEConfig, mesh: Mesh, optimizer=None):
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    from ..parallel.compat import donated_train_step
-
-    jstep = donated_train_step(
-        step, mesh=mesh, param_shardings=param_shardings, batch_sharding=batch_sharding
+    jstep = jax.jit(
+        step,
+        in_shardings=(param_shardings, None, batch_sharding),
+        out_shardings=(param_shardings, None, None),
+        donate_argnums=(0, 1),
     )
 
     def init(key):

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..parallel.compat import axis_size, shard_map_compat
 from ..parallel.mesh import AXIS_DP, AXIS_PP
 from .llama import LlamaConfig, rms_norm, rope
 
@@ -125,11 +124,11 @@ def _pipeline_local(params: dict, tokens_mb: jax.Array, cfg: PipelineConfig,
                     *, pp_axis: str, dp_axis: str) -> tuple[jax.Array, jax.Array]:
     """Per-device body: tokens_mb [M, mb_local, T] → ([1,1] loss sum, [1,1]
     token count).  The cross-device reduction happens OUTSIDE the shard_map:
-    claiming a replicated scalar output (out_specs=P()) requires replication
-    tracking that older jax cannot prove through the fori_loop, so each
-    device returns its mapped partial sums instead."""
+    claiming a replicated scalar output (out_specs=P()) would need the
+    replication checker (``check_vma``) to prove it through the fori_loop,
+    so each device returns its mapped partial sums instead."""
     base = cfg.base
-    s = axis_size(pp_axis)
+    s = jax.lax.axis_size(pp_axis)
     stage = jax.lax.axis_index(pp_axis)
     m, mb, t = tokens_mb.shape
     d = base.d_model
@@ -158,8 +157,8 @@ def _pipeline_local(params: dict, tokens_mb: jax.Array, cfg: PipelineConfig,
         logits = (h @ params["lm_head"]).astype(jnp.float32)
         logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
         nll = -jnp.take_along_axis(logp, tgt_mb[:, 1:][..., None], axis=-1)[..., 0]
-        # accumulate as [1,1] (never rank 0): scalar residuals of the grad
-        # partial-eval are mishandled by older jax's shard_map
+        # accumulate as [1,1] (never rank 0) so the per-device partial
+        # sums map straight onto the (dp, pp) out_specs
         valid = valid_out.astype(jnp.float32).reshape(1, 1)
         loss_sum = loss_sum + valid * jnp.sum(nll, keepdims=True)
         tok_count = tok_count + valid * float(nll.size)
@@ -180,7 +179,7 @@ def make_loss_fn(cfg: PipelineConfig, mesh: Mesh, *, pp_axis: str = AXIS_PP, dp_
     part_spec = P(dp_axis, pp_axis)  # per-device [1,1] partial sums
 
     def loss(params, tokens_mb):
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             partial(_pipeline_local, cfg=cfg, pp_axis=pp_axis, dp_axis=dp_axis),
             mesh=mesh,
             in_specs=(pspecs, tok_spec),
@@ -208,10 +207,11 @@ def make_train_step(cfg: PipelineConfig, mesh: Mesh, optimizer=None):
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    from ..parallel.compat import donated_train_step
-
-    jstep = donated_train_step(
-        step, mesh=mesh, param_shardings=param_shardings, batch_sharding=tok_sharding
+    jstep = jax.jit(
+        step,
+        in_shardings=(param_shardings, None, tok_sharding),
+        out_shardings=(param_shardings, None, None),
+        donate_argnums=(0, 1),
     )
 
     def init(key):

@@ -34,12 +34,18 @@ def _lib_path() -> str:
 
 
 def _build(out: str) -> bool:
+    # several processes may build at once (a fresh checkout under parallel
+    # test workers, a stack of service binaries): each compiles to a name
+    # of its own and renames it into place, so no one loads a half-written
+    # library
+    tmp = f"{out}.{os.getpid()}.tmp"
     for cc in ("cc", "gcc", "clang"):
         try:
             subprocess.run(
-                [cc, "-O2", "-shared", "-fPIC", "-o", out, _SRC],
+                [cc, "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
                 check=True, capture_output=True, timeout=60,
             )
+            os.replace(tmp, out)
             return True
         except (FileNotFoundError, subprocess.CalledProcessError, subprocess.TimeoutExpired):
             continue
@@ -58,6 +64,8 @@ def load_strategy_scan() -> Optional[ctypes.CDLL]:
             import glob
 
             for stale in glob.glob(os.path.join(_DIR, "libstrategy_scan-*.so")):
+                if stale == lib_file:
+                    continue  # another process built it meanwhile
                 try:
                     os.unlink(stale)  # drop artifacts of older source revisions
                 except OSError:

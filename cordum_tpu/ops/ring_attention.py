@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.compat import axis_size, shard_map_compat
 from ..parallel.mesh import AXIS_DP, AXIS_SP
 
 _NEG = -1e30
@@ -31,7 +30,7 @@ _NEG = -1e30
 
 def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool, scale: float):
     """Per-device body under shard_map; q: [B, Tq, H, D], k/v: [B, Tk, Hkv, D]."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, tq, h, d = q.shape
     tk = k.shape[1]
@@ -90,7 +89,7 @@ def ring_attention(
     with the same sharding as q."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     spec = P(dp_axis, sp_axis, None, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(_ring_attention_local, axis_name=sp_axis, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),

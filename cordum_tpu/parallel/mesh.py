@@ -18,6 +18,7 @@ Meshes are created over whatever devices JAX exposes (TPU slice in prod,
 from __future__ import annotations
 
 import math
+import os
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -112,19 +113,46 @@ def device_kind(devices: Optional[Sequence[jax.Device]] = None) -> str:
 
 
 def hbm_stats(devices: Optional[Sequence[jax.Device]] = None) -> tuple[float, float]:
-    """(used_gb, total_gb) summed over devices; (0,0) when unsupported."""
+    """(used_gb, total_gb) summed over devices.  (0, 0) only for a backend
+    that keeps no ``memory_stats`` (the CPU's returns None); a device that
+    raises is a fault and propagates."""
     devs = list(devices) if devices is not None else list(jax.devices())
     used = total = 0.0
     for d in devs:
-        try:
-            st = d.memory_stats()
-        except Exception:
-            return 0.0, 0.0
+        st = d.memory_stats()
         if not st:
             return 0.0, 0.0
         used += st.get("bytes_in_use", 0) / 1e9
         total += st.get("bytes_limit", st.get("bytes_reservable_limit", 0)) / 1e9
     return used, total
+
+
+#: where compiled programs persist when JAX_COMPILATION_CACHE_DIR is not set:
+#: one fixed path inside the checkout (the path is part of the cache key, so
+#: a directory that moves never hits)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile.  ``JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads it
+    itself and no other directory is set in code); otherwise the cache
+    lives at :data:`DEFAULT_COMPILE_CACHE_DIR` — on an accelerator only:
+    the CPU backend compiles test sizes in no time, and XLA's CPU loader
+    logs a machine-feature warning on every cached read.  The minimum
+    compile time drops to zero so the small page gather/scatter/copy
+    programs persist too.  Returns the directory in use ("" = none)."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not path:
+        if jax.default_backend() == "cpu":
+            return ""
+        path = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def shard_batch(mesh: Mesh, batch, axes: Sequence[str] = (AXIS_DP,)):

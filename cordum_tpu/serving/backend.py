@@ -65,6 +65,23 @@ class StepEntry:
     draft: int = 0  # >0: speculative row with this many drafted tokens
 
 
+def make_ragged_program(cfg: Any, *, sample_logits: bool, donate: bool) -> Any:
+    """The ONE jitted serving program: ``llama.ragged_step`` over
+    ``(params, k_pages, v_pages, tokens, positions, page_tables, token_seq,
+    out_idx)``.  With ``donate`` the two arenas are donated, so the
+    in-place page writes never copy an arena."""
+    import jax
+
+    from ..models import llama
+
+    def ragged_program(p, kp, vp, toks, pos, pt, ts, oi):
+        return llama.ragged_step(
+            p, kp, vp, toks, pos, pt, ts, oi, cfg, sample_logits=sample_logits
+        )
+
+    return jax.jit(ragged_program, donate_argnums=(1, 2) if donate else ())
+
+
 class LlamaServingBackend:
     # the ragged program returns per-position predictions for every buffer
     # row, so draft verification rows (StepEntry.draft > 0) are supported
@@ -133,33 +150,30 @@ class LlamaServingBackend:
 
         from ..models import llama
 
-        if self._params_provider is not None:
-            self._params = self._params_provider()
-        else:
-            self._params = llama.init_params(jax.random.PRNGKey(self._seed), self.cfg)
-        self._k_pages, self._v_pages = llama.init_kv_pages(
-            self.cfg, self.num_pages, self.page_size
+        self._params, self._k_pages, self._v_pages = self._make_state(
+            self._params_provider() if self._params_provider is not None else None
         )
-        # sharded-serving hook: a subclass may re-place params and arenas
-        # onto a TP mesh (NamedSharding) before the program compiles
-        self._params, self._k_pages, self._v_pages = self._place_state(
-            self._params, self._k_pages, self._v_pages
-        )
-        cfg = self.cfg
-        sample = bool(self.sample_output)
         # donate the page arenas on real accelerators so the in-place
         # update never copies the arena; CPU jax spams donation warnings
-        donate = (jax.default_backend() != "cpu")
-        self._ragged_jit = jax.jit(
-            lambda p, kp, vp, toks, pos, pt, ts, oi: llama.ragged_step(
-                p, kp, vp, toks, pos, pt, ts, oi, cfg, sample_logits=sample
-            ),
-            donate_argnums=(1, 2) if donate else (),
+        self._ragged_jit = make_ragged_program(
+            self.cfg, sample_logits=bool(self.sample_output),
+            donate=jax.default_backend() != "cpu",
         )
 
-    def _place_state(self, params: Any, k_pages: Any, v_pages: Any):
-        """Device-placement hook (identity here).  ShardedServingBackend
-        overrides it to apply the TP NamedSharding layout."""
+    def _make_state(self, params: Any):
+        """Weights (``params``, or seeded random ones when None) and the
+        two zeroed page arenas, on the default device.
+        ShardedServingBackend overrides it to create them already laid out
+        over the TP mesh."""
+        import jax
+
+        from ..models import llama
+
+        if params is None:
+            params = llama.init_params(jax.random.PRNGKey(self._seed), self.cfg)
+        k_pages, v_pages = llama.init_kv_pages(
+            self.cfg, self.num_pages, self.page_size
+        )
         return params, k_pages, v_pages
 
     def compiled_programs(self) -> int:

@@ -16,13 +16,10 @@ program (``models/llama.ragged_step``) over its slice of the model:
     dead-code-eliminated) and own nothing but their arena shard.  Rank 0
     owns token streaming, admission, and the session registry.
 
-Mesh construction is capability-gated: on real multi-chip hardware
-:func:`rank_mesh` builds the jax.distributed / multi-device TP mesh and the
-arenas genuinely split; on the 1-chip CPU CI host every rank holds a FULL
-local replica on a trivial mesh (the PR 15 gang-training fallback) — the
-rank-role split, the replay protocol, the per-rank record format and the
-compile-count ceiling are all still exercised for real, only the memory
-saving is simulated.
+:func:`rank_mesh` builds the ``tp``-way mesh over the devices JAX exposes
+(a four-chip host, or the eight virtual CPU devices of tier-1) and the
+weights and arenas genuinely split over it; a host with fewer devices than
+``tp`` is an error, not a replica.
 
 Per-rank migration records: :meth:`ShardedServingBackend.export_kv` slices
 every PR 12 page record along the head axis and stamps a
@@ -151,40 +148,20 @@ def entry_from_wire(d: dict) -> StepEntry:
 
 
 def rank_mesh(tp: int):
-    """The TP mesh this rank's program runs over.
-
-    On hardware with enough devices this is the real ``tp``-way mesh
-    (multi-host when ``jax.distributed`` has been initialized — every
-    process then contributes its local chips to the global device list).
-    On the CPU CI host (1 device) it degenerates to a size-1 mesh and the
-    rank holds a full replica — the PR 15 gang fallback."""
+    """The TP mesh this rank's program runs over: ``tp``-way over the
+    devices JAX exposes (dp absorbs the rest).  Too few devices, or a count
+    ``tp`` does not divide, is an error — never a silent full replica on
+    the first device."""
     import jax
 
     from ..parallel.mesh import simple_mesh
 
     n = len(jax.devices())
-    if tp > 1 and n >= tp and n % tp == 0:
-        return simple_mesh(tp)
-    return simple_mesh(1)
-
-
-def init_distributed(coordinator: str, num_processes: int, process_id: int) -> bool:
-    """Join the multi-host ``jax.distributed`` mesh — the real-hardware
-    rendezvous path (one call per gang member before the first device op).
-    Returns False (and leaves the local backend untouched) when the runtime
-    lacks distributed support or the coordinator is unreachable, which is
-    the expected outcome on the CPU CI host."""
-    try:
-        import jax
-
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-        return True
-    except Exception:  # noqa: BLE001 - CPU CI / already-initialized fallback
-        return False
+    if n < tp or n % tp:
+        raise ValueError(
+            f"tp={tp} needs a multiple of {tp} devices; jax exposes {n} "
+            f"({jax.default_backend()})")
+    return simple_mesh(tp)
 
 
 class ShardedServingBackend(LlamaServingBackend):
@@ -194,8 +171,7 @@ class ShardedServingBackend(LlamaServingBackend):
     shapes, same ONE compiled program (per rank) — plus:
 
       * ``rank``/``tp`` identity and the rank's ``[lo, hi)`` KV-head slice;
-      * weights + arenas placed with NamedSharding over :func:`rank_mesh`
-        (full local replica on the 1-chip CI fallback);
+      * weights + arenas placed with NamedSharding over :func:`rank_mesh`;
       * follower ranks (``rank > 0``) compile with ``sample_logits=False``
         — lm_head never runs there;
       * :meth:`export_kv` emits per-rank head-sliced records (the importer
@@ -214,13 +190,35 @@ class ShardedServingBackend(LlamaServingBackend):
         self.sample_output = (self.rank == 0) if sample_output is None else bool(sample_output)
         self.mesh: Any = None
 
-    def _place_state(self, params: Any, k_pages: Any, v_pages: Any):
+    def _make_state(self, params: Any):
+        """Weights and arenas laid out over the TP mesh.  Seeded weights and
+        the zeroed arenas are CREATED sharded (``jit`` with
+        ``out_shardings``): building them on one device first would put the
+        whole model there, which at real widths no single chip holds."""
+        import jax
+        from jax.sharding import NamedSharding
+
         from ..models import llama
 
-        self.mesh = rank_mesh(self.tp)
-        return llama.shard_serving_state(
-            params, k_pages, v_pages, self.cfg, self.mesh
-        )
+        self.mesh = mesh = rank_mesh(self.tp)
+        cfg = self.cfg
+        if params is None:
+            shardings = jax.tree.map(
+                lambda s: NamedSharding(mesh, s), llama.param_specs(cfg))
+
+            def sharded_init(key):
+                return llama.init_params(key, cfg)
+
+            params = jax.jit(sharded_init, out_shardings=shardings)(
+                jax.random.PRNGKey(self._seed))
+        else:
+            params = llama.shard_params(params, cfg, mesh)
+        arena = NamedSharding(mesh, llama.KV_ARENA_SPEC)
+        k_pages, v_pages = jax.jit(
+            lambda: llama.init_kv_pages(cfg, self.num_pages, self.page_size),
+            out_shardings=(arena, arena),
+        )()
+        return params, k_pages, v_pages
 
     def export_kv(self, pages: list[int], start_tok: int, end_tok: int) -> list[dict]:
         """This rank's head slice of every page record.  A gang's full

@@ -49,6 +49,25 @@ async def echo_handler(ctx: JobContext) -> Any:
 # ---------------------------------------------------------------------------
 
 
+def make_matmul_program(iters: int):
+    """The jitted ``matmul`` op: ``iters`` rounds of k→m→k through two
+    matmuls, then the final projection to ``(b, n, m)``."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def matmul_program(x, y, y_back):
+        # carry shape must stay (b, n, k) across iterations, so each
+        # step goes k→m→k through two matmuls
+        def body(i, acc):
+            return jnp.tanh((acc @ y) @ y_back)
+
+        acc = jax.lax.fori_loop(0, iters, body, x)
+        return acc @ y  # final projection to (b, n, m)
+
+    return matmul_program
+
+
 class TPUCompute:
     """Lazily-initialized JAX compute state shared by the TPU handlers.
 
@@ -85,18 +104,7 @@ class TPUCompute:
         compiled = fn is not None  # device span attr: compile vs cached split
         if fn is None:
             dt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
-
-            @jax.jit
-            def run(x, y, y_back):
-                # carry shape must stay (b, n, k) across iterations, so each
-                # step goes k→m→k through two matmuls
-                def body(i, acc):
-                    return jnp.tanh((acc @ y) @ y_back)
-
-                acc = jax.lax.fori_loop(0, iters, body, x)
-                return acc @ y  # final projection to (b, n, m)
-
-            fn = (run, dt)
+            fn = (make_matmul_program(iters), dt)
             self._matmul_cache[key] = fn
         run, dt = fn
         kx, ky, kb = jax.random.split(jax.random.PRNGKey(self._seed), 3)

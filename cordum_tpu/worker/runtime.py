@@ -1325,42 +1325,34 @@ def _host_cpu_load() -> float:
 
 
 def _device_telemetry() -> dict:
-    """Slice telemetry probes; degrades gracefully off-TPU and when JAX is
-    not yet initialized."""
-    try:
-        import jax
+    """Slice telemetry probes over whatever devices JAX exposes.  A worker
+    that cannot see its device fails to start: nothing here is caught, so
+    it never heartbeats as an empty slice."""
+    import jax
 
-        devs = jax.devices()
-        from ..parallel.mesh import hbm_stats, slice_topology
+    from ..parallel.mesh import hbm_stats, slice_topology
 
-        kind = devs[0].device_kind if devs else ""
-        return {
-            "is_tpu": devs[0].platform == "tpu" if devs else False,
-            "device_kind": kind,
-            "chip_count": len(devs),
-            "topology": slice_topology(devs),
-            "hbm": lambda: hbm_stats(devs),
-            "healthy": lambda: _devices_alive(devs),
-        }
-    except Exception:
-        return {
-            "is_tpu": False,
-            "device_kind": "",
-            "chip_count": 0,
-            "topology": "",
-            "hbm": lambda: (0.0, 0.0),
-            "healthy": lambda: True,
-        }
+    devs = jax.devices()
+    return {
+        "is_tpu": devs[0].platform == "tpu",
+        "device_kind": devs[0].device_kind,
+        "chip_count": len(devs),
+        "topology": slice_topology(devs),
+        "hbm": lambda: hbm_stats(devs),
+        "healthy": lambda: _devices_alive(devs),
+    }
 
 
 def _devices_alive(devs) -> bool:
-    """Liveness probe: a trivial computation must complete on each device."""
-    try:
-        import jax.numpy as jnp
-        import jax
+    """Liveness probe: a trivial computation must complete on a device.
+    Only the runtime's own failure (the device or its client is gone) reads
+    as unhealthy; anything else is a bug and propagates."""
+    import jax
+    import jax.numpy as jnp
 
+    try:
         for d in devs[:1]:  # probing one device per beat keeps it cheap
             jax.block_until_ready(jax.device_put(jnp.zeros((1,)), d) + 1)
-        return True
-    except Exception:
+    except jax.errors.JaxRuntimeError:
         return False
+    return True

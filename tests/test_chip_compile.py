@@ -1,0 +1,206 @@
+"""Compile the main path's jitted programs for a DESCRIBED TPU v5e, at the
+sizes ``chip_smoke.py`` runs on the chip (its ``REAL`` table), and hold each
+to 16 GB per device from ``memory_analysis()``.
+
+Nothing runs and no chip is attached: the TPU compiler is installed beside
+the CPU backend and compiles for a topology that is only described.  A
+compile that passes is not a chip run; what it catches is what the chip's
+compiler would refuse — a program that does not fit, a layout it cannot
+partition.  The topology is described inside a module-scoped fixture (never
+at import: one process at a time may load the TPU library, and every xdist
+worker imports every test file), and all of these live in this one file so
+that one worker loads the library once.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import chip_smoke
+from cordum_tpu.models import embedder, llama
+from cordum_tpu.serving.backend import make_ragged_program
+from cordum_tpu.worker.handlers import make_matmul_program
+
+SZ = chip_smoke.REAL
+HBM_BYTES = 16 * 1024**3  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip, so the cache is off here
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe means "cannot run here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def shaped(tree, sharding):
+    """Shapes of ``tree`` placed by ``sharding`` (one sharding, or a pytree
+    of them matching ``tree``)."""
+    if not isinstance(sharding, (dict, list, tuple)):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
+    return jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), tree, sharding)
+
+
+def device_bytes(compiled) -> int:
+    """What one device holds while the program runs: arguments, outputs not
+    aliased onto them, temporaries and the code."""
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes
+            + ma.generated_code_size_in_bytes)
+
+
+def smoke_cfg(**kw):
+    return dataclasses.replace(
+        llama.LlamaConfig.llama3_8b(), n_layers=SZ["n_layers"],
+        max_seq_len=SZ["max_seq_len"], **kw)
+
+
+def serving_shapes(cfg, num_pages, max_seq_len, params_sharding, arena_sharding, small):
+    """Argument shapes of the ragged program, as the backend builds them."""
+    params = shaped(
+        jax.eval_shape(lambda k: llama.init_params(k, cfg), jax.random.PRNGKey(0)),
+        params_sharding)
+    arena = jax.ShapeDtypeStruct(
+        (cfg.n_layers, num_pages, SZ["page_size"], cfg.n_kv_heads, cfg.head_dim),
+        cfg.dtype, sharding=arena_sharding)
+    s_rows = SZ["max_sessions"]
+    t_buf = s_rows + SZ["prefill_budget"]
+    pages_per_seq = -(-max_seq_len // SZ["page_size"])
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=small)
+
+    return params, arena, (i32(t_buf), i32(t_buf), i32(s_rows + 1, pages_per_seq),
+                           i32(t_buf), i32(s_rows))
+
+
+def test_widths_are_the_published_ones():
+    cfg = smoke_cfg()
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff,
+            cfg.vocab_size) == (4096, 32, 8, 128, 14336, 128256)
+    assert jnp.dtype(cfg.dtype) == jnp.bfloat16
+
+
+@pytest.mark.parametrize("sample_logits", [True, False])
+def test_ragged_step_fits_one_chip(one_chip, sample_logits):
+    cfg = smoke_cfg()
+    params, arena, meta = serving_shapes(
+        cfg, SZ["pages"], cfg.max_seq_len, one_chip, one_chip, one_chip)
+    program = make_ragged_program(cfg, sample_logits=sample_logits, donate=True)
+    compiled = program.lower(params, arena, arena, *meta).compile()
+    ma = compiled.memory_analysis()
+    arena_bytes = arena.size * arena.dtype.itemsize
+    # donation is real: both arenas alias onto the outputs
+    assert ma.alias_size_in_bytes >= 2 * arena_bytes
+    # plus ONE extra arena copy: the page scatter/copy programs do not
+    # donate, so an import or a copy-on-write holds a second arena briefly
+    assert device_bytes(compiled) + arena_bytes <= 0.95 * HBM_BYTES
+
+
+@pytest.mark.parametrize("name", ["_gather_page", "_scatter_page", "_copy_page"])
+def test_page_programs_compile_on_the_real_arena(one_chip, name):
+    cfg = smoke_cfg()
+    shape = (cfg.n_layers, SZ["pages"], SZ["page_size"], cfg.n_kv_heads, cfg.head_dim)
+    arena = jax.ShapeDtypeStruct(shape, cfg.dtype, sharding=one_chip)
+    pid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    block = jax.ShapeDtypeStruct(shape[:1] + shape[2:], cfg.dtype, sharding=one_chip)
+    args = {"_gather_page": (arena, pid), "_scatter_page": (arena, pid, block),
+            "_copy_page": (arena, pid, pid)}[name]
+    compiled = getattr(llama, name).lower(*args).compile()
+    arena_bytes = arena.size * arena.dtype.itemsize
+    ma = compiled.memory_analysis()
+    if name != "_gather_page":
+        # not donated: the output is a whole second arena
+        assert ma.alias_size_in_bytes == 0
+        assert ma.output_size_in_bytes >= arena_bytes
+    assert device_bytes(compiled) <= HBM_BYTES
+
+
+def test_reference_forward_fits_beside_the_serving_state(one_chip):
+    cfg = smoke_cfg()
+    params, arena, _ = serving_shapes(
+        cfg, SZ["pages"], cfg.max_seq_len, one_chip, one_chip, one_chip)
+    tokens = jax.ShapeDtypeStruct((1, SZ["ref_len"]), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda p, t: llama.forward(p, t, cfg)).lower(params, tokens).compile()
+    # the reference runs while both arenas are still resident
+    arena_bytes = arena.size * arena.dtype.itemsize
+    assert device_bytes(compiled) + 2 * arena_bytes <= 0.95 * HBM_BYTES
+
+
+def test_embedder_largest_micro_batch_compiles(one_chip):
+    cfg = embedder.EmbedderConfig()
+    params = shaped(
+        jax.eval_shape(lambda k: embedder.init_params(k, cfg), jax.random.PRNGKey(0)),
+        one_chip)
+    rows = 32  # the micro-batcher's default row limit, its widest batch bucket
+    ids = jax.ShapeDtypeStruct((rows, cfg.max_len), jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((rows, cfg.max_len), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, i, m: embedder.forward(p, i, m, cfg)).lower(params, ids, mask).compile()
+    assert device_bytes(compiled) <= HBM_BYTES
+
+
+def test_matmul_op_compiles(one_chip):
+    n = SZ["matmul_n"]
+    x = jax.ShapeDtypeStruct((2, n, n), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((n, n), jnp.bfloat16, sharding=one_chip)
+    compiled = make_matmul_program(1).lower(x, w, w).compile()
+    assert device_bytes(compiled) <= HBM_BYTES
+
+
+def test_sharded_ragged_step_full_depth_on_four_chips(topo):
+    """The --chips 4 program: all 32 layers, tensor-parallel over a 2x2 v5e
+    host.  No single chip holds the model; a quarter each must fit."""
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.llama3_8b(), max_seq_len=SZ["tp_max_seq_len"])
+    assert cfg.n_layers == 32
+    import numpy as np
+
+    from cordum_tpu.parallel.mesh import AXIS_DP, AXIS_TP
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), (AXIS_DP, AXIS_TP))
+    pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), llama.param_specs(cfg))
+    params, arena, meta = serving_shapes(
+        cfg, SZ["tp_pages"], cfg.max_seq_len, pshard,
+        NamedSharding(mesh, llama.KV_ARENA_SPEC), NamedSharding(mesh, P()))
+    program = make_ragged_program(cfg, sample_logits=True, donate=True)
+    compiled = program.lower(params, arena, arena, *meta).compile()
+    weight_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weight_bytes > HBM_BYTES * 0.9  # one chip could not hold it
+    per_device = device_bytes(compiled)
+    assert per_device <= 0.95 * HBM_BYTES
+    assert per_device < 0.5 * weight_bytes  # no device holds the whole model
+    text = compiled.as_text()
+    # row-parallel wo and w_down end in a sum over tp, in every layer
+    assert text.count(" all-reduce(") + text.count(" all-reduce-start(") >= 2 * cfg.n_layers
+
+    # weights are CREATED sharded: the init program's output on each device
+    # is a quarter of the model, never the whole
+    init = jax.jit(lambda k: llama.init_params(k, cfg), out_shardings=pshard)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=NamedSharding(mesh, P()))
+    init_ma = init.lower(key).compile().memory_analysis()
+    assert init_ma.output_size_in_bytes < 0.3 * weight_bytes
+    assert init_ma.output_size_in_bytes + init_ma.temp_size_in_bytes <= 0.95 * HBM_BYTES

@@ -266,39 +266,6 @@ def test_cl005_allows_subjects_module(tmp_path):
     ) == []
 
 
-# ---------------------------------------------------------------- CL006
-
-CL006_BAD = """\
-from jax.experimental.shard_map import shard_map
-
-def build(f, mesh, spec):
-    return shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
-"""
-
-CL006_GOOD = """\
-from cordum_tpu.parallel.compat import shard_map_compat
-
-def build(f, mesh, spec):
-    return shard_map_compat(f, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False)
-"""
-
-
-def test_cl006_fires_on_gated_kwarg(tmp_path):
-    findings = run_lint(tmp_path, "a.py", CL006_BAD, select={"CL006"})
-    assert rule_ids(findings) == ["CL006"]
-    assert "check_vma" in findings[0].message
-
-
-def test_cl006_quiet_via_compat_shim(tmp_path):
-    assert run_lint(tmp_path, "a.py", CL006_GOOD, select={"CL006"}) == []
-
-
-def test_cl006_allows_compat_module(tmp_path):
-    assert run_lint(
-        tmp_path, "cordum_tpu/parallel/compat.py", CL006_BAD, select={"CL006"}
-    ) == []
-
-
 # ---------------------------------------------------------------- CL007
 
 CL007_BAD = """\
@@ -504,7 +471,7 @@ def test_cli_select_and_list_rules(tmp_path, capsys):
 
     assert cli_main(["--list-rules", "--root", str(tmp_path)]) == 0
     listing = capsys.readouterr().out
-    for rid in ("CL001", "CL002", "CL003", "CL004", "CL005", "CL006"):
+    for rid in ("CL001", "CL002", "CL003", "CL004", "CL005", "CL007"):
         assert rid in listing
 
 

@@ -72,8 +72,8 @@ async def test_worker_echo_roundtrip():
 
 async def test_blocking_sync_handler_keeps_heartbeats_flowing():
     """A plain-def handler doing blocking work is dispatched to the executor
-    by the runtime, so heartbeats keep flowing while it runs (VERDICT weak #5:
-    previously a blocking handler silently stopped heartbeats)."""
+    by the runtime, so heartbeats keep flowing while it runs (a blocking
+    handler on the event loop would silently stop them)."""
     import time as _time
 
     kv, bus, js, ms, eng = make_stack()

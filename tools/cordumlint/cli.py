@@ -30,7 +30,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help=f"config JSON (default: {DEFAULT_CONFIG} at root if present)")
     p.add_argument("--format", choices=("text", "json"), default="text")
     p.add_argument("--select", default="",
-                   help="comma-separated rule ids to run (e.g. CL001,CL006)")
+                   help="comma-separated rule ids to run (e.g. CL001,CL007)")
     p.add_argument("--ignore", default="", help="comma-separated rule ids to skip")
     p.add_argument("--baseline", default=None,
                    help=f"baseline JSON path (default: {DEFAULT_BASELINE} at root)")

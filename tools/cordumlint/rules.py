@@ -340,54 +340,6 @@ class SubjectLiterals(Rule):
 
 
 # ---------------------------------------------------------------------------
-# CL006
-# ---------------------------------------------------------------------------
-
-_GATED_KWARGS = {"check_vma", "check_rep"}
-_JAX_WRAPPERS = {"shard_map", "_shard_map", "jit", "pjit"}
-
-
-class JaxCompatKwargs(Rule):
-    """CL006: version-gated jax kwargs (``check_vma``/``check_rep``) passed
-    straight to ``shard_map``/``jit``.  These kwargs get renamed between jax
-    minors; a direct pass breaks whole test tiers on version skew (the exact
-    bug that took down 9 seed tests on jax 0.4.37).  Route through
-    ``cordum_tpu.parallel.compat.shard_map_compat`` which translates or
-    drops them per installed version."""
-
-    id = "CL006"
-    name = "jax-compat-kwargs"
-    description = (
-        "version-gated kwargs (check_vma/check_rep) must go through "
-        "parallel/compat.py, not straight into shard_map/jit"
-    )
-    default_allow_paths = ("cordum_tpu/parallel/compat.py",)
-
-    def _callee_name(self, fn: ast.expr) -> str:
-        if isinstance(fn, ast.Name):
-            return fn.id
-        if isinstance(fn, ast.Attribute):
-            return fn.attr
-        return ""
-
-    def check(self, ctx: LintContext) -> Iterator[Finding]:
-        for node in ast.walk(ctx.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            if self._callee_name(node.func) not in _JAX_WRAPPERS:
-                continue
-            for kw in node.keywords:
-                if kw.arg in _GATED_KWARGS:
-                    yield self.finding(
-                        ctx, kw.value,
-                        f"version-gated kwarg '{kw.arg}' passed directly to "
-                        f"{self._callee_name(node.func)}; use "
-                        "parallel.compat.shard_map_compat so one module owns "
-                        "the version skew",
-                    )
-
-
-# ---------------------------------------------------------------------------
 # CL007
 # ---------------------------------------------------------------------------
 
@@ -450,6 +402,5 @@ RULES: tuple[type[Rule], ...] = (
     NoBlockingInAsync,
     StateTransitionDiscipline,
     SubjectLiterals,
-    JaxCompatKwargs,
     NoJsonOnHotPath,
 ) + PROGRAM_RULES

@@ -80,36 +80,51 @@ def log(msg: str) -> None:
     print(f"[smoke] {msg}", flush=True)
 
 
-def spawn_stack(logdir: str) -> list[subprocess.Popen]:
+def spawn_stack(
+    logdir: str,
+    *,
+    statebus_port: int = STATEBUS_PORT,
+    kernel_port: int = KERNEL_PORT,
+    gateway_port: int = GATEWAY_PORT,
+    force_cpu: bool = True,
+    pool_stanza: str = "",
+    worker_env: dict | None = None,
+) -> list[subprocess.Popen]:
+    """Start the service binaries as child processes; the worker is the
+    LAST entry of the returned list.  ``statebus_port`` needs the next port
+    free too (two keyspace partitions).  With ``force_cpu=False`` the one
+    worker takes whatever platform JAX finds (``chip_smoke.py`` runs it on
+    the chip); ``pool_stanza`` is extra YAML for the ``tpu`` pool and
+    ``worker_env`` overrides the worker's environment."""
     base_env = dict(os.environ)
     base_env.update({
         # sharded control plane: 2 statebus keyspace partitions (one process,
         # consecutive ports) × 2 scheduler shards — the ISSUE 5 smoke topology
         "CORDUM_STATEBUS_URL": (
-            f"statebus://127.0.0.1:{STATEBUS_PORT},"
-            f"statebus://127.0.0.1:{STATEBUS_PORT + 1}"
+            f"statebus://127.0.0.1:{statebus_port},"
+            f"statebus://127.0.0.1:{statebus_port + 1}"
         ),
         "CORDUM_SCHEDULER_SHARDS": "2",
         "PYTHONPATH": REPO + os.pathsep + base_env.get("PYTHONPATH", ""),
-        "CORDUM_FORCE_CPU": "1",
-        "JAX_PLATFORMS": "cpu",
         # hermetic placement: don't let the harness's own CPU burn flip
         # workers to overloaded (the smoke asserts exact worker identities)
         "CORDUM_HOST_LOAD": "0",
     })
+    if force_cpu:
+        base_env.update({"CORDUM_FORCE_CPU": "1", "JAX_PLATFORMS": "cpu"})
     sched_env = {
-        "SAFETY_KERNEL_ADDR": f"http://127.0.0.1:{KERNEL_PORT}",
+        "SAFETY_KERNEL_ADDR": f"http://127.0.0.1:{kernel_port}",
         "POOL_CONFIG_PATH": os.path.join(logdir, "pools.yaml"),
         "TIMEOUT_CONFIG_PATH": os.path.join(logdir, "timeouts.yaml"),
         "SCHEDULER_SHARD_COUNT": "2",
     }
     services = [
         ("statebus", "cordum_tpu.cmd.statebus",
-         {"STATEBUS_PORT": str(STATEBUS_PORT),
+         {"STATEBUS_PORT": str(statebus_port),
           "STATEBUS_PARTITIONS": "2",
           "STATEBUS_AOF": os.path.join(logdir, "state.aof")}),
         ("kernel", "cordum_tpu.cmd.safety_kernel",
-         {"SAFETY_KERNEL_PORT": str(KERNEL_PORT),
+         {"SAFETY_KERNEL_PORT": str(kernel_port),
           "SAFETY_POLICY_PATH": os.path.join(logdir, "safety.yaml")}),
         ("scheduler-0", "cordum_tpu.cmd.scheduler",
          {**sched_env, "SCHEDULER_SHARD_INDEX": "0"}),
@@ -117,7 +132,7 @@ def spawn_stack(logdir: str) -> list[subprocess.Popen]:
          {**sched_env, "SCHEDULER_SHARD_INDEX": "1"}),
         ("wfengine", "cordum_tpu.cmd.workflow_engine", {}),
         ("gateway", "cordum_tpu.cmd.gateway",
-         {"GATEWAY_HTTP_ADDR": f"127.0.0.1:{GATEWAY_PORT}",
+         {"GATEWAY_HTTP_ADDR": f"127.0.0.1:{gateway_port}",
           "CORDUM_API_KEYS": "smoke-key",
           "CORDUM_ADMIN_KEYS": "smoke-admin",
           # the gateway reads the slo: stanza for the fleet SLO tracker
@@ -132,13 +147,14 @@ def spawn_stack(logdir: str) -> list[subprocess.Popen]:
           # the dispatch pipeline's per-job latency, and step 7 asserts a
           # flushed batch of >= 8 (docs/BATCHING.md tuning knobs)
           "WORKER_MAX_BATCH_SIZE": "32",
-          "WORKER_BATCH_WAIT_MS": "900"}),
+          "WORKER_BATCH_WAIT_MS": "900",
+          **(worker_env or {})}),
     ]
     # config files used by scheduler + kernel
     with open(os.path.join(logdir, "pools.yaml"), "w") as f:
         f.write(
             "topics:\n  job.default: tpu\n  job.hello-pack.echo: tpu\n  job.tpu.>: tpu\n"
-            "pools:\n  tpu:\n    requires: []\n"
+            "pools:\n  tpu:\n    requires: []\n" + pool_stanza +
             # SLO objective for the fleet telemetry step: every smoke job
             # submits at the default BATCH class
             "slo:\n  batch:\n    job_class: BATCH\n    latency_ms: 5000\n"
