@@ -431,6 +431,24 @@ class Metrics:
             "cordum_serving_inter_token_seconds",
             "Wall time per decode step (inter-token latency)",
         )
+        self.serving_step_phase = Histogram(
+            "cordum_serving_step_phase_seconds",
+            "Wall time of each phase of a serving step cycle, every cycle "
+            "(phase = assemble | pack | dispatch | wait | unpack | emit; the "
+            "six are contiguous and sum to the cycle)",
+            buckets=(0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
+                     0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0),
+        )
+        self.serving_queue = Histogram(
+            "cordum_serving_queue_seconds",
+            "Engine submit to admission into the step loop (the wait for a "
+            "session slot and KV pages), per locally born session",
+        )
+        self.serving_prefill = Histogram(
+            "cordum_serving_prefill_seconds",
+            "Admission to the return of the step that sampled the first "
+            "token, per locally born session; queue + prefill = engine TTFT",
+        )
         self.serving_admitted = Counter(
             "cordum_serving_sessions_admitted_total",
             "Sessions admitted into the decode loop",
@@ -742,6 +760,9 @@ class Metrics:
             self.sched_tick_fallbacks,
             self.serving_batch_occupancy,
             self.serving_inter_token,
+            self.serving_step_phase,
+            self.serving_queue,
+            self.serving_prefill,
             self.serving_admitted,
             self.serving_retired,
             self.serving_sessions,

@@ -434,7 +434,10 @@ def ragged_step(
     pt_tok = page_tables[token_seq]  # [T, P] — each token's own table row
     page_idx = jnp.take_along_axis(pt_tok, pos2 // ps, axis=1)[:, 0]  # [T]
     slot = positions % ps
-    x = params["embed"][tokens][:, None, :]  # [T, 1, d]
+    # the named scopes are metadata only: they label the operations in a
+    # device trace (per-kernel time by scope) and change none of them
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens][:, None, :]  # [T, 1, d]
     for li, layer in enumerate(params["layers"]):
         attn_in = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = (attn_in @ layer["wq"]).reshape(t_buf, 1, h, hd)
@@ -446,16 +449,20 @@ def ragged_step(
         # tokens must attend to its earlier ones within the same call (the
         # causal mask cuts the other direction), and a decode token must
         # attend to itself
-        k_pages = k_pages.at[li, page_idx, slot].set(k[:, 0])
-        v_pages = v_pages.at[li, page_idx, slot].set(v[:, 0])
-        kc = k_pages[li][pt_tok].reshape(t_buf, -1, kvh, hd)  # [T, P*ps, ...]
-        vc = v_pages[li][pt_tok].reshape(t_buf, -1, kvh, hd)
-        attn = _attention(q, kc, vc, cfg, q_offset=pos2)
+        with jax.named_scope("kv_write"):
+            k_pages = k_pages.at[li, page_idx, slot].set(k[:, 0])
+            v_pages = v_pages.at[li, page_idx, slot].set(v[:, 0])
+        with jax.named_scope("attn_gather"):
+            kc = k_pages[li][pt_tok].reshape(t_buf, -1, kvh, hd)  # [T, P*ps, ...]
+            vc = v_pages[li][pt_tok].reshape(t_buf, -1, kvh, hd)
+        with jax.named_scope("attn_scores"):  # the K/V repeat, both products
+            attn = _attention(q, kc, vc, cfg, q_offset=pos2)
         x = x + (attn.reshape(t_buf, 1, h * hd) @ layer["wo"])
-        mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(mlp_in @ layer["w_gate"])
-        up = mlp_in @ layer["w_up"]
-        x = x + ((gate * up) @ layer["w_down"])
+        with jax.named_scope("mlp"):
+            mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+            gate = jax.nn.silu(mlp_in @ layer["w_gate"])
+            up = mlp_in @ layer["w_up"]
+            x = x + ((gate * up) @ layer["w_down"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     # lm_head over EVERY buffer row: speculative verification needs the
     # next-token prediction at each fed draft position, not just the
@@ -469,8 +476,10 @@ def ragged_step(
         # follower ranks: K/V writes above are the whole job — skip the
         # [T, V] projection entirely (static flag → XLA never emits it)
         return jnp.zeros((t_buf,), jnp.int32), k_pages, v_pages
-    logits = x[:, 0] @ params["lm_head"]  # [T, V]
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), k_pages, v_pages
+    with jax.named_scope("lm_head"):
+        logits = x[:, 0] @ params["lm_head"]  # [T, V]
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return nxt, k_pages, v_pages
 
 
 def loss_fn(params: Params, tokens: jax.Array, cfg: LlamaConfig, *, mesh=None) -> jax.Array:

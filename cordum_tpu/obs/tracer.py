@@ -101,6 +101,42 @@ class Tracer:
             attrs=dict(attrs or {}),
         )
 
+    def listening(self) -> bool:
+        """Would :meth:`emit` publish anything?  False with no bus or with
+        no listener on ``sys.trace.span`` (1×1 bench, span-less
+        deployments), so hot paths that stamp their own boundaries can skip
+        building ``Span`` objects nobody will see; wire-backed buses always
+        answer True."""
+        return self.bus is not None and self.bus.has_listener(subj.TRACE_SPAN)
+
+    def record(
+        self,
+        name: str,
+        *,
+        trace_id: str,
+        start_us: int,
+        end_us: int,
+        span_id: str = "",
+        parent_span_id: str = "",
+        status: str = SPAN_OK,
+        attrs: Optional[dict[str, str]] = None,
+    ) -> Span:
+        """A FINISHED span from boundaries the caller stamped itself (the
+        serving loop stamps every cycle and builds spans only for the ones
+        it keeps).  Touches no ambient context; hand the result to
+        :meth:`emit`."""
+        return Span(
+            span_id=span_id or fast_id(),
+            parent_span_id=parent_span_id,
+            trace_id=trace_id,
+            name=name,
+            service=self.service,
+            start_us=start_us,
+            end_us=end_us,
+            status=status,
+            attrs=attrs or {},
+        )
+
     async def finish(self, span: Span, *, status: str = SPAN_OK) -> None:
         if not span.end_us:
             span.end_us = now_us()

@@ -29,13 +29,41 @@ the lock is a safety net for the compat wrappers (:meth:`prefill` /
 """
 from __future__ import annotations
 
+import contextlib
+import sys
 import threading
+import time
 from dataclasses import dataclass
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Iterator, Optional
 
 import numpy as np
 
 DEFAULT_MAX_SEQS = 16
+
+# the step cycle's six contiguous phases, in order (docs/OBSERVABILITY.md
+# §Serving spans and metrics): the engine stamps ``assemble`` and ``emit``
+# on the event loop, the backend the four between them on its executor
+# thread
+STEP_PHASES = ("assemble", "pack", "dispatch", "wait", "unpack", "emit")
+
+
+@contextlib.contextmanager
+def step_phase(name: str, step: int, marks: list[int]) -> Iterator[None]:
+    """One phase of step cycle ``step``: appends the phase's END boundary to
+    ``marks`` (``time.time_ns()``, the clock of ``now_us()`` and of the
+    profiler's host plane) and meanwhile holds the host annotation
+    ``cordum.step.<name>`` open, so the span the engine builds from the
+    stamps and the event in a device trace carry one name.  The annotation
+    is inert with no profiler session, and a process that never imported
+    jax has no profiler to annotate for (this module stays jax-free for
+    the fakes)."""
+    jax = sys.modules.get("jax")
+    with (
+        jax.profiler.TraceAnnotation(f"cordum.step.{name}", step=step)
+        if jax is not None else contextlib.nullcontext()
+    ):
+        yield
+    marks.append(time.time_ns())
 
 
 @dataclass
@@ -138,6 +166,11 @@ class LlamaServingBackend:
         self._compiled_shapes: set = set()  # observability: program count
         self._metrics = metrics
         self.last_step_compiled = False  # did the latest step() pay XLA?
+        # the latest step()'s boundaries, ns: (entry, arrays packed, program
+        # dispatched, result on the host, return) — the engine reads them
+        # after the call to split its step cycle into phases
+        self.last_phases: tuple[int, ...] = ()
+        self._steps_done = 0  # numbers the host annotations
         # page-arena mutation lock: steps read-modify-write the K/V arrays
         # from executor threads
         self._dev_lock = threading.Lock()
@@ -195,6 +228,8 @@ class LlamaServingBackend:
         thread."""
         import jax.numpy as jnp
 
+        marks = [time.time_ns()]
+        n_step = self._steps_done
         self._ensure()
         if not entries:
             return []
@@ -210,60 +245,69 @@ class LlamaServingBackend:
                 f"{total} tokens in one step; backend max_batch_tokens is "
                 f"{t_buf}"
             )
-        tokens = np.zeros((t_buf,), np.int32)
-        positions = np.zeros((t_buf,), np.int32)
-        # padding tokens map to the padding row (all null pages): their
-        # writes land on page 0 and no live sequence's gather can see them
-        token_seq = np.full((t_buf,), s_rows, np.int32)
-        tables = np.zeros((s_rows + 1, self.pages_per_seq), np.int32)
-        out_idx = np.zeros((s_rows,), np.int32)
-        ti = 0
-        spans: list[tuple[int, int]] = []  # entry i's [lo, hi) buffer slots
-        for i, e in enumerate(entries):
-            row = self._clamp(e.tokens)
-            n = len(row)
-            if not n:
-                raise ValueError("empty StepEntry.tokens")
-            if e.start + n > self.max_context:
-                raise ValueError(
-                    f"entry spans positions [{e.start}, {e.start + n}); "
-                    f"backend max_context is {self.max_context}"
+        with step_phase("pack", n_step, marks):
+            tokens = np.zeros((t_buf,), np.int32)
+            positions = np.zeros((t_buf,), np.int32)
+            # padding tokens map to the padding row (all null pages): their
+            # writes land on page 0 and no live sequence's gather can see them
+            token_seq = np.full((t_buf,), s_rows, np.int32)
+            tables = np.zeros((s_rows + 1, self.pages_per_seq), np.int32)
+            out_idx = np.zeros((s_rows,), np.int32)
+            ti = 0
+            spans: list[tuple[int, int]] = []  # entry i's [lo, hi) buffer slots
+            for i, e in enumerate(entries):
+                row = self._clamp(e.tokens)
+                n = len(row)
+                if not n:
+                    raise ValueError("empty StepEntry.tokens")
+                if e.start + n > self.max_context:
+                    raise ValueError(
+                        f"entry spans positions [{e.start}, {e.start + n}); "
+                        f"backend max_context is {self.max_context}"
+                    )
+                tokens[ti:ti + n] = row
+                positions[ti:ti + n] = np.arange(e.start, e.start + n)
+                token_seq[ti:ti + n] = i
+                tables[i, : len(e.pages)] = e.pages
+                out_idx[i] = ti + n - 1
+                spans.append((ti, ti + n))
+                ti += n
+            shape_key = ("ragged", t_buf, s_rows, self.pages_per_seq)
+            self.last_step_compiled = shape_key not in self._compiled_shapes
+            if self.last_step_compiled:
+                self._compiled_shapes.add(shape_key)
+                if self._metrics is not None:
+                    self._metrics.serving_compiles.inc(entry="ragged")
+        with contextlib.ExitStack() as held:
+            # dispatch opens before the lock is taken, so a wait for it
+            # shows there; the lock is held until the result is on the host
+            with step_phase("dispatch", n_step, marks):
+                held.enter_context(self._dev_lock)
+                nxt, self._k_pages, self._v_pages = self._ragged_jit(
+                    self._params, self._k_pages, self._v_pages,
+                    jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(tables), jnp.asarray(token_seq),
+                    jnp.asarray(out_idx),
                 )
-            tokens[ti:ti + n] = row
-            positions[ti:ti + n] = np.arange(e.start, e.start + n)
-            token_seq[ti:ti + n] = i
-            tables[i, : len(e.pages)] = e.pages
-            out_idx[i] = ti + n - 1
-            spans.append((ti, ti + n))
-            ti += n
-        shape_key = ("ragged", t_buf, s_rows, self.pages_per_seq)
-        self.last_step_compiled = shape_key not in self._compiled_shapes
-        if self.last_step_compiled:
-            self._compiled_shapes.add(shape_key)
-            if self._metrics is not None:
-                self._metrics.serving_compiles.inc(entry="ragged")
-        with self._dev_lock:
-            nxt, self._k_pages, self._v_pages = self._ragged_jit(
-                self._params, self._k_pages, self._v_pages,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(tables), jnp.asarray(token_seq),
-                jnp.asarray(out_idx),
-            )
-            out = np.asarray(nxt)
+            with step_phase("wait", n_step, marks):
+                out = np.asarray(nxt)
         # out is [T] per-position predictions: a sampled entry's token is
         # the prediction after its LAST fed slot (== out_idx[i], the same
         # value the old sequence-final projection produced); a draft row
         # gets the whole span — one verification vote per fed position
-        res: list[Any] = []
-        for e, (lo, hi) in zip(entries, spans):
-            if e.draft > 0:
-                res.append([int(t) for t in out[lo:hi]])
-            elif e.sample:
-                res.append(int(out[hi - 1]))
-            else:
-                res.append(None)
-        if self.on_step is not None:
-            self.on_step(entries)
+        with step_phase("unpack", n_step, marks):
+            res: list[Any] = []
+            for e, (lo, hi) in zip(entries, spans):
+                if e.draft > 0:
+                    res.append([int(t) for t in out[lo:hi]])
+                elif e.sample:
+                    res.append(int(out[hi - 1]))
+                else:
+                    res.append(None)
+            if self.on_step is not None:
+                self.on_step(entries)
+        self._steps_done = n_step + 1
+        self.last_phases = tuple(marks)
         return res
 
     # ------------------------------------------------------------------

@@ -29,6 +29,7 @@ consumers.
 from __future__ import annotations
 
 import asyncio
+import statistics
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -37,7 +38,10 @@ from typing import Any, Awaitable, Callable, Optional
 from ..infra import logging as logx
 from ..infra.metrics import Metrics
 from ..obs.tracer import Tracer
-from .backend import StepEntry
+from ..protocol.types import OP_SERVING_PREFILL, SPAN_ERROR, Span
+from ..utils.eager import eager
+from ..utils.ids import fast_id
+from .backend import STEP_PHASES, StepEntry, step_phase
 from .pager import CacheExhausted, PageAllocator
 from .prefixcache import PrefixCache, PrefixNode
 from .tiering import SessionTiering
@@ -60,6 +64,14 @@ INTERACTIVE_CLASSES = frozenset({"INTERACTIVE", "CRITICAL"})
 DEFAULT_DRAFT_K = 4
 SPEC_EWMA_ALPHA = 0.4
 SPEC_FLEET_ALPHA = 0.2
+# step-cycle traces (docs/OBSERVABILITY.md §Serving spans and metrics): every
+# cycle is stamped, and one becomes a ``step`` trace when this long has
+# passed since the last kept cycle began, or when it took more than
+# STEP_STALL_FACTOR times the median of the STEP_MEDIAN_WINDOW cycles before
+# it — a stalled cycle is always kept, with the phase it stalled in
+STEP_SAMPLE_PERIOD_NS = 250_000_000
+STEP_STALL_FACTOR = 3.0
+STEP_MEDIAN_WINDOW = 64
 
 
 class SessionCancelled(Exception):
@@ -149,6 +161,21 @@ class ServingStats:
 
 
 @dataclass
+class _TtftClock:
+    """A locally born session's way to its first token: ``serving.queue``
+    (submit → admitted) then ``serving.prefill`` (admitted → the step that
+    sampled the first token has returned), contiguous on ONE clock — the
+    monotonic one, anchored on the wall at submit — so the two always sum
+    to the session's ``ttft_seconds`` entry."""
+
+    pending_ahead: int  # sessions queued before it at submit
+    waits_at_submit: int  # stats.admission_waits when it was queued
+    admitted_at: float = 0.0  # monotonic
+    chunks: int = 0  # prefill chunks fed up to the first token
+    steps: int = 0  # steps ridden up to the first token
+
+
+@dataclass
 class _Session:
     job_id: str
     req: GenRequest
@@ -177,6 +204,10 @@ class _Session:
     accept_ewma: float = 1.0
     draft_plan: list[int] = field(default_factory=list)
     enqueued_at: float = field(default_factory=time.monotonic)
+    enqueued_ns: int = field(default_factory=time.time_ns)  # same instant, wall
+    # open until the first token; None for migrated-in, resumed and restored
+    # sessions (their first token belongs to a previous worker's clock)
+    ttft: Optional[_TtftClock] = None
 
     @property
     def prefill_seq(self) -> list[int]:
@@ -312,6 +343,14 @@ class ServingEngine:
         # freeze is complete only once the in-flight step (which may still
         # produce one token for the session) has scattered its results
         self._in_step: frozenset[str] = frozenset()
+        # names this worker's step traces (``step-<worker_id>-<n>``); the
+        # owning worker sets it when it attaches the engine
+        self.worker_id = ""
+        # finished spans wait here until a step is on the device (or the
+        # loop parks): no publish sits between one step and the next
+        self._spans: list[Span] = []
+        self._cycle_ns: deque[int] = deque(maxlen=STEP_MEDIAN_WINDOW)
+        self._kept_cycle_ns = 0  # start of the last cycle kept as a trace
 
     # ------------------------------------------------------------------
     def parts(self, payload: Any) -> Optional[GenRequest]:
@@ -394,6 +433,11 @@ class ServingEngine:
             # from the prefix's last token
             sess.out_tokens = list(gen.resume_tokens)
             sess.last_token = gen.resume_tokens[-1]
+        else:
+            sess.ttft = _TtftClock(
+                pending_ahead=len(self._pending),
+                waits_at_submit=self.stats.admission_waits,
+            )
         self._pending.append(sess)
         self._ensure_loop()
         self._wake.set()
@@ -576,6 +620,7 @@ class ServingEngine:
             self.stats.admitted += 1
             if self.metrics is not None:
                 self.metrics.serving_admitted.inc()
+            self._queue_closed(sess, hit_tokens)
             if sess.out_tokens and sess.on_tokens is not None:
                 # failover resume: replay the already-streamed prefix at
                 # offset 0 — consumers dedupe by offset, so a client that
@@ -659,6 +704,117 @@ class ServingEngine:
         if t0 is not None and self.metrics is not None:
             self.metrics.serving_hibernate_pause.observe(time.monotonic() - t0)
         return out
+
+    # ------------------------------------------------------------------
+    # the flight recorder's serving side (docs/OBSERVABILITY.md §Serving
+    # spans and metrics): boundaries are stamped always, histograms observed
+    # always, Span objects built only while someone listens, and published
+    # only by _flush_spans
+    # ------------------------------------------------------------------
+    def _ttft_span(
+        self, name: str, sess: _Session, start: float, end: float,
+        attrs: dict[str, str],
+    ) -> None:
+        """Buffer one of the two per-request spans on the request's own
+        trace, under the worker's ``execute`` span; ``start``/``end`` are
+        monotonic readings, placed on the wall by the submit anchor."""
+        tr = self.tracer
+        if tr is None or not sess.trace_id or not tr.listening():
+            return
+
+        def wall_us(t: float) -> int:
+            return (sess.enqueued_ns + int((t - sess.enqueued_at) * 1e9)) // 1000
+
+        self._spans.append(tr.record(
+            name, trace_id=sess.trace_id, parent_span_id=sess.parent_span_id,
+            start_us=wall_us(start), end_us=wall_us(end), attrs=attrs,
+        ))
+
+    def _queue_closed(self, sess: _Session, hit_tokens: int) -> None:
+        """``_admit`` moved a locally born session into the step loop."""
+        clk = sess.ttft
+        if clk is None:
+            return
+        clk.admitted_at = now = time.monotonic()
+        if self.metrics is not None:
+            self.metrics.serving_queue.observe(now - sess.enqueued_at)
+        self._ttft_span("serving.queue", sess, sess.enqueued_at, now, {
+            "pending_ahead": str(clk.pending_ahead),
+            "admission_waits": str(
+                self.stats.admission_waits - clk.waits_at_submit),
+            "prefix_hit_tokens": str(hit_tokens),
+        })
+
+    def _first_token(self, sess: _Session) -> None:
+        """The step that sampled the session's first token has returned:
+        TTFT, on the clock the two spans share."""
+        now = time.monotonic()
+        self.stats.ttft_seconds.append(now - sess.enqueued_at)
+        clk, sess.ttft = sess.ttft, None
+        if clk is None:
+            return
+        if self.metrics is not None:
+            self.metrics.serving_prefill.observe(now - clk.admitted_at)
+        self._ttft_span("serving.prefill", sess, clk.admitted_at, now, {
+            "prompt_tokens": str(len(sess.req.prompt)),
+            "chunks": str(clk.chunks),
+            "steps": str(clk.steps),
+        })
+
+    def _cycle_closed(
+        self, n_step: int, marks: list[int], attrs: dict[str, str]
+    ) -> None:
+        """One step cycle ended.  ``marks`` are the loop's own stamps
+        ``[cycle start, step handed over, step returned, cycle end]``; the
+        backend's five lie between the middle two.  Six contiguous phases:
+        the histogram sees every cycle, the flight recorder the kept ones."""
+        c0, handed, returned, c1 = marks
+        ph = tuple(getattr(self.backend, "last_phases", ()))
+        bounds = (c0, *ph, c1)
+        if len(ph) != 5 or any(a > b for a, b in zip(bounds, bounds[1:])):
+            # a backend that stamps nothing (the fakes, the gang group):
+            # its whole call reads as ``wait``
+            bounds = (c0, handed, handed, handed, returned, returned, c1)
+        if self.metrics is not None:
+            for name, a, b in zip(STEP_PHASES, bounds, bounds[1:]):
+                self.metrics.serving_step_phase.observe((b - a) / 1e9, phase=name)
+        dur = c1 - c0
+        recent = self._cycle_ns
+        keep = c0 - self._kept_cycle_ns >= STEP_SAMPLE_PERIOD_NS or (
+            len(recent) == recent.maxlen
+            and dur > STEP_STALL_FACTOR * statistics.median(recent)
+        )
+        recent.append(dur)
+        if not keep:
+            return
+        self._kept_cycle_ns = c0
+        tr = self.tracer
+        if tr is None or not tr.listening():
+            return
+        trace_id = f"step-{self.worker_id}-{n_step}"
+        root_id = fast_id()
+        us = [b // 1000 for b in bounds]
+        # children first: the collector decides a trace's retention when
+        # its root lands
+        for name, a, b in zip(STEP_PHASES, us, us[1:]):
+            self._spans.append(tr.record(
+                f"step.{name}", trace_id=trace_id, parent_span_id=root_id,
+                start_us=a, end_us=b,
+            ))
+        self._spans.append(tr.record(
+            "step", trace_id=trace_id, span_id=root_id,
+            start_us=us[0], end_us=us[-1], attrs=attrs,
+        ))
+
+    async def _flush_spans(self) -> None:
+        """Publish what was recorded since the last flush.  Called only
+        with a step on the device, before the loop parks, and from
+        ``stop()``."""
+        if not self._spans or self.tracer is None:
+            return
+        spans, self._spans = self._spans, []
+        for sp in spans:
+            await self.tracer.emit(sp)
 
     async def _emit(self, sess: _Session, new_tokens: list[int]) -> None:
         if sess.on_tokens is None:
@@ -925,17 +1081,36 @@ class ServingEngine:
     async def _decode_loop(self) -> None:
         """The continuous-batching loop: one ragged XLA call per step over
         every active session — decode rows and prefill chunks mixed;
-        admission and retirement happen between steps, never inside one."""
+        admission and retirement happen between steps, never inside one.
+
+        One cycle is six contiguous phases (``backend.STEP_PHASES``):
+        ``assemble`` here, ``pack``/``dispatch``/``wait``/``unpack`` inside
+        ``backend.step``, ``emit`` here again."""
         while not self._closed:
-            await self._admit()
-            # evict cancellations before assembling the batch
-            for sess in [s for s in self._active.values() if s.cancelled]:
-                self._retire(sess, error=SessionCancelled(sess.job_id))
+            n_step = self.stats.steps
+            marks = [time.time_ns()]
+            entries: list[StepEntry] = []
+            rows: list[tuple[_Session, int, bool, list[int]]] = []
+            with step_phase("assemble", n_step, marks):
+                await self._admit()
+                # evict cancellations before assembling the batch
+                for sess in [s for s in self._active.values() if s.cancelled]:
+                    self._retire(sess, error=SessionCancelled(sess.job_id))
+                if self._active:
+                    self._plan_drafts()
+                    entries, rows = self._assemble(await self._resolve_cow())
+                if entries:
+                    t0 = time.monotonic()
+                    self._in_step = frozenset(s.job_id for s, _, _, _ in rows)
+                    # handed to the executor before anything is published:
+                    # the flush below runs while the device does
+                    done, step_call = eager(self._run_step(entries))
             if not self._active:
                 self._gauge()
                 if not self._pending:
                     if self._closed:
                         return
+                    await self._flush_spans()
                     self._wake.clear()
                     # re-check after clear: a submit may have landed between
                     # the emptiness check and the clear
@@ -944,212 +1119,233 @@ class ServingEngine:
                 else:
                     await asyncio.sleep(0.001)  # pages freeing: poll soon
                 continue
-            self._plan_drafts()
-            entries, rows = self._assemble(await self._resolve_cow())
             if not entries:  # defensive: all rows parked past the budget
                 await asyncio.sleep(0.001)
                 continue
-            t0 = time.monotonic()
-            step_span = None
-            if self.tracer is not None and rows[0][0].trace_id:
-                oldest = min((r[0] for r in rows), key=lambda s: s.enqueued_at)
-                step_span = self.tracer.begin(
-                    "decode-step", trace_id=oldest.trace_id,
-                    parent_span_id=oldest.parent_span_id,
-                    attrs={"occupancy": str(len(rows))},
-                )
-            self._in_step = frozenset(s.job_id for s, _, _, _ in rows)
-            try:
-                results = await self.run_blocking(self.backend.step, entries)
-            except Exception as e:  # noqa: BLE001 - whole-step failure
+            await self._flush_spans()
+            results, step_err = step_call if done else await step_call
+            if step_err is not None:
                 # a poisoned step fails every rider (pages freed); the next
                 # tick starts clean — mirrors the batcher's isolation intent
                 # without re-running autoregressive state per item
                 self._in_step = frozenset()
-                logx.warn("serving step failed", occupancy=len(rows), err=str(e))
-                if step_span is not None and self.tracer is not None:
-                    step_span.attrs["error"] = type(e).__name__
-                    await self.tracer.finish(step_span, status="ERROR")
+                logx.warn("serving step failed", occupancy=len(rows),
+                          err=str(step_err))
+                if self.tracer is not None and self.tracer.listening():
+                    self._spans.append(self.tracer.record(
+                        "step", trace_id=f"step-{self.worker_id}-{n_step}",
+                        start_us=marks[0] // 1000,
+                        end_us=time.time_ns() // 1000, status=SPAN_ERROR,
+                        attrs={"occupancy": str(len(rows)),
+                               "error": type(step_err).__name__},
+                    ))
                 for sess, _, _, _ in rows:
                     self.stats.failed += 1
-                    self._retire(sess, error=e)
+                    self._retire(sess, error=step_err)
                 continue
+            marks.append(time.time_ns())
             dt = time.monotonic() - t0
-            generated = 0
-            prefill_fed = 0
-            retired_this_step = 0
-            step_drafted = 0
-            step_accepted = 0
-            emits = []
-            retires = []
-            for (sess, chunk, samples, drafted), tok in zip(rows, results):
-                if drafted:
-                    # speculative verification row: the backend returned
-                    # one next-token prediction per fed position.  Accept
-                    # the longest draft prefix the model agrees with, then
-                    # the bonus token — the prediction after the last
-                    # accepted draft, which is exactly what a sequential
-                    # decode would have sampled next (so the burst is
-                    # token-identical to the oracle by construction).
-                    preds = [int(t) for t in tok]
-                    a = 0
-                    while a < len(drafted) and drafted[a] == preds[a]:
-                        a += 1
-                    burst = drafted[:a] + [preds[a]]
-                    eos = sess.req.eos_token
-                    if eos is not None and eos in burst:
-                        burst = burst[:burst.index(eos) + 1]
-                    rejected = len(drafted) - a
-                    step_drafted += len(drafted)
-                    step_accepted += a
-                    frac = a / len(drafted)
-                    sess.accept_ewma += SPEC_EWMA_ALPHA * (
-                        frac - sess.accept_ewma
-                    )
-                    self.spec_accept_ewma += SPEC_FLEET_ALPHA * (
-                        frac - self.spec_accept_ewma
-                    )
-                    self.stats.drafted_tokens += len(drafted)
-                    self.stats.accepted_tokens += a
-                    self.stats.rolled_back_tokens += rejected
-                    if self.metrics is not None:
-                        self.metrics.serving_spec_drafted.inc(
-                            float(len(drafted)))
-                        self.metrics.serving_spec_accepted.inc(float(a))
-                        if rejected:
-                            self.metrics.serving_spec_rolled_back.inc(
-                                float(rejected))
-                    # page write-position rollback: pos advances over the
-                    # verified burst ONLY.  Rejected draft positions sit at
-                    # >= the new pos; every later step writes its own K/V
-                    # there before any gather runs (writes precede gathers
-                    # inside the ragged program, and positions are consumed
-                    # contiguously), so the arena never serves speculated
-                    # garbage.
-                    first = not sess.out_tokens
-                    sess.pos += len(burst)
-                    sess.last_token = burst[-1]
-                    sess.out_tokens.extend(burst)
-                    generated += len(burst)
-                    if first:
-                        self.stats.ttft_seconds.append(
-                            time.monotonic() - sess.enqueued_at
-                        )
-                    emits.append(self._emit(sess, burst))
-                else:
-                    if sess.prefilled:
-                        sess.pos += 1  # decode row: wrote its token at pos
-                    else:
-                        sess.prefill_pos += chunk
-                        sess.pos = sess.prefill_pos
-                        prefill_fed += chunk
-                        self.stats.prefill_chunks += 1
-                    if samples and tok is not None:
-                        t = int(tok)
-                        sess.last_token = t
-                        sess.out_tokens.append(t)
-                        generated += 1
-                        if len(sess.out_tokens) == 1:
-                            # first token of a locally born session: TTFT
-                            # (resume prefixes pre-populate out_tokens, so
-                            # migrated/resumed sessions never land here)
-                            self.stats.ttft_seconds.append(
-                                time.monotonic() - sess.enqueued_at
-                            )
-                        emits.append(self._emit(sess, [t]))
-                if sess.done or sess.cancelled:
-                    retired_this_step += 1
-                    # deferred below the emit gather: the future must not
-                    # resolve before the session's final token packet is
-                    # delivered, or a submitter that stops the engine the
-                    # moment submit() returns races the stream's tail (the
-                    # exactly-once contract spec bursts lean on)
-                    retires.append(sess)
-                elif (
-                    self.on_prefill_done is not None
-                    and not sess.handoff_signaled
-                    and not sess.frozen
-                    and (sess.prefilled or (
-                        self.handoff_threshold_tokens > 0
-                        and sess.prefill_pos >= self.handoff_threshold_tokens
-                    ))
-                ):
-                    # post-prefill hand-off trigger: the prompt finished
-                    # prefilling (or crossed the threshold mid-prefill) and
-                    # the session still has tokens to generate — the hook
-                    # fires once; the owner decides whether/where to migrate
-                    sess.handoff_signaled = True
-                    try:
-                        self.on_prefill_done(sess.job_id)
-                    except Exception as e:  # noqa: BLE001 - policy is best-effort
-                        logx.warn("prefill-done hook failed",
-                                  job_id=sess.job_id, err=str(e))
-            self.stats.steps += 1
-            self.stats.decoded_tokens += generated
-            self.stats.prefill_tokens += prefill_fed
-            if step_drafted:
-                self.stats.spec_steps += 1
-            self.stats.occupancy_sum += len(rows)
-            self.stats.max_occupancy = max(self.stats.max_occupancy, len(rows))
-            self.stats.step_seconds.append(dt)
-            if self.capacity is not None:
-                # one mixed step at the backend's static flat-buffer shape;
-                # warmup compiles are flagged so the steady-state tokens/s
-                # rows in the capacity matrix exclude them.  The step's
-                # device time is apportioned by delivered tokens between
-                # prompt ingestion (the OP_SERVING_PREFILL row) and token
-                # generation (the llm.generate row), so prefill tokens/s
-                # and decode tokens/s are separately measurable — the
-                # disaggregation policy's two placement signals
-                # (docs/SERVING.md §Disaggregation)
-                from ..protocol.types import OP_SERVING_PREFILL
+            with step_phase("emit", n_step, marks):
+                attrs = await self._scatter(rows, results, dt)
+                self._gauge()
+                # yield to the loop so intake/cancel/heartbeat tasks run
+                # between steps even under a saturated decode set
+                await asyncio.sleep(0)
+            self._cycle_closed(n_step, marks, attrs)
 
-                compiled = bool(getattr(self.backend, "last_step_compiled",
-                                        False))
-                total_toks = generated + prefill_fed
-                if prefill_fed:
-                    self.capacity.observe(
-                        OP_SERVING_PREFILL,
-                        device_s=dt * prefill_fed / total_toks,
-                        bucket=str(self.step_tokens),
-                        items=prefill_fed, tokens=prefill_fed,
-                        compiled=compiled,
-                    )
-                if generated or not prefill_fed:
-                    self.capacity.observe(
-                        "llm.generate",
-                        device_s=(dt * generated / total_toks
-                                  if total_toks else dt),
-                        bucket=str(self.step_tokens),
-                        items=generated, tokens=generated,
-                        compiled=compiled,
-                    )
-            if emits:
-                await asyncio.gather(*emits)
-            for sess in retires:
-                self._retire(
-                    sess,
-                    error=SessionCancelled(sess.job_id)
-                    if sess.cancelled else None,
+    async def _run_step(
+        self, entries: list[StepEntry]
+    ) -> tuple[list[Any], Optional[Exception]]:
+        """``backend.step`` off the loop; a whole-step failure comes back as
+        a value, so the hand-over and the await share one error path."""
+        try:
+            return await self.run_blocking(self.backend.step, entries), None
+        except Exception as e:  # noqa: BLE001 - whole-step failure
+            return [], e
+
+    async def _scatter(
+        self,
+        rows: list[tuple[_Session, int, bool, list[int]]],
+        results: list[Any],
+        dt: float,
+    ) -> dict[str, str]:
+        """Scatter one step's results back to its riders, stream the new
+        tokens, retire the finishers.  ``dt`` is the whole ``backend.step``
+        wall.  Returns the cycle's ``step`` span attrs."""
+        generated = 0
+        prefill_fed = 0
+        retired_this_step = 0
+        step_drafted = 0
+        step_accepted = 0
+        emits = []
+        retires = []
+        for (sess, chunk, samples, drafted), tok in zip(rows, results):
+            if sess.ttft is not None:
+                sess.ttft.steps += 1
+                if not sess.prefilled:
+                    sess.ttft.chunks += 1
+            if drafted:
+                # speculative verification row: the backend returned
+                # one next-token prediction per fed position.  Accept
+                # the longest draft prefix the model agrees with, then
+                # the bonus token — the prediction after the last
+                # accepted draft, which is exactly what a sequential
+                # decode would have sampled next (so the burst is
+                # token-identical to the oracle by construction).
+                preds = [int(t) for t in tok]
+                a = 0
+                while a < len(drafted) and drafted[a] == preds[a]:
+                    a += 1
+                burst = drafted[:a] + [preds[a]]
+                eos = sess.req.eos_token
+                if eos is not None and eos in burst:
+                    burst = burst[:burst.index(eos) + 1]
+                rejected = len(drafted) - a
+                step_drafted += len(drafted)
+                step_accepted += a
+                frac = a / len(drafted)
+                sess.accept_ewma += SPEC_EWMA_ALPHA * (
+                    frac - sess.accept_ewma
                 )
-            # every token of this step is appended AND emitted: a freeze
-            # waiting on wait_quiesced() now sees a fully consistent session
-            self._in_step = frozenset()
-            if self.metrics is not None:
-                self.metrics.serving_batch_occupancy.observe(float(len(rows)))
-                self.metrics.serving_inter_token.observe(dt)
-            if step_span is not None and self.tracer is not None:
-                step_span.attrs["retired"] = str(retired_this_step)
-                step_span.attrs["prefill_tokens"] = str(prefill_fed)
-                step_span.attrs["step_ms"] = f"{dt * 1000:.2f}"
-                if self.speculative:
-                    step_span.attrs["drafted"] = str(step_drafted)
-                    step_span.attrs["accepted"] = str(step_accepted)
-                await self.tracer.finish(step_span)
-            self._gauge()
-            # yield to the loop so intake/cancel/heartbeat tasks run between
-            # steps even under a saturated decode set
-            await asyncio.sleep(0)
+                self.spec_accept_ewma += SPEC_FLEET_ALPHA * (
+                    frac - self.spec_accept_ewma
+                )
+                self.stats.drafted_tokens += len(drafted)
+                self.stats.accepted_tokens += a
+                self.stats.rolled_back_tokens += rejected
+                if self.metrics is not None:
+                    self.metrics.serving_spec_drafted.inc(
+                        float(len(drafted)))
+                    self.metrics.serving_spec_accepted.inc(float(a))
+                    if rejected:
+                        self.metrics.serving_spec_rolled_back.inc(
+                            float(rejected))
+                # page write-position rollback: pos advances over the
+                # verified burst ONLY.  Rejected draft positions sit at
+                # >= the new pos; every later step writes its own K/V
+                # there before any gather runs (writes precede gathers
+                # inside the ragged program, and positions are consumed
+                # contiguously), so the arena never serves speculated
+                # garbage.
+                first = not sess.out_tokens
+                sess.pos += len(burst)
+                sess.last_token = burst[-1]
+                sess.out_tokens.extend(burst)
+                generated += len(burst)
+                if first:
+                    self._first_token(sess)
+                emits.append(self._emit(sess, burst))
+            else:
+                if sess.prefilled:
+                    sess.pos += 1  # decode row: wrote its token at pos
+                else:
+                    sess.prefill_pos += chunk
+                    sess.pos = sess.prefill_pos
+                    prefill_fed += chunk
+                    self.stats.prefill_chunks += 1
+                if samples and tok is not None:
+                    t = int(tok)
+                    sess.last_token = t
+                    sess.out_tokens.append(t)
+                    generated += 1
+                    if len(sess.out_tokens) == 1:
+                        # first token of a locally born session: TTFT
+                        # (resume prefixes pre-populate out_tokens, so
+                        # migrated/resumed sessions never land here)
+                        self._first_token(sess)
+                    emits.append(self._emit(sess, [t]))
+            if sess.done or sess.cancelled:
+                retired_this_step += 1
+                # deferred below the emit gather: the future must not
+                # resolve before the session's final token packet is
+                # delivered, or a submitter that stops the engine the
+                # moment submit() returns races the stream's tail (the
+                # exactly-once contract spec bursts lean on)
+                retires.append(sess)
+            elif (
+                self.on_prefill_done is not None
+                and not sess.handoff_signaled
+                and not sess.frozen
+                and (sess.prefilled or (
+                    self.handoff_threshold_tokens > 0
+                    and sess.prefill_pos >= self.handoff_threshold_tokens
+                ))
+            ):
+                # post-prefill hand-off trigger: the prompt finished
+                # prefilling (or crossed the threshold mid-prefill) and
+                # the session still has tokens to generate — the hook
+                # fires once; the owner decides whether/where to migrate
+                sess.handoff_signaled = True
+                try:
+                    self.on_prefill_done(sess.job_id)
+                except Exception as e:  # noqa: BLE001 - policy is best-effort
+                    logx.warn("prefill-done hook failed",
+                              job_id=sess.job_id, err=str(e))
+        self.stats.steps += 1
+        self.stats.decoded_tokens += generated
+        self.stats.prefill_tokens += prefill_fed
+        if step_drafted:
+            self.stats.spec_steps += 1
+        self.stats.occupancy_sum += len(rows)
+        self.stats.max_occupancy = max(self.stats.max_occupancy, len(rows))
+        self.stats.step_seconds.append(dt)
+        if self.capacity is not None:
+            # one mixed step at the backend's static flat-buffer shape;
+            # warmup compiles are flagged so the steady-state tokens/s
+            # rows in the capacity matrix exclude them.  The step's
+            # device time is apportioned by delivered tokens between
+            # prompt ingestion (the OP_SERVING_PREFILL row) and token
+            # generation (the llm.generate row), so prefill tokens/s
+            # and decode tokens/s are separately measurable — the
+            # disaggregation policy's two placement signals
+            # (docs/SERVING.md §Disaggregation)
+            compiled = bool(getattr(self.backend, "last_step_compiled",
+                                    False))
+            total_toks = generated + prefill_fed
+            if prefill_fed:
+                self.capacity.observe(
+                    OP_SERVING_PREFILL,
+                    device_s=dt * prefill_fed / total_toks,
+                    bucket=str(self.step_tokens),
+                    items=prefill_fed, tokens=prefill_fed,
+                    compiled=compiled,
+                )
+            if generated or not prefill_fed:
+                self.capacity.observe(
+                    "llm.generate",
+                    device_s=(dt * generated / total_toks
+                              if total_toks else dt),
+                    bucket=str(self.step_tokens),
+                    items=generated, tokens=generated,
+                    compiled=compiled,
+                )
+        if emits:
+            await asyncio.gather(*emits)
+        for sess in retires:
+            self._retire(
+                sess,
+                error=SessionCancelled(sess.job_id)
+                if sess.cancelled else None,
+            )
+        # every token of this step is appended AND emitted: a freeze
+        # waiting on wait_quiesced() now sees a fully consistent session
+        self._in_step = frozenset()
+        if self.metrics is not None:
+            self.metrics.serving_batch_occupancy.observe(float(len(rows)))
+            self.metrics.serving_inter_token.observe(dt)
+        attrs = {
+            "occupancy": str(len(rows)),
+            "live_tokens": str(sum(chunk for _, chunk, _, _ in rows)),
+            "prefill_tokens": str(prefill_fed),
+            "retired": str(retired_this_step),
+            "compiled": str(bool(
+                getattr(self.backend, "last_step_compiled", False)
+            )).lower(),
+        }
+        if self.speculative:
+            attrs["drafted"] = str(step_drafted)
+            attrs["accepted"] = str(step_accepted)
+        return attrs
 
     # ------------------------------------------------------------------
     # live migration (serving/migration.py, docs/SERVING.md §Migration,
@@ -1472,3 +1668,4 @@ class ServingEngine:
             except Exception as e:  # noqa: BLE001 - logged, never swallowed
                 logx.warn("decode loop crashed during shutdown", err=str(e))
             self._loop_task = None
+        await self._flush_spans()

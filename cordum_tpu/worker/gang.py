@@ -556,6 +556,7 @@ class GangRunner:
             speculative=bool(payload.get("speculative", False)),
             draft_k=int(payload.get("draft_k", 0) or 0) or 4,
         )
+        engine.worker_id = worker.worker_id
         prompts = payload.get("prompts")
         if not isinstance(prompts, list) or not prompts:
             one = payload.get("prompt") or payload.get("tokens") or [1, 2, 3]

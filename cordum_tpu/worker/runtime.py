@@ -242,6 +242,7 @@ class Worker:
         Jobs whose payload it recognizes (``serving.parts``) become decode
         sessions; everything else keeps the per-job handler path."""
         self._serving = serving
+        serving.worker_id = self.worker_id  # names its step traces
         if self.serving_role == SERVING_ROLE_PREFILL:
             # post-prefill hand-off (docs/SERVING.md §Disaggregation): the
             # engine fires once per session when its prompt finishes

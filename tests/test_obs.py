@@ -332,6 +332,76 @@ async def test_tracer_error_marks_span(bus):
     assert pkt.payload.attrs["error"] == "ValueError"
 
 
+async def test_tracer_listening_and_record(bus):
+    """``listening`` answers whether an emit would publish; ``record`` makes
+    a finished span from the caller's own stamps, leaves the ambient context
+    alone, and ``emit`` publishes it as it stands."""
+    t = Tracer("worker", bus)
+    assert not Tracer("worker").listening() and not t.listening()
+    await bus.subscribe(subj.TRACE_SPAN, _sink)
+    assert t.listening()
+    sp = t.record("step.wait", trace_id="step-w-7", parent_span_id="root",
+                  start_us=1_000, end_us=23_500, attrs={"k": "v"})
+    assert (sp.name, sp.service, sp.trace_id, sp.parent_span_id) == (
+        "step.wait", "worker", "step-w-7", "root")
+    assert sp.span_id and sp.duration_us == 22_500 and sp.status == "OK"
+    assert current_trace_context() == ("", "")
+    root = t.record("step", trace_id="step-w-7", span_id="root",
+                    start_us=1_000, end_us=24_000)
+    assert root.span_id == "root"
+    await t.emit(sp)
+    await t.emit(root)
+    got = [p.payload for s, p in bus.published if s == subj.TRACE_SPAN]
+    assert [(g.name, g.start_us, g.end_us) for g in got] == [
+        ("step.wait", 1_000, 23_500), ("step", 1_000, 24_000)]
+
+
+async def test_serving_spans_reach_the_collector_and_assemble():
+    """Through the worker: a generation's trace shows ``serving.queue`` and
+    ``serving.prefill`` under its ``execute`` span, and a kept cycle is a
+    stored trace of its own whose root ``step`` landed last."""
+    from cordum_tpu.serving.backend import STEP_PHASES
+    from tests.test_serving import FakeBackend, make_serving_worker, make_stack, settle
+
+    kv, bus, js, ms, eng = make_stack()
+    await eng.start()
+    metrics = Metrics()
+    collector = SpanCollector(kv, bus, metrics=metrics)
+    await collector.start()
+    w = make_serving_worker(bus, ms, backend=FakeBackend(num_pages=64, step_delay=0.002),
+                            max_sessions=4)
+    await w.start()
+    await settle(bus)
+    ptr = await ms.put_context("gen", {"op": "llm.generate", "tokens": [4, 5, 9],
+                                       "max_new_tokens": 6})
+    await bus.publish(subj.SUBMIT, BusPacket.wrap(
+        JobRequest(job_id="gen", topic="job.tpu.generate", context_ptr=ptr),
+        trace_id="tr-gen"))
+    for _ in range(300):
+        await settle(bus, rounds=2)
+        if await js.get_state("gen") == "SUCCEEDED":
+            break
+    await w.stop()  # the engine flushes what it still holds
+    await settle(bus)
+    doc = assemble("tr-gen", await collector.spans("tr-gen"))
+    by_name = {sp["name"]: sp for sp in doc["spans"]}
+    execute = by_name["execute"]
+    queue, prefill = by_name["serving.queue"], by_name["serving.prefill"]
+    assert queue["parent_span_id"] == prefill["parent_span_id"] == execute["span_id"]
+    assert execute["start_us"] <= queue["start_us"] and queue["end_us"] == prefill["start_us"]
+    assert prefill["end_us"] <= execute["end_us"]
+    assert not any(n.startswith("step") for n in by_name)  # cycles have their own traces
+    step = await collector.spans("step-w-srv-0")
+    assert [sp.name for sp in step] == [f"step.{p}" for p in STEP_PHASES] + ["step"]
+    tree = assemble("step-w-srv-0", step)
+    assert [sp["depth"] for sp in tree["spans"]].count(1) == 6
+    assert tree["total_us"] == step[-1].duration_us == sum(sp.duration_us for sp in step[:-1])
+    # the collector's stage histogram has the phases as stages
+    assert 'stage="step.wait"' in metrics.render()
+    await collector.stop()
+    await eng.stop()
+
+
 def test_span_wire_roundtrip():
     sp = _mk("a", "b", "execute", 1, 2)
     sp.attrs = {"k": "v"}

@@ -900,3 +900,368 @@ async def test_sdk_generate_streams_tokens():
             assert got2 == got
         finally:
             await c.close()
+
+
+# ------------------------------------------- the flight recorder's serving side
+# (docs/OBSERVABILITY.md §Serving spans and metrics)
+
+class SpanSink:
+    """A worker ``Tracer`` on a loopback bus with one listener on
+    ``sys.trace.span`` that keeps every span it hears."""
+
+    def __init__(self, bus=None):
+        from cordum_tpu.infra.bus import LoopbackBus
+        from cordum_tpu.obs.tracer import Tracer
+
+        self.bus = bus or LoopbackBus()
+        self.tracer = Tracer("worker", self.bus)
+        self.spans = []
+
+    async def listen(self):
+        from cordum_tpu.protocol import subjects as subj
+
+        async def on_span(subject, pkt):
+            self.spans.append(pkt.span)
+
+        await self.bus.subscribe(subj.TRACE_SPAN, on_span)
+        return self
+
+    def named(self, *names):
+        return [s for s in self.spans if s.name in names]
+
+    def step_traces(self):
+        traces = {}
+        for s in self.spans:
+            if s.name == "step" or s.name.startswith("step."):
+                traces.setdefault(s.trace_id, []).append(s)
+        return traces
+
+
+def traced_engine(sink, backend, **kw):
+    eng = ServingEngine(backend, run_blocking=run_blocking, tracer=sink.tracer,
+                        **kw)
+    eng.worker_id = "w-t"
+    return eng
+
+
+async def generate(eng, n, *, prompt_len=5, new=6, trace=True):
+    return await asyncio.gather(*[
+        eng.submit(
+            GenRequest(prompt=[i + 1] * prompt_len, max_new_tokens=new,
+                       stream=False),
+            job_id=f"j{i}", trace_id=f"tr-{i}" if trace else "",
+            parent_span_id=f"exec-{i}" if trace else "",
+        ) for i in range(n)
+    ])
+
+
+async def test_serving_queue_and_prefill_spans_split_each_ttft():
+    """Every locally born request gets exactly one ``serving.queue`` and one
+    ``serving.prefill`` on its own trace under its ``execute`` span; the two
+    are contiguous and sum to its ``ttft_seconds`` entry."""
+    from cordum_tpu.infra.metrics import Metrics
+
+    sink = await SpanSink().listen()
+    metrics = Metrics()
+    # two slots for six requests, prompts longer than the 8-token budget:
+    # real queueing, several chunks
+    be = FakeBackend(num_pages=64, step_delay=0.002, max_batch_tokens=8)
+    eng = traced_engine(sink, be, max_sessions=2, metrics=metrics)
+    n = 6
+    await generate(eng, n, prompt_len=13)
+    await eng.stop()
+    await sink.bus.drain()
+    ttfts = []
+    for i in range(n):
+        mine = [s for s in sink.spans if s.trace_id == f"tr-{i}"]
+        assert sorted(s.name for s in mine) == ["serving.prefill", "serving.queue"]
+        q, p = sorted(mine, key=lambda s: s.name, reverse=True)
+        assert q.parent_span_id == p.parent_span_id == f"exec-{i}"
+        assert q.service == p.service == "worker"
+        assert q.end_us == p.start_us  # contiguous
+        assert set(q.attrs) == {"pending_ahead", "admission_waits", "prefix_hit_tokens"}
+        assert set(p.attrs) == {"prompt_tokens", "chunks", "steps"}
+        assert p.attrs["prompt_tokens"] == "13"
+        assert int(p.attrs["steps"]) >= int(p.attrs["chunks"]) >= 2
+        ttfts.append((p.end_us - q.start_us) / 1e6)
+    # later arrivals queued behind the first two
+    assert max(int(s.attrs["pending_ahead"]) for s in sink.named("serving.queue")) >= 3
+    assert len(eng.stats.ttft_seconds) == n
+    for span_sum, ttft in zip(sorted(ttfts), sorted(eng.stats.ttft_seconds)):
+        assert abs(span_sum - ttft) < 3e-6  # two microsecond roundings
+    # the always-on series close at the same boundaries
+    for hist in (metrics.serving_queue, metrics.serving_prefill):
+        ((_, _, _, count),) = hist._snapshot()
+        assert count == n
+
+
+async def test_migrated_in_and_resumed_sessions_get_no_ttft_spans():
+    """A session adopted from a peer, and one resumed after a failover, had
+    their first token on another worker's clock: neither span, and no
+    ``ttft_seconds`` entry."""
+    sink = await SpanSink().listen()
+    be = FakeBackend(num_pages=64, step_delay=0.002)
+    eng = traced_engine(sink, be, max_sessions=4)
+    prompt = [3, 1, 4]
+    carried = fake_ref(prompt, 2)
+    fut = await eng.install_session(
+        GenRequest(prompt=prompt, max_new_tokens=5, stream=False),
+        job_id="mig", trace_id="tr-mig", parent_span_id="exec-mig",
+        state={"pos": len(prompt) + 1, "prefill_pos": len(prompt),
+               "out_tokens": carried, "last_token": carried[-1]},
+        records=[],
+    )
+    resumed = eng.submit(
+        GenRequest(prompt=prompt, max_new_tokens=5, stream=False,
+                   resume_tokens=carried),
+        job_id="res", trace_id="tr-res", parent_span_id="exec-res",
+    )
+    local = eng.submit(
+        GenRequest(prompt=prompt, max_new_tokens=5, stream=False),
+        job_id="loc", trace_id="tr-loc", parent_span_id="exec-loc",
+    )
+    out_res, out_loc = await asyncio.gather(resumed, local)
+    assert await fut == out_res["tokens"] == out_loc["tokens"] == fake_ref(prompt, 5)
+    await eng.stop()
+    await sink.bus.drain()
+    ttft_spans = sink.named("serving.queue", "serving.prefill")
+    assert sorted((s.trace_id, s.name) for s in ttft_spans) == [
+        ("tr-loc", "serving.prefill"), ("tr-loc", "serving.queue")]
+    assert len(eng.stats.ttft_seconds) == 1
+
+
+@pytest.mark.parametrize("kind", ["stamping", "plain"])
+async def test_sampled_cycle_children_are_contiguous_and_sum_to_step(kind, llama_env):
+    """A kept cycle is a trace of its own: root ``step`` with six contiguous
+    children that sum to it exactly.  The real backend stamps its four
+    phases; a backend that stamps nothing reads as one ``wait``."""
+    from cordum_tpu.serving.backend import STEP_PHASES
+
+    sink = await SpanSink().listen()
+    if kind == "stamping":
+        be = llama_env[2]
+    else:
+        be = FakeBackend(num_pages=64, step_delay=0.004)
+    eng = traced_engine(sink, be, max_sessions=4)
+    await generate(eng, 3, new=8)
+    await eng.stop()
+    await sink.bus.drain()
+    traces = sink.step_traces()
+    assert traces and len(traces) < eng.stats.steps  # a sample, not every cycle
+    for trace_id, spans in traces.items():
+        assert trace_id.startswith("step-w-t-")
+        assert [s.name for s in spans] == [f"step.{p}" for p in STEP_PHASES] + ["step"]
+        *children, root = spans  # the root is published last
+        assert all(s.service == "worker" for s in spans)
+        assert not root.parent_span_id
+        assert all(c.parent_span_id == root.span_id for c in children)
+        assert children[0].start_us == root.start_us and children[-1].end_us == root.end_us
+        for a, b in zip(children, children[1:]):
+            assert a.end_us == b.start_us
+        assert sum(c.duration_us for c in children) == root.duration_us
+        assert {"occupancy", "live_tokens", "prefill_tokens", "retired",
+                "compiled"} <= set(root.attrs)
+        by = {c.name: c.duration_us for c in children}
+        if kind == "plain":
+            assert by["step.pack"] == by["step.dispatch"] == by["step.unpack"] == 0
+            # the fake's 4 ms sleep lies between the hand-over (stamped by
+            # the loop, late under load) and the return
+            assert by["step.assemble"] + by["step.wait"] >= 4000
+    if kind == "stamping":
+        # the first cycle is always kept, and it is the one that compiled
+        first = traces["step-w-t-0"][-1]
+        assert first.attrs["compiled"] in ("true", "false")
+        assert be.last_phases == tuple(sorted(be.last_phases)) and len(be.last_phases) == 5
+
+
+class SlowAtBackend(FakeBackend):
+    """A fake whose listed step numbers stall."""
+
+    def __init__(self, slow_at, stall_s, **kw):
+        super().__init__(**kw)
+        self.slow_at, self.stall_s = set(slow_at), stall_s
+
+    def step(self, entries):
+        import time as _t
+
+        if self.steps in self.slow_at:
+            _t.sleep(self.stall_s)
+        return super().step(entries)
+
+
+async def test_stalled_cycle_is_kept_whatever_the_rate_cap_says():
+    """A cycle over three times the running median of the last 64 is kept
+    though the last kept cycle began under 250 ms before it."""
+    from cordum_tpu.serving import engine as engine_mod
+
+    sink = await SpanSink().listen()
+    be = SlowAtBackend({70, 73}, 0.06, num_pages=64, max_context=256)
+    eng = traced_engine(sink, be, max_sessions=2, max_new_tokens_cap=128)
+    await generate(eng, 1, new=90)
+    await eng.stop()
+    await sink.bus.drain()
+    roots = {t: spans[-1] for t, spans in sink.step_traces().items()}
+    assert "step-w-t-0" in roots
+    a, b = roots["step-w-t-70"], roots["step-w-t-73"]
+    assert b.start_us - a.start_us < engine_mod.STEP_SAMPLE_PERIOD_NS / 1e3
+    for root in (a, b):
+        assert root.duration_us >= 60_000
+        wait = next(s for s in sink.spans
+                    if s.trace_id == root.trace_id and s.name == "step.wait")
+        assert wait.duration_us >= 60_000  # the phase it stalled in
+    assert len(roots) < eng.stats.steps / 2
+
+
+async def test_no_span_is_published_between_a_step_returning_and_the_next_hand_over():
+    """Finished spans wait until the next step is on the device: the order of
+    hand-overs, returns and span publishes on a recording bus."""
+    from cordum_tpu.infra.bus import LoopbackBus
+    from cordum_tpu.protocol import subjects as subj
+
+    events = []
+
+    class RecordingBus(LoopbackBus):
+        async def publish(self, subject, pkt):
+            if subject == subj.TRACE_SPAN:
+                events.append("span")
+            await super().publish(subject, pkt)
+
+    async def recording_run_blocking(fn, *args):
+        events.append("handed")
+        try:
+            return await asyncio.get_running_loop().run_in_executor(None, fn, *args)
+        finally:
+            events.append("returned")
+
+    sink = await SpanSink(RecordingBus()).listen()
+    be = FakeBackend(num_pages=64, step_delay=0.01, max_context=256)
+    eng = ServingEngine(be, run_blocking=recording_run_blocking,
+                        tracer=sink.tracer, max_sessions=4,
+                        max_new_tokens_cap=64)
+    # all submitted at once and never idle in between: 40 steps of 10 ms
+    # cross the 250 ms sampling period, so several cycles are kept
+    await generate(eng, 6, new=40)
+    last_return = max(i for i, e in enumerate(events) if e == "returned")
+    busy = events[:last_return]
+    assert busy.count("span") >= 6 * 2 + 7  # the requests' spans and a cycle's
+    on_device = False
+    for e in busy:
+        if e == "handed":
+            on_device = True
+        elif e == "returned":
+            on_device = False
+        else:
+            assert on_device, "a span was published with no step on the device"
+    await eng.stop()
+
+
+async def test_no_span_is_built_without_a_listener(monkeypatch):
+    """With nobody on ``sys.trace.span`` the loop stamps and counts, and
+    builds no ``Span`` object at all."""
+    from cordum_tpu.infra.metrics import Metrics
+    from cordum_tpu.obs import tracer as tracer_mod
+
+    built = []
+    real = tracer_mod.Span
+
+    def counting(*a, **kw):
+        built.append(kw.get("name"))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(tracer_mod, "Span", counting)
+    sink = SpanSink()  # a tracer and a bus, nobody listening
+    metrics = Metrics()
+    eng = traced_engine(sink, FakeBackend(num_pages=64), max_sessions=4,
+                        metrics=metrics)
+    await generate(eng, 3)
+    await eng.stop()
+    assert eng.stats.steps > 0 and built == []
+    assert not eng._spans
+    # the same run with a listener builds them (the counter does count)
+    await sink.listen()
+    eng = traced_engine(sink, FakeBackend(num_pages=64), max_sessions=4)
+    await generate(eng, 3)
+    await eng.stop()
+    assert "step" in built and "serving.queue" in built
+
+
+async def test_step_phase_histogram_sees_every_cycle():
+    """``cordum_serving_step_phase_seconds`` is observed for all six phases
+    on every cycle, kept or not, and needs no tracer."""
+    from cordum_tpu.infra.metrics import Metrics
+    from cordum_tpu.serving.backend import STEP_PHASES
+
+    metrics = Metrics()
+    eng = ServingEngine(FakeBackend(num_pages=64, step_delay=0.001),
+                        run_blocking=run_blocking, max_sessions=4,
+                        metrics=metrics)
+    await generate(eng, 3, new=10, trace=False)
+    await eng.stop()
+    counts = {dict(key)["phase"]: (total, s)
+              for key, _, s, total in metrics.serving_step_phase._snapshot()}
+    assert set(counts) == set(STEP_PHASES)
+    assert {total for total, _ in counts.values()} == {eng.stats.steps}
+    # the fake's sleep is the wait; the phases sum to about the cycles
+    assert counts["wait"][1] >= 0.001 * eng.stats.steps
+    assert "cordum_serving_step_phase_seconds" in metrics.render()
+
+
+async def test_failed_step_leaves_an_error_step_span():
+    """A step that raises fails its riders and is kept as a lone ``step``
+    root with status ERROR (the phases of a call that never returned are
+    unknown)."""
+
+    class Boom(FakeBackend):
+        def step(self, entries):
+            if self.steps == 2:
+                self.steps += 1
+                raise RuntimeError("poisoned")
+            return super().step(entries)
+
+    sink = await SpanSink().listen()
+    eng = traced_engine(sink, Boom(num_pages=64), max_sessions=4)
+    with pytest.raises(RuntimeError):
+        await generate(eng, 1)
+    out = await eng.submit(  # the loop goes on
+        GenRequest(prompt=[2, 7], max_new_tokens=4, stream=False), job_id="next")
+    assert out["tokens"] == fake_ref([2, 7], 4)
+    await eng.stop()
+    await sink.bus.drain()
+    (err,) = [s for s in sink.spans if s.status == "ERROR"]
+    assert err.name == "step" and err.attrs["error"] == "RuntimeError"
+    assert [s.name for s in sink.spans if s.trace_id == err.trace_id] == ["step"]
+
+
+def test_ragged_step_scopes_are_metadata_only():
+    """The named scopes in ``ragged_step`` change no equation of its jaxpr,
+    and its lowered text names all six."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from cordum_tpu.models import llama
+
+    cfg = llama.LlamaConfig.tiny()
+    params = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+    k_pages, v_pages = jax.eval_shape(lambda: llama.init_kv_pages(cfg, 8, 4))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (params, k_pages, v_pages, i32(8), i32(8), i32(5, 4), i32(8), i32(4))
+
+    def step(*a):
+        return llama.ragged_step(*a, cfg)
+
+    scoped = jax.make_jaxpr(step)(*args)
+    lowered = jax.jit(step).lower(*args).as_text(debug_info=True)
+    real = jax.named_scope
+    jax.named_scope = lambda name: contextlib.nullcontext()
+    try:
+        bare = jax.make_jaxpr(step)(*args)
+    finally:
+        jax.named_scope = real
+    assert len(scoped.eqns) == len(bare.eqns) and str(scoped) == str(bare)
+    for scope in ("embed", "kv_write", "attn_gather", "attn_scores", "mlp", "lm_head"):
+        assert f"/{scope}/" in lowered or f"/{scope}\"" in lowered, scope
