@@ -250,8 +250,9 @@ def forward(
 # block-granular page arena shaped [L, num_pages, page_size, kvh, hd]; a
 # sequence's logical position ``p`` lives at page ``page_table[p // ps]``,
 # slot ``p % ps`` (the Ragged Paged Attention layout, PAPERS.md — here a
-# gather-based jnp formulation that runs anywhere; a Pallas kernel that walks
-# the page table in VMEM is the TPU upgrade path).  Page 0 is the NULL page:
+# jnp formulation that runs anywhere, :func:`paged_attention`: a walk over
+# the page table in blocks of pages with an online softmax; a Pallas kernel
+# that walks it in VMEM is the TPU upgrade path).  Page 0 is the NULL page:
 # padding rows and padded page-table tails point at it, so their writes land
 # harmlessly in slots no live sequence ever attends to (the causal mask cuts
 # every k_pos > position).
@@ -371,6 +372,94 @@ def scatter_kv_pages(
     return k_pages, v_pages
 
 
+#: token positions one block of :func:`paged_attention`'s walk aims at, and
+#: the least number of blocks a page table is cut into.  Chosen on the chip
+#: (PERF.md §6, PR 25): a block costs about 7 us beyond its bytes, so the
+#: walk's granularity — the last block is half empty on average — weighs
+#: more than the count of blocks, down to 64 positions at T = 32
+ATTN_BLOCK_TOKENS = 128
+ATTN_MIN_BLOCKS = 8
+
+
+def attn_block_pages(page_size: int, pages_per_seq: int) -> int:
+    """Pages in one block of :func:`paged_attention`'s walk over a page
+    table ``pages_per_seq`` wide — derived from the shapes alone, so the
+    backend counts blocks on the host exactly as the program walks them."""
+    return max(1, min(ATTN_BLOCK_TOKENS // page_size,
+                      -(-pages_per_seq // ATTN_MIN_BLOCKS)))
+
+
+def paged_attention(
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    layer: int,
+    tables: jax.Array,
+    positions: jax.Array,
+    block_pages: int,
+) -> jax.Array:
+    """Causal attention of every buffer slot over its own sequence's pages.
+
+    q: [T, h, hd]; k_pages / v_pages: the arenas ``[L, N, ps, kvh, hd]``;
+    tables: [T, P] int32, each slot's page-table row; positions: [T] int32.
+    Returns [T, h, hd] in q's dtype.  Three properties (docs/SERVING.md
+    §The ragged entry point):
+
+    * **grouped query heads** — q is read as ``[T, kvh, rep, hd]`` and both
+      products keep ``kvh`` a batch dimension with ``rep`` the row
+      dimension, so K and V are never repeated to ``h`` heads;
+    * **blocks of pages, online softmax** — the table is walked
+      ``block_pages`` pages at a time (its width padded to whole blocks
+      with the null page); a block's K and V come straight out of the
+      arena in one gather that carries the layer index, scores and the
+      running maximum / sum / accumulator are float32, probabilities are
+      cast to the arena's dtype for the value product;
+    * **the walk ends at the longest live row** — the trip count is
+      ``ceil((max(positions) + 1) / block_tokens)``, a traced bound on
+      static shapes: one program, and blocks past it are never read.
+
+    Position 0 passes the causal mask ``k_pos <= position`` for every slot
+    (padding slots sit at position 0 on the null page), so the running
+    maximum is finite from the first block on and a later, wholly masked
+    block contributes exact zeros."""
+    t, h, hd = q.shape
+    ps, kvh = k_pages.shape[2], k_pages.shape[3]
+    bp = block_pages
+    bt = bp * ps  # token positions a block
+    n_blocks = -(-tables.shape[1] // bp)
+    tables = jnp.pad(tables, ((0, 0), (0, n_blocks * bp - tables.shape[1])))
+    qg = q.reshape(t, kvh, h // kvh, hd)
+    scale = 1.0 / math.sqrt(hd)
+    offs = jnp.arange(bt, dtype=positions.dtype)
+
+    def block(j, carry):
+        m, l, acc = carry
+        with jax.named_scope("attn_gather"):
+            ids = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
+            kb = k_pages[layer, ids].reshape(t, bt, kvh, hd)
+            vb = v_pages[layer, ids].reshape(t, bt, kvh, hd)
+        with jax.named_scope("attn_scores"):
+            s = jnp.einsum("tgrd,tkgd->tgrk", qg, kb,
+                           preferred_element_type=jnp.float32) * scale
+            live = (j * bt + offs)[None, :] <= positions[:, None]  # [T, bt]
+            s = jnp.where(live[:, None, None, :], s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            l = l * alpha + jnp.sum(p, axis=-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "tgrk,tkgd->tgrd", p.astype(vb.dtype), vb,
+                preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    stat = (t, kvh, h // kvh)
+    init = (jnp.full(stat, -1e30, jnp.float32), jnp.zeros(stat, jnp.float32),
+            jnp.zeros(stat + (hd,), jnp.float32))
+    walked = (jnp.max(positions) + bt) // bt  # == ceil((max + 1) / bt) >= 1
+    _, l, acc = jax.lax.fori_loop(0, walked, block, init)
+    return (acc / l[..., None]).astype(q.dtype).reshape(t, h, hd)
+
+
 def ragged_step(
     params: Params,
     k_pages: jax.Array,
@@ -416,9 +505,15 @@ def ragged_step(
     causal mask ``k_pos <= position`` — in-chunk tokens see each other
     exactly as a full-sequence forward would, padding rows park on the null
     page, and no token can reach another sequence's pages because the
-    gather walks only its own page-table row.  (This is the gather-based
-    jnp formulation that runs anywhere; a Pallas kernel walking the page
-    table in VMEM is the TPU upgrade path.)
+    gather walks only its own page-table row.  The attention is
+    :func:`paged_attention`: the row is walked in blocks of
+    :func:`attn_block_pages` pages with an online softmax, query heads
+    grouped by their KV head (K and V are read as stored, never repeated),
+    and the walk ends at the block that holds the step's longest live row
+    — a traced trip count from ``positions``, so the cost follows what is
+    live, not the context the table could hold.  (A jnp formulation that
+    runs anywhere; a Pallas kernel walking the page table in VMEM is the
+    TPU upgrade path.)
 
     ``sample_logits`` is a STATIC flag for serving-gang followers
     (docs/SERVING.md §Sharded serving): rank 0 alone owns sampling, so
@@ -434,6 +529,7 @@ def ragged_step(
     pt_tok = page_tables[token_seq]  # [T, P] — each token's own table row
     page_idx = jnp.take_along_axis(pt_tok, pos2 // ps, axis=1)[:, 0]  # [T]
     slot = positions % ps
+    block_pages = attn_block_pages(ps, page_tables.shape[1])
     # the named scopes are metadata only: they label the operations in a
     # device trace (per-kernel time by scope) and change none of them
     with jax.named_scope("embed"):
@@ -452,11 +548,9 @@ def ragged_step(
         with jax.named_scope("kv_write"):
             k_pages = k_pages.at[li, page_idx, slot].set(k[:, 0])
             v_pages = v_pages.at[li, page_idx, slot].set(v[:, 0])
-        with jax.named_scope("attn_gather"):
-            kc = k_pages[li][pt_tok].reshape(t_buf, -1, kvh, hd)  # [T, P*ps, ...]
-            vc = v_pages[li][pt_tok].reshape(t_buf, -1, kvh, hd)
-        with jax.named_scope("attn_scores"):  # the K/V repeat, both products
-            attn = _attention(q, kc, vc, cfg, q_offset=pos2)
+        # attn_gather and attn_scores label the body of the block walk
+        attn = paged_attention(
+            q[:, 0], k_pages, v_pages, li, pt_tok, positions, block_pages)
         x = x + (attn.reshape(t_buf, 1, h * hd) @ layer["wo"])
         with jax.named_scope("mlp"):
             mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)

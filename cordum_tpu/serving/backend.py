@@ -170,6 +170,14 @@ class LlamaServingBackend:
         # dispatched, result on the host, return) — the engine reads them
         # after the call to split its step cycle into phases
         self.last_phases: tuple[int, ...] = ()
+        # the latest step()'s attention walk: (blocks read, blocks a page
+        # table holds) — ``llama.paged_attention`` stops at the block of the
+        # step's longest live row, which the host knows from the entries it
+        # packs
+        bp = llama.attn_block_pages(self.page_size, self.pages_per_seq)
+        self._attn_block_tokens = bp * self.page_size
+        self._attn_blocks_total = -(-self.pages_per_seq // bp)
+        self.last_attn_blocks: tuple[int, int] = (0, 0)
         self._steps_done = 0  # numbers the host annotations
         # page-arena mutation lock: steps read-modify-write the K/V arrays
         # from executor threads
@@ -254,6 +262,7 @@ class LlamaServingBackend:
             tables = np.zeros((s_rows + 1, self.pages_per_seq), np.int32)
             out_idx = np.zeros((s_rows,), np.int32)
             ti = 0
+            longest = 1  # positions of the longest row (padding sits at 0)
             spans: list[tuple[int, int]] = []  # entry i's [lo, hi) buffer slots
             for i, e in enumerate(entries):
                 row = self._clamp(e.tokens)
@@ -272,6 +281,7 @@ class LlamaServingBackend:
                 out_idx[i] = ti + n - 1
                 spans.append((ti, ti + n))
                 ti += n
+                longest = max(longest, e.start + n)
             shape_key = ("ragged", t_buf, s_rows, self.pages_per_seq)
             self.last_step_compiled = shape_key not in self._compiled_shapes
             if self.last_step_compiled:
@@ -308,6 +318,9 @@ class LlamaServingBackend:
                 self.on_step(entries)
         self._steps_done = n_step + 1
         self.last_phases = tuple(marks)
+        self.last_attn_blocks = (
+            -(-longest // self._attn_block_tokens), self._attn_blocks_total
+        )
         return res
 
     # ------------------------------------------------------------------

@@ -148,6 +148,10 @@ class ServingStats:
     occupancy_sum: int = 0
     max_occupancy: int = 0
     admission_waits: int = 0  # admissions delayed by cache exhaustion
+    # the attention's block walk, summed over steps: blocks read (up to the
+    # step's longest live row) of the blocks the page tables hold
+    attn_blocks_walked: int = 0
+    attn_blocks_total: int = 0
     # per-step wall time (seconds), capped ring for inter-token p50/p99
     step_seconds: deque = field(default_factory=lambda: deque(maxlen=4096))
     # submit → first sampled token (seconds), capped ring for TTFT p50
@@ -1333,6 +1337,9 @@ class ServingEngine:
         if self.metrics is not None:
             self.metrics.serving_batch_occupancy.observe(float(len(rows)))
             self.metrics.serving_inter_token.observe(dt)
+        walked, of = getattr(self.backend, "last_attn_blocks", (0, 0))
+        self.stats.attn_blocks_walked += walked
+        self.stats.attn_blocks_total += of
         attrs = {
             "occupancy": str(len(rows)),
             "live_tokens": str(sum(chunk for _, chunk, _, _ in rows)),
@@ -1342,6 +1349,8 @@ class ServingEngine:
                 getattr(self.backend, "last_step_compiled", False)
             )).lower(),
         }
+        if of:
+            attrs["kv_blocks"] = f"{walked}/{of}"
         if self.speculative:
             attrs["drafted"] = str(step_drafted)
             attrs["accepted"] = str(step_accepted)

@@ -304,6 +304,10 @@ class ServingGangGroup:
         # capacity observatory's steady-state filter
         return any(r.last_step_compiled for r in self.ranks)
 
+    @property
+    def last_attn_blocks(self) -> tuple[int, int]:
+        return self.leader.last_attn_blocks
+
     def compiled_programs(self) -> int:
         return self.leader.compiled_programs()
 
