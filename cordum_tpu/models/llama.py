@@ -64,6 +64,9 @@ class LlamaConfig:
     def tiny(cls) -> "LlamaConfig":
         return cls(vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128, max_seq_len=128)
 
+    def serving_spec(self) -> Any:
+        return serving_spec(self)
+
 
 # ---------------------------------------------------------------------------
 # params
@@ -275,10 +278,14 @@ def forward(
 
 
 def init_kv_pages(
-    cfg: LlamaConfig, num_pages: int, page_size: int, dtype: Any = None
+    cfg: Any, num_pages: int, page_size: int, dtype: Any = None,
+    n_layers: Optional[int] = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Preallocated page arenas for K and V: [L, num_pages, page_size, kvh, hd]."""
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    """Preallocated page arenas for K and V: [L, num_pages, page_size, kvh, hd].
+    ``n_layers`` is for a model whose layers are of two kinds, each kind
+    with an arena pair of its own (``models/afmoe``): the layers of ONE kind."""
+    layers = cfg.n_layers if n_layers is None else n_layers
+    shape = (layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
     dt = dtype or cfg.dtype
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
@@ -381,6 +388,15 @@ ATTN_BLOCK_TOKENS = 128
 ATTN_MIN_BLOCKS = 8
 
 
+def window_ring_pages(window: int, page_size: int, max_batch_tokens: int) -> int:
+    """Pages a sequence holds of a window layer, as a ring (logical page
+    ``n`` in slot ``n % ring``): the least ring in which the newest write of
+    a step (a chunk of up to ``max_batch_tokens`` positions) cannot land on
+    a page that the chunk's oldest token still sees — the window, one
+    chunk, and a page of misalignment."""
+    return (window + max_batch_tokens + page_size - 3) // page_size + 1
+
+
 def attn_block_pages(page_size: int, pages_per_seq: int) -> int:
     """Pages in one block of :func:`paged_attention`'s walk over a page
     table ``pages_per_seq`` wide — derived from the shapes alone, so the
@@ -397,6 +413,7 @@ def paged_attention(
     tables: jax.Array,
     positions: jax.Array,
     block_pages: int,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Causal attention of every buffer slot over its own sequence's pages.
 
@@ -421,27 +438,55 @@ def paged_attention(
     Position 0 passes the causal mask ``k_pos <= position`` for every slot
     (padding slots sit at position 0 on the null page), so the running
     maximum is finite from the first block on and a later, wholly masked
-    block contributes exact zeros."""
+    block contributes exact zeros.
+
+    **A window layer** (``window`` = W, docs/SERVING.md §Two kinds of page)
+    adds a lower bound to the mask and to the walk: position ``p`` sees keys
+    ``p - W + 1 .. p``, and ``tables`` is then a RING of pages per sequence
+    (:func:`window_ring_pages` wide): logical page ``n`` of the row sits in
+    ring slot ``n % ring``, so a row holds a bounded number of pages however
+    long it grows.  Each slot starts its walk at the block that holds its
+    own oldest visible key and the trip count is the longest such walk of
+    the step — at most ``W / block_tokens + 2`` blocks, whatever the row's
+    length.  The first block of a slot's walk holds a visible key, so the
+    running maximum is finite from it on, as above.  With ``window=None``
+    the program is the one it was."""
     t, h, hd = q.shape
     ps, kvh = k_pages.shape[2], k_pages.shape[3]
     bp = block_pages
     bt = bp * ps  # token positions a block
-    n_blocks = -(-tables.shape[1] // bp)
-    tables = jnp.pad(tables, ((0, 0), (0, n_blocks * bp - tables.shape[1])))
+    if window is None:
+        n_blocks = -(-tables.shape[1] // bp)
+        tables = jnp.pad(tables, ((0, 0), (0, n_blocks * bp - tables.shape[1])))
     qg = q.reshape(t, kvh, h // kvh, hd)
     scale = 1.0 / math.sqrt(hd)
     offs = jnp.arange(bt, dtype=positions.dtype)
+    if window is not None:
+        ring = tables.shape[1]
+        first = jnp.maximum(positions - (window - 1), 0) // bt  # [T] first block
+        walked = jnp.max(positions // bt - first) + 1
+        lane = jnp.arange(bp, dtype=positions.dtype)
 
     def block(j, carry):
         m, l, acc = carry
         with jax.named_scope("attn_gather"):
-            ids = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
+            if window is None:
+                ids = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
+            else:
+                at = first + j  # [T]: each slot's own block of this trip
+                ids = jnp.take_along_axis(
+                    tables, (at[:, None] * bp + lane[None, :]) % ring, axis=1)
             kb = k_pages[layer, ids].reshape(t, bt, kvh, hd)
             vb = v_pages[layer, ids].reshape(t, bt, kvh, hd)
         with jax.named_scope("attn_scores"):
             s = jnp.einsum("tgrd,tkgd->tgrk", qg, kb,
                            preferred_element_type=jnp.float32) * scale
-            live = (j * bt + offs)[None, :] <= positions[:, None]  # [T, bt]
+            if window is None:
+                live = (j * bt + offs)[None, :] <= positions[:, None]  # [T, bt]
+            else:
+                k_pos = at[:, None] * bt + offs[None, :]
+                live = (k_pos <= positions[:, None]) & (
+                    k_pos > positions[:, None] - window)
             s = jnp.where(live[:, None, None, :], s, -1e30)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             alpha = jnp.exp(m - m_new)
@@ -455,9 +500,29 @@ def paged_attention(
     stat = (t, kvh, h // kvh)
     init = (jnp.full(stat, -1e30, jnp.float32), jnp.zeros(stat, jnp.float32),
             jnp.zeros(stat + (hd,), jnp.float32))
-    walked = (jnp.max(positions) + bt) // bt  # == ceil((max + 1) / bt) >= 1
+    if window is None:
+        walked = (jnp.max(positions) + bt) // bt  # == ceil((max + 1) / bt) >= 1
     _, l, acc = jax.lax.fori_loop(0, walked, block, init)
     return (acc / l[..., None]).astype(q.dtype).reshape(t, h, hd)
+
+
+def serving_spec(cfg: LlamaConfig) -> Any:
+    """The family's specification for the serving backend
+    (``serving/modelspec.py``): one kind of page, no counters."""
+    from ..serving.modelspec import ModelSpec
+
+    def program(sample_logits):
+        def ragged_program(p, kp, vp, toks, pos, pt, ts, oi):
+            return ragged_step(p, kp, vp, toks, pos, pt, ts, oi, cfg, sample_logits=sample_logits)
+
+        return ragged_program
+
+    return ModelSpec(
+        family="llama", cfg=cfg, vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
+        init_params=lambda key: init_params(key, cfg),
+        init_arenas=lambda n, ps, _w: init_kv_pages(cfg, n, ps),
+        program=program,
+    )
 
 
 def ragged_step(

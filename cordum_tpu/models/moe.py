@@ -12,6 +12,10 @@ Router/dispatch design (compiler-friendly):
   * position-in-expert computed with a cumulative-sum over the one-hot
     dispatch mask; tokens beyond ``capacity`` drop to the residual path
   * dispatch/combine as einsums against the one-hot mask (dense, static)
+
+This is the TRAINING path, and it drops tokens beyond ``capacity``.  The
+serving path's expert layer is ``models/afmoe.py`` ``expert_layer``: dropless,
+told which experts it holds, grouped products over tokens sorted by expert.
 """
 from __future__ import annotations
 

@@ -6,7 +6,8 @@ state.  This package adds the serving path:
 
   * :class:`PageAllocator` — block-granular KV-page bookkeeping over a
     preallocated cache arena (page 0 reserved as the null page)
-  * :class:`LlamaServingBackend` — the XLA side: ONE ragged paged-
+  * :class:`ServingBackend` (``LlamaServingBackend``, its older name) —
+    the XLA side, built from a :class:`ModelSpec`: ONE ragged paged-
     attention entry point (:class:`StepEntry` rows over a static flat
     token buffer) serving any mix of prefill chunks and decode steps in a
     single device call — one compiled program, no length/batch buckets
@@ -23,7 +24,7 @@ state.  This package adds the serving path:
 worker holding its KV pages via the ``cordum.session_key`` affinity map
 (``controlplane/scheduler/strategy.py``).
 """
-from .backend import LlamaServingBackend, StepEntry
+from .backend import LlamaServingBackend, ServingBackend, StepEntry
 from .engine import (
     GenRequest,
     ServingEngine,
@@ -32,6 +33,7 @@ from .engine import (
     SessionMigrated,
     SessionRequeued,
 )
+from .modelspec import ModelSpec, UnsupportedForModel
 from .migration import MigrationError, MigrationServer, migrate_session
 from .pager import CacheExhausted, PageAllocator
 
@@ -41,12 +43,15 @@ __all__ = [
     "LlamaServingBackend",
     "MigrationError",
     "MigrationServer",
+    "ModelSpec",
     "PageAllocator",
+    "ServingBackend",
     "ServingEngine",
     "ServingStats",
     "SessionCancelled",
     "SessionMigrated",
     "SessionRequeued",
     "StepEntry",
+    "UnsupportedForModel",
     "migrate_session",
 ]

@@ -1,8 +1,10 @@
 """The XLA half of the serving path: ONE ragged mixed prefill+decode entry.
 
-One backend per worker process owns the KV-page arena (``models/llama``
-``init_kv_pages``) and a single jitted program (``models/llama``
-``ragged_step``).  Every device call — a decode step over the live
+One backend per worker process owns the KV-page arenas and a single jitted
+program, both supplied by a model specification (``serving/modelspec.py``:
+the llama family's ``init_kv_pages`` and ``ragged_step`` are the first; a
+family with window layers brings a second kind of page, held in rings).
+Every device call — a decode step over the live
 sessions, a chunk of some prompt's prefill, or any mix of the two — flows
 through :meth:`step` with the same static operand shapes:
 
@@ -33,7 +35,7 @@ import contextlib
 import sys
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Callable, Iterator, Optional
 
 import numpy as np
@@ -91,26 +93,29 @@ class StepEntry:
     phase: str = "decode"  # "prefill" | "decode" — observability + fakes
     key: str = ""  # session/job id — observability + fakes
     draft: int = 0  # >0: speculative row with this many drafted tokens
+    # the session's ring of window-layer pages (a model with window layers)
+    window_pages: list[int] = field(default_factory=list)
 
 
-def make_ragged_program(cfg: Any, *, sample_logits: bool, donate: bool) -> Any:
-    """The ONE jitted serving program: ``llama.ragged_step`` over
-    ``(params, k_pages, v_pages, tokens, positions, page_tables, token_seq,
-    out_idx)``.  With ``donate`` the two arenas are donated, so the
-    in-place page writes never copy an arena."""
+def make_ragged_program(model: Any, *, sample_logits: bool, donate: bool) -> Any:
+    """The ONE jitted serving program of ``model`` (a ``ModelSpec`` or a
+    family's config): its ``ragged_program`` over ``(params, *arenas,
+    tokens, positions, *page tables, token_seq, out_idx)`` — for the llama
+    family ``llama.ragged_step`` over two arenas and one table.  With
+    ``donate`` the arenas are donated, so the in-place page writes never
+    copy an arena."""
     import jax
 
-    from ..models import llama
+    from .modelspec import spec_for
 
-    def ragged_program(p, kp, vp, toks, pos, pt, ts, oi):
-        return llama.ragged_step(
-            p, kp, vp, toks, pos, pt, ts, oi, cfg, sample_logits=sample_logits
-        )
+    spec = spec_for(model)
+    return jax.jit(
+        spec.program(sample_logits),
+        donate_argnums=tuple(range(1, 1 + spec.n_arenas)) if donate else (),
+    )
 
-    return jax.jit(ragged_program, donate_argnums=(1, 2) if donate else ())
 
-
-class LlamaServingBackend:
+class ServingBackend:
     # the ragged program returns per-position predictions for every buffer
     # row, so draft verification rows (StepEntry.draft > 0) are supported
     # natively — the engine gates its drafter on this capability flag
@@ -135,19 +140,23 @@ class LlamaServingBackend:
         max_seqs: int = 0,
         max_batch_tokens: int = 0,
         seed: int = 0,
+        params: Any = None,
         params_provider: Optional[Callable[[], Any]] = None,
         metrics: Any = None,
     ) -> None:
         # lazy model import keeps this module (and the engine importing it
         # for StepEntry) jax-free until a real backend is constructed
         from ..models import llama
+        from .modelspec import spec_for
 
-        self.cfg = cfg or llama.LlamaConfig.tiny()
+        # ``cfg``: a ModelSpec, a family's config object, or None (tiny llama)
+        self.spec = spec_for(llama.LlamaConfig.tiny() if cfg is None else cfg)
+        self.cfg = self.spec.cfg
         self.page_size = max(1, page_size)
         self.num_pages = max(2, num_pages)
         # static page-table width: the worst-case per-sequence footprint
         self.max_context = min(
-            max_context or self.cfg.max_seq_len, self.cfg.max_seq_len
+            max_context or self.spec.max_seq_len, self.spec.max_seq_len
         )
         self.pages_per_seq = -(-self.max_context // self.page_size)
         # static ragged-step shapes: S sequence rows (+1 padding row) over a
@@ -157,11 +166,29 @@ class LlamaServingBackend:
         self.max_batch_tokens = max(
             self.max_seqs, max_batch_tokens or 2 * self.max_seqs
         )
+        # a model with window layers holds a second kind of page: a ring of
+        # ``ring_pages`` per sequence, out of a pool that holds a whole ring
+        # for each of ``max_seqs`` sequences beside that kind's null page
+        # (so a free sequence row always finds its ring); 0 and 0 without a
+        # window
+        self.window = self.spec.window
+        self.ring_pages = (
+            llama.window_ring_pages(self.window, self.page_size, self.max_batch_tokens)
+            if self.window else 0
+        )
+        self.num_window_pages = (
+            self.max_seqs * self.ring_pages + 1 if self.window else 0
+        )
         self._seed = seed
-        self._params_provider = params_provider
+        # the weights: ``params`` as given, else what ``params_provider``
+        # returns at first use, else seeded random ones
+        self._params_provider = (
+            (lambda: params) if params is not None else params_provider
+        )
         self._params: Any = None
-        self._k_pages: Any = None
-        self._v_pages: Any = None
+        # the program's arenas in its argument order: K and V of the
+        # whole-row kind, then K and V of the window kind where there is one
+        self._arenas: Optional[list] = None
         self._ragged_jit: Any = None
         self._compiled_shapes: set = set()  # observability: program count
         self._metrics = metrics
@@ -178,50 +205,90 @@ class LlamaServingBackend:
         self._attn_block_tokens = bp * self.page_size
         self._attn_blocks_total = -(-self.pages_per_seq // bp)
         self.last_attn_blocks: tuple[int, int] = (0, 0)
+        # the same of the window layers' walk (blocks read; 0 with no
+        # window); the counters the program returned behind the tokens
+        # (``spec.aux_shape``; None where the family returns none) and what
+        # the family says they add to ``ServingStats`` (``spec.count_aux``)
+        self.last_window_blocks = 0
+        self.last_aux: Any = None
+        self.last_counters: dict[str, int] = {}
         self._steps_done = 0  # numbers the host annotations
         # page-arena mutation lock: steps read-modify-write the K/V arrays
         # from executor threads
         self._dev_lock = threading.Lock()
 
     # ------------------------------------------------------------------
+    # the whole-row kind's arenas under the names they always had (the
+    # migration, copy-on-write and gang code, chip_smoke and the llama
+    # benchmark family read and assign them)
+    @property
+    def _k_pages(self) -> Any:
+        return self._arenas[0] if self._arenas else None
+
+    @_k_pages.setter
+    def _k_pages(self, value: Any) -> None:
+        self._set_arena(0, value)
+
+    @property
+    def _v_pages(self) -> Any:
+        return self._arenas[1] if self._arenas else None
+
+    @_v_pages.setter
+    def _v_pages(self, value: Any) -> None:
+        self._set_arena(1, value)
+
+    def _set_arena(self, i: int, value: Any) -> None:
+        if self._arenas is None:
+            self._arenas = [None] * self.spec.n_arenas
+        self._arenas[i] = value
+
+    def release_arenas(self) -> None:
+        """Drop every page arena (the weights stay): for an owner that is
+        done serving and wants the device memory back.  A later step would
+        find no arena; build a new backend instead."""
+        with self._dev_lock:
+            self._arenas = [None] * self.spec.n_arenas
+
     def _ensure(self) -> None:
         if self._params is not None:
             return
         import jax
 
-        from ..models import llama
-
-        self._params, self._k_pages, self._v_pages = self._make_state(
+        self._params, *arenas = self._make_state(
             self._params_provider() if self._params_provider is not None else None
         )
+        self._arenas = list(arenas)
         # donate the page arenas on real accelerators so the in-place
         # update never copies the arena; CPU jax spams donation warnings
         self._ragged_jit = make_ragged_program(
-            self.cfg, sample_logits=bool(self.sample_output),
+            self.spec, sample_logits=bool(self.sample_output),
             donate=jax.default_backend() != "cpu",
         )
 
     def _make_state(self, params: Any):
-        """Weights (``params``, or seeded random ones when None) and the
-        two zeroed page arenas, on the default device.
+        """``(params, *arenas)``: the weights (``params``, or seeded random
+        ones when None) and the zeroed page arenas, on the default device.
         ShardedServingBackend overrides it to create them already laid out
         over the TP mesh."""
         import jax
 
-        from ..models import llama
-
         if params is None:
-            params = llama.init_params(jax.random.PRNGKey(self._seed), self.cfg)
-        k_pages, v_pages = llama.init_kv_pages(
-            self.cfg, self.num_pages, self.page_size
-        )
-        return params, k_pages, v_pages
+            params = self.spec.init_params(jax.random.PRNGKey(self._seed))
+        return (params, *self.spec.init_arenas(
+            self.num_pages, self.page_size, self.num_window_pages))
 
     def compiled_programs(self) -> int:
         return len(self._compiled_shapes)
 
+    @property
+    def kv_whole_row(self) -> bool:
+        """Every layer's pages cover the whole row under one table — what
+        prefix sharing, hibernation, migration and the gang assume.  False
+        for a model with window layers (``ModelSpec.window``)."""
+        return self.spec.kv_whole_row
+
     def _clamp(self, row: list[int]) -> list[int]:
-        vmax = self.cfg.vocab_size - 1
+        vmax = self.spec.vocab_size - 1
         return [min(max(0, int(t)), vmax) for t in row]
 
     # ------------------------------------------------------------------
@@ -259,7 +326,9 @@ class LlamaServingBackend:
             # padding tokens map to the padding row (all null pages): their
             # writes land on page 0 and no live sequence's gather can see them
             token_seq = np.full((t_buf,), s_rows, np.int32)
-            tables = np.zeros((s_rows + 1, self.pages_per_seq), np.int32)
+            tables = [np.zeros((s_rows + 1, self.pages_per_seq), np.int32)]
+            if self.window:
+                tables.append(np.zeros((s_rows + 1, self.ring_pages), np.int32))
             out_idx = np.zeros((s_rows,), np.int32)
             ti = 0
             longest = 1  # positions of the longest row (padding sits at 0)
@@ -277,7 +346,13 @@ class LlamaServingBackend:
                 tokens[ti:ti + n] = row
                 positions[ti:ti + n] = np.arange(e.start, e.start + n)
                 token_seq[ti:ti + n] = i
-                tables[i, : len(e.pages)] = e.pages
+                tables[0][i, : len(e.pages)] = e.pages
+                if self.window:
+                    if not e.window_pages:
+                        raise ValueError(
+                            "StepEntry.window_pages is empty: this model's window "
+                            "layers keep their K and V in a ring of their own")
+                    tables[1][i, : len(e.window_pages)] = e.window_pages
                 out_idx[i] = ti + n - 1
                 spans.append((ti, ti + n))
                 ti += n
@@ -293,10 +368,10 @@ class LlamaServingBackend:
             # shows there; the lock is held until the result is on the host
             with step_phase("dispatch", n_step, marks):
                 held.enter_context(self._dev_lock)
-                nxt, self._k_pages, self._v_pages = self._ragged_jit(
-                    self._params, self._k_pages, self._v_pages,
+                nxt, *self._arenas = self._ragged_jit(
+                    self._params, *self._arenas,
                     jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(tables), jnp.asarray(token_seq),
+                    *(jnp.asarray(tb) for tb in tables), jnp.asarray(token_seq),
                     jnp.asarray(out_idx),
                 )
             with step_phase("wait", n_step, marks):
@@ -314,6 +389,18 @@ class LlamaServingBackend:
                     res.append(int(out[hi - 1]))
                 else:
                     res.append(None)
+            if self.spec.aux_shape:
+                # the family's counters rode behind the tokens, one transfer
+                self.last_aux = out[t_buf:].reshape(self.spec.aux_shape)
+                if self.spec.count_aux is not None:
+                    self.last_counters = self.spec.count_aux(self.last_aux, ti)
+            if self.window:
+                # as the program walks: each slot from the block of its
+                # oldest visible key to its own, the step's longest walk
+                bt = self._attn_block_tokens
+                first = np.maximum(positions[:ti] - (self.window - 1), 0) // bt
+                self.last_window_blocks = int(
+                    (positions[:ti] // bt - first).max()) + 1
             if self.on_step is not None:
                 self.on_step(entries)
         self._steps_done = n_step + 1
@@ -336,6 +423,7 @@ class LlamaServingBackend:
         list; record ``i`` is the page ORDINAL within it (the receiver maps
         ordinals onto its own freshly allocated arena blocks).  Blocking
         (device reads); call from an executor thread."""
+        self.spec.require_whole_row("page export (migration, hibernation)")
         if end_tok <= start_tok:
             return []
         self._ensure()
@@ -363,6 +451,7 @@ class LlamaServingBackend:
         """Scatter migrated page records into freshly allocated arena
         blocks (``pages``, the receiving session's page list).  Blocking;
         call from an executor thread."""
+        self.spec.require_whole_row("page import (migration, hibernation)")
         if not records:
             return
         self._ensure()
@@ -399,6 +488,7 @@ class LlamaServingBackend:
         copy, every other table keeps attending to the original.  One
         cached executable serves every CoW (traced page indices).
         Blocking; call from an executor thread."""
+        self.spec.require_whole_row("page copy (prefix sharing)")
         self._ensure()
         from ..models import llama
 
@@ -445,3 +535,8 @@ class LlamaServingBackend:
             ) for tok, pos, pages in chunk])
             out.extend(int(t) for t in res if t is not None)
         return out
+
+
+#: the name the class had while the llama family was the only one; the same
+#: class, kept for its importers
+LlamaServingBackend = ServingBackend

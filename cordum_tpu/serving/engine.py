@@ -42,6 +42,7 @@ from ..protocol.types import OP_SERVING_PREFILL, SPAN_ERROR, Span
 from ..utils.eager import eager
 from ..utils.ids import fast_id
 from .backend import STEP_PHASES, StepEntry, step_phase
+from .modelspec import require_whole_row
 from .pager import CacheExhausted, PageAllocator
 from .prefixcache import PrefixCache, PrefixNode
 from .tiering import SessionTiering
@@ -152,6 +153,22 @@ class ServingStats:
     # step's longest live row) of the blocks the page tables hold
     attn_blocks_walked: int = 0
     attn_blocks_total: int = 0
+    # a model with window layers (docs/SERVING.md §Two kinds of page): blocks
+    # its window layers' walk read, summed over steps; ring slots written
+    # again after a lap (a page's worth of the row fell out of the window);
+    # peaks of the pages sessions held, by kind
+    window_blocks_walked: int = 0
+    window_pages_reused: int = 0
+    kv_pages_held_full: int = 0
+    kv_pages_held_window: int = 0
+    # a model with an expert layer (docs/SERVING.md §The expert layer),
+    # summed over steps and expert layers: assignments the router made
+    # (live tokens x top_k), those to experts held here, held experts that
+    # got at least one token, and the busiest held expert's tokens
+    moe_assignments: int = 0
+    moe_assignments_here: int = 0
+    moe_experts_touched: int = 0
+    moe_max_expert_load: int = 0
     # per-step wall time (seconds), capped ring for inter-token p50/p99
     step_seconds: deque = field(default_factory=lambda: deque(maxlen=4096))
     # submit → first sampled token (seconds), capped ring for TTFT p50
@@ -188,6 +205,8 @@ class _Session:
     trace_id: str = ""
     parent_span_id: str = ""
     pages: list[int] = field(default_factory=list)
+    # the session's ring of window-layer pages (a model with window layers)
+    window_pages: list[int] = field(default_factory=list)
     pos: int = 0  # sequence positions cached so far
     prefill_pos: int = 0  # prompt tokens fed so far (== pos until prefilled)
     last_token: int = 0
@@ -297,6 +316,17 @@ class ServingEngine:
             self.step_tokens,
         )
         self.allocator = PageAllocator(backend.num_pages, backend.page_size)
+        # a model with window layers keeps a second kind of page, in a ring
+        # of ``ring_pages`` per session: its own pool, reservations and
+        # refcounts (docs/SERVING.md §Two kinds of page)
+        self.ring_pages = int(getattr(backend, "ring_pages", 0) or 0)
+        self.window_allocator: Optional[PageAllocator] = (
+            PageAllocator(backend.num_window_pages, backend.page_size)
+            if self.ring_pages else None
+        )
+        # what assumes ONE kind of page per session — the prefix cache,
+        # hibernation, live migration — is off for such a model
+        self.kv_whole_row = bool(getattr(backend, "kv_whole_row", True))
         # prefix cache + session tiering (docs/SERVING.md §Prefix cache and
         # tiering): the radix index over cached full-page prefixes, and the
         # hibernate/restore machinery that tiers idle resident state to the
@@ -307,7 +337,10 @@ class ServingEngine:
         # write, so the cache is disabled outright rather than half-armed —
         # arena-less test fakes recompute K/V from the tokens actually fed,
         # so a silent prefill skip would change their outputs.
-        can_share = prefix_cache and callable(getattr(backend, "copy_page", None))
+        can_share = (
+            prefix_cache and self.kv_whole_row
+            and callable(getattr(backend, "copy_page", None))
+        )
         self.prefix: Optional[PrefixCache] = (
             PrefixCache(self.allocator, metrics=metrics)
             if can_share else None
@@ -425,6 +458,13 @@ class ServingEngine:
                 f"request needs {footprint} KV pages; cache holds "
                 f"{self.allocator.capacity}"
             )
+        if self.window_allocator is not None and (
+            self._ring_for(total) > self.window_allocator.capacity
+        ):
+            raise ValueError(
+                f"request needs {self._ring_for(total)} window-layer KV pages; "
+                f"cache holds {self.window_allocator.capacity}"
+            )
         sess = _Session(
             job_id=job_id, req=gen,
             future=asyncio.get_running_loop().create_future(),
@@ -541,6 +581,11 @@ class ServingEngine:
             self.metrics.serving_sessions.set(float(len(self._active)))
             self.metrics.serving_kv_pages_in_use.set(float(self.allocator.used_pages))
 
+    def _ring_for(self, n_tokens: int) -> int:
+        """Window-layer pages a session of ``n_tokens`` positions holds: its
+        whole row while that is shorter than the ring, the ring after."""
+        return min(self.ring_pages, self.allocator.pages_for(n_tokens))
+
     async def _admit(self) -> None:
         """Move pending sessions straight into the step loop while pages
         and session slots allow; FIFO so exhaustion delays but never
@@ -594,6 +639,19 @@ class ServingEngine:
                 pages = self._alloc_with_evict(
                     sess.job_id, footprint - len(shared), shared
                 )
+                if self.window_allocator is not None:
+                    # reserved per kind.  Only the whole-row kind can run
+                    # out: the backend's window pool holds a whole ring for
+                    # each of its ``max_seqs`` rows, ``max_sessions`` is at
+                    # most that, and a session holds at most one ring
+                    sess.window_pages = self.window_allocator.alloc(
+                        sess.job_id, self._ring_for(
+                            len(sess.req.prompt) + sess.req.max_new_tokens))
+                    # the allocators keep the peaks; the stats mirror them
+                    self.stats.kv_pages_held_full = (
+                        self.allocator.stats.peak_pages_in_use)
+                    self.stats.kv_pages_held_window = (
+                        self.window_allocator.stats.peak_pages_in_use)
             except CacheExhausted:
                 self.stats.admission_waits += 1
                 break  # head-of-line waits for a retirement to free pages
@@ -846,6 +904,8 @@ class ServingEngine:
             if self.tiering is not None and sess.req.session_key:
                 self.tiering.note_turn(sess.req.session_key, covered)
         self.allocator.free(sess.job_id)
+        if self.window_allocator is not None:
+            self.window_allocator.free(sess.job_id)
         self._active.pop(sess.job_id, None)
         if error is None:
             self.stats.retired += 1
@@ -1042,6 +1102,7 @@ class ServingEngine:
                 tokens=[sess.last_token, *plan], start=sess.pos,
                 pages=sess.pages, sample=True, phase="decode",
                 key=sess.job_id, draft=len(plan),
+                window_pages=sess.window_pages,
             ))
             rows.append((sess, 1 + len(plan), True, plan))
             budget -= 1 + len(plan)
@@ -1075,7 +1136,7 @@ class ServingEngine:
                 tokens=seq[sess.prefill_pos:sess.prefill_pos + chunk],
                 start=sess.prefill_pos, pages=sess.pages,
                 sample=samples, phase="prefill",
-                key=sess.job_id,
+                key=sess.job_id, window_pages=sess.window_pages,
             ))
             rows.append((sess, chunk, samples, []))
             budget -= chunk
@@ -1183,6 +1244,7 @@ class ServingEngine:
         step_accepted = 0
         emits = []
         retires = []
+        pos_before = [sess.pos for sess, _, _, _ in rows] if self.ring_pages else []
         for (sess, chunk, samples, drafted), tok in zip(rows, results):
             if sess.ttft is not None:
                 sess.ttft.steps += 1
@@ -1351,10 +1413,37 @@ class ServingEngine:
         }
         if of:
             attrs["kv_blocks"] = f"{walked}/{of}"
+        if self.ring_pages:
+            attrs["window_blocks"] = str(self._count_window(rows, pos_before))
+        counters = getattr(self.backend, "last_counters", None)
+        if counters:
+            # what the model family's program counted this step, named by
+            # the family (``ModelSpec.count_aux``): the expert layer's four
+            for name, n in counters.items():
+                setattr(self.stats, name, getattr(self.stats, name) + n)
+            attrs["moe_here"] = str(counters["moe_assignments_here"])
+            attrs["moe_touched"] = str(counters["moe_experts_touched"])
         if self.speculative:
             attrs["drafted"] = str(step_drafted)
             attrs["accepted"] = str(step_accepted)
         return attrs
+
+    def _count_window(
+        self, rows: list[tuple[_Session, int, bool, list[int]]], pos_before: list[int],
+    ) -> int:
+        """One step's part of the window counters of ``ServingStats`` (a
+        model with window layers); returns the blocks its window layers'
+        walk read."""
+        st = self.stats
+        ps, ring = self.allocator.page_size, self.ring_pages
+        for (sess, _, _, _), before in zip(rows, pos_before):
+            # logical pages first written this step, of those past the ring
+            new = -(-sess.pos // ps) - max(-(-before // ps), ring)
+            if new > 0:
+                st.window_pages_reused += new
+        blocks = int(getattr(self.backend, "last_window_blocks", 0))
+        st.window_blocks_walked += blocks
+        return blocks
 
     # ------------------------------------------------------------------
     # live migration (serving/migration.py, docs/SERVING.md §Migration,
@@ -1379,6 +1468,8 @@ class ServingEngine:
         resumes prefill on the target).  Drain uses :meth:`session_ids`
         instead and ignores immunity (a draining worker must move
         everything)."""
+        if not self.kv_whole_row:
+            return []  # its pages cannot be shipped: nothing is movable
         now = time.monotonic()
         cands = [
             s for s in self._active.values()
@@ -1392,7 +1483,9 @@ class ServingEngine:
         """The session's immutable metadata (the migration hello frame);
         None when it is not actively decoding here."""
         sess = self._active.get(job_id)
-        if sess is None or sess.cancelled:
+        if sess is None or sess.cancelled or not self.kv_whole_row:
+            # a model with window layers is never offered for migration:
+            # the drain falls back to a scheduler requeue (re-prefill)
             return None
         req = sess.req
         return {
@@ -1427,6 +1520,7 @@ class ServingEngine:
         """Page records covering positions ``[start_tok, end_tok)`` at
         their true lengths (backends without an arena export nothing — the
         receiver rebuilds from the metadata via ``restore_session``)."""
+        require_whole_row(self.kv_whole_row, "page export (migration, hibernation)")
         sess = self._active.get(job_id)
         fn = getattr(self.backend, "export_kv", None)
         if sess is None or fn is None:
@@ -1474,6 +1568,7 @@ class ServingEngine:
         :meth:`restore_hibernated` later owns the token stream and the
         terminal result.  False when the session is not live here (or
         tiering is disabled)."""
+        require_whole_row(self.kv_whole_row, "hibernation")
         if self.tiering is None:
             return False
         meta = self.describe_session(job_id)
@@ -1577,6 +1672,7 @@ class ServingEngine:
         future (token list).  ``origin="hibernate"`` (the
         :meth:`restore_hibernated` path) books the adoption under the
         hibernate counters instead of the migration ones."""
+        require_whole_row(self.kv_whole_row, "adopting a migrated or hibernated session")
         if self._closed:
             raise RuntimeError("serving engine is stopped")
         if job_id in self._active or any(

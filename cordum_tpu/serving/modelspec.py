@@ -1,0 +1,84 @@
+"""The model seam of the serving path (docs/SERVING.md §Model seam).
+
+:class:`~cordum_tpu.serving.backend.ServingBackend` knows no model family:
+it packs host arrays, calls ONE jitted program and keeps what the program
+returns.  A :class:`ModelSpec` supplies the rest — the weights' init, the
+page arenas, the program — and states what the family's cache can do.
+
+The program's signature is ``(params, *arenas, tokens, positions, *tables,
+token_seq, out_idx) -> (out, *arenas)``: one int32 page table ``[S+1,
+width]`` per KIND of page (the whole-row kind first; a family with window
+layers adds the ring kind), an arena pair per kind, and ``out`` int32
+``[T + prod(aux_shape)]`` — the per-slot next tokens, then whatever counters
+the family returns in the same transfer.  ``make_ragged_program`` wraps it
+under the name the device trace is searched for.
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Callable, Optional
+
+
+class UnsupportedForModel(RuntimeError):
+    """The serving feature cannot run this model family (it assumes that
+    every layer's pages cover the whole row under one table): refused
+    loudly, never served wrong."""
+
+
+def require_whole_row(whole_row: bool, feature: str) -> None:
+    """Refuse ``feature`` for a model whose window layers live in page rings."""
+    if not whole_row:
+        raise UnsupportedForModel(
+            f"{feature} needs whole-row KV pages; this model keeps its window "
+            "layers in page rings (ModelSpec.window)")
+
+
+@dataclass(frozen=True)
+class ModelSpec:
+    family: str
+    cfg: Any
+    vocab_size: int
+    max_seq_len: int
+    #: ``(key) -> params``
+    init_params: Callable[[Any], Any]
+    #: ``(num_pages, page_size, window_pages) -> arenas`` (a K, V pair per kind)
+    init_arenas: Callable[[int, int, int], tuple]
+    #: ``(sample_logits) -> ragged_program``, the function that is jitted:
+    #: ``(params, *arenas, tokens, positions, *tables, token_seq, out_idx)
+    #: -> (out, *arenas)`` with its arguments spelled out
+    program: Callable[[bool], Callable[..., tuple]]
+    #: the window of the family's window layers; None when every layer sees
+    #: the whole row.  THE capability: with a window, a sequence's pages are
+    #: of two kinds (whole-row tables and bounded rings), and what assumes
+    #: one kind — prefix sharing, hibernation, live migration, the
+    #: tensor-parallel gang — refuses the family (:meth:`require_whole_row`)
+    window: Optional[int] = None
+    #: shape of the int32 counters behind the tokens in ``out``
+    aux_shape: tuple[int, ...] = ()
+    #: ``(aux, live_tokens) -> {ServingStats field: this step's addend}``:
+    #: the family names what its counters count, once, for every reader
+    count_aux: Optional[Callable[[Any, int], dict[str, int]]] = None
+
+    @property
+    def kv_whole_row(self) -> bool:
+        return self.window is None
+
+    @property
+    def n_arenas(self) -> int:
+        """Arena arrays the program takes and returns: a K, V pair per kind."""
+        return 2 if self.window is None else 4
+
+    def require_whole_row(self, feature: str) -> None:
+        require_whole_row(self.kv_whole_row, feature)
+
+
+def spec_for(model: Any) -> ModelSpec:
+    """A :class:`ModelSpec` from what a caller hands the backend: a spec, or
+    a family's config object, which knows its own (``serving_spec()``: each
+    family module exports its specification; this module imports no model)."""
+    if isinstance(model, ModelSpec):
+        return model
+    make = getattr(model, "serving_spec", None)
+    if make is None:
+        raise TypeError(f"no serving model specification for {type(model).__name__}")
+    return make()

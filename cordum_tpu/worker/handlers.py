@@ -421,31 +421,38 @@ def make_serving_engine(
     speculative: bool = True,
     draft_k: int = 0,
     cold_tier: str = "",
+    model=None,
+    params=None,
     metrics=None,
 ):
     """Build the worker's continuous-batching serving engine over a paged
-    Llama backend that shares ``compute``'s model params (one copy of the
-    weights per worker process; the KV page arena is the serving addition).
+    backend.  By default it serves ``compute``'s llama model and shares its
+    params (one copy of the weights per worker process; the KV page arena is
+    the serving addition).  ``model`` — a ``serving.modelspec.ModelSpec`` or
+    a family's config object — serves another model instead, with ``params``
+    as its weights (seeded random ones when None).
 
     The backend's static ragged-step shapes are sized here: ``max_sessions``
     sequence rows over a flat token buffer of ``max_sessions +
     prefill_budget`` slots, so a full decode set always fits and prefill
     chunks ride the remaining ``prefill_budget`` tokens per step.
     """
-    from ..serving.backend import LlamaServingBackend
+    from ..serving.backend import ServingBackend
     from ..serving.engine import ServingEngine
 
     def params_provider():
         compute._ensure_llama()
         return compute._llama_params
 
-    backend = LlamaServingBackend(
-        compute.llama_cfg,
+    backend = ServingBackend(
+        model if model is not None else compute.llama_cfg,
         num_pages=cache_pages,
         page_size=page_size,
         max_seqs=max_sessions,
         max_batch_tokens=max_sessions + max(1, prefill_budget),
-        params_provider=params_provider,
+        params=params,
+        # another model's weights are never the compute's llama ones
+        params_provider=params_provider if model is None else None,
         metrics=metrics,
     )
     engine = ServingEngine(
@@ -494,6 +501,8 @@ def attach_default_tpu_worker(
     serving_speculative: bool = True,
     serving_draft_k: int = 0,
     serving_cold_tier: str = "",
+    serving_model=None,
+    serving_params=None,
     gang: bool = True,
     gang_rendezvous_timeout_s: float = 10.0,
     gang_peer_timeout_s: float = 30.0,
@@ -524,6 +533,7 @@ def attach_default_tpu_worker(
             speculative=serving_speculative,
             draft_k=serving_draft_k,
             cold_tier=serving_cold_tier,
+            model=serving_model, params=serving_params,
             metrics=metrics,
         ))
     if gang:
