@@ -756,14 +756,9 @@ async def phase_tp4(args, sz: dict) -> dict:
             in_use, state))
 
     # the collectives a Megatron layout needs are in the compiled program
-    ps = be.pages_per_seq
-    s_rows, t_buf = be.max_seqs, be.max_batch_tokens
-    i32 = np.int32
     text = be._ragged_jit.lower(
         be._params, be._k_pages, be._v_pages,
-        np.zeros((t_buf,), i32), np.zeros((t_buf,), i32),
-        np.zeros((s_rows + 1, ps), i32), np.zeros((t_buf,), i32),
-        np.zeros((s_rows,), i32)).compile().as_text()
+        np.zeros((be.feed_layout.size,), np.int32)).compile().as_text()
     collectives = {op: text.count(f" {op}(") + text.count(f" {op}-start(")
                    for op in ("all-reduce", "all-gather", "reduce-scatter",
                               "collective-permute", "all-to-all")}

@@ -14,6 +14,9 @@ through :meth:`step` with the same static operand shapes:
     (the +1 row is the all-null padding row), per-token sequence ids and
     positions, and each sequence's sampling index.
 
+All of them travel as ONE packed int32 host vector (:class:`FeedLayout`):
+one transfer in, one call, and one transfer out, started at dispatch.
+
 Because the shapes never change, XLA compiles exactly **one** program —
 there is no prompt-length bucket ladder, no pow2 batch buckets, and no
 recompile cliff when sessions join or leave (the Ragged Paged Attention
@@ -97,20 +100,62 @@ class StepEntry:
     window_pages: list[int] = field(default_factory=list)
 
 
-def make_ragged_program(model: Any, *, sample_logits: bool, donate: bool) -> Any:
+@dataclass(frozen=True)
+class FeedLayout:
+    """Where a step's integer operands lie in ONE int32 vector, fixed by the
+    backend's static shapes: ``[tokens T | positions T | token_seq T |
+    out_idx S | table of kind 0 (S+1) x P0 | table of kind 1 (S+1) x P1
+    ...]``.  The host packs into views of the vector (:meth:`split` of a
+    numpy array) and the jitted program takes it apart again (:meth:`split`
+    of the traced operand: static slices and reshapes, free on the device),
+    so both sides read the layout from here."""
+
+    tokens: int  # T: slots of the flat token buffer
+    seqs: int  # S: sequence rows (a table has one more, the padding row)
+    table_widths: tuple[int, ...]  # pages a row, per kind of page
+
+    @property
+    def size(self) -> int:
+        return 3 * self.tokens + self.seqs + (self.seqs + 1) * sum(self.table_widths)
+
+    def split(self, feed: Any) -> tuple[Any, Any, Any, Any, list]:
+        """``(tokens, positions, token_seq, out_idx, tables)`` of ``feed``
+        (int32 ``[size]``, numpy or traced): views, not copies."""
+        t, rows = self.tokens, self.seqs + 1
+        lo = 3 * t + self.seqs
+        tables = []
+        for width in self.table_widths:
+            tables.append(feed[lo:lo + rows * width].reshape(rows, width))
+            lo += rows * width
+        return feed[:t], feed[t:2 * t], feed[2 * t:3 * t], feed[3 * t:3 * t + self.seqs], tables
+
+
+def make_ragged_program(
+    model: Any, layout: FeedLayout, *, sample_logits: bool, donate: bool
+) -> Any:
     """The ONE jitted serving program of ``model`` (a ``ModelSpec`` or a
-    family's config): its ``ragged_program`` over ``(params, *arenas,
-    tokens, positions, *page tables, token_seq, out_idx)`` — for the llama
-    family ``llama.ragged_step`` over two arenas and one table.  With
-    ``donate`` the arenas are donated, so the in-place page writes never
-    copy an arena."""
+    family's config): ``(params, *arenas, feed) -> (out, *arenas)``.  ``feed``
+    is the step's packed int32 vector; inside the jit it is taken apart by
+    ``layout`` and handed to the family's ``ragged_program`` as the operands
+    that one has always taken, ``(params, *arenas, tokens, positions, *page
+    tables, token_seq, out_idx)`` — for the llama family ``llama.ragged_step``
+    over two arenas and one table.  With ``donate`` the arenas are donated,
+    so the in-place page writes never copy an arena."""
     import jax
 
     from .modelspec import spec_for
 
     spec = spec_for(model)
+    family = spec.program(sample_logits)
+
+    # the name the device trace and chip_smoke's compile log are searched for
+    def ragged_program(params, *arenas_feed):
+        *arenas, feed = arenas_feed
+        tokens, positions, token_seq, out_idx, tables = layout.split(feed)
+        return family(params, *arenas, tokens, positions, *tables, token_seq, out_idx)
+
     return jax.jit(
-        spec.program(sample_logits),
+        ragged_program,
         donate_argnums=tuple(range(1, 1 + spec.n_arenas)) if donate else (),
     )
 
@@ -178,6 +223,12 @@ class ServingBackend:
         )
         self.num_window_pages = (
             self.max_seqs * self.ring_pages + 1 if self.window else 0
+        )
+        # how the step's integer operands are packed into one host vector
+        self.feed_layout = FeedLayout(
+            self.max_batch_tokens, self.max_seqs,
+            (self.pages_per_seq, self.ring_pages) if self.window
+            else (self.pages_per_seq,),
         )
         self._seed = seed
         # the weights: ``params`` as given, else what ``params_provider``
@@ -261,7 +312,7 @@ class ServingBackend:
         # donate the page arenas on real accelerators so the in-place
         # update never copies the arena; CPU jax spams donation warnings
         self._ragged_jit = make_ragged_program(
-            self.spec, sample_logits=bool(self.sample_output),
+            self.spec, self.feed_layout, sample_logits=bool(self.sample_output),
             donate=jax.default_backend() != "cpu",
         )
 
@@ -301,8 +352,6 @@ class ServingBackend:
         one next-token argmax per fed position) for draft verification
         rows (``entry.draft > 0``).  Blocking; call from an executor
         thread."""
-        import jax.numpy as jnp
-
         marks = [time.time_ns()]
         n_step = self._steps_done
         self._ensure()
@@ -321,15 +370,13 @@ class ServingBackend:
                 f"{t_buf}"
             )
         with step_phase("pack", n_step, marks):
-            tokens = np.zeros((t_buf,), np.int32)
-            positions = np.zeros((t_buf,), np.int32)
+            # one vector a step (never reused: the transfer may still read
+            # it when the call returns), packed through its views
+            feed = np.zeros((self.feed_layout.size,), np.int32)
+            tokens, positions, token_seq, out_idx, tables = self.feed_layout.split(feed)
             # padding tokens map to the padding row (all null pages): their
             # writes land on page 0 and no live sequence's gather can see them
-            token_seq = np.full((t_buf,), s_rows, np.int32)
-            tables = [np.zeros((s_rows + 1, self.pages_per_seq), np.int32)]
-            if self.window:
-                tables.append(np.zeros((s_rows + 1, self.ring_pages), np.int32))
-            out_idx = np.zeros((s_rows,), np.int32)
+            token_seq[:] = s_rows
             ti = 0
             longest = 1  # positions of the longest row (padding sits at 0)
             spans: list[tuple[int, int]] = []  # entry i's [lo, hi) buffer slots
@@ -368,12 +415,12 @@ class ServingBackend:
             # shows there; the lock is held until the result is on the host
             with step_phase("dispatch", n_step, marks):
                 held.enter_context(self._dev_lock)
+                # numpy straight into the call: its one transfer rides the
+                # call's own path; the result's copy back starts now, so
+                # ``wait`` finds it on the host when the program ends
                 nxt, *self._arenas = self._ragged_jit(
-                    self._params, *self._arenas,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    *(jnp.asarray(tb) for tb in tables), jnp.asarray(token_seq),
-                    jnp.asarray(out_idx),
-                )
+                    self._params, *self._arenas, feed)
+                nxt.copy_to_host_async()
             with step_phase("wait", n_step, marks):
                 out = np.asarray(nxt)
         # out is [T] per-position predictions: a sampled entry's token is

@@ -10,8 +10,13 @@ token_seq, out_idx) -> (out, *arenas)``: one int32 page table ``[S+1,
 width]`` per KIND of page (the whole-row kind first; a family with window
 layers adds the ring kind), an arena pair per kind, and ``out`` int32
 ``[T + prod(aux_shape)]`` — the per-slot next tokens, then whatever counters
-the family returns in the same transfer.  ``make_ragged_program`` wraps it
-under the name the device trace is searched for.
+the family returns in the same transfer.  The backend does not call it with
+those operands one by one: ``make_ragged_program`` wraps it as ``(params,
+*arenas, feed) -> (out, *arenas)`` under the name the device trace is
+searched for, where ``feed`` is the step's ONE packed int32 vector
+(``backend.FeedLayout``: ``[tokens T | positions T | token_seq T | out_idx
+S | a table per kind]``), taken apart inside the jit.  The signature a
+family writes is the one above, unchanged.
 """
 from __future__ import annotations
 
@@ -45,7 +50,8 @@ class ModelSpec:
     init_arenas: Callable[[int, int, int], tuple]
     #: ``(sample_logits) -> ragged_program``, the function that is jitted:
     #: ``(params, *arenas, tokens, positions, *tables, token_seq, out_idx)
-    #: -> (out, *arenas)`` with its arguments spelled out
+    #: -> (out, *arenas)`` with its arguments spelled out (the wrapper
+    #: unpacks the step's packed feed into them)
     program: Callable[[bool], Callable[..., tuple]]
     #: the window of the family's window layers; None when every layer sees
     #: the whole row.  THE capability: with a window, a sequence's pages are

@@ -21,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 import chip_smoke
 from cordum_tpu.models import embedder, llama
-from cordum_tpu.serving.backend import make_ragged_program
+from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
 from cordum_tpu.worker.handlers import make_matmul_program
 
 SZ = chip_smoke.REAL
@@ -87,14 +87,10 @@ def serving_shapes(cfg, num_pages, max_seq_len, params_sharding, arena_sharding,
         (cfg.n_layers, num_pages, SZ["page_size"], cfg.n_kv_heads, cfg.head_dim),
         cfg.dtype, sharding=arena_sharding)
     s_rows = SZ["max_sessions"]
-    t_buf = s_rows + SZ["prefill_budget"]
-    pages_per_seq = -(-max_seq_len // SZ["page_size"])
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=small)
-
-    return params, arena, (i32(t_buf), i32(t_buf), i32(s_rows + 1, pages_per_seq),
-                           i32(t_buf), i32(s_rows))
+    layout = FeedLayout(s_rows + SZ["prefill_budget"], s_rows,
+                        (-(-max_seq_len // SZ["page_size"]),))
+    feed = jax.ShapeDtypeStruct((layout.size,), jnp.int32, sharding=small)
+    return params, arena, layout, feed
 
 
 def test_widths_are_the_published_ones():
@@ -107,10 +103,10 @@ def test_widths_are_the_published_ones():
 @pytest.mark.parametrize("sample_logits", [True, False])
 def test_ragged_step_fits_one_chip(one_chip, sample_logits):
     cfg = smoke_cfg()
-    params, arena, meta = serving_shapes(
+    params, arena, layout, feed = serving_shapes(
         cfg, SZ["pages"], cfg.max_seq_len, one_chip, one_chip, one_chip)
-    program = make_ragged_program(cfg, sample_logits=sample_logits, donate=True)
-    compiled = program.lower(params, arena, arena, *meta).compile()
+    program = make_ragged_program(cfg, layout, sample_logits=sample_logits, donate=True)
+    compiled = program.lower(params, arena, arena, feed).compile()
     ma = compiled.memory_analysis()
     arena_bytes = arena.size * arena.dtype.itemsize
     # donation is real: both arenas alias onto the outputs
@@ -141,7 +137,7 @@ def test_page_programs_compile_on_the_real_arena(one_chip, name):
 
 def test_reference_forward_fits_beside_the_serving_state(one_chip):
     cfg = smoke_cfg()
-    params, arena, _ = serving_shapes(
+    params, arena, _, _ = serving_shapes(
         cfg, SZ["pages"], cfg.max_seq_len, one_chip, one_chip, one_chip)
     tokens = jax.ShapeDtypeStruct((1, SZ["ref_len"]), jnp.int32, sharding=one_chip)
     compiled = jax.jit(lambda p, t: llama.forward(p, t, cfg)).lower(params, tokens).compile()
@@ -183,11 +179,11 @@ def test_sharded_ragged_step_full_depth_on_four_chips(topo):
 
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), (AXIS_DP, AXIS_TP))
     pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), llama.param_specs(cfg))
-    params, arena, meta = serving_shapes(
+    params, arena, layout, feed = serving_shapes(
         cfg, SZ["tp_pages"], cfg.max_seq_len, pshard,
         NamedSharding(mesh, llama.KV_ARENA_SPEC), NamedSharding(mesh, P()))
-    program = make_ragged_program(cfg, sample_logits=True, donate=True)
-    compiled = program.lower(params, arena, arena, *meta).compile()
+    program = make_ragged_program(cfg, layout, sample_logits=True, donate=True)
+    compiled = program.lower(params, arena, arena, feed).compile()
     weight_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     assert weight_bytes > HBM_BYTES * 0.9  # one chip could not hold it
     per_device = device_bytes(compiled)
