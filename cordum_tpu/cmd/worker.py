@@ -65,6 +65,7 @@ from ..infra.memstore import MemoryStore
 from ..infra.metrics import Metrics
 from ..obs.profiler import RuntimeProfiler
 from ..obs.telemetry import TelemetryExporter
+from ..serving.tiering import StatebusColdTier
 from ..worker.handlers import attach_default_tpu_worker
 from ..worker.runtime import Worker
 from . import _boot
@@ -183,10 +184,9 @@ async def main() -> None:
     await worker.start()
     # statebus-backed cold tier: re-populate the mirror from the journal
     # so sessions hibernated before a restart are restorable here
-    tiering = getattr(worker._serving, "tiering", None)
-    arena = getattr(tiering, "arena", None)
-    if callable(getattr(arena, "load", None)):
-        await arena.load()
+    tiering = worker.serving.tiering if worker.serving is not None else None
+    if tiering is not None and isinstance(tiering.arena, StatebusColdTier):
+        await tiering.arena.load()
     await telemetry.start()
     await profiler.start()
     # SIGTERM drains by default (live-migrate sessions, finish jobs, exit);

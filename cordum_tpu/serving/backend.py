@@ -39,7 +39,8 @@ import sys
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Iterator, Optional
+from types import MappingProxyType
+from typing import Any, Callable, Iterator, Mapping, Optional
 
 import numpy as np
 
@@ -98,6 +99,75 @@ class StepEntry:
     draft: int = 0  # >0: speculative row with this many drafted tokens
     # the session's ring of window-layer pages (a model with window layers)
     window_pages: list[int] = field(default_factory=list)
+
+
+class StepBackend:
+    """What runs a :class:`~cordum_tpu.serving.engine.ServingEngine`'s step:
+    the one contract between the engine and a backend (docs/SERVING.md
+    §Backend contract).  ``ServingBackend`` (and through it
+    ``ShardedServingBackend``), ``ServingGangGroup`` and the tests' fake
+    inherit it; the engine reads these members and asks for nothing else.
+    The values here are what a backend without the feature reports."""
+
+    # the static shapes, fixed at construction
+    SHAPES = ("num_pages", "page_size", "max_context", "max_seqs",
+              "max_batch_tokens", "ring_pages", "num_window_pages")
+    num_pages: int
+    page_size: int
+    max_context: int
+    max_seqs: int
+    max_batch_tokens: int
+    ring_pages: int = 0  # a window layer's ring, pages a sequence
+    num_window_pages: int = 0  # that kind's pool
+    # the one capability: every layer's pages cover the whole row under one
+    # table — what prefix sharing, hibernation, migration and the gang
+    # assume.  False for a model with window layers (``ModelSpec.window``).
+    kv_whole_row: bool = True
+    # the latest step's report, written by ``step`` and read by the engine
+    # after the call
+    REPORT = ("last_step_compiled", "last_phases", "last_attn_blocks",
+              "last_window_blocks", "last_counters")
+    last_step_compiled: bool = False  # did it pay XLA?
+    # its boundaries, ns: (entry, arrays packed, program dispatched, result
+    # on the host, return) — the engine splits its step cycle by them
+    last_phases: tuple[int, ...] = ()
+    # the attention walk: (blocks read, blocks a page table holds); the
+    # window layers' blocks read; what the family's program counted, under
+    # the ``ServingStats`` names it adds to
+    last_attn_blocks: tuple[int, int] = (0, 0)
+    last_window_blocks: int = 0
+    last_counters: Mapping[str, int] = MappingProxyType({})
+    # observation tap: called with the entry list after every successful
+    # step — the serving-gang leader broadcasts it so followers replay the
+    # identical program against their head shards
+    on_step: Optional[Callable[[list["StepEntry"]], None]] = None
+
+    def stamp_whole_call(self, t0: int) -> None:
+        """``last_phases`` of a step with no pack, dispatch or unpack of its
+        own, entered at ``t0`` (``time.time_ns()``) and returning now: five
+        ordered marks that read as one ``wait``."""
+        t1 = max(t0, time.time_ns())
+        self.last_phases = (t0, t0, t0, t1, t1)
+
+    def step(self, entries: list[StepEntry]) -> list[Any]:
+        """One mixed prefill+decode call.  One value per entry, aligned:
+        the next token (``int``) of a sampled entry, ``None`` for a prefill
+        chunk that does not complete its prompt, one prediction per fed
+        position (``list[int]``) for a draft row.  Blocking."""
+        raise NotImplementedError
+
+    def copy_page(self, src: int, dst: int) -> None:
+        """Duplicate physical page ``src`` into ``dst``.  Blocking."""
+        raise NotImplementedError
+
+    def export_kv(self, pages: list[int], start_tok: int, end_tok: int) -> list[dict]:
+        """Records of the pages of ``pages`` that cover positions
+        ``[start_tok, end_tok)``, ``"i"`` the page's ordinal.  Blocking."""
+        raise NotImplementedError
+
+    def import_kv(self, pages: list[int], records: list[dict]) -> None:
+        """Scatter exported records into ``pages``.  Blocking."""
+        raise NotImplementedError
 
 
 @dataclass(frozen=True)
@@ -160,20 +230,11 @@ def make_ragged_program(
     )
 
 
-class ServingBackend:
-    # the ragged program returns per-position predictions for every buffer
-    # row, so draft verification rows (StepEntry.draft > 0) are supported
-    # natively — the engine gates its drafter on this capability flag
-    # (test fakes without it keep the legacy single-sample step contract)
-    supports_draft = True
+class ServingBackend(StepBackend):
     # sharded serving (serving/shard.py): follower ranks set this False and
     # compile a program whose lm_head is dead-code-eliminated — rank 0
     # alone pays sampling (docs/SERVING.md §Sharded serving)
     sample_output = True
-    # observation tap: called with the entry list after every successful
-    # step — the serving-gang leader broadcasts it so followers replay the
-    # identical program against their head shards
-    on_step: Optional[Callable[[list["StepEntry"]], None]] = None
 
     def __init__(
         self,
@@ -217,6 +278,7 @@ class ServingBackend:
         # (so a free sequence row always finds its ring); 0 and 0 without a
         # window
         self.window = self.spec.window
+        self.kv_whole_row = self.spec.kv_whole_row
         self.ring_pages = (
             llama.window_ring_pages(self.window, self.page_size, self.max_batch_tokens)
             if self.window else 0
@@ -243,26 +305,17 @@ class ServingBackend:
         self._ragged_jit: Any = None
         self._compiled_shapes: set = set()  # observability: program count
         self._metrics = metrics
-        self.last_step_compiled = False  # did the latest step() pay XLA?
-        # the latest step()'s boundaries, ns: (entry, arrays packed, program
-        # dispatched, result on the host, return) — the engine reads them
-        # after the call to split its step cycle into phases
-        self.last_phases: tuple[int, ...] = ()
-        # the latest step()'s attention walk: (blocks read, blocks a page
-        # table holds) — ``llama.paged_attention`` stops at the block of the
-        # step's longest live row, which the host knows from the entries it
-        # packs
+        # ``last_attn_blocks``: ``llama.paged_attention`` stops at the block
+        # of the step's longest live row, which the host knows from the
+        # entries it packs
         bp = llama.attn_block_pages(self.page_size, self.pages_per_seq)
         self._attn_block_tokens = bp * self.page_size
         self._attn_blocks_total = -(-self.pages_per_seq // bp)
-        self.last_attn_blocks: tuple[int, int] = (0, 0)
-        # the same of the window layers' walk (blocks read; 0 with no
-        # window); the counters the program returned behind the tokens
-        # (``spec.aux_shape``; None where the family returns none) and what
-        # the family says they add to ``ServingStats`` (``spec.count_aux``)
-        self.last_window_blocks = 0
+        # the counters the program returned behind the tokens
+        # (``spec.aux_shape``; None where the family returns none);
+        # ``last_counters`` is what the family says they add to
+        # ``ServingStats`` (``spec.count_aux``)
         self.last_aux: Any = None
-        self.last_counters: dict[str, int] = {}
         self._steps_done = 0  # numbers the host annotations
         # page-arena mutation lock: steps read-modify-write the K/V arrays
         # from executor threads
@@ -330,13 +383,6 @@ class ServingBackend:
 
     def compiled_programs(self) -> int:
         return len(self._compiled_shapes)
-
-    @property
-    def kv_whole_row(self) -> bool:
-        """Every layer's pages cover the whole row under one table — what
-        prefix sharing, hibernation, migration and the gang assume.  False
-        for a model with window layers (``ModelSpec.window``)."""
-        return self.spec.kv_whole_row
 
     def _clamp(self, row: list[int]) -> list[int]:
         vmax = self.spec.vocab_size - 1

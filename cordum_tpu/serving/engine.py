@@ -41,7 +41,7 @@ from ..obs.tracer import Tracer
 from ..protocol.types import OP_SERVING_PREFILL, SPAN_ERROR, Span
 from ..utils.eager import eager
 from ..utils.ids import fast_id
-from .backend import STEP_PHASES, StepEntry, step_phase
+from .backend import STEP_PHASES, StepBackend, StepEntry, step_phase
 from .modelspec import require_whole_row
 from .pager import CacheExhausted, PageAllocator
 from .prefixcache import PrefixCache, PrefixNode
@@ -261,7 +261,7 @@ class ServingEngine:
 
     def __init__(
         self,
-        backend: Any,
+        backend: StepBackend,
         *,
         run_blocking: Callable[..., Awaitable[Any]],
         max_sessions: int = DEFAULT_MAX_SESSIONS,
@@ -304,46 +304,33 @@ class ServingEngine:
         # the backend's static page-table width caps a session's lifetime
         # footprint; anything longer must be rejected at submit (the arena
         # may hold far more pages than one table row can address)
-        self.max_context = int(getattr(backend, "max_context", 0) or 0)
+        self.max_context = backend.max_context
         # the flat token buffer bounds decode rows + prefill chunk tokens
         # per step; every admitted session must at least fit a decode row
-        self.step_tokens = int(
-            getattr(backend, "max_batch_tokens", 0) or 2 * self.max_sessions
-        )
+        self.step_tokens = backend.max_batch_tokens
         self.max_sessions = min(
-            self.max_sessions,
-            int(getattr(backend, "max_seqs", 0) or self.max_sessions),
-            self.step_tokens,
+            self.max_sessions, backend.max_seqs, self.step_tokens
         )
         self.allocator = PageAllocator(backend.num_pages, backend.page_size)
         # a model with window layers keeps a second kind of page, in a ring
         # of ``ring_pages`` per session: its own pool, reservations and
         # refcounts (docs/SERVING.md §Two kinds of page)
-        self.ring_pages = int(getattr(backend, "ring_pages", 0) or 0)
+        self.ring_pages = backend.ring_pages
         self.window_allocator: Optional[PageAllocator] = (
             PageAllocator(backend.num_window_pages, backend.page_size)
             if self.ring_pages else None
         )
         # what assumes ONE kind of page per session — the prefix cache,
         # hibernation, live migration — is off for such a model
-        self.kv_whole_row = bool(getattr(backend, "kv_whole_row", True))
+        self.kv_whole_row = backend.kv_whole_row
         # prefix cache + session tiering (docs/SERVING.md §Prefix cache and
         # tiering): the radix index over cached full-page prefixes, and the
         # hibernate/restore machinery that tiers idle resident state to the
         # host-RAM cold arena.  hibernate_after_s <= 0 disables the sweep
         # (the cache still shares; pressure is handled by LRU eviction).
-        # Sharing also requires the backend's page-copy primitive (CoW):
-        # without one a shared page could never be duplicated on divergent
-        # write, so the cache is disabled outright rather than half-armed —
-        # arena-less test fakes recompute K/V from the tokens actually fed,
-        # so a silent prefill skip would change their outputs.
-        can_share = (
-            prefix_cache and self.kv_whole_row
-            and callable(getattr(backend, "copy_page", None))
-        )
         self.prefix: Optional[PrefixCache] = (
             PrefixCache(self.allocator, metrics=metrics)
-            if can_share else None
+            if prefix_cache and self.kv_whole_row else None
         )
         self.tiering: Optional[SessionTiering] = (
             SessionTiering(
@@ -357,13 +344,9 @@ class ServingEngine:
         # speculative decoding (docs/SERVING.md §Speculative decoding):
         # the self-speculative drafter proposes k tokens per decoding
         # session per step; verification rides the same ragged program as
-        # prefill-shaped draft rows with per-position sampling.  Gated on
-        # the backend's per-position prediction support — fakes and legacy
-        # backends without ``supports_draft`` keep the exact legacy step
-        # shape (byte-for-byte: no draft rows are ever assembled).
-        self.speculative = bool(speculative) and bool(
-            getattr(backend, "supports_draft", False)
-        )
+        # prefill-shaped draft rows with per-position sampling.  Off, no
+        # draft row is ever assembled.
+        self.speculative = bool(speculative)
         self.draft_k = max(1, int(draft_k or DEFAULT_DRAFT_K))
         self._drafter = drafter or self._ngram_draft
         # engine-level acceptance EWMA — the capacity block publishes it
@@ -443,7 +426,7 @@ class ServingEngine:
         if self._closed:
             raise RuntimeError("serving engine is stopped")
         total = len(gen.prompt) + gen.max_new_tokens
-        if self.max_context and total > self.max_context:
+        if total > self.max_context:
             # beyond the backend's static page-table width: prefill would
             # silently truncate and the session would poison its step —
             # fail this job alone, before it becomes a session
@@ -550,11 +533,9 @@ class ServingEngine:
 
     async def _export_prefix_page(self, page: int) -> Optional[dict]:
         """One full arena page as a PR 12 migration record — the tiering
-        sweep's export half (None = the backend has no arena to export)."""
-        fn = getattr(self.backend, "export_kv", None)
-        if fn is None:
-            return None
-        recs = await self.run_blocking(fn, [page], 0, self.allocator.page_size)
+        sweep's export half."""
+        recs = await self.run_blocking(
+            self.backend.export_kv, [page], 0, self.allocator.page_size)
         return recs[0] if recs else None
 
     def _on_loop_done(self, task: asyncio.Task) -> None:
@@ -723,21 +704,20 @@ class ServingEngine:
     ) -> list[PrefixNode]:
         """Re-warm the cold nodes on a matched path (hibernate restore):
         allocate a fresh page, scatter the host-RAM record back, promote.
-        The path truncates at the first node that cannot restore (no
-        import support, exhaustion even after eviction, or an eviction
-        racing the scatter).  The pause — what the turn waits before its
+        The path truncates at the first node that cannot restore
+        (exhaustion even after eviction, or an eviction racing the
+        scatter).  The pause — what the turn waits before its
         prefill can start — feeds
         ``cordum_serving_hibernate_pause_seconds``."""
         out: list[PrefixNode] = []
         t0 = None
-        imp = getattr(self.backend, "import_kv", None)
         for node in nodes:
             if node.dropped:
                 break
             if node.warm:
                 out.append(node)
                 continue
-            if imp is None or node.record is None or self.prefix is None:
+            if node.record is None or self.prefix is None:
                 break
             if t0 is None:
                 t0 = time.monotonic()
@@ -751,7 +731,8 @@ class ServingEngine:
                 except CacheExhausted:
                     break
             try:
-                await self.run_blocking(imp, [page], [dict(node.record, i=0)])
+                await self.run_blocking(
+                    self.backend.import_kv, [page], [dict(node.record, i=0)])
             except Exception as e:  # noqa: BLE001 - keep the record, skip the hit
                 self.allocator.release([page])
                 logx.warn("prefix restore failed", err=str(e))
@@ -831,11 +812,10 @@ class ServingEngine:
         backend's five lie between the middle two.  Six contiguous phases:
         the histogram sees every cycle, the flight recorder the kept ones."""
         c0, handed, returned, c1 = marks
-        ph = tuple(getattr(self.backend, "last_phases", ()))
-        bounds = (c0, *ph, c1)
-        if len(ph) != 5 or any(a > b for a, b in zip(bounds, bounds[1:])):
-            # a backend that stamps nothing (the fakes, the gang group):
-            # its whole call reads as ``wait``
+        bounds = (c0, *self.backend.last_phases, c1)
+        if any(a > b for a, b in zip(bounds, bounds[1:])):
+            # the stamps are wall-clock and the wall may step backwards:
+            # the whole call then reads as ``wait``, from the loop's own stamps
             bounds = (c0, handed, handed, handed, returned, returned, c1)
         if self.metrics is not None:
             for name, a, b in zip(STEP_PHASES, bounds, bounds[1:]):
@@ -1033,11 +1013,6 @@ class ServingEngine:
         other holder left) dropping the cache's reference may already
         make this session the sole owner — no copy, no fresh page."""
         old = sess.pages[idx]
-        copy = getattr(self.backend, "copy_page", None)
-        if copy is None:
-            # arena-less backends (test fakes) have no page contents to
-            # copy and no way to share them — nothing to protect
-            return True
         if self.allocator.free_pages < 1 and self.prefix is not None:
             self.prefix.drop_subtree(old)
             if self.allocator.refcount(old) <= 1:
@@ -1050,7 +1025,7 @@ class ServingEngine:
                 if self.allocator.refcount(old) <= 1:
                     return True
             return False
-        await self.run_blocking(copy, old, fresh)
+        await self.run_blocking(self.backend.copy_page, old, fresh)
         if sess.cancelled or sess.job_id not in self._active:
             self.allocator.release([fresh])  # retired during the copy
             return True
@@ -1365,8 +1340,7 @@ class ServingEngine:
             # and decode tokens/s are separately measurable — the
             # disaggregation policy's two placement signals
             # (docs/SERVING.md §Disaggregation)
-            compiled = bool(getattr(self.backend, "last_step_compiled",
-                                    False))
+            compiled = self.backend.last_step_compiled
             total_toks = generated + prefill_fed
             if prefill_fed:
                 self.capacity.observe(
@@ -1399,7 +1373,7 @@ class ServingEngine:
         if self.metrics is not None:
             self.metrics.serving_batch_occupancy.observe(float(len(rows)))
             self.metrics.serving_inter_token.observe(dt)
-        walked, of = getattr(self.backend, "last_attn_blocks", (0, 0))
+        walked, of = self.backend.last_attn_blocks
         self.stats.attn_blocks_walked += walked
         self.stats.attn_blocks_total += of
         attrs = {
@@ -1407,15 +1381,13 @@ class ServingEngine:
             "live_tokens": str(sum(chunk for _, chunk, _, _ in rows)),
             "prefill_tokens": str(prefill_fed),
             "retired": str(retired_this_step),
-            "compiled": str(bool(
-                getattr(self.backend, "last_step_compiled", False)
-            )).lower(),
+            "compiled": str(self.backend.last_step_compiled).lower(),
         }
         if of:
             attrs["kv_blocks"] = f"{walked}/{of}"
         if self.ring_pages:
             attrs["window_blocks"] = str(self._count_window(rows, pos_before))
-        counters = getattr(self.backend, "last_counters", None)
+        counters = self.backend.last_counters
         if counters:
             # what the model family's program counted this step, named by
             # the family (``ModelSpec.count_aux``): the expert layer's four
@@ -1441,7 +1413,7 @@ class ServingEngine:
             new = -(-sess.pos // ps) - max(-(-before // ps), ring)
             if new > 0:
                 st.window_pages_reused += new
-        blocks = int(getattr(self.backend, "last_window_blocks", 0))
+        blocks = self.backend.last_window_blocks
         st.window_blocks_walked += blocks
         return blocks
 
@@ -1518,14 +1490,13 @@ class ServingEngine:
         self, job_id: str, start_tok: int, end_tok: int
     ) -> list[dict]:
         """Page records covering positions ``[start_tok, end_tok)`` at
-        their true lengths (backends without an arena export nothing — the
-        receiver rebuilds from the metadata via ``restore_session``)."""
+        their true lengths."""
         require_whole_row(self.kv_whole_row, "page export (migration, hibernation)")
         sess = self._active.get(job_id)
-        fn = getattr(self.backend, "export_kv", None)
-        if sess is None or fn is None:
+        if sess is None:
             return []
-        return await self.run_blocking(fn, sess.pages, start_tok, end_tok)
+        return await self.run_blocking(
+            self.backend.export_kv, sess.pages, start_tok, end_tok)
 
     def freeze_session(self, job_id: str) -> bool:
         """Pause the session's decode (it sits out subsequent steps);
@@ -1680,7 +1651,7 @@ class ServingEngine:
         ):
             raise ValueError(f"session {job_id} already live on this worker")
         total = len(req.prompt) + req.max_new_tokens
-        if self.max_context and total > self.max_context:
+        if total > self.max_context:
             raise ValueError(
                 f"migrated session spans {total} tokens; backend max_context "
                 f"is {self.max_context}"
@@ -1691,9 +1662,8 @@ class ServingEngine:
             )
         pages = self.allocator.alloc(job_id, self.allocator.pages_for(total))
         try:
-            imp = getattr(self.backend, "import_kv", None)
-            if imp is not None and records:
-                await self.run_blocking(imp, pages, records)
+            if records:
+                await self.run_blocking(self.backend.import_kv, pages, records)
         except BaseException:
             self.allocator.free(job_id)
             raise
@@ -1714,11 +1684,6 @@ class ServingEngine:
         # a migrated-in session never re-fires the source's hand-off hook:
         # it is already where the policy put it
         sess.handoff_signaled = True
-        # arena-less backends (test fakes) rebuild their per-session decode
-        # state from the metadata instead of imported pages
-        restore = getattr(self.backend, "restore_session", None)
-        if restore is not None:
-            restore(job_id, sess.prefill_seq, sess.prefill_pos)
         self._active[job_id] = sess
         self.stats.admitted += 1
         if origin == "migration":

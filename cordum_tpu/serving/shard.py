@@ -33,11 +33,12 @@ backend (and vice versa) unchanged.
 from __future__ import annotations
 
 import threading
-from typing import Any, Callable, Optional
+import time
+from typing import Any, Optional
 
 import numpy as np
 
-from .backend import LlamaServingBackend, StepEntry
+from .backend import LlamaServingBackend, StepBackend, StepEntry
 
 __all__ = [
     "heads_for_rank",
@@ -233,11 +234,11 @@ class ShardedServingBackend(LlamaServingBackend):
         return [slice_rank_record(r, self.rank, self.tp, lo, hi) for r in records]
 
 
-class ServingGangGroup:
+class ServingGangGroup(StepBackend):
     """An in-process TP serving gang: rank 0 (the leader, sampling) plus
-    ``tp - 1`` followers, driven lock-step and quacking like a single
-    backend — the engine, bench ``--tp`` and the property suite use it
-    exactly where a :class:`LlamaServingBackend` goes.
+    ``tp - 1`` followers, driven lock-step as ONE backend — the engine,
+    bench ``--tp`` and the property suite use it exactly where a
+    :class:`LlamaServingBackend` goes.
 
     Every rank replays the identical entry batch, so the arenas stay in
     step by construction; step results come from the leader alone (the
@@ -246,9 +247,6 @@ class ServingGangGroup:
     ``_run_serving``) are this same loop with the follower ``step()`` calls
     shipped over the bus as ``GangMsg(kind="step")``.
     """
-
-    supports_draft = True
-    on_step: Optional[Callable[[list[StepEntry]], None]] = None
 
     def __init__(self, cfg: Any = None, *, tp: int = 2, metrics: Any = None,
                  **kw: Any) -> None:
@@ -264,50 +262,15 @@ class ServingGangGroup:
             for r in range(tp)
         ]
         self.tp = tp
+        self.leader = self.ranks[0]
+        self.cfg = self.leader.cfg
+        self._forward(self.SHAPES)
         self._lock = threading.Lock()
 
-    # -- backend facade ------------------------------------------------
-    @property
-    def leader(self) -> ShardedServingBackend:
-        return self.ranks[0]
-
-    @property
-    def cfg(self):
-        return self.leader.cfg
-
-    @property
-    def page_size(self) -> int:
-        return self.leader.page_size
-
-    @property
-    def num_pages(self) -> int:
-        return self.leader.num_pages
-
-    @property
-    def max_context(self) -> int:
-        return self.leader.max_context
-
-    @property
-    def pages_per_seq(self) -> int:
-        return self.leader.pages_per_seq
-
-    @property
-    def max_seqs(self) -> int:
-        return self.leader.max_seqs
-
-    @property
-    def max_batch_tokens(self) -> int:
-        return self.leader.max_batch_tokens
-
-    @property
-    def last_step_compiled(self) -> bool:
-        # any rank paying XLA makes the step a warmup step for the
-        # capacity observatory's steady-state filter
-        return any(r.last_step_compiled for r in self.ranks)
-
-    @property
-    def last_attn_blocks(self) -> tuple[int, int]:
-        return self.leader.last_attn_blocks
+    def _forward(self, names: tuple[str, ...]) -> None:
+        """The contract's members, the leader's."""
+        for name in names:
+            setattr(self, name, getattr(self.leader, name))
 
     def compiled_programs(self) -> int:
         return self.leader.compiled_programs()
@@ -317,12 +280,18 @@ class ServingGangGroup:
 
     # -- lock-step execution -------------------------------------------
     def step(self, entries: list[StepEntry]) -> list[Any]:
+        t0 = time.time_ns()
         with self._lock:
             res = self.leader.step(entries)
             for follower in self.ranks[1:]:
                 follower.step(entries)
+        self._forward(self.REPORT)
+        # any rank paying XLA makes the step a warmup step for the
+        # capacity observatory's steady-state filter
+        self.last_step_compiled = any(r.last_step_compiled for r in self.ranks)
         if self.on_step is not None:
             self.on_step(entries)
+        self.stamp_whole_call(t0)
         return res
 
     def export_kv(self, pages: list[int], start_tok: int, end_tok: int) -> list[dict]:

@@ -612,17 +612,14 @@ async def test_preempt_ignored_for_executing_job():
 async def test_serving_interactive_prefill_rides_before_batch():
     from cordum_tpu.serving.engine import GenRequest, ServingEngine, _Session
 
-    class StubBackend:
-        num_pages = 64
-        page_size = 16
-        max_context = 512
-        max_seqs = 8
-        max_batch_tokens = 8  # tight budget: one prefill chunk per step
+    from .fakes import FakeBackend
 
     async def run_blocking(fn, *a):
         return fn(*a)
 
-    eng = ServingEngine(StubBackend(), run_blocking=run_blocking,
+    be = FakeBackend(  # tight budget: one prefill chunk per step
+        num_pages=64, page_size=16, max_context=512, max_seqs=8, max_batch_tokens=8)
+    eng = ServingEngine(be, run_blocking=run_blocking,
                         max_concurrent_prefills=1)
     loop = asyncio.get_running_loop()
     # batch session admitted FIRST; both need prefill

@@ -499,7 +499,7 @@ async def test_serving_steps_feed_capacity_profiler():
     flat-buffer bucket — ONE row per worker, not a pow2 ladder — with the
     warmup compile flagged so steady-state tokens/s excludes it."""
     from cordum_tpu.serving.engine import GenRequest, ServingEngine
-    from tests.test_serving import FakeBackend, run_blocking
+    from tests.fakes import FakeBackend, run_blocking
 
     cap = CapacityProfiler("cpu")
     be = FakeBackend(num_pages=64)

@@ -361,7 +361,8 @@ async def test_serving_spans_reach_the_collector_and_assemble():
     ``serving.prefill`` under its ``execute`` span, and a kept cycle is a
     stored trace of its own whose root ``step`` landed last."""
     from cordum_tpu.serving.backend import STEP_PHASES
-    from tests.test_serving import FakeBackend, make_serving_worker, make_stack, settle
+    from tests.fakes import FakeBackend
+    from tests.test_serving import make_serving_worker, make_stack, settle
 
     kv, bus, js, ms, eng = make_stack()
     await eng.start()

@@ -22,7 +22,7 @@ from cordum_tpu.serving.pager import (
 )
 from cordum_tpu.serving.prefixcache import PrefixCache
 
-from .test_serving import FakeBackend, run_blocking
+from .fakes import FakeBackend, fake_ref, run_blocking
 from .test_serving_failover import wait_until
 
 # ------------------------------------------------------- allocator refcounts
@@ -194,99 +194,6 @@ def test_demote_promote_roundtrip():
 # --------------------------------------------- engine (arena-modeling fake)
 
 
-class ArenaFakeBackend(FakeBackend):
-    """FakeBackend + a host-integer 'arena': page contents are real state,
-    samples read the FULL written prefix through the page table, and
-    copy_page / export_kv / import_kv move actual slots — so prefix
-    sharing, CoW, and hibernate bugs change emitted tokens instead of
-    hiding behind per-session accumulators."""
-
-    def __init__(self, **kw):
-        super().__init__(**kw)
-        self.arena: dict[int, list[int]] = {}
-        self.copies = 0
-        self.fed_prefill: dict[str, int] = {}  # key -> prompt tokens fed
-
-    def _row(self, page):
-        return self.arena.setdefault(page, [0] * self.page_size)
-
-    def _read(self, pages, n):
-        ps = self.page_size
-        return [self._row(pages[i // ps])[i % ps] for i in range(n)]
-
-    @staticmethod
-    def _sample(seq):
-        return (sum(seq) * 3 + len(seq)) % 251
-
-    def step(self, entries):
-        import time as _t
-
-        if self.step_delay:
-            _t.sleep(self.step_delay)
-        assert len(entries) <= self.max_seqs, "max_seqs exceeded"
-        assert sum(len(e.tokens) for e in entries) <= self.max_batch_tokens, \
-            "flat token budget exceeded"
-        self.last_step_compiled = self.steps == 0
-        self.steps += 1
-        self.decode_batches.append(len(entries))
-        ps = self.page_size
-        out = []
-        for e in entries:
-            for i, t in enumerate(e.tokens):
-                pos = e.start + i
-                self._row(e.pages[pos // ps])[pos % ps] = t
-            written = e.start + len(e.tokens)
-            if e.phase == "prefill":
-                self.prefill_chunks += 1
-                self.fed_prefill[e.key] = (
-                    self.fed_prefill.get(e.key, 0) + len(e.tokens)
-                )
-                if e.sample:
-                    self.prefills += 1
-                    out.append(self._sample(self._read(e.pages, written)))
-                else:
-                    out.append(None)
-            else:
-                out.append(self._sample(self._read(e.pages, written)))
-        return out
-
-    def copy_page(self, src, dst):
-        self.arena[dst] = list(self._row(src))
-        self.copies += 1
-
-    def export_kv(self, pages, start_tok, end_tok):
-        if end_tok <= start_tok:
-            return []
-        ps = self.page_size
-        first, last = start_tok // ps, -(-end_tok // ps)
-        recs = []
-        for o in range(first, min(last, len(pages))):
-            used = min(ps, end_tok - o * ps)
-            recs.append({"i": o, "used": used,
-                         "k": list(self._row(pages[o])[:used]), "v": [],
-                         "shape": [used]})
-        return recs
-
-    def import_kv(self, pages, records):
-        ps = self.page_size
-        for rec in records:
-            row = [0] * ps
-            for j, t in enumerate(rec["k"]):
-                row[j] = t
-            self.arena[pages[rec["i"]]] = row
-
-
-def arena_ref(prompt, n_new):
-    """Sequential oracle for ArenaFakeBackend: each sample is a function of
-    the entire written prefix, so any aliasing corruption diverges."""
-    seq = list(prompt)
-    out = [ArenaFakeBackend._sample(seq)]
-    for _ in range(n_new - 1):
-        seq.append(out[-1])
-        out.append(ArenaFakeBackend._sample(seq))
-    return out
-
-
 class Tap:
     """Token-stream sink asserting exactly-once delivery: the engine emits
     (tokens, end_offset, done); replays must agree with what streamed."""
@@ -307,23 +214,27 @@ class Tap:
                 raise AssertionError(f"gap in stream at {idx}")
 
 
-async def test_prefix_cache_requires_cow_capability():
-    """Arena-less backends can neither share page contents nor duplicate
-    them on divergent write: the cache must stay off entirely."""
-    eng = ServingEngine(FakeBackend(), run_blocking=run_blocking)
-    assert eng.prefix is None and eng.tiering is None
-    await eng.stop()
+async def test_prefix_cache_requires_whole_rows():
+    """A backend whose pages do not cover the whole row under one table
+    (the contract's one capability) cannot share them: the cache and the
+    tiering stay off entirely, as they do when the caller says so."""
+    windowed = FakeBackend()
+    windowed.kv_whole_row = False
+    for be, kw in ((windowed, {}), (FakeBackend(), {"prefix_cache": False})):
+        eng = ServingEngine(be, run_blocking=run_blocking, **kw)
+        assert eng.prefix is None and eng.tiering is None
+        await eng.stop()
 
 
 async def test_prefix_hit_skips_prefill_token_identical():
-    be = ArenaFakeBackend(num_pages=32, page_size=4, max_context=128)
+    be = FakeBackend(num_pages=32, page_size=4, max_context=128)
     eng = ServingEngine(be, run_blocking=run_blocking, max_new_tokens_cap=64)
     assert eng.prefix is not None
     prompt = [9, 2, 7, 1, 8, 3, 5, 4, 6]  # two full pages + one token
     out1 = await asyncio.wait_for(eng.submit(
         GenRequest(prompt=prompt, max_new_tokens=6, stream=False),
         job_id="a"), timeout=20)
-    assert out1["tokens"] == arena_ref(prompt, 6)
+    assert out1["tokens"] == fake_ref(prompt, 6)
     assert eng.stats.prefix_misses == 1 and be.fed_prefill["a"] == len(prompt)
     out2 = await asyncio.wait_for(eng.submit(
         GenRequest(prompt=prompt, max_new_tokens=6, stream=False),
@@ -342,7 +253,7 @@ async def test_page_aligned_hit_cow_protects_shared_page():
     """A prompt that is an exact page multiple backs its hit up one token;
     re-feeding the final token writes into shared territory, which the CoW
     guard must copy — the cached page stays byte-identical for later hits."""
-    be = ArenaFakeBackend(num_pages=32, page_size=4, max_context=128)
+    be = FakeBackend(num_pages=32, page_size=4, max_context=128)
     eng = ServingEngine(be, run_blocking=run_blocking, max_new_tokens_cap=64)
     prompt = [11, 3, 7, 2, 9, 5, 8, 1]  # exactly two pages
     out1 = await asyncio.wait_for(eng.submit(
@@ -353,7 +264,7 @@ async def test_page_aligned_hit_cow_protects_shared_page():
     out2 = await asyncio.wait_for(eng.submit(
         GenRequest(prompt=prompt, max_new_tokens=5, stream=False),
         job_id="b"), timeout=20)
-    assert out2["tokens"] == out1["tokens"] == arena_ref(prompt, 5)
+    assert out2["tokens"] == out1["tokens"] == fake_ref(prompt, 5)
     assert eng.stats.prefix_hits == 1 and eng.stats.prefix_hit_tokens == 7
     assert be.copies >= 1 and eng.stats.cow_copies >= 1
     # the shared pages the cache holds were never scribbled on
@@ -367,7 +278,7 @@ async def test_page_aligned_hit_cow_protects_shared_page():
 
 
 async def test_exhaustion_lru_evicts_cached_prefixes():
-    be = ArenaFakeBackend(num_pages=8, page_size=4, max_context=128,
+    be = FakeBackend(num_pages=8, page_size=4, max_context=128,
                           max_batch_tokens=64)
     eng = ServingEngine(be, run_blocking=run_blocking, max_new_tokens_cap=64)
     p_old = list(range(1, 17))       # 16 tokens: 4 full pages when cached
@@ -375,7 +286,7 @@ async def test_exhaustion_lru_evicts_cached_prefixes():
     out = await asyncio.wait_for(eng.submit(
         GenRequest(prompt=p_old, max_new_tokens=4, stream=False),
         job_id="old"), timeout=20)
-    assert out["tokens"] == arena_ref(p_old, 4)
+    assert out["tokens"] == fake_ref(p_old, 4)
     cached = eng.prefix.warm_pages
     assert cached >= 4
     # footprint 5 > free pages: admission LRU-evicts the cache's pages
@@ -383,7 +294,7 @@ async def test_exhaustion_lru_evicts_cached_prefixes():
     out = await asyncio.wait_for(eng.submit(
         GenRequest(prompt=p_new, max_new_tokens=4, stream=False),
         job_id="new"), timeout=20)
-    assert out["tokens"] == arena_ref(p_new, 4)
+    assert out["tokens"] == fake_ref(p_new, 4)
     assert eng.prefix.stats.evicted_pages >= 1
     eng.allocator.check_consistency()
     await eng.stop()
@@ -394,7 +305,7 @@ async def test_turn_hibernate_restore_roundtrip():
     the idle sweep (device pages freed), and the next turn re-warms them —
     token-identical to never having hibernated, with the tier accounting
     and worker hooks following along."""
-    be = ArenaFakeBackend(num_pages=32, page_size=4, max_context=128)
+    be = FakeBackend(num_pages=32, page_size=4, max_context=128)
     eng = ServingEngine(be, run_blocking=run_blocking, max_new_tokens_cap=64,
                         hibernate_after_s=30.0)
     events: list[tuple[str, str]] = []
@@ -405,7 +316,7 @@ async def test_turn_hibernate_restore_roundtrip():
         GenRequest(prompt=prompt, max_new_tokens=7, stream=False,
                    session_key="conv"),
         job_id="t1"), timeout=20)
-    assert out1["tokens"] == arena_ref(prompt, 7)
+    assert out1["tokens"] == fake_ref(prompt, 7)
     warm = eng.prefix.warm_pages
     assert warm >= 2 and eng.tiering.resident_sessions == 1
     assert eng.tiering.tier_counts() == (1, 0)
@@ -421,7 +332,7 @@ async def test_turn_hibernate_restore_roundtrip():
         GenRequest(prompt=p2, max_new_tokens=4, stream=False,
                    session_key="conv"),
         job_id="t2"), timeout=20)
-    assert out2["tokens"] == arena_ref(p2, 4)
+    assert out2["tokens"] == fake_ref(p2, 4)
     assert eng.stats.prefix_hits == 1
     assert eng.prefix.stats.restored_pages >= warm
     assert ("restored", "conv") in events
@@ -434,7 +345,7 @@ async def test_live_hibernate_restore_exactly_once():
     arena (waiter sees SessionHibernated, device pages freed);
     restore_hibernated resumes it token-identically and the stream dedupes
     to an exactly-once sequence across the gap."""
-    be = ArenaFakeBackend(num_pages=32, page_size=4, max_context=128,
+    be = FakeBackend(num_pages=32, page_size=4, max_context=128,
                           step_delay=0.01)
     eng = ServingEngine(be, run_blocking=run_blocking, max_new_tokens_cap=64)
     tap = Tap()
@@ -454,7 +365,7 @@ async def test_live_hibernate_restore_exactly_once():
     assert eng.stats.hibernated_out == 1
     fut = await eng.restore_hibernated("h1", on_tokens=tap)
     toks = await asyncio.wait_for(fut, timeout=20)
-    assert toks == arena_ref(prompt, 24)
+    assert toks == fake_ref(prompt, 24)
     assert eng.stats.restored_in == 1
     await wait_until(lambda: len(tap.buf) == 24, msg="stream complete")
     assert tap.buf == toks  # exactly-once across the hibernate gap
@@ -468,7 +379,7 @@ async def test_random_interleaving_accounting_property():
     hibernate sweeps: every session's tokens match the sequential oracle
     and the allocator's invariants hold at every checkpoint."""
     rng = random.Random(99)
-    be = ArenaFakeBackend(num_pages=24, page_size=4, max_context=96,
+    be = FakeBackend(num_pages=24, page_size=4, max_context=96,
                           step_delay=0.001)
     eng = ServingEngine(be, run_blocking=run_blocking, max_sessions=6,
                         max_new_tokens_cap=64, hibernate_after_s=30.0)
@@ -484,7 +395,7 @@ async def test_random_interleaving_accounting_property():
             prompt = [rng.randrange(1, 200) for _ in range(rng.randint(1, 10))]
         n_new = rng.randint(2, 10)
         jid = f"r{i}"
-        expected[jid] = arena_ref(prompt, n_new)
+        expected[jid] = fake_ref(prompt, n_new)
         tasks.append(asyncio.ensure_future(eng.submit(
             GenRequest(prompt=prompt, max_new_tokens=n_new, stream=False,
                        session_key=f"conv{i % 5}"),

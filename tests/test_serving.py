@@ -11,6 +11,8 @@ import pytest
 from cordum_tpu.serving.engine import GenRequest, ServingEngine, SessionCancelled
 from cordum_tpu.serving.pager import CacheExhausted, PageAllocator
 
+from .fakes import FakeBackend, fake_ref, run_blocking
+
 
 # ---------------------------------------------------------------- allocator
 
@@ -280,71 +282,6 @@ def test_ragged_single_program_no_recompile_cliff(llama_env):
 # -------------------------------------------- engine (fake backend, fast)
 
 
-class FakeBackend:
-    """Deterministic integer-arithmetic backend implementing the ragged
-    ``step()`` interface: prefill chunks accumulate a per-session prompt
-    sum, the completing chunk samples ``(sum(prompt) * 3 + len(prompt)) %
-    251``, and a decode row samples ``(last * 3 + pos) % 251``.  Tracks
-    per-step row counts and supports an optional step delay so cancel
-    tests get a window."""
-
-    def __init__(self, num_pages=16, page_size=4, max_context=64,
-                 step_delay=0.0, max_seqs=16, max_batch_tokens=32):
-        self.num_pages = num_pages
-        self.page_size = page_size
-        self.max_context = max_context
-        self.max_seqs = max_seqs
-        self.max_batch_tokens = max_batch_tokens
-        self.step_delay = step_delay
-        self.steps = 0
-        self.decode_batches: list[int] = []  # rows per mixed step
-        self.prefills = 0  # completed prompts
-        self.prefill_chunks = 0
-        self.last_step_compiled = False
-        self._fed: dict[str, tuple[int, int]] = {}  # key -> (sum, count)
-
-    def step(self, entries):
-        import time as _t
-
-        if self.step_delay:
-            _t.sleep(self.step_delay)
-        # the static-shape contract the real backend enforces
-        assert len(entries) <= self.max_seqs, "max_seqs exceeded"
-        assert sum(len(e.tokens) for e in entries) <= self.max_batch_tokens, \
-            "flat token budget exceeded"
-        self.last_step_compiled = self.steps == 0  # one program, one compile
-        self.steps += 1
-        self.decode_batches.append(len(entries))
-        out = []
-        for e in entries:
-            if e.phase == "prefill":
-                s, c = self._fed.get(e.key, (0, 0))
-                s, c = s + sum(e.tokens), c + len(e.tokens)
-                self._fed[e.key] = (s, c)
-                self.prefill_chunks += 1
-                if e.sample:
-                    self.prefills += 1
-                    out.append((s * 3 + c) % 251)
-                else:
-                    out.append(None)
-            else:
-                out.append((e.tokens[0] * 3 + e.start) % 251)
-        return out
-
-
-def fake_ref(prompt, n_new):
-    out = [(sum(prompt) * 3 + len(prompt)) % 251]
-    pos = len(prompt)
-    for _ in range(n_new - 1):
-        out.append((out[-1] * 3 + pos) % 251)
-        pos += 1
-    return out
-
-
-async def run_blocking(fn, *args):
-    return await asyncio.get_running_loop().run_in_executor(None, fn, *args)
-
-
 async def test_engine_join_leave_matches_sequential():
     """Sessions joining and retiring mid-flight get exactly the tokens a
     sequential per-session decode would produce — continuous batching is a
@@ -371,7 +308,8 @@ async def test_engine_join_leave_matches_sequential():
         assert out["tokens"] == fake_ref(prompt, n_new), job_id
         assert out["finish_reason"] == "length"
     assert max(be.decode_batches) >= 2, "sessions never actually shared a step"
-    assert eng.allocator.free_pages == eng.allocator.capacity  # all freed
+    # all freed: what is still held is the prefix cache's alone
+    assert eng.allocator.used_pages == eng.prefix.warm_pages
     assert eng.stats.retired == 4 and eng.stats.failed == 0
     await eng.stop()
 
@@ -736,7 +674,7 @@ async def test_worker_generate_e2e_stream_and_terminal_result():
         # per-token stream packets are transport, never job-store events
         evts = await js.events(f"g{i}")
         assert not any(e.get("event") == "progress" for e in evts), evts
-    assert w.serving.allocator.used_pages == 0
+    assert w.serving.allocator.used_pages == w.serving.prefix.warm_pages
     assert metrics.serving_admitted.value() >= n
     assert metrics.serving_retired.value(reason="finished") >= n
     await w.stop()
@@ -1009,7 +947,7 @@ async def test_migrated_in_and_resumed_sessions_get_no_ttft_spans():
         job_id="mig", trace_id="tr-mig", parent_span_id="exec-mig",
         state={"pos": len(prompt) + 1, "prefill_pos": len(prompt),
                "out_tokens": carried, "last_token": carried[-1]},
-        records=[],
+        records=[{"i": 0, "used": 4, "k": prompt + carried[:1], "v": [], "shape": [4]}],
     )
     resumed = eng.submit(
         GenRequest(prompt=prompt, max_new_tokens=5, stream=False,
@@ -1074,28 +1012,13 @@ async def test_sampled_cycle_children_are_contiguous_and_sum_to_step(kind, llama
         assert be.last_phases == tuple(sorted(be.last_phases)) and len(be.last_phases) == 5
 
 
-class SlowAtBackend(FakeBackend):
-    """A fake whose listed step numbers stall."""
-
-    def __init__(self, slow_at, stall_s, **kw):
-        super().__init__(**kw)
-        self.slow_at, self.stall_s = set(slow_at), stall_s
-
-    def step(self, entries):
-        import time as _t
-
-        if self.steps in self.slow_at:
-            _t.sleep(self.stall_s)
-        return super().step(entries)
-
-
 async def test_stalled_cycle_is_kept_whatever_the_rate_cap_says():
     """A cycle over three times the running median of the last 64 is kept
     though the last kept cycle began under 250 ms before it."""
     from cordum_tpu.serving import engine as engine_mod
 
     sink = await SpanSink().listen()
-    be = SlowAtBackend({70, 73}, 0.06, num_pages=64, max_context=256)
+    be = FakeBackend(slow_at={70: 0.06, 73: 0.06}, num_pages=64, max_context=256)
     eng = traced_engine(sink, be, max_sessions=2, max_new_tokens_cap=128)
     await generate(eng, 1, new=90)
     await eng.stop()
@@ -1210,16 +1133,8 @@ async def test_failed_step_leaves_an_error_step_span():
     """A step that raises fails its riders and is kept as a lone ``step``
     root with status ERROR (the phases of a call that never returned are
     unknown)."""
-
-    class Boom(FakeBackend):
-        def step(self, entries):
-            if self.steps == 2:
-                self.steps += 1
-                raise RuntimeError("poisoned")
-            return super().step(entries)
-
     sink = await SpanSink().listen()
-    eng = traced_engine(sink, Boom(num_pages=64), max_sessions=4)
+    eng = traced_engine(sink, FakeBackend(num_pages=64, fail_at={2}), max_sessions=4)
     with pytest.raises(RuntimeError):
         await generate(eng, 1)
     out = await eng.submit(  # the loop goes on

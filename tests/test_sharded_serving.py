@@ -24,7 +24,8 @@ from cordum_tpu.serving.shard import (
     slice_rank_record,
 )
 
-from .test_serving import ref_greedy, run_blocking
+from .fakes import FakeBackend, fake_ref, run_blocking
+from .test_serving import ref_greedy
 from .test_serving_failover import install_into, wait_until
 
 
@@ -340,11 +341,9 @@ async def test_statebus_cold_tier_restores_after_restart(kv):
     The restore consumes the journal entry."""
     from cordum_tpu.serving.tiering import StatebusColdTier
 
-    from .test_prefix_tiering import ArenaFakeBackend, arena_ref
-
     def mk_engine():
-        be = ArenaFakeBackend(num_pages=32, page_size=4, max_context=128,
-                              step_delay=0.01)
+        be = FakeBackend(num_pages=32, page_size=4, max_context=128,
+                         step_delay=0.01)
         eng = ServingEngine(be, run_blocking=run_blocking,
                             max_new_tokens_cap=64)
         eng.tiering.arena = StatebusColdTier(kv, worker_id="w0")
@@ -372,7 +371,7 @@ async def test_statebus_cold_tier_restores_after_restart(kv):
     assert "h1" in eng2.tiering.arena
     fut = await eng2.restore_hibernated("h1")
     toks = await asyncio.wait_for(fut, timeout=20)
-    assert toks == arena_ref(prompt, 24)
+    assert toks == fake_ref(prompt, 24)
     await eng2.tiering.arena.flush()
     assert await kv.keys("serving:cold:w0:") == []  # journal consumed
     await eng2.stop()

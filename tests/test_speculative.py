@@ -22,63 +22,21 @@ from cordum_tpu.serving.engine import (
 from cordum_tpu.serving.pager import PageAllocator
 from cordum_tpu.sdk.client import merge_stream_packet
 
-from .test_serving import FakeBackend, fake_ref, run_blocking
-
-MOD = 251  # the FakeBackend recurrence modulus
+from .fakes import MOD, FakeBackend, fake_ref, run_blocking
 
 
 # ---------------------------------------------------------------------------
-# a draft-capable FakeBackend + scripted drafters
+# scripted drafters
 # ---------------------------------------------------------------------------
-
-
-class SpecFakeBackend(FakeBackend):
-    """FakeBackend extended with the draft-row contract: a ``draft > 0``
-    entry returns one next-token prediction per fed position — the same
-    position-local recurrence ``(token * 3 + position) % 251`` the decode
-    rows use, so the engine's accept-longest-prefix logic is exercised
-    against an exact oracle."""
-
-    supports_draft = True
-
-    def step(self, entries):
-        base = super().step(entries)
-        out = []
-        for e, tok in zip(entries, base):
-            if getattr(e, "draft", 0) > 0:
-                out.append([(e.tokens[i] * 3 + (e.start + i)) % MOD
-                            for i in range(len(e.tokens))])
-            else:
-                out.append(tok)
-        return out
-
-
-class RecordingBackend(FakeBackend):
-    """Plain (non-draft-capable) backend that records every StepEntry —
-    the spec-disabled identity guard reads the metadata off it."""
-
-    def __init__(self, **kw):
-        super().__init__(**kw)
-        self.seen: list[list[tuple]] = []
-
-    def step(self, entries):
-        self.seen.append([
-            (list(e.tokens), e.start, e.phase, getattr(e, "draft", 0))
-            for e in entries
-        ])
-        return super().step(entries)
 
 
 def perfect_drafter(history, k):
-    """The fake recurrence's exact continuation: token at sequence index
-    j is ``(token[j-1] * 3 + (j - 1)) % 251``, so every draft verifies."""
+    """The fake's exact continuation (its sample over the whole history),
+    so every draft verifies."""
     h = list(history)
-    out = []
     for _ in range(k):
-        nxt = (h[-1] * 3 + len(h) - 1) % MOD
-        out.append(nxt)
-        h.append(nxt)
-    return out
+        h.append(FakeBackend.sample(h))
+    return h[len(history):]
 
 
 def garbage_drafter(history, k):
@@ -149,10 +107,10 @@ async def test_spec_engine_token_identical_and_fewer_steps():
     far fewer backend steps — speculation is a schedule change, not a math
     change."""
     prompts = [[5, 9, 17, 3], [100, 42], [7, 3, 11]]
-    base_be = SpecFakeBackend()
+    base_be = FakeBackend()
     base_outs, base_eng = await _run_engine(base_be, prompts, 12,
                                             speculative=False)
-    spec_be = SpecFakeBackend()
+    spec_be = FakeBackend()
     spec_outs, spec_eng = await _run_engine(spec_be, prompts, 12,
                                             speculative=True, draft_k=4,
                                             drafter=perfect_drafter)
@@ -172,7 +130,7 @@ async def test_spec_engine_garbage_drafts_roll_back_token_identical():
     """Every draft rejected: output still exactly sequential (the bonus
     token carries each step), every proposal counted as rolled back."""
     prompts = [[5, 9, 17, 3], [8, 1]]
-    outs, eng = await _run_engine(SpecFakeBackend(), prompts, 10,
+    outs, eng = await _run_engine(FakeBackend(), prompts, 10,
                                   speculative=True, draft_k=4,
                                   drafter=garbage_drafter)
     for p, out in zip(prompts, outs):
@@ -187,7 +145,7 @@ async def test_spec_engine_partial_accept_rolls_back_tail():
     """A row that verifies 2 of k drafts advances exactly 3 tokens (2
     accepted + the bonus) and rolls back the rest — still token-exact."""
     prompt = [5, 9, 17, 3]
-    outs, eng = await _run_engine(SpecFakeBackend(), [prompt], 12,
+    outs, eng = await _run_engine(FakeBackend(), [prompt], 12,
                                   speculative=True, draft_k=4,
                                   drafter=cut2_drafter)
     assert outs[0] == fake_ref(prompt, 12)
@@ -195,31 +153,8 @@ async def test_spec_engine_partial_accept_rolls_back_tail():
     assert eng.stats.rolled_back_tokens > 0
 
 
-async def test_spec_gated_off_without_backend_support():
-    """A backend without ``supports_draft`` keeps the legacy path
-    byte-identical: no draft metadata, single-token decode rows, same
-    outputs — even with ``speculative=True`` requested."""
-    prompts = [[5, 9, 17, 3], [100, 42]]
-    be = RecordingBackend()
-    outs, eng = await _run_engine(be, prompts, 8,
-                                  speculative=True, draft_k=4,
-                                  drafter=perfect_drafter)
-    assert eng.speculative is False
-    for p, out in zip(prompts, outs):
-        assert out == fake_ref(p, 8)
-    for step in be.seen:
-        for tokens, _start, phase, draft in step:
-            assert draft == 0
-            if phase == "decode":
-                assert len(tokens) == 1
-    assert eng.stats.drafted_tokens == 0 and eng.stats.spec_steps == 0
-
-
 async def test_spec_flag_off_never_drafts_on_capable_backend():
-    class RecordingSpecBackend(SpecFakeBackend, RecordingBackend):
-        pass
-
-    be = RecordingSpecBackend()
+    be = FakeBackend()
     outs, eng = await _run_engine(be, [[5, 9, 17, 3]], 8, speculative=False)
     assert eng.speculative is False
     assert outs[0] == fake_ref([5, 9, 17, 3], 8)
@@ -237,7 +172,7 @@ async def test_adaptive_k_ramps_down_on_rejection():
         seen.append((k, max_new - (len(history) - len(prompt))))
         return garbage_drafter(history, k)
 
-    outs, _ = await _run_engine(SpecFakeBackend(), [prompt], max_new,
+    outs, _ = await _run_engine(FakeBackend(), [prompt], max_new,
                                 speculative=True, draft_k=4, drafter=capture)
     assert outs[0] == fake_ref(prompt, max_new)
     assert seen[0][0] == 4  # optimistic start: EWMA seeds at 1.0
@@ -250,7 +185,7 @@ async def test_spec_burst_never_overshoots_max_new():
     remaining - 1 clamp means a burst can never write past the admitted
     page footprint."""
     for max_new in (3, 7, 12):
-        outs, _ = await _run_engine(SpecFakeBackend(), [[5, 9, 17, 3]],
+        outs, _ = await _run_engine(FakeBackend(), [[5, 9, 17, 3]],
                                     max_new, speculative=True, draft_k=4,
                                     drafter=perfect_drafter)
         assert outs[0] == fake_ref([5, 9, 17, 3], max_new)
@@ -262,7 +197,7 @@ async def test_eos_inside_burst_truncates_exactly():
     seq = fake_ref(prompt, 12)
     eos = seq[5]
     expected = seq[:seq.index(eos) + 1]
-    eng = ServingEngine(SpecFakeBackend(), run_blocking=run_blocking,
+    eng = ServingEngine(FakeBackend(), run_blocking=run_blocking,
                         max_new_tokens_cap=12, speculative=True, draft_k=4,
                         drafter=perfect_drafter)
     r = await eng.submit(GenRequest(prompt=prompt, max_new_tokens=12,
@@ -274,7 +209,7 @@ async def test_eos_inside_burst_truncates_exactly():
 
 async def test_spec_metrics_counters():
     metrics = Metrics()
-    await _run_engine(SpecFakeBackend(), [[5, 9, 17, 3]], 10,
+    await _run_engine(FakeBackend(), [[5, 9, 17, 3]], 10,
                       speculative=True, draft_k=4, drafter=cut2_drafter,
                       metrics=metrics)
     drafted = metrics.serving_spec_drafted.value()
@@ -519,7 +454,7 @@ async def test_engine_burst_packets_carry_worker_sink_offsets():
         packets.append((list(new_tokens), n_generated))
 
     prompt, max_new = [5, 9, 17, 3], 12
-    eng = ServingEngine(SpecFakeBackend(), run_blocking=run_blocking,
+    eng = ServingEngine(FakeBackend(), run_blocking=run_blocking,
                         max_new_tokens_cap=max_new, speculative=True,
                         draft_k=4, drafter=perfect_drafter)
     r = await eng.submit(GenRequest(prompt=prompt, max_new_tokens=max_new),
@@ -554,7 +489,7 @@ async def test_resume_tokens_replay_with_speculation():
     async def sink(new_tokens, n_generated, done):
         packets.append((list(new_tokens), n_generated))
 
-    eng = ServingEngine(SpecFakeBackend(), run_blocking=run_blocking,
+    eng = ServingEngine(FakeBackend(), run_blocking=run_blocking,
                         max_new_tokens_cap=max_new, speculative=True,
                         draft_k=4, drafter=perfect_drafter)
     r = await eng.submit(
