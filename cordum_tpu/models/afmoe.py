@@ -238,10 +238,8 @@ def ragged_step(
     ring = window_tables.shape[1]
     pos2 = positions[:, None]
     live = token_seq < page_tables.shape[0] - 1  # the last row is the padding row
-    pt_tok = page_tables[token_seq]  # [T, P]
-    wt_tok = window_tables[token_seq]  # [T, R]
-    page_idx = jnp.take_along_axis(pt_tok, pos2 // ps, axis=1)[:, 0]
-    wpage_idx = jnp.take_along_axis(wt_tok, (pos2 // ps) % ring, axis=1)[:, 0]
+    page_idx = page_tables[token_seq, positions // ps]  # [T]
+    wpage_idx = window_tables[token_seq, (positions // ps) % ring]
     slot = positions % ps
     block_pages = attn_block_pages(ps, page_tables.shape[1])
     arena_layer = {li: n for kind in (cfg.full_layers, cfg.window_layers)
@@ -268,13 +266,14 @@ def ragged_step(
             with jax.named_scope("kv_write"):
                 wk_pages = wk_pages.at[ai, wpage_idx, slot].set(k)
                 wv_pages = wv_pages.at[ai, wpage_idx, slot].set(v)
-            attn = paged_attention(q, wk_pages, wv_pages, ai, wt_tok, positions,
-                                   block_pages, window=cfg.window)
+            attn = paged_attention(q, wk_pages, wv_pages, ai, window_tables, token_seq,
+                                   positions, block_pages, window=cfg.window)
         else:
             with jax.named_scope("kv_write"):
                 k_pages = k_pages.at[ai, page_idx, slot].set(k)
                 v_pages = v_pages.at[ai, page_idx, slot].set(v)
-            attn = paged_attention(q, k_pages, v_pages, ai, pt_tok, positions, block_pages)
+            attn = paged_attention(q, k_pages, v_pages, ai, page_tables, token_seq,
+                                   positions, block_pages)
         with jax.named_scope("attn_gate"):
             attn = attn.reshape(t_buf, h * hd) * jax.nn.sigmoid(a @ layer["wg"])
         x = x + rms_norm(attn @ layer["wo"], layer["norm_post_attn"], cfg.norm_eps)

@@ -26,6 +26,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import AXIS_DP, AXIS_SP, AXIS_TP
@@ -254,7 +255,7 @@ def forward(
 # sequence's logical position ``p`` lives at page ``page_table[p // ps]``,
 # slot ``p % ps`` (the Ragged Paged Attention layout, PAPERS.md — here a
 # jnp formulation that runs anywhere, :func:`paged_attention`: a walk over
-# the page table in blocks of pages with an online softmax; a Pallas kernel
+# the page table's rows in blocks of pages with an online softmax; a Pallas kernel
 # that walks it in VMEM is the TPU upgrade path).  Page 0 is the NULL page:
 # padding rows and padded page-table tails point at it, so their writes land
 # harmlessly in slots no live sequence ever attends to (the causal mask cuts
@@ -341,7 +342,6 @@ def gather_kv_pages(
     arrays of shape ``[L, used, kvh, hd]`` upcast to float32 — an exact
     round trip for the bf16/fp32 arenas, and a wire format the receiver
     can cast back without knowing the sender's dtype."""
-    import numpy as np
 
     out = []
     for pid, n in zip(page_ids, used):
@@ -364,7 +364,6 @@ def scatter_kv_pages(
     program); slots past the true length are zero-filled, which is inert —
     the causal mask makes unwritten positions unreachable, and the resumed
     session overwrites them as it decodes.  Returns the updated arenas."""
-    import numpy as np
 
     dt = k_pages.dtype
     ps = k_pages.shape[2]
@@ -405,105 +404,211 @@ def attn_block_pages(page_size: int, pages_per_seq: int) -> int:
                       -(-pages_per_seq // ATTN_MIN_BLOCKS)))
 
 
+#: query slots a tile of :func:`paged_attention`'s walk holds, and tiles a
+#: trip computes together: 8 x 8 slots, one step's buffer at T = 64.  The
+#: walk's loop body is compiled once a layer, and XLA unrolls it over its
+#: tensors, so its size sets the program's (PERF.md section 6, PR 29: static
+#: classes of rows, 260 padded slots a trip, made the 7B program 2.4 times
+#: as large and a warm start 10 s longer)
+ATTN_TILE_SLOTS = 8
+ATTN_GROUP_TILES = 8
+
+
+def attn_tiles(n_slots: int, n_rows: int) -> int:
+    """The most tiles a step can hold, in whole groups: a tile is up to
+    ``ATTN_TILE_SLOTS`` consecutive slots of ONE table row, so every row
+    wastes less than one tile and ``n_slots // ATTN_TILE_SLOTS + n_rows``
+    bound them — from the shapes alone, whatever the engine feeds."""
+    return -(-(n_slots // ATTN_TILE_SLOTS + n_rows) // ATTN_GROUP_TILES) * ATTN_GROUP_TILES
+
+
+def walk_order(newest: Any, live: Any) -> Any:
+    """The order in which :func:`paged_attention` walks its tiles (numpy or
+    jax arrays, one entry a tile): the live ones first, by falling newest
+    position, so that a group holds tiles of like length and the groups of
+    short ones end their walks early."""
+    xp = np if isinstance(newest, np.ndarray) else jnp
+    return xp.argsort(xp.where(live, -newest, 1), stable=True)
+
+
+def walk_blocks(oldest: Any, newest: Any, block_tokens: int,
+                window: Optional[int] = None) -> tuple[Any, Any]:
+    """The rule of :func:`paged_attention`'s walk, for the program's traced
+    bound and the host's count alike (numpy or jax int arrays, one entry a
+    tile of one group): ``oldest`` / ``newest`` are the positions of the
+    tile's first and last fed slot (0 and 0 for a tile with none).  Returns
+    ``(first, trips)``: the block each tile's walk starts at — 0, or under a
+    window the block of the oldest key the tile's oldest slot sees — and the
+    trips of the group, its longest tile-walk: each ends at the block of its
+    tile's newest slot."""
+    first = 0 * oldest if window is None else (
+        (oldest - (window - 1)).clip(0) // block_tokens)
+    return first, (newest // block_tokens - first).max() + 1
+
+
+# jitted, with the layer a traced operand: the layers of a step program trace
+# and lower ONE walk a kind of page (a warm start pays the tracing)
+@partial(jax.jit, static_argnames=("block_pages", "window"))
 def paged_attention(
     q: jax.Array,
     k_pages: jax.Array,
     v_pages: jax.Array,
-    layer: int,
+    layer: Any,
     tables: jax.Array,
+    token_seq: jax.Array,
     positions: jax.Array,
     block_pages: int,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Causal attention of every buffer slot over its own sequence's pages.
+    """Causal attention of every fed buffer slot over its own sequence's
+    pages, walked once a TILE of a table row's slots and not once a slot.
 
     q: [T, h, hd]; k_pages / v_pages: the arenas ``[L, N, ps, kvh, hd]``;
-    tables: [T, P] int32, each slot's page-table row; positions: [T] int32.
-    Returns [T, h, hd] in q's dtype.  Three properties (docs/SERVING.md
-    §The ragged entry point):
+    tables: [S+1, P] int32, the page tables (row S is the padding row, which
+    is not walked: nothing reads a padding slot's attention); token_seq: [T]
+    int32 table row of each slot; positions: [T] int32.  Returns [T, h, hd]
+    in q's dtype.  It leans on one contract of the caller: **a row's slots
+    are contiguous in the buffer** (as ``ServingBackend.step`` packs them).
+    Four properties (docs/SERVING.md §The ragged entry point):
 
-    * **grouped query heads** — q is read as ``[T, kvh, rep, hd]`` and both
-      products keep ``kvh`` a batch dimension with ``rep`` the row
-      dimension, so K and V are never repeated to ``h`` heads;
-    * **blocks of pages, online softmax** — the table is walked
-      ``block_pages`` pages at a time (its width padded to whole blocks
-      with the null page); a block's K and V come straight out of the
-      arena in one gather that carries the layer index, scores and the
-      running maximum / sum / accumulator are float32, probabilities are
-      cast to the arena's dtype for the value product;
-    * **the walk ends at the longest live row** — the trip count is
-      ``ceil((max(positions) + 1) / block_tokens)``, a traced bound on
-      static shapes: one program, and blocks past it are never read.
+    * **tiles** — a row's slots are cut into tiles of ``ATTN_TILE_SLOTS``
+      (a decode row is one tile, a draft row of 1 + k slots one, a 48-slot
+      chunk six); :func:`attn_tiles` bounds their number from the shapes
+      alone.  The tiles are found once a step from ``token_seq`` and
+      ``positions`` (identical in every layer: XLA computes it once),
+      ordered by :func:`walk_order` and walked ``ATTN_GROUP_TILES`` at a
+      time; a group none of whose tiles is fed is not walked at all, so the
+      padding slots, most of the buffer at low occupancy, cost nothing;
+    * **a tile's slots are the products' rows** — q is read as ``[kvh,
+      slots x rep, hd]`` per tile and both products keep ``kvh`` a batch
+      dimension, so K and V are never repeated to ``h`` heads.  A trip
+      gathers ``block_pages`` pages of each tile's row in one gather that
+      carries the layer index (the table's width padded to whole blocks
+      with the null page): a chunk's pages are read once a tile of eight
+      slots, not once a slot;
+    * **online softmax** — scores and the running maximum / sum /
+      accumulator are float32 per slot and head, probabilities are cast to
+      the arena's dtype for the value product, the causal mask is per slot
+      from ``positions``;
+    * **a group's walk ends at its longest tile** (:func:`walk_blocks`) — a
+      traced trip count on static shapes: one program, and blocks past it
+      are never read.
 
-    Position 0 passes the causal mask ``k_pos <= position`` for every slot
-    (padding slots sit at position 0 on the null page), so the running
-    maximum is finite from the first block on and a later, wholly masked
-    block contributes exact zeros.
+    A masked key scores ``-1e30``, not ``-inf``.  A slot whose first walked
+    blocks hold none of its visible keys (under a window the TILE starts the
+    walk, at its oldest slot's oldest key) carries a maximum of ``-1e30``
+    through them (finite sums of values nobody keeps), and at its first
+    visible key the maximum becomes that key's score, ``alpha = exp(-1e30 -
+    score)`` is exactly 0 and the state restarts from it; a later, wholly
+    masked block contributes exact zeros.  In a full layer position 0 is
+    visible to every slot, so this only happens under a window.  A tile's
+    slots beyond its count, a group that was not walked (zeros) and the
+    output of a padding slot are finite values nothing reads.
 
     **A window layer** (``window`` = W, docs/SERVING.md §Two kinds of page)
     adds a lower bound to the mask and to the walk: position ``p`` sees keys
     ``p - W + 1 .. p``, and ``tables`` is then a RING of pages per sequence
     (:func:`window_ring_pages` wide): logical page ``n`` of the row sits in
     ring slot ``n % ring``, so a row holds a bounded number of pages however
-    long it grows.  Each slot starts its walk at the block that holds its
-    own oldest visible key and the trip count is the longest such walk of
-    the step — at most ``W / block_tokens + 2`` blocks, whatever the row's
-    length.  The first block of a slot's walk holds a visible key, so the
-    running maximum is finite from it on, as above.  With ``window=None``
-    the program is the one it was."""
+    long it grows.  Each tile starts its walk at the block that holds the
+    oldest key its oldest slot sees and ends it at its newest slot's block;
+    a group's trip count is its longest such walk — at most ``W /
+    block_tokens + 2`` blocks, whatever the row's length.  The ring is the
+    window, one step's buffer and a page wide, so no key a slot sees has
+    been overwritten by its row's newest write; a ring slot the walk reads
+    twice is masked by its logical position."""
     t, h, hd = q.shape
     ps, kvh = k_pages.shape[2], k_pages.shape[3]
+    rep = h // kvh
+    s_rows = tables.shape[0] - 1
     bp = block_pages
     bt = bp * ps  # token positions a block
-    if window is None:
-        n_blocks = -(-tables.shape[1] // bp)
-        tables = jnp.pad(tables, ((0, 0), (0, n_blocks * bp - tables.shape[1])))
-    qg = q.reshape(t, kvh, h // kvh, hd)
+    w, g = ATTN_TILE_SLOTS, ATTN_GROUP_TILES
+    n_tiles = attn_tiles(t, s_rows)
     scale = 1.0 / math.sqrt(hd)
-    offs = jnp.arange(bt, dtype=positions.dtype)
-    if window is not None:
-        ring = tables.shape[1]
-        first = jnp.maximum(positions - (window - 1), 0) // bt  # [T] first block
-        walked = jnp.max(positions // bt - first) + 1
-        lane = jnp.arange(bp, dtype=positions.dtype)
-
-    def block(j, carry):
-        m, l, acc = carry
-        with jax.named_scope("attn_gather"):
-            if window is None:
-                ids = jax.lax.dynamic_slice_in_dim(tables, j * bp, bp, axis=1)
-            else:
-                at = first + j  # [T]: each slot's own block of this trip
-                ids = jnp.take_along_axis(
-                    tables, (at[:, None] * bp + lane[None, :]) % ring, axis=1)
-            kb = k_pages[layer, ids].reshape(t, bt, kvh, hd)
-            vb = v_pages[layer, ids].reshape(t, bt, kvh, hd)
-        with jax.named_scope("attn_scores"):
-            s = jnp.einsum("tgrd,tkgd->tgrk", qg, kb,
-                           preferred_element_type=jnp.float32) * scale
-            if window is None:
-                live = (j * bt + offs)[None, :] <= positions[:, None]  # [T, bt]
-            else:
-                k_pos = at[:, None] * bt + offs[None, :]
-                live = (k_pos <= positions[:, None]) & (
-                    k_pos > positions[:, None] - window)
-            s = jnp.where(live[:, None, None, :], s, -1e30)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            l = l * alpha + jnp.sum(p, axis=-1)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "tgrk,tkgd->tgrd", p.astype(vb.dtype), vb,
-                preferred_element_type=jnp.float32)
-        return m_new, l, acc
-
-    stat = (t, kvh, h // kvh)
-    init = (jnp.full(stat, -1e30, jnp.float32), jnp.zeros(stat, jnp.float32),
-            jnp.zeros(stat + (hd,), jnp.float32))
+    itype = positions.dtype
+    # the tiles: a fed slot starts one where it is the first of its row's
+    # run in the buffer or a whole number of tiles behind it
+    at = jnp.arange(t, dtype=itype)
+    fed = token_seq < s_rows
+    prev = jnp.concatenate([token_seq[:1] + 1, token_seq[:-1]])
+    run_lo = jax.lax.cummax(jnp.where(token_seq != prev, at, 0))  # [T] its row's first slot
+    nxt = jnp.concatenate([token_seq[1:], token_seq[-1:] + 1])
+    run_hi = jnp.flip(jax.lax.cummin(jnp.flip(jnp.where(token_seq != nxt, at, t - 1))))
+    starts = fed & ((at - run_lo) % w == 0)
+    last = jnp.minimum(at + (w - 1), run_hi)  # [T] the last slot of a tile that starts here
+    pad = (0, max(0, n_tiles - t))  # a buffer of fewer slots than tiles
+    order = walk_order(jnp.pad(positions[last], pad), jnp.pad(starts, pad))[:n_tiles]
+    live = jnp.pad(starts, pad)[order]
+    slot0 = jnp.where(live, order, 0)  # [tiles] a tile's first buffer slot
+    slots = jnp.minimum(slot0[:, None] + jnp.arange(w, dtype=itype)[None, :], t - 1)
+    oldest = jnp.where(live, positions[slot0], 0)
+    newest = jnp.where(live, positions[last[slot0]], 0)
+    trow = jnp.where(live, token_seq[slot0], s_rows)  # idle tiles sit on the padding row
+    tab = tables[trow]  # [tiles, P]
     if window is None:
-        walked = (jnp.max(positions) + bt) // bt  # == ceil((max + 1) / bt) >= 1
-    _, l, acc = jax.lax.fori_loop(0, walked, block, init)
-    return (acc / l[..., None]).astype(q.dtype).reshape(t, h, hd)
+        n_blocks = -(-tab.shape[1] // bp)
+        tab = jnp.pad(tab, ((0, 0), (0, n_blocks * bp - tab.shape[1])))
+    else:
+        ring = tab.shape[1]
+        lane = jnp.arange(bp, dtype=itype)
+    offs = jnp.arange(bt, dtype=itype)
+    # each tile's queries [tiles, kvh, slots x rep, hd] and their positions
+    qt = q.reshape(t, kvh, rep, hd)[slots].transpose(0, 2, 1, 3, 4).reshape(
+        n_tiles, kvh, w * rep, hd)
+    pt = jnp.repeat(positions[slots], rep, axis=1)[:, :, None]  # [tiles, slots x rep, 1]
+
+    def group(i, out):
+        lo = i * g
+        qc, pc, tab_c = (jax.lax.dynamic_slice_in_dim(x, lo, g) for x in (qt, pt, tab))
+        first, trips = walk_blocks(jax.lax.dynamic_slice_in_dim(oldest, lo, g),
+                                   jax.lax.dynamic_slice_in_dim(newest, lo, g), bt, window)
+
+        def block(j, carry):
+            m, l, acc = carry
+            with jax.named_scope("attn_gather"):
+                if window is None:
+                    ids = jax.lax.dynamic_slice_in_dim(tab_c, j * bp, bp, axis=1)
+                    k_pos = (j * bt + offs)[None, None, :]
+                else:
+                    blk = first + j  # [G]: each tile's own block of this trip
+                    ids = jnp.take_along_axis(
+                        tab_c, (blk[:, None] * bp + lane[None, :]) % ring, axis=1)
+                    k_pos = (blk[:, None] * bt + offs[None, :])[:, None, :]
+                kb = k_pages[layer, ids].reshape(g, bt, kvh, hd)
+                vb = v_pages[layer, ids].reshape(g, bt, kvh, hd)
+            with jax.named_scope("attn_scores"):
+                s = jnp.einsum("rgmd,rkgd->rgmk", qc, kb,
+                               preferred_element_type=jnp.float32) * scale
+                seen = k_pos <= pc  # [G, slots x rep, bt]
+                if window is not None:
+                    seen &= k_pos > pc - window
+                s = jnp.where(seen[:, None], s, -1e30)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new[..., None])
+                l = l * alpha + jnp.sum(p, axis=-1)
+                acc = acc * alpha[..., None] + jnp.einsum(
+                    "rgmk,rkgd->rgmd", p.astype(vb.dtype), vb,
+                    preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        stat = (g, kvh, w * rep)
+        init = (jnp.full(stat, -1e30, jnp.float32), jnp.zeros(stat, jnp.float32),
+                jnp.zeros(stat + (hd,), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, trips, block, init)
+        done = (acc / l[..., None]).astype(q.dtype).reshape(g, kvh, w, rep, hd)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, done.transpose(0, 2, 1, 3, 4).reshape(g * w, h, hd), lo * w, axis=0)
+
+    # the groups that hold a live tile (they come first), each to its own end
+    walked = (jnp.sum(live, dtype=itype) + (g - 1)) // g
+    out = jax.lax.fori_loop(0, walked, group, jnp.zeros((n_tiles * w, h, hd), q.dtype))
+    # where a buffer slot finds its output: its tile's rank, its place in it
+    rank = jnp.zeros((t,), itype).at[jnp.where(live, slot0, t)].set(
+        jnp.arange(n_tiles, dtype=itype), mode="drop")
+    mine = at - (at - run_lo) % w  # [T] the slot that starts this slot's tile
+    return out[rank[mine] * w + (at - mine)]
 
 
 def serving_spec(cfg: LlamaConfig) -> Any:
@@ -571,14 +676,19 @@ def ragged_step(
     exactly as a full-sequence forward would, padding rows park on the null
     page, and no token can reach another sequence's pages because the
     gather walks only its own page-table row.  The attention is
-    :func:`paged_attention`: the row is walked in blocks of
-    :func:`attn_block_pages` pages with an online softmax, query heads
-    grouped by their KV head (K and V are read as stored, never repeated),
-    and the walk ends at the block that holds the step's longest live row
-    — a traced trip count from ``positions``, so the cost follows what is
-    live, not the context the table could hold.  (A jnp formulation that
-    runs anywhere; a Pallas kernel walking the page table in VMEM is the
-    TPU upgrade path.)
+    :func:`paged_attention`: the walk runs over TILES of a table row's
+    slots, not over the buffer slots — a row's fed slots are cut into tiles
+    of ``ATTN_TILE_SLOTS``, a tile's pages are gathered once a block of
+    :func:`attn_block_pages` pages and its slots are the rows of
+    grouped-query products (K and V are read as stored, never repeated);
+    the tiles are ordered by length and walked a group at a time, each group
+    to the block that holds its longest tile — traced trip counts from
+    ``token_seq`` and ``positions``, so the cost follows the slots that are
+    fed and their rows' lengths, not the buffer's size nor the context the
+    table could hold.  It leans on this function's own packing contract: a
+    row's slots are contiguous in the buffer.  (A jnp formulation that runs
+    anywhere; a Pallas kernel walking the page table in VMEM is the TPU
+    upgrade path.)
 
     ``sample_logits`` is a STATIC flag for serving-gang followers
     (docs/SERVING.md §Sharded serving): rank 0 alone owns sampling, so
@@ -591,8 +701,7 @@ def ragged_step(
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     ps = k_pages.shape[2]
     pos2 = positions[:, None]  # [T, 1]
-    pt_tok = page_tables[token_seq]  # [T, P] — each token's own table row
-    page_idx = jnp.take_along_axis(pt_tok, pos2 // ps, axis=1)[:, 0]  # [T]
+    page_idx = page_tables[token_seq, positions // ps]  # [T] — each token's own page
     slot = positions % ps
     block_pages = attn_block_pages(ps, page_tables.shape[1])
     # the named scopes are metadata only: they label the operations in a
@@ -615,7 +724,7 @@ def ragged_step(
             v_pages = v_pages.at[li, page_idx, slot].set(v[:, 0])
         # attn_gather and attn_scores label the body of the block walk
         attn = paged_attention(
-            q[:, 0], k_pages, v_pages, li, pt_tok, positions, block_pages)
+            q[:, 0], k_pages, v_pages, li, page_tables, token_seq, positions, block_pages)
         x = x + (attn.reshape(t_buf, 1, h * hd) @ layer["wo"])
         with jax.named_scope("mlp"):
             mlp_in = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)

@@ -126,16 +126,19 @@ class StepBackend:
     # the latest step's report, written by ``step`` and read by the engine
     # after the call
     REPORT = ("last_step_compiled", "last_phases", "last_attn_blocks",
-              "last_window_blocks", "last_counters")
+              "last_window_blocks", "last_attn_rows", "last_counters")
     last_step_compiled: bool = False  # did it pay XLA?
     # its boundaries, ns: (entry, arrays packed, program dispatched, result
     # on the host, return) — the engine splits its step cycle by them
     last_phases: tuple[int, ...] = ()
     # the attention walk: (blocks read, blocks a page table holds); the
-    # window layers' blocks read; what the family's program counted, under
-    # the ``ServingStats`` names it adds to
+    # window layers' blocks read; (tiles' table rows gathered, query slots
+    # computed), blocks of them, by one full and one window layer together;
+    # what the family's program counted, under the ``ServingStats`` names
+    # it adds to
     last_attn_blocks: tuple[int, int] = (0, 0)
     last_window_blocks: int = 0
+    last_attn_rows: tuple[int, int] = (0, 0)
     last_counters: Mapping[str, int] = MappingProxyType({})
     # observation tap: called with the entry list after every successful
     # step — the serving-gang leader broadcasts it so followers replay the
@@ -305,9 +308,11 @@ class ServingBackend(StepBackend):
         self._ragged_jit: Any = None
         self._compiled_shapes: set = set()  # observability: program count
         self._metrics = metrics
-        # ``last_attn_blocks``: ``llama.paged_attention`` stops at the block
-        # of the step's longest live row, which the host knows from the
-        # entries it packs
+        # ``last_attn_blocks`` / ``last_window_blocks`` / ``last_attn_rows``:
+        # ``llama.paged_attention`` cuts the rows into tiles and walks each
+        # group of tiles to its longest one, all of which follows from the
+        # rows' buffer slots and positions, which the host knows from the
+        # entries it packs (``_count_walk``)
         bp = llama.attn_block_pages(self.page_size, self.pages_per_seq)
         self._attn_block_tokens = bp * self.page_size
         self._attn_blocks_total = -(-self.pages_per_seq // bp)
@@ -424,7 +429,6 @@ class ServingBackend(StepBackend):
             # writes land on page 0 and no live sequence's gather can see them
             token_seq[:] = s_rows
             ti = 0
-            longest = 1  # positions of the longest row (padding sits at 0)
             spans: list[tuple[int, int]] = []  # entry i's [lo, hi) buffer slots
             for i, e in enumerate(entries):
                 row = self._clamp(e.tokens)
@@ -449,7 +453,6 @@ class ServingBackend(StepBackend):
                 out_idx[i] = ti + n - 1
                 spans.append((ti, ti + n))
                 ti += n
-                longest = max(longest, e.start + n)
             shape_key = ("ragged", t_buf, s_rows, self.pages_per_seq)
             self.last_step_compiled = shape_key not in self._compiled_shapes
             if self.last_step_compiled:
@@ -487,21 +490,40 @@ class ServingBackend(StepBackend):
                 self.last_aux = out[t_buf:].reshape(self.spec.aux_shape)
                 if self.spec.count_aux is not None:
                     self.last_counters = self.spec.count_aux(self.last_aux, ti)
-            if self.window:
-                # as the program walks: each slot from the block of its
-                # oldest visible key to its own, the step's longest walk
-                bt = self._attn_block_tokens
-                first = np.maximum(positions[:ti] - (self.window - 1), 0) // bt
-                self.last_window_blocks = int(
-                    (positions[:ti] // bt - first).max()) + 1
+            self._count_walk(np.array(spans), positions)
             if self.on_step is not None:
                 self.on_step(entries)
         self._steps_done = n_step + 1
         self.last_phases = tuple(marks)
-        self.last_attn_blocks = (
-            -(-longest // self._attn_block_tokens), self._attn_blocks_total
-        )
         return res
+
+    def _count_walk(self, spans: Any, positions: Any) -> None:
+        """The step's walk as ``llama.paged_attention`` makes it, counted on
+        the host from ``spans`` (int [rows, 2]: each fed row's buffer slots)
+        and the packed ``positions``: the rows cut into tiles, the tiles in
+        the program's order, each group of them walked by the program's rule
+        (``llama.walk_blocks``)."""
+        from ..models import llama
+
+        w, g = llama.ATTN_TILE_SLOTS, llama.ATTN_GROUP_TILES
+        lo = np.concatenate([np.arange(a, b, w) for a, b in spans])  # a tile's first slot
+        hi = np.minimum(lo + w, np.repeat(spans[:, 1], -(-(spans[:, 1] - spans[:, 0]) // w)))
+        order = llama.walk_order(positions[hi - 1], np.ones(len(lo), bool))
+        oldest, newest = positions[lo][order], positions[hi - 1][order]
+        bt = self._attn_block_tokens
+        rows = slots = 0
+        for window in ((None, self.window) if self.window else (None,)):
+            longest = 0
+            for a in range(0, len(order), g):
+                trips = int(llama.walk_blocks(oldest[a:a + g], newest[a:a + g], bt, window)[1])
+                longest = max(longest, trips)
+                rows += g * trips
+                slots += g * w * trips
+            if window is None:
+                self.last_attn_blocks = (longest, self._attn_blocks_total)
+            else:
+                self.last_window_blocks = longest
+        self.last_attn_rows = (rows, slots)
 
     # ------------------------------------------------------------------
     # live KV-page migration (serving/migration.py, docs/PROTOCOL.md §Page

@@ -153,6 +153,10 @@ class ServingStats:
     # step's longest live row) of the blocks the page tables hold
     attn_blocks_walked: int = 0
     attn_blocks_total: int = 0
+    # table rows the walk's tiles gathered, a block of pages each, summed
+    # over steps, by one full and one window layer together: x a block's
+    # bytes x the layers of the kind, what the attention read
+    attn_rows_gathered: int = 0
     # a model with window layers (docs/SERVING.md §Two kinds of page): blocks
     # its window layers' walk read, summed over steps; ring slots written
     # again after a lap (a page's worth of the row fell out of the window);
@@ -1376,6 +1380,8 @@ class ServingEngine:
         walked, of = self.backend.last_attn_blocks
         self.stats.attn_blocks_walked += walked
         self.stats.attn_blocks_total += of
+        kv_rows, q_rows = self.backend.last_attn_rows
+        self.stats.attn_rows_gathered += kv_rows
         attrs = {
             "occupancy": str(len(rows)),
             "live_tokens": str(sum(chunk for _, chunk, _, _ in rows)),
@@ -1385,6 +1391,8 @@ class ServingEngine:
         }
         if of:
             attrs["kv_blocks"] = f"{walked}/{of}"
+            attrs["kv_rows"] = str(kv_rows)
+            attrs["q_rows"] = str(q_rows)
         if self.ring_pages:
             attrs["window_blocks"] = str(self._count_window(rows, pos_before))
         counters = self.backend.last_counters

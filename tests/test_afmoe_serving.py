@@ -157,10 +157,44 @@ def test_paged_attention_with_a_window_reads_a_ring(window, ring_pages, block_pa
         want.append(dense_window_attention(q, k, v, window)[-1])
         qs.append(q[-1])
     got = llama.paged_attention(
-        jnp.asarray(np.stack(qs)), jnp.asarray(kp), jnp.asarray(vp), 0, jnp.asarray(tables),
+        jnp.asarray(np.stack(qs)), jnp.asarray(kp), jnp.asarray(vp), 0,
+        jnp.asarray(np.concatenate([tables, tables[:1] * 0])),  # and the padding row
+        jnp.arange(len(lens), dtype=jnp.int32),
         jnp.asarray([n - 1 for n in lens], jnp.int32), block_pages, window=window)
     np.testing.assert_allclose(np.asarray(got), np.stack(want), atol=2e-5)
     assert llama.window_ring_pages(window, ps, 1) <= ring_pages
+
+
+@pytest.mark.parametrize("rows", [
+    [(5, 1), (0, 1), (143, 1)],  # decode rows only
+    [(7, 12)],  # one chunk
+    [(40, 1), (99, 1), (3, 1), (60, 12)],  # decode rows and a chunk
+    [(100, 7), (41, 5)],  # two chunks of unlike size
+    [(90, 12)],  # a chunk whose first slot's window opens two blocks before its last slot
+    [(200, 3), (17, 3)],  # draft rows
+], ids=["decode", "chunk", "decode+chunk", "two-chunks", "chunk-across-blocks", "drafts"])
+def test_the_host_counts_the_trips_the_program_walks(rows):
+    """``last_attn_blocks`` / ``last_window_blocks`` / ``last_attn_rows``
+    against the walk by hand: the rows cut into tiles of 8 slots, the tiles
+    by falling newest position, 8 a group, a group from the block of the
+    oldest key its tiles' oldest slots see to the block of their newest."""
+    cfg = tiny()
+    be = backend_for(cfg, afmoe.init_params(jax.random.PRNGKey(3), cfg))
+    book = Rows(be, len(rows))
+    be.step([book.entry(i, [1 + i] * n, start) for i, (start, n) in enumerate(rows)])
+    bt = llama.attn_block_pages(PS, be.pages_per_seq) * PS
+    w, g = llama.ATTN_TILE_SLOTS, llama.ATTN_GROUP_TILES
+    tiles = sorted(((s + k, min(s + k + w, s + n) - 1) for s, n in rows for k in range(0, n, w)),
+                   key=lambda tile: -tile[1])
+    assert len(tiles) <= llama.attn_tiles(be.max_batch_tokens, be.max_seqs)
+    full = [max(hi // bt for _, hi in tiles[a:a + g]) + 1 for a in range(0, len(tiles), g)]
+    ring = [max(hi // bt - max(lo - cfg.window + 1, 0) // bt for lo, hi in tiles[a:a + g]) + 1
+            for a in range(0, len(tiles), g)]
+    assert be.last_attn_blocks == (max(full), be.pages_per_seq * PS // bt)
+    assert be.last_window_blocks == max(ring)
+    assert be.last_attn_rows == (g * sum(full + ring), g * w * sum(full + ring))
+    # the step's longest walk is its longest row's, whatever the tiles
+    assert be.last_attn_blocks[0] == max(s + n - 1 for s, n in rows) // bt + 1
 
 
 def reference_expert_part(cfg, layer, m, first, held):
@@ -239,12 +273,14 @@ async def test_engine_serves_mixed_rows_bounded_and_counted(held):
     eng = ServingEngine(be, run_blocking=run_blocking, max_sessions=3, max_new_tokens_cap=64)
     assert eng.prefix is None and eng.tiering is None  # sharing is off for this family
     seen = []  # per step: (live tokens, counts, window pages per row, blocks)
+    seen_rows = []  # per step: table rows the walks gathered
     inner = be.step
 
     def tapped(entries):
         out = inner(entries)
         seen.append((sum(len(e.tokens) for e in entries), be.last_aux.copy(),
                      [len(e.window_pages) for e in entries], be.last_window_blocks))
+        seen_rows.append(be.last_attn_rows[0])
         return out
     be.step = tapped
     rng = np.random.default_rng(held)
@@ -274,10 +310,12 @@ async def test_engine_serves_mixed_rows_bounded_and_counted(held):
         assert st.moe_assignments_here == st.moe_assignments  # all experts are here
     else:
         assert 0 < st.moe_assignments_here < st.moe_assignments
-    # the window layers' walk is bounded by the window, the full layer's is not
+    # the window layers' walk is bounded by the window and a step's buffer,
+    # the full layer's is not
     bt = llama.attn_block_pages(PS, be.pages_per_seq) * PS
-    assert max(b for _, _, _, b in seen) <= cfg.window // bt + 2
+    assert max(b for _, _, _, b in seen) <= (cfg.window + be.max_batch_tokens) // bt + 2
     assert st.attn_blocks_walked > st.window_blocks_walked
+    assert st.attn_rows_gathered == sum(seen_rows) > 0
 
 
 async def test_what_the_family_cannot_do_is_refused():

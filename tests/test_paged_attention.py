@@ -1,8 +1,8 @@
 """``llama.paged_attention``: the ragged step's attention (docs/SERVING.md
-§The ragged entry point) — grouped-query products over blocks of pages, an
-online softmax, and a walk that ends at the step's longest live row — held
-to a plain dense per-row reference, and the counter the serving engine
-keeps of the walk."""
+§The ragged entry point) — one gather of pages a table row and block, a
+row's slots as the rows of grouped-query products, an online softmax, and a
+walk that ends at the step's longest live row — held to a plain dense
+per-slot reference, and the counters the serving engine keeps of the walk."""
 import asyncio
 import math
 
@@ -24,18 +24,24 @@ NAN_PAGE = 39
 NUM_PAGES = 40
 
 
-def dense_reference(q, k_pages, v_pages, layer, tables, positions):
+def dense_reference(q, k_pages, v_pages, layer, tables, token_seq, positions,
+                    window=None):
     """Per slot and head, softmax(q . K / sqrt(hd)) . V over the slot's own
-    positions [0, position], in float64, K and V read page by page."""
+    visible positions ([0, position], or the last ``window`` of them), in
+    float64, K and V read page by page out of the slot's table row (a ring
+    under a window: logical page n in slot n % ring)."""
     q, kp, vp = (np.asarray(x, np.float64) for x in (q, k_pages, v_pages))
     t, h, hd = q.shape
+    ps = kp.shape[2]
     rep = h // kp.shape[3]
     out = np.zeros((t, h, hd))
     for i in range(t):
-        n = int(positions[i]) + 1
-        pages = np.asarray(tables[i])[: -(-n // PS)]
-        k = kp[layer][pages].reshape(-1, kp.shape[3], hd)[:n]
-        v = vp[layer][pages].reshape(-1, kp.shape[3], hd)[:n]
+        row = np.asarray(tables[token_seq[i]])
+        hi = int(positions[i]) + 1
+        lo = 0 if window is None else max(0, hi - window)
+        at = np.arange(lo, hi)
+        pages = row[(at // ps) % len(row)] if window else row[at // ps]
+        k, v = kp[layer][pages, at % ps], vp[layer][pages, at % ps]
         for j in range(h):
             s = k[:, j // rep] @ q[i, j] / math.sqrt(hd)
             p = np.exp(s - s.max())
@@ -61,21 +67,29 @@ def arena_and_rows(rep, dtype, seed=0):
     token_seq = np.array([0, 1, 2, 2, 2, 2, 2, 2, 3, 4, 5, 5], np.int32)
     positions = np.array([CONTEXT - 1, 0, 9, 10, 11, 12, 13, 14, 6, 7, 0, 0], np.int32)
     q = jnp.asarray(rng.normal(size=(len(positions), KVH * rep, HD)), dtype)
-    return q, k_pages, v_pages, rows[token_seq], positions
+    return q, k_pages, v_pages, rows, token_seq, positions
+
+
+def attend(q, k_pages, v_pages, layer, tables, token_seq, positions, block_pages,
+           window=None):
+    return llama.paged_attention(
+        q, k_pages, v_pages, layer, jnp.asarray(tables), jnp.asarray(token_seq),
+        jnp.asarray(positions), block_pages, window=window)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("rep", [1, 2, 4, 8])
 def test_matches_dense_reference(rep, dtype, tol):
-    q, k_pages, v_pages, tables, positions = arena_and_rows(rep, dtype)
+    q, k_pages, v_pages, tables, token_seq, positions = arena_and_rows(rep, dtype)
+    fed = token_seq < len(tables) - 1
     for layer in range(LAYERS):
-        got = llama.paged_attention(
-            q, k_pages, v_pages, layer, jnp.asarray(tables), jnp.asarray(positions),
-            BLOCK_PAGES)
+        got = attend(q, k_pages, v_pages, layer, tables, token_seq, positions, BLOCK_PAGES)
         assert got.dtype == q.dtype and got.shape == q.shape
-        want = dense_reference(q, k_pages, v_pages, layer, tables, positions)
-        np.testing.assert_allclose(np.asarray(got, np.float64), want, atol=tol, rtol=tol)
+        got = np.asarray(got, np.float64)
+        assert np.isfinite(got).all()  # the padding slots too
+        want = dense_reference(q, k_pages, v_pages, layer, tables, token_seq, positions)
+        np.testing.assert_allclose(got[fed], want[fed], atol=tol, rtol=tol)
 
 
 @pytest.mark.parametrize("longest", [0, BLOCK_TOKENS - 1, BLOCK_TOKENS, 2 * BLOCK_TOKENS + 5])
@@ -87,16 +101,98 @@ def test_blocks_past_the_longest_live_row_are_not_read(longest):
     k_pages = jnp.asarray(rng.normal(size=shape), jnp.float32).at[:, NAN_PAGE].set(jnp.nan)
     v_pages = jnp.asarray(rng.normal(size=shape), jnp.float32).at[:, NAN_PAGE].set(jnp.nan)
     walked_pages = (longest // BLOCK_TOKENS + 1) * BLOCK_PAGES
-    tables = np.full((4, P), NAN_PAGE, np.int32)
+    tables = np.full((5, P), NAN_PAGE, np.int32)
+    tables[4] = 0  # the padding row: the null page
     for i in range(4):
         tables[i, :walked_pages] = rng.integers(1, NAN_PAGE, size=min(P, walked_pages))
+    token_seq = np.arange(4, dtype=np.int32)
     positions = np.array([longest, 0, longest // 2, 0], np.int32)
     q = jnp.asarray(rng.normal(size=(4, KVH * 2, HD)), jnp.float32)
-    got = np.asarray(llama.paged_attention(
-        q, k_pages, v_pages, 1, jnp.asarray(tables), jnp.asarray(positions), BLOCK_PAGES))
+    got = np.asarray(attend(q, k_pages, v_pages, 1, tables, token_seq, positions, BLOCK_PAGES))
     assert np.isfinite(got).all()
-    want = dense_reference(q, k_pages, v_pages, 1, tables, positions)
+    want = dense_reference(q, k_pages, v_pages, 1, tables, token_seq, positions)
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+
+
+# ------------------------------------------------------ the walk over tiles
+# steps as ``ServingBackend.step`` packs them: each row's slots contiguous,
+# the tail of the buffer on the padding row.  (start, slots) a row.
+ROW_T, ROW_S = 32, 16
+ROW_STEPS = {
+    "decode-rows-only": [(5, 1), (0, 1), (43, 1), (17, 1), (30, 1)],
+    "one-chunk-of-T-slots": [(7, ROW_T)],
+    "15-decode-rows-and-a-chunk": [(3 * i, 1) for i in range(15)] + [(11, ROW_T - 15)],
+    "two-chunks-of-unlike-size": [(0, 19), (25, 6), (40, 1)],
+    "draft-rows-of-1+k-slots": [(9, 5), (33, 5), (2, 5), (21, 1), (14, 5)],
+    "no-live-row-but-one": [(38, 1)],
+    # window 8 over blocks of 8 positions: a tile's first slot reaches back
+    # into the block before, its later slots see nothing there
+    "window:a-chunk-straddles-the-windows-edge": [(15, 20), (3, 1)],
+    # a ring of 11 pages (44 positions): positions 70.. sit on their second
+    # lap, and a block of the chunk's walk spans the ring's seam
+    "window:a-row-whose-walk-wraps-its-ring": [(70, 27), (60, 3), (39, 2)],
+}
+
+
+def packed(rows, t_buf, s_rows):
+    token_seq = np.full((t_buf,), s_rows, np.int32)
+    positions = np.zeros((t_buf,), np.int32)
+    ti = 0
+    for i, (start, n) in enumerate(rows):
+        token_seq[ti:ti + n] = i
+        positions[ti:ti + n] = np.arange(start, start + n)
+        ti += n
+    return token_seq, positions
+
+
+@pytest.mark.parametrize("case", list(ROW_STEPS))
+def test_every_fed_slot_of_a_tiled_step_matches_the_reference(case):
+    rows = ROW_STEPS[case]
+    window = 8 if case.startswith("window:") else None
+    ps, kvh, rep, hd, bp = 4, 2, 3, 8, 2
+    width = llama.window_ring_pages(window, ps, ROW_T) if window else 24
+    assert not window or width == 11
+    rng = np.random.default_rng(len(case))
+    n_pages = 1 + ROW_S * width
+    shape = (1, n_pages, ps, kvh, hd)
+    k_pages, v_pages = (rng.normal(size=shape).astype(np.float32) for _ in range(2))
+    tables = np.zeros((ROW_S + 1, width), np.int32)
+    tables[:ROW_S] = 1 + rng.permutation(ROW_S * width).reshape(ROW_S, width)
+    token_seq, positions = packed(rows, ROW_T, ROW_S)
+    fed = token_seq < ROW_S
+    q = rng.normal(size=(ROW_T, kvh * rep, hd)).astype(np.float32)
+    got = np.asarray(attend(jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages), 0,
+                            tables, token_seq, positions, bp, window=window))
+    assert np.isfinite(got).all()  # a slot nobody reads is finite too
+    want = dense_reference(q, k_pages, v_pages, 0, tables, token_seq, positions, window)
+    np.testing.assert_allclose(got[fed], want[fed], atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("t_buf,s_rows,want", [(64, 16, 24), (32, 16, 24), (12, 4, 8), (2, 8, 8)])
+def test_the_tiles_bound_every_split_of_the_buffer(t_buf, s_rows, want):
+    """A tile holds up to 8 slots of ONE row, so a row wastes less than one:
+    T // 8 + S tiles hold any split of the buffer over the rows."""
+    assert llama.attn_tiles(t_buf, s_rows) == want
+    assert want % llama.ATTN_GROUP_TILES == 0
+    rng = np.random.default_rng(t_buf)
+    for _ in range(200):
+        cuts = np.sort(rng.integers(0, t_buf + 1, size=s_rows))
+        counts = np.diff(np.concatenate([[0], cuts]))
+        assert sum(-(-int(n) // llama.ATTN_TILE_SLOTS) for n in counts) <= want
+
+
+@pytest.mark.parametrize("window", [None, 20])
+def test_one_rule_for_the_traced_bound_and_the_host_count(window):
+    """``walk_blocks`` on numpy and on jax arrays alike; under a window a
+    tile walks from its OLDEST slot's oldest visible key."""
+    oldest, newest = np.array([100, 0, 37, 0]), np.array([130, 0, 37, 0])
+    first, trips = llama.walk_blocks(oldest, newest, 16, window)
+    jfirst, jtrips = jax.jit(lambda a, b: llama.walk_blocks(a, b, 16, window))(oldest, newest)
+    assert list(first) == list(np.asarray(jfirst)) and int(trips) == int(jtrips)
+    if window is None:
+        assert list(first) == [0, 0, 0, 0] and trips == 130 // 16 + 1
+    else:  # the long tile's first key is 100 - 19 = 81: blocks 5..8
+        assert list(first) == [5, 0, 1, 0] and trips == 4
 
 
 @pytest.mark.parametrize("page_size,pages_per_seq,want", [
@@ -162,10 +258,10 @@ def whole_row_step(params, k_pages, v_pages, tokens, positions, page_tables,
                    token_seq, out_idx):
     """The attention this PR replaced, as the control: each slot gathers
     its whole page-table row, K and V are repeated to all query heads."""
-    def whole_row(q, k_pages, v_pages, layer, tables, positions, block_pages):
+    def whole_row(q, k_pages, v_pages, layer, tables, token_seq, positions, block_pages):
         t = q.shape[0]
-        kc = k_pages[layer][tables].reshape(t, -1, *k_pages.shape[3:])
-        vc = v_pages[layer][tables].reshape(t, -1, *v_pages.shape[3:])
+        kc = k_pages[layer][tables[token_seq]].reshape(t, -1, *k_pages.shape[3:])
+        vc = v_pages[layer][tables[token_seq]].reshape(t, -1, *v_pages.shape[3:])
         return llama._attention(
             q[:, None], kc, vc, WALK_CFG, q_offset=positions[:, None])[:, 0]
 
@@ -180,7 +276,8 @@ def whole_row_step(params, k_pages, v_pages, tokens, positions, page_tables,
 def test_no_array_of_the_whole_context_in_the_program():
     bad, loops = oversized(lambda *a: llama.ragged_step(*a, WALK_CFG))
     assert not bad, bad
-    assert loops == WALK_CFG.n_layers  # the walk was looked into, once a layer
+    # the walk was looked into: a layer's loop over groups, the blocks' inside it
+    assert loops == 2 * WALK_CFG.n_layers
 
 
 def test_the_walk_over_the_program_sees_a_repeat_and_a_whole_row_gather():
@@ -188,6 +285,80 @@ def test_the_walk_over_the_program_sees_a_repeat_and_a_whole_row_gather():
     assert any(b.startswith("gather") for b in bad), bad
     assert any("160,8,8]" in b for b in bad), bad  # K repeated to 8 heads
     assert any(b.endswith("[12,8,1,160]") for b in bad), bad  # the scores
+
+
+def walk_gathers(jaxpr):
+    """Result shapes of the arena gathers inside the program's loops (a
+    loop inside a loop is looked into once)."""
+    found = {}
+    for eqn in all_eqns(jaxpr):
+        if eqn.primitive.name == "while":
+            for inner in all_eqns(eqn.params["body_jaxpr"].jaxpr):
+                if inner.primitive.name == "gather" and inner.outvars[0].aval.ndim == 5:
+                    found[id(inner)] = tuple(inner.outvars[0].aval.shape)
+    return list(found.values())
+
+
+@pytest.mark.parametrize("name", ["mistral-7b-v0.3", "internlm2-1.8b",
+                                  "trinity-large-preview-ep8"])
+def test_the_walk_gathers_a_block_a_table_row_in_every_configuration(name):
+    """The serving program of each benchmark configuration at its cell's
+    pool, traced over shapes (nothing is built or compiled): every gather of
+    pages inside a walk has S rows, never T, in full and window layers."""
+    import importlib
+    import json
+    import pathlib
+
+    from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
+    from cordum_tpu.serving.modelspec import spec_for
+
+    doc = json.loads((pathlib.Path(__file__).parent.parent / "benchmarks" / "configs"
+                      / f"{name}.json").read_text())
+    cfg = importlib.import_module(f"benchmarks.families.{doc['family']}").program_config(doc)
+    spec, pool = spec_for(cfg), doc["pool"]
+    s_rows, ps = pool["max_sessions"], pool["page_size"]
+    t_buf = s_rows + pool["prefill_budget"]
+    ring = llama.window_ring_pages(spec.window, ps, t_buf) if spec.window else 0
+    widths = (cfg.max_seq_len // ps,) + ((ring,) if ring else ())
+    layout = FeedLayout(t_buf, s_rows, widths)
+    params = jax.eval_shape(spec.init_params, jax.random.PRNGKey(0))
+    arenas = jax.eval_shape(
+        lambda: spec.init_arenas(pool["pages"], ps, s_rows * ring + 1 if ring else 0))
+    program = make_ragged_program(cfg, layout, sample_logits=True, donate=False)
+    closed = jax.make_jaxpr(program)(
+        params, *arenas, jax.ShapeDtypeStruct((layout.size,), jnp.int32))
+    shapes = walk_gathers(closed.jaxpr)
+    kvh, hd = cfg.n_kv_heads, cfg.head_dim
+    bp = llama.attn_block_pages(ps, widths[0])
+    # K and V of a group of tiles: never a gather a buffer slot, and ONE
+    # walk traced a kind of page, whatever the number of layers
+    assert set(shapes) == {(llama.ATTN_GROUP_TILES, bp, ps, kvh, hd)}
+    assert llama.ATTN_GROUP_TILES < t_buf and len(shapes) == 2 * len(widths)
+
+
+def test_the_walk_over_slots_would_be_seen(monkeypatch):
+    """The control of the test above: with tiles of ONE slot, a group a
+    buffer (the walk this one replaced), a trip gathers T rows."""
+    def rows_gathered():
+        cfg = WALK_CFG
+        params = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+        arena = jax.ShapeDtypeStruct(
+            (cfg.n_layers, WALK_PAGES, WALK_PS, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        closed = jax.make_jaxpr(lambda *a: llama.ragged_step(*a, cfg))(
+            params, arena, arena, i32(WALK_T), i32(WALK_T),
+            i32(WALK_S + 1, cfg.max_seq_len // WALK_PS), i32(WALK_T), i32(WALK_S))
+        return {s[0] for s in walk_gathers(closed.jaxpr)}
+
+    assert rows_gathered() == {llama.ATTN_GROUP_TILES}
+    monkeypatch.setattr(llama, "ATTN_TILE_SLOTS", 1)
+    monkeypatch.setattr(llama, "ATTN_GROUP_TILES", WALK_T + WALK_S)
+    # a fresh function under a fresh jit: the walk's trace is cached by the
+    # function and its shapes, not by the constants
+    walk = llama.paged_attention.__wrapped__
+    monkeypatch.setattr(llama, "paged_attention",
+                        jax.jit(lambda *a: walk(*a), static_argnums=(7,)))
+    assert rows_gathered() == {WALK_T + WALK_S}
 
 
 # ------------------------------------------------------- backend and engine
@@ -236,7 +407,7 @@ def test_engine_counts_the_walk_and_stamps_the_step_span(backend, monkeypatch, p
         eng = ServingEngine(backend, run_blocking=run_blocking,
                             tracer=Tracer("worker", bus))
         eng.worker_id = "w-a"
-        seen = []
+        seen, rows = [], []
         real_step = backend.step
 
         def step(entries):
@@ -244,6 +415,14 @@ def test_engine_counts_the_walk_and_stamps_the_step_span(backend, monkeypatch, p
             longest = max(e.start + len(e.tokens) for e in entries)
             seen.append((-(-longest // 16), 8))
             assert backend.last_attn_blocks == seen[-1]
+            # one fed row: its tiles of 8 slots by falling position, 8 a group,
+            # a group to the block of its newest slot
+            (e,) = entries
+            ends = sorted((min(e.start + k + 8, e.start + len(e.tokens)) - 1
+                           for k in range(0, len(e.tokens), 8)), reverse=True)
+            trips = sum(ends[a] // 16 + 1 for a in range(0, len(ends), 8))
+            rows.append((8 * trips, 64 * trips))
+            assert backend.last_attn_rows == rows[-1]
             return res
 
         monkeypatch.setattr(backend, "step", step)
@@ -259,8 +438,10 @@ def test_engine_counts_the_walk_and_stamps_the_step_span(backend, monkeypatch, p
         assert seen[-1][0] == -(-(prompt_len + new - 1) // 16)
         steps = [sp for sp in spans if sp.name == "step"]
         assert steps  # the first cycle is always kept
+        assert eng.stats.attn_rows_gathered == sum(kv for kv, _ in rows)
         for sp in steps:
             n = int(sp.trace_id.rsplit("-", 1)[1])
             assert sp.attrs["kv_blocks"] == f"{seen[n][0]}/8"
+            assert (sp.attrs["kv_rows"], sp.attrs["q_rows"]) == tuple(map(str, rows[n]))
 
     asyncio.run(main())

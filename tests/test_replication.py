@@ -188,16 +188,23 @@ async def test_sync_mode_zero_acked_commit_loss_on_primary_crash():
     try:
         await wait_for(lambda: primary.repl.sessions, msg="replica attach")
 
-        async def writer(i: int) -> None:
-            await kv.set(f"sync-{i}", str(i).encode(), )
-            acked.append(i)
+        writing = True
 
-        # concurrent burst; crash the primary mid-stream
+        async def writer(i: int) -> None:
+            while writing:  # a stream, not a burst: a fast machine acks 60
+                # writes between two polls, and then nothing is mid-stream
+                await kv.set(f"sync-{i}", str(i).encode(), )
+                acked.append(i)
+                i += 60
+
+        # concurrent streams; crash the primary mid-stream
         tasks = [asyncio.ensure_future(writer(i)) for i in range(60)]
         await wait_for(lambda: len(acked) >= 10, msg="some acks")
         await primary.crash()
         # the failover walk retries the parked writes on the promoted
         # replica, so every writer eventually completes
+        await wait_for(lambda: replica.role == "primary", msg="promotion")
+        writing = False
         await asyncio.gather(*tasks)
         assert replica.role == "primary"
         for i in acked:
