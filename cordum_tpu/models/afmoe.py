@@ -60,6 +60,11 @@ class AfmoeConfig:
     first_expert: int = 0  # this chip holds [first_expert, first_expert + experts_held)
     experts_held: int = 16
     top_k: int = 2
+    # group-limited selection (:func:`route`): the experts in ``n_group``
+    # equal groups, a token's picks inside its ``topk_group`` best; 1 and 1
+    # is no limit (this family's published router)
+    n_group: int = 1
+    topk_group: int = 1
     n_shared: int = 1
     route_scale: float = 2.448
     route_norm: bool = True
@@ -77,6 +82,7 @@ class AfmoeConfig:
                 f"held of {self.n_experts}")
         if self.n_heads % self.n_kv_heads or not 0 <= self.n_dense_layers <= self.n_layers:
             raise ValueError("heads must group evenly; dense layers lead")
+        check_groups(self)
 
     @property
     def window_layers(self) -> tuple[int, ...]:
@@ -92,6 +98,16 @@ class AfmoeConfig:
 
     def serving_spec(self) -> Any:
         return serving_spec(self)
+
+
+def check_groups(cfg: Any) -> None:
+    """Refuse routing groups :func:`route` cannot select under."""
+    if cfg.n_experts % cfg.n_group or not 1 <= cfg.topk_group <= cfg.n_group:
+        raise ValueError(f"{cfg.n_experts} experts in {cfg.n_group} groups, "
+                         f"{cfg.topk_group} kept")
+    if cfg.n_group > 1 and (cfg.n_experts // cfg.n_group < 2
+                            or cfg.topk_group * (cfg.n_experts // cfg.n_group) < cfg.top_k):
+        raise ValueError("a group needs two experts to score by, the kept groups top_k to pick")
 
 
 # ---------------------------------------------------------------------------
@@ -150,15 +166,34 @@ def init_arenas(cfg: AfmoeConfig, num_pages: int, page_size: int, window_pages: 
 # ---------------------------------------------------------------------------
 
 
-def route(m: jax.Array, layer: Params, cfg: AfmoeConfig) -> tuple[jax.Array, jax.Array]:
+def route(m: jax.Array, layer: Params, cfg: Any) -> tuple[jax.Array, jax.Array]:
     """Every token over ALL ``n_experts``, in float32: ``(sel [T, k] expert
-    ids, w [T, k] weights)``.  The bias takes part in the selection only;
-    the weights are the selected sigmoid scores, normalised over the k
-    selected (held here or not) and scaled."""
+    ids, w [T, k] weights)``.  The selection bias, where the layer has one,
+    takes part in the selection only; the weights are the selected sigmoid
+    scores, normalised over the k selected (held here or not) and scaled.
+
+    THE selection code of every sparse family (``cfg``: an ``AfmoeConfig``,
+    or another family's config with the same routing fields).  With
+    ``cfg.n_group`` > 1 the selection is group-limited: the experts lie in
+    ``n_group`` equal groups, a group's score is the sum of its two best
+    experts', and a token picks its ``top_k`` among the experts of its
+    ``topk_group`` best groups only, so its experts span at most that many
+    groups (in a deployment: chips).  One group is no limit and the program
+    it always was."""
     scores = jax.nn.sigmoid(jnp.matmul(
         m.astype(jnp.float32), layer["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, sel = jax.lax.top_k(scores + layer["router_bias"], cfg.top_k)
+    pick = scores + layer["router_bias"] if "router_bias" in layer else scores
+    if cfg.n_group > 1:
+        with jax.named_scope("moe_group_select"):
+            t, n = pick.shape
+            by_group = pick.reshape(t, cfg.n_group, n // cfg.n_group)
+            best2, _ = jax.lax.top_k(by_group, 2)
+            _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), cfg.topk_group)  # [T, topk_group]
+            keep = jnp.zeros((t, cfg.n_group), bool).at[
+                jnp.arange(t)[:, None], kept].set(True)
+            pick = jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(t, n)
+    _, sel = jax.lax.top_k(pick, cfg.top_k)
     w = jnp.take_along_axis(scores, sel, axis=1)
     if cfg.route_norm:
         w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
@@ -166,7 +201,7 @@ def route(m: jax.Array, layer: Params, cfg: AfmoeConfig) -> tuple[jax.Array, jax
 
 
 def expert_layer(
-    m: jax.Array, layer: Params, cfg: AfmoeConfig, live: jax.Array
+    m: jax.Array, layer: Params, cfg: Any, live: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
     """This chip's part of the expert layer for ``m`` [T, d]: the shared
     expert plus the weighted outputs of the HELD experts, float32 [T, d];
@@ -296,7 +331,7 @@ def ragged_step(
     return jnp.concatenate([nxt, tail]), k_pages, v_pages, wk_pages, wv_pages
 
 
-def step_counters(cfg: AfmoeConfig, counts: Any, live_tokens: int) -> dict[str, int]:
+def step_counters(cfg: Any, counts: Any, live_tokens: int) -> dict[str, int]:
     """What one step's ``counts`` (int [expert layers, experts held], as
     :func:`ragged_step` returned them) add to ``ServingStats``: assignments
     the router made, those to experts held here, held experts that got a
@@ -314,7 +349,7 @@ def serving_spec(cfg: AfmoeConfig) -> Any:
     """The family's specification for the serving backend
     (``serving/modelspec.py``): two kinds of page, the experts' counts
     behind the tokens."""
-    from ..serving.modelspec import ModelSpec
+    from ..serving.modelspec import ModelSpec, kv_pair
 
     def program(sample_logits):
         def ragged_program(p, kp, vp, wkp, wvp, toks, pos, pt, wpt, ts, oi):
@@ -328,10 +363,11 @@ def serving_spec(cfg: AfmoeConfig) -> Any:
         init_params=lambda key: init_params(key, cfg),
         init_arenas=lambda n, ps, w: init_arenas(cfg, n, ps, w),
         program=program, window=cfg.window,
+        arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),) * 2,
         aux_shape=(cfg.n_expert_layers, cfg.experts_held),
         count_aux=lambda counts, live: step_counters(cfg, counts, live),
     )
 
 
-__all__ = ["AfmoeConfig", "init_params", "init_arenas", "route", "expert_layer",
+__all__ = ["AfmoeConfig", "check_groups", "init_params", "init_arenas", "route", "expert_layer",
            "ragged_step", "serving_spec", "step_counters", "SLIDING", "FULL"]

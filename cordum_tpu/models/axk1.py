@@ -1,0 +1,329 @@
+"""A.X-K1 decoder (``model_type: axk1``, the DeepSeek-V3 line of latent
+attention) on the serving path.
+
+What the block has that ``models/llama`` and ``models/afmoe`` have not
+(docs/SERVING.md §The latent page, §The absorbed walk):
+
+  * **latent attention (MLA)** — queries through a low-rank pair
+    (``wqa`` -> norm -> ``wqb``), keys and values through ONE shared latent
+    ``c`` [kv_rank] a token (``wkva`` -> norm) that ``wkvb`` expands per head
+    into a position-free key part and a value, and ONE rotated key part
+    ``kr`` [rope_dim] shared by all heads (decoupled RoPE, YaRN-scaled).
+    **The cache keeps ``(c | kr)`` a token and layer** — ``kv_rank +
+    rope_dim`` numbers (576), no head axis, no V — in one arena ``[L, pages,
+    page_size, 576]``;
+  * **the absorbed form** — the serving step never expands a cached row to
+    K and V by head.  With ``wkvb`` split per head into ``Wuk_h`` and
+    ``Wuv_h``: ``ql_h = q_nope_h Wuk_h^T``, the score of head ``h`` is ``s
+    (ql_h | q_rope_h) . (c | kr)``, ``ol_h = softmax(score_h) c`` and ``o_h =
+    ol_h Wuv_h``: the walk (``llama.paged_attention``) sees one shared key
+    head 576 wide under all query heads and takes a key's leading 512
+    columns as its value.  Identical to the published form in exact
+    arithmetic (``benchmarks/families/axk1_reference.py`` is the published
+    one).  Prefill chunks and decode rows take the same walk: expanding a
+    row costs ``kv_rank x h x (nope + v)`` multiply-adds a key, which a
+    chunk of fewer than about 170 slots does not win back;
+  * **a group-limited sigmoid router** over ``models/afmoe``'s dropless
+    expert layer, which is told which experts this chip holds: THE
+    selection code and THE expert layer of both sparse families
+    (``afmoe.route``, ``afmoe.expert_layer``), nothing of them copied.
+
+Pre-norm residual block, no bias anywhere, untied head, one leading dense
+layer.  The residual stream is float32 as in ``models/afmoe`` (the router
+reads it unrounded); every matrix product takes its inputs in ``cfg.dtype``.
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from .afmoe import check_groups, expert_layer, step_counters
+from .llama import attn_block_pages, paged_attention, rms_norm
+
+Params = dict
+LANES = 128  # a TPU tile's minor dimension
+
+
+@dataclass(frozen=True)
+class Axk1Config:
+    vocab_size: int = 256
+    d_model: int = 64
+    n_heads: int = 4
+    q_rank: int = 32  # q_lora_rank
+    kv_rank: int = 32  # kv_lora_rank: the latent a token keeps
+    nope_dim: int = 16  # qk_nope_head_dim
+    rope_dim: int = 8  # qk_rope_head_dim: ONE rotated key part, shared by the heads
+    v_dim: int = 16  # v_head_dim
+    d_ff: int = 128  # the leading dense layers' SwiGLU width
+    d_expert: int = 32  # every routed expert's and the shared expert's width
+    n_layers: int = 3
+    n_dense_layers: int = 1
+    n_experts: int = 16  # the router's width: experts of the whole layer
+    first_expert: int = 0  # this chip holds [first_expert, first_expert + experts_held)
+    experts_held: int = 16
+    top_k: int = 4
+    n_group: int = 4
+    topk_group: int = 2
+    n_shared: int = 1
+    route_scale: float = 2.5
+    route_norm: bool = True
+    rope_theta: float = 10000.0
+    # YaRN (rope_scaling): factor, original context, the two betas and the
+    # two mscales; factor 1 is plain RoPE
+    rope_factor: float = 1.0
+    rope_original_len: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    norm_eps: float = 1e-6
+    max_seq_len: int = 256
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self) -> None:
+        if not 0 <= self.first_expert <= self.first_expert + self.experts_held <= self.n_experts:
+            raise ValueError(
+                f"experts [{self.first_expert}, {self.first_expert + self.experts_held}) "
+                f"held of {self.n_experts}")
+        if not 0 <= self.n_dense_layers <= self.n_layers or self.rope_dim % 2:
+            raise ValueError("dense layers lead; the rotated part pairs its dimensions")
+        check_groups(self)
+
+    @property
+    def n_kv_heads(self) -> int:
+        """Key heads the walk sees: the absorbed form has ONE, shared."""
+        return 1
+
+    @property
+    def latent_dim(self) -> int:
+        """Numbers the cache keeps a token and layer: ``(c | kr)``."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Columns of the arena: ``latent_dim`` in whole 128-lane tiles, the
+        rest zeros.  A TPU pads a 576-wide row to 640 in memory whatever the
+        shape says; stated in the shape, the arena keeps the page-major
+        layout the walk's gather needs (left to the runtime, a padded shape
+        is laid out pages-minor and the program copies the whole arena every
+        step: PERF.md section 6, PR 30)."""
+        return -(-self.latent_dim // LANES) * LANES
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
+
+    @property
+    def softmax_scale(self) -> float:
+        """``(nope + rope)^-0.5 x m^2``, ``m`` YaRN's ``mscale_all_dim`` term."""
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
+    def serving_spec(self) -> Any:
+        return serving_spec(self)
+
+
+# ---------------------------------------------------------------------------
+# rotary positions, YaRN
+# ---------------------------------------------------------------------------
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: Axk1Config) -> jax.Array:
+    """The ``rope_dim / 2`` inverse frequencies: per frequency a blend of
+    the original ``theta^(-2i/d)`` and the interpolated one (``/ factor``) by
+    a linear ramp between the correction dimensions of ``beta_fast`` and
+    ``beta_slow`` rotations over the original context — fast dimensions keep
+    their frequency, slow ones are interpolated."""
+    d = cfg.rope_dim
+    extra = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if cfg.rope_factor <= 1:
+        return extra
+
+    def correction_dim(rotations: float) -> float:
+        return d * math.log(cfg.rope_original_len / (rotations * 2 * math.pi)) / (
+            2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0, 1)
+    return extra / cfg.rope_factor * ramp + extra * (1 - ramp)
+
+
+def rope(x: jax.Array, positions: jax.Array, cfg: Axk1Config) -> jax.Array:
+    """x: [T, ..., rope_dim] rotated at ``positions`` [T], half-split pairing
+    (dimension i with i + d/2), cos and sin scaled by YaRN's ``mscale /
+    mscale_all_dim`` ratio (1 in the published configuration)."""
+    ang = positions.astype(jnp.float32)[:, None] * yarn_inv_freq(cfg)[None, :]
+    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
+    ratio = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+             / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    cos, sin = jnp.cos(ang) * ratio, jnp.sin(ang) * ratio
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# params, arenas
+# ---------------------------------------------------------------------------
+
+
+def init_params(key: jax.Array, cfg: Axk1Config) -> Params:
+    """Seeded weights: normal(0, 1/sqrt(fan_in)) matrices, norms at 1, no
+    selection bias.  ``wkvb`` is the published ``[kv_rank, heads x (nope +
+    v)]``, a head's key part before its value."""
+    d, h = cfg.d_model, cfg.n_heads
+    fe, held = cfg.d_expert, cfg.experts_held
+    keys = jax.random.split(key, cfg.n_layers + 2)
+
+    def dense(k, shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)).astype(cfg.dtype)
+
+    ones = lambda n: jnp.ones((n,), cfg.dtype)  # noqa: E731
+    layers = []
+    for i in range(cfg.n_layers):
+        lk = jax.random.split(keys[i], 16)
+        layer = {
+            "norm_in": ones(d), "norm_post": ones(d),
+            "q_norm": ones(cfg.q_rank), "kv_norm": ones(cfg.kv_rank),
+            "wqa": dense(lk[0], (d, cfg.q_rank), d),
+            "wqb": dense(lk[1], (cfg.q_rank, h * (cfg.nope_dim + cfg.rope_dim)), cfg.q_rank),
+            "wkva": dense(lk[2], (d, cfg.latent_dim), d),
+            "wkvb": dense(lk[3], (cfg.kv_rank, h * (cfg.nope_dim + cfg.v_dim)), cfg.kv_rank),
+            "wo": dense(lk[4], (h * cfg.v_dim, d), h * cfg.v_dim),
+        }
+        if i < cfg.n_dense_layers:
+            layer.update(w_gate=dense(lk[5], (d, cfg.d_ff), d), w_up=dense(lk[6], (d, cfg.d_ff), d),
+                         w_down=dense(lk[7], (cfg.d_ff, d), cfg.d_ff))
+        else:
+            fs = fe * cfg.n_shared
+            layer.update(
+                router=dense(lk[5], (d, cfg.n_experts), d),
+                e_gate=dense(lk[7], (held, d, fe), d), e_up=dense(lk[8], (held, d, fe), d),
+                e_down=dense(lk[9], (held, fe, d), fe),
+                s_gate=dense(lk[10], (d, fs), d), s_up=dense(lk[11], (d, fs), d),
+                s_down=dense(lk[12], (fs, d), fs))
+        layers.append(layer)
+    return {"embed": dense(keys[-2], (cfg.vocab_size, d), d), "layers": layers,
+            "final_norm": ones(d), "lm_head": dense(keys[-1], (d, cfg.vocab_size), d)}
+
+
+def init_arenas(cfg: Axk1Config, num_pages: int, page_size: int) -> tuple[jax.Array]:
+    """The ONE arena: ``[L, num_pages, page_size, latent_width]``, a slot
+    ``(c | kr | zeros to the tile)``."""
+    return (jnp.zeros((cfg.n_layers, num_pages, page_size, cfg.latent_width), cfg.dtype),)
+
+
+# ---------------------------------------------------------------------------
+# the ragged serving step
+# ---------------------------------------------------------------------------
+
+
+def ragged_step(
+    params: Params,
+    c_pages: jax.Array,
+    tokens: jax.Array,
+    positions: jax.Array,
+    page_tables: jax.Array,
+    token_seq: jax.Array,
+    out_idx: jax.Array,
+    cfg: Axk1Config,
+    *,
+    sample_logits: bool = True,
+) -> tuple[jax.Array, jax.Array]:
+    """One ragged mixed prefill+decode step (the contract of
+    ``llama.ragged_step``) over the latent arena ``c_pages`` [L, N, ps,
+    latent_width].  Every token's ``(c | kr)`` is written at ``(table[row][pos
+    // ps], pos % ps)`` before the walk, so a chunk's later tokens see its
+    earlier ones.  Returns ``(out, c_pages)``, ``out`` int32 [T + expert
+    layers x experts_held]: the per-slot next-token argmax, then the
+    assignments each held expert got in each expert layer."""
+    t_buf = tokens.shape[0]
+    h, nope, rd, vd, rank = cfg.n_heads, cfg.nope_dim, cfg.rope_dim, cfg.v_dim, cfg.kv_rank
+    ps = c_pages.shape[2]
+    live = token_seq < page_tables.shape[0] - 1  # the last row is the padding row
+    page_idx = page_tables[token_seq, positions // ps]  # [T]
+    slot = positions % ps
+    block_pages = attn_block_pages(ps, page_tables.shape[1])
+    scale = cfg.softmax_scale
+    counts = []
+    dt = params["embed"].dtype
+    # zeros from a latent's 576 numbers to the arena's whole tiles, beside
+    # every stored row and every query (they add nothing to a score)
+    zeros = c_pages.shape[3] - cfg.latent_dim
+    c_pad, q_pad = jnp.zeros((t_buf, zeros), dt), jnp.zeros((t_buf, h, zeros), dt)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(jnp.float32)  # [T, d], float32 throughout
+    for li, layer in enumerate(params["layers"]):
+        a = rms_norm(x, layer["norm_in"], cfg.norm_eps).astype(dt)
+        with jax.named_scope("mla_q_proj"):
+            cq = rms_norm(a @ layer["wqa"], layer["q_norm"], cfg.norm_eps)
+            q = (cq @ layer["wqb"]).reshape(t_buf, h, nope + rd)
+            q_rope = rope(q[..., nope:], positions, cfg)
+        with jax.named_scope("mla_kv_proj"):
+            ckr = a @ layer["wkva"]  # [T, rank + rd]
+            c = rms_norm(ckr[:, :rank], layer["kv_norm"], cfg.norm_eps)
+            latent = jnp.concatenate([c, rope(ckr[:, rank:], positions, cfg), c_pad], axis=-1)
+        with jax.named_scope("kv_write"):
+            c_pages = c_pages.at[li, page_idx, slot].set(latent)
+        wkvb = layer["wkvb"].reshape(rank, h, nope + vd)
+        with jax.named_scope("mla_absorb_q"):
+            ql = jnp.einsum("thn,chn->thc", q[..., :nope], wkvb[..., :nope])  # [T, h, rank]
+        # one shared key head under the h query heads; a key's leading
+        # ``rank`` columns are its value
+        ol = paged_attention(
+            jnp.concatenate([ql, q_rope, q_pad], axis=-1), c_pages, None, li, page_tables,
+            token_seq, positions, block_pages, v_dim=rank, scale=scale)
+        with jax.named_scope("mla_absorb_out"):
+            o = jnp.einsum("thc,chv->thv", ol, wkvb[..., nope:])  # [T, h, vd]
+        x = x + o.reshape(t_buf, h * vd) @ layer["wo"]
+        m = rms_norm(x, layer["norm_post"], cfg.norm_eps)  # float32
+        if li < cfg.n_dense_layers:
+            with jax.named_scope("mlp"):
+                mb = m.astype(dt)
+                f = (jax.nn.silu(mb @ layer["w_gate"]) * (mb @ layer["w_up"])) @ layer["w_down"]
+        else:
+            f, n = expert_layer(m, layer, cfg, live)
+            counts.append(n)
+        x = x + f
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(dt)
+    tail = jnp.concatenate(counts) if counts else jnp.zeros((0,), jnp.int32)
+    if not sample_logits:
+        nxt = jnp.zeros((t_buf,), jnp.int32)
+    else:
+        with jax.named_scope("lm_head"):
+            nxt = jnp.argmax(x @ params["lm_head"], axis=-1).astype(jnp.int32)
+    return jnp.concatenate([nxt, tail]), c_pages
+
+
+def serving_spec(cfg: Axk1Config) -> Any:
+    """The family's specification for the serving backend
+    (``serving/modelspec.py``): one kind of page with ONE latent arena, the
+    experts' counts behind the tokens."""
+    from ..serving.modelspec import ModelSpec
+
+    def program(sample_logits):
+        def ragged_program(p, cp, toks, pos, pt, ts, oi):
+            return ragged_step(p, cp, toks, pos, pt, ts, oi, cfg, sample_logits=sample_logits)
+
+        return ragged_program
+
+    return ModelSpec(
+        family="axk1", cfg=cfg, vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
+        init_params=lambda key: init_params(key, cfg),
+        init_arenas=lambda n, ps, _w: init_arenas(cfg, n, ps),
+        program=program, arenas=(((cfg.latent_width,),),),
+        aux_shape=(cfg.n_expert_layers, cfg.experts_held),
+        count_aux=lambda counts, live: step_counters(cfg, counts, live),
+    )
+
+
+__all__ = ["Axk1Config", "init_params", "init_arenas", "ragged_step", "rope", "serving_spec",
+           "yarn_inv_freq", "yarn_mscale"]

@@ -268,7 +268,7 @@ def forward(
 # makes copy-on-write prefix sharing a pure control-plane feature.  The
 # contract the serving layer must keep for an aliased page:
 #   * read-only — a write lands in every table that maps the page, so the
-#     engine CoW-copies (``copy_kv_page``) before any position inside a
+#     engine CoW-copies (``copy_page``) before any position inside a
 #     shared page is written;
 #   * identical logical prefix — a page's K/V depends on every position
 #     before it (attention), so a page may only be shared between
@@ -305,7 +305,6 @@ def _scatter_page(pages: jax.Array, pid: jax.Array, block: jax.Array) -> jax.Arr
     return jax.lax.dynamic_update_index_in_dim(pages, block, pid, axis=1)
 
 
-@jax.jit
 def _copy_page(pages: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
     return jax.lax.dynamic_update_index_in_dim(
         pages,
@@ -314,15 +313,24 @@ def _copy_page(pages: jax.Array, src: jax.Array, dst: jax.Array) -> jax.Array:
     )
 
 
-def copy_kv_page(
-    k_pages: jax.Array, v_pages: jax.Array, src: int, dst: int
-) -> tuple[jax.Array, jax.Array]:
-    """Duplicate one arena page on device — the copy-on-write half of
-    prefix sharing (docs/SERVING.md §Prefix cache and tiering).  Both
-    indices are traced operands, so every CoW of every session reuses the
-    same cached executable; the copy never leaves the device (no host
-    round trip, unlike the migration gather/scatter pair)."""
-    return _copy_page(k_pages, src, dst), _copy_page(v_pages, src, dst)
+# the arena donated: the copy is in place and holds no second arena (a latent
+# arena of 4 GB beside 8 GB of weights leaves no room for one); both programs
+# carry the function's name in a compile log
+_copy_page_in_place = jax.jit(_copy_page, donate_argnums=0)
+_copy_page = jax.jit(_copy_page)
+
+
+def copy_page(arenas: list[jax.Array], src: int, dst: int) -> list[jax.Array]:
+    """Duplicate one page on device in every arena of its kind (K and V by
+    head, or a latent kind's one array) — the copy-on-write half of prefix
+    sharing (docs/SERVING.md §Prefix cache and tiering).  Both indices are
+    traced operands, so every CoW of every session reuses the same cached
+    executable an arena shape; the copy never leaves the device (no host
+    round trip, unlike the migration gather/scatter pair).  Off the CPU the
+    arenas are DONATED, as the step donates them: the caller keeps only what
+    is returned."""
+    copy = _copy_page if jax.default_backend() == "cpu" else _copy_page_in_place
+    return [copy(a, src, dst) for a in arenas]
 
 
 def gather_kv_pages(
@@ -412,14 +420,32 @@ def attn_block_pages(page_size: int, pages_per_seq: int) -> int:
 #: as large and a warm start 10 s longer)
 ATTN_TILE_SLOTS = 8
 ATTN_GROUP_TILES = 8
+#: the most product rows (slots x query heads a K/V head) a tile holds.  A
+#: grouped-query model has 2 to 8 query heads a K/V head and keeps tiles of
+#: ``ATTN_TILE_SLOTS``; the absorbed form of latent attention has ONE shared
+#: key head under all its query heads (64 of them), and a tile of 8 slots
+#: would be 512 rows of which a decode row fills 64: its tile narrows to 4
+#: slots.  Measured on the chip at fixed steps of the latent program, 1 / 2 /
+#: 4 / 8 slots a tile (PERF.md section 6, PR 30): a 48-slot chunk at depth 8k
+#: and two decode rows 43.8 / 37.4 / 34.0 / 34.1 ms, fifteen decode rows and
+#: a chunk at 16k 72.5 / 61.8 / 59.7 / 68.2, five decode rows alone 20.1 /
+#: 21.1 / 22.2 / 30.2
+ATTN_TILE_ROWS = 256
 
 
-def attn_tiles(n_slots: int, n_rows: int) -> int:
+def attn_tile_slots(rep: int) -> int:
+    """Query slots a tile holds at ``rep`` query heads a K/V head."""
+    return max(1, min(ATTN_TILE_SLOTS, ATTN_TILE_ROWS // rep))
+
+
+def attn_tiles(n_slots: int, n_rows: int, tile_slots: int = ATTN_TILE_SLOTS) -> int:
     """The most tiles a step can hold, in whole groups: a tile is up to
-    ``ATTN_TILE_SLOTS`` consecutive slots of ONE table row, so every row
-    wastes less than one tile and ``n_slots // ATTN_TILE_SLOTS + n_rows``
-    bound them — from the shapes alone, whatever the engine feeds."""
-    return -(-(n_slots // ATTN_TILE_SLOTS + n_rows) // ATTN_GROUP_TILES) * ATTN_GROUP_TILES
+    ``tile_slots`` consecutive slots of ONE table row, so every row wastes
+    less than one tile and ``n_slots // tile_slots + n_rows`` bound them (as
+    ``n_slots`` does: a tile holds a slot) — from the shapes alone, whatever
+    the engine feeds."""
+    most = min(n_slots, n_slots // tile_slots + n_rows)
+    return -(-most // ATTN_GROUP_TILES) * ATTN_GROUP_TILES
 
 
 def walk_order(newest: Any, live: Any) -> Any:
@@ -448,23 +474,27 @@ def walk_blocks(oldest: Any, newest: Any, block_tokens: int,
 
 # jitted, with the layer a traced operand: the layers of a step program trace
 # and lower ONE walk a kind of page (a warm start pays the tracing)
-@partial(jax.jit, static_argnames=("block_pages", "window"))
+@partial(jax.jit, static_argnames=("block_pages", "window", "v_dim", "scale"))
 def paged_attention(
     q: jax.Array,
     k_pages: jax.Array,
-    v_pages: jax.Array,
+    v_pages: Optional[jax.Array],
     layer: Any,
     tables: jax.Array,
     token_seq: jax.Array,
     positions: jax.Array,
     block_pages: int,
     window: Optional[int] = None,
+    *,
+    v_dim: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Causal attention of every fed buffer slot over its own sequence's
     pages, walked once a TILE of a table row's slots and not once a slot.
 
-    q: [T, h, hd]; k_pages / v_pages: the arenas ``[L, N, ps, kvh, hd]``;
-    tables: [S+1, P] int32, the page tables (row S is the padding row, which
+    q: [T, h, hd]; k_pages / v_pages: the arenas ``[L, N, ps, kvh, hd]``
+    (or ``k_pages`` [L, N, ps, hd] with no head axis and ``v_pages`` None: a
+    latent cache, below); tables: [S+1, P] int32, the page tables (row S is the padding row, which
     is not walked: nothing reads a padding slot's attention); token_seq: [T]
     int32 table row of each slot; positions: [T] int32.  Returns [T, h, hd]
     in q's dtype.  It leans on one contract of the caller: **a row's slots
@@ -473,8 +503,9 @@ def paged_attention(
 
     * **tiles** — a row's slots are cut into tiles of ``ATTN_TILE_SLOTS``
       (a decode row is one tile, a draft row of 1 + k slots one, a 48-slot
-      chunk six); :func:`attn_tiles` bounds their number from the shapes
-      alone.  The tiles are found once a step from ``token_seq`` and
+      chunk six; narrower where one K/V head serves very many query heads:
+      :func:`attn_tile_slots`); :func:`attn_tiles` bounds their number from
+      the shapes alone.  The tiles are found once a step from ``token_seq`` and
       ``positions`` (identical in every layer: XLA computes it once),
       ordered by :func:`walk_order` and walked ``ATTN_GROUP_TILES`` at a
       time; a group none of whose tiles is fed is not walked at all, so the
@@ -516,16 +547,28 @@ def paged_attention(
     block_tokens + 2`` blocks, whatever the row's length.  The ring is the
     window, one step's buffer and a page wide, so no key a slot sees has
     been overwritten by its row's newest write; a ring slot the walk reads
-    twice is masked by its logical position."""
+    twice is masked by its logical position.
+
+    **A latent cache** (the absorbed form of latent attention,
+    ``models/axk1.py``): ``k_pages`` is the ONE array ``[L, N, ps, hd]`` a
+    layer keeps, no head axis — one shared key under all ``h`` query heads
+    — and ``v_pages`` is None: a slot's value is the leading ``v_dim``
+    columns of its key, so a trip's ONE gather feeds both products and the
+    accumulators are ``v_dim`` wide; returns [T, h, v_dim].  ``scale`` is the
+    softmax scale where it is not ``1 / sqrt(hd)``.  With a V arena and no
+    scale the program is the one it was."""
     t, h, hd = q.shape
-    ps, kvh = k_pages.shape[2], k_pages.shape[3]
+    ps = k_pages.shape[2]
+    kvh = k_pages.shape[3] if k_pages.ndim == 5 else 1
+    vd = hd if v_pages is not None else v_dim
     rep = h // kvh
     s_rows = tables.shape[0] - 1
     bp = block_pages
     bt = bp * ps  # token positions a block
-    w, g = ATTN_TILE_SLOTS, ATTN_GROUP_TILES
-    n_tiles = attn_tiles(t, s_rows)
-    scale = 1.0 / math.sqrt(hd)
+    w, g = attn_tile_slots(rep), ATTN_GROUP_TILES
+    n_tiles = attn_tiles(t, s_rows, w)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     itype = positions.dtype
     # the tiles: a fed slot starts one where it is the first of its row's
     # run in the buffer or a whole number of tiles behind it
@@ -576,7 +619,8 @@ def paged_attention(
                         tab_c, (blk[:, None] * bp + lane[None, :]) % ring, axis=1)
                     k_pos = (blk[:, None] * bt + offs[None, :])[:, None, :]
                 kb = k_pages[layer, ids].reshape(g, bt, kvh, hd)
-                vb = v_pages[layer, ids].reshape(g, bt, kvh, hd)
+                vb = (kb[..., :vd] if v_pages is None
+                      else v_pages[layer, ids].reshape(g, bt, kvh, hd))
             with jax.named_scope("attn_scores"):
                 s = jnp.einsum("rgmd,rkgd->rgmk", qc, kb,
                                preferred_element_type=jnp.float32) * scale
@@ -595,15 +639,15 @@ def paged_attention(
 
         stat = (g, kvh, w * rep)
         init = (jnp.full(stat, -1e30, jnp.float32), jnp.zeros(stat, jnp.float32),
-                jnp.zeros(stat + (hd,), jnp.float32))
+                jnp.zeros(stat + (vd,), jnp.float32))
         _, l, acc = jax.lax.fori_loop(0, trips, block, init)
-        done = (acc / l[..., None]).astype(q.dtype).reshape(g, kvh, w, rep, hd)
+        done = (acc / l[..., None]).astype(q.dtype).reshape(g, kvh, w, rep, vd)
         return jax.lax.dynamic_update_slice_in_dim(
-            out, done.transpose(0, 2, 1, 3, 4).reshape(g * w, h, hd), lo * w, axis=0)
+            out, done.transpose(0, 2, 1, 3, 4).reshape(g * w, h, vd), lo * w, axis=0)
 
     # the groups that hold a live tile (they come first), each to its own end
     walked = (jnp.sum(live, dtype=itype) + (g - 1)) // g
-    out = jax.lax.fori_loop(0, walked, group, jnp.zeros((n_tiles * w, h, hd), q.dtype))
+    out = jax.lax.fori_loop(0, walked, group, jnp.zeros((n_tiles * w, h, vd), q.dtype))
     # where a buffer slot finds its output: its tile's rank, its place in it
     rank = jnp.zeros((t,), itype).at[jnp.where(live, slot0, t)].set(
         jnp.arange(n_tiles, dtype=itype), mode="drop")
@@ -614,7 +658,7 @@ def paged_attention(
 def serving_spec(cfg: LlamaConfig) -> Any:
     """The family's specification for the serving backend
     (``serving/modelspec.py``): one kind of page, no counters."""
-    from ..serving.modelspec import ModelSpec
+    from ..serving.modelspec import ModelSpec, kv_pair
 
     def program(sample_logits):
         def ragged_program(p, kp, vp, toks, pos, pt, ts, oi):
@@ -626,7 +670,7 @@ def serving_spec(cfg: LlamaConfig) -> Any:
         family="llama", cfg=cfg, vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
         init_params=lambda key: init_params(key, cfg),
         init_arenas=lambda n, ps, _w: init_kv_pages(cfg, n, ps),
-        program=program,
+        program=program, arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),),
     )
 
 

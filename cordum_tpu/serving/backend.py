@@ -123,10 +123,17 @@ class StepBackend:
     # table — what prefix sharing, hibernation, migration and the gang
     # assume.  False for a model with window layers (``ModelSpec.window``).
     kv_whole_row: bool = True
+    # a page is K and V records by head — what migration and hibernation
+    # records and the gang's head sharding carry.  False for a latent page
+    # (``ModelSpec.arenas``); such a model still shares prefixes: copying a
+    # page maps over whatever arenas its kind has
+    kv_by_head: bool = True
+    # bytes one page of the whole-row kind holds, all its arenas and layers
+    page_bytes: int = 0
     # the latest step's report, written by ``step`` and read by the engine
     # after the call
     REPORT = ("last_step_compiled", "last_phases", "last_attn_blocks",
-              "last_window_blocks", "last_attn_rows", "last_counters")
+              "last_window_blocks", "last_attn_rows", "last_attn_live", "last_counters")
     last_step_compiled: bool = False  # did it pay XLA?
     # its boundaries, ns: (entry, arrays packed, program dispatched, result
     # on the host, return) — the engine splits its step cycle by them
@@ -134,11 +141,13 @@ class StepBackend:
     # the attention walk: (blocks read, blocks a page table holds); the
     # window layers' blocks read; (tiles' table rows gathered, query slots
     # computed), blocks of them, by one full and one window layer together;
-    # what the family's program counted, under the ``ServingStats`` names
-    # it adds to
+    # the query slots among those that were FED and needed their block (the
+    # rest is what the tiles pad); what the family's program counted, under
+    # the ``ServingStats`` names it adds to
     last_attn_blocks: tuple[int, int] = (0, 0)
     last_window_blocks: int = 0
     last_attn_rows: tuple[int, int] = (0, 0)
+    last_attn_live: int = 0
     last_counters: Mapping[str, int] = MappingProxyType({})
     # observation tap: called with the entry list after every successful
     # step — the serving-gang leader broadcasts it so followers replay the
@@ -282,6 +291,7 @@ class ServingBackend(StepBackend):
         # window
         self.window = self.spec.window
         self.kv_whole_row = self.spec.kv_whole_row
+        self.kv_by_head = self.spec.kv_by_head
         self.ring_pages = (
             llama.window_ring_pages(self.window, self.page_size, self.max_batch_tokens)
             if self.window else 0
@@ -302,9 +312,11 @@ class ServingBackend(StepBackend):
             (lambda: params) if params is not None else params_provider
         )
         self._params: Any = None
-        # the program's arenas in its argument order: K and V of the
-        # whole-row kind, then K and V of the window kind where there is one
+        # the program's arenas in its argument order, a kind after the other
+        # (``spec.arenas``): the whole-row kind's (K and V by head, or one
+        # latent array), then the window kind's where there is one
         self._arenas: Optional[list] = None
+        self._row_kind = slice(0, len(self.spec.arenas[0]))  # the whole-row kind's
         self._ragged_jit: Any = None
         self._compiled_shapes: set = set()  # observability: program count
         self._metrics = metrics
@@ -315,6 +327,8 @@ class ServingBackend(StepBackend):
         # entries it packs (``_count_walk``)
         bp = llama.attn_block_pages(self.page_size, self.pages_per_seq)
         self._attn_block_tokens = bp * self.page_size
+        # the walk's tile follows the query heads a K/V head, as the program's
+        self._tile_slots = llama.attn_tile_slots(self.cfg.n_heads // self.cfg.n_kv_heads)
         self._attn_blocks_total = -(-self.pages_per_seq // bp)
         # the counters the program returned behind the tokens
         # (``spec.aux_shape``; None where the family returns none);
@@ -327,9 +341,9 @@ class ServingBackend(StepBackend):
         self._dev_lock = threading.Lock()
 
     # ------------------------------------------------------------------
-    # the whole-row kind's arenas under the names they always had (the
-    # migration, copy-on-write and gang code, chip_smoke and the llama
-    # benchmark family read and assign them)
+    # the whole-row kind's K and V arenas under the names they always had
+    # (the gang code, chip_smoke and the llama benchmark family read and
+    # assign them); a latent kind has one arena, ``_k_pages``, and no V
     @property
     def _k_pages(self) -> Any:
         return self._arenas[0] if self._arenas else None
@@ -367,6 +381,7 @@ class ServingBackend(StepBackend):
             self._params_provider() if self._params_provider is not None else None
         )
         self._arenas = list(arenas)
+        self.page_bytes = sum(a.nbytes // a.shape[1] for a in self._arenas[self._row_kind])
         # donate the page arenas on real accelerators so the in-place
         # update never copies the arena; CPU jax spams donation warnings
         self._ragged_jit = make_ragged_program(
@@ -505,14 +520,18 @@ class ServingBackend(StepBackend):
         (``llama.walk_blocks``)."""
         from ..models import llama
 
-        w, g = llama.ATTN_TILE_SLOTS, llama.ATTN_GROUP_TILES
+        w, g = self._tile_slots, llama.ATTN_GROUP_TILES
         lo = np.concatenate([np.arange(a, b, w) for a, b in spans])  # a tile's first slot
         hi = np.minimum(lo + w, np.repeat(spans[:, 1], -(-(spans[:, 1] - spans[:, 0]) // w)))
         order = llama.walk_order(positions[hi - 1], np.ones(len(lo), bool))
         oldest, newest = positions[lo][order], positions[hi - 1][order]
         bt = self._attn_block_tokens
-        rows = slots = 0
+        rows = slots = live = 0
+        fed = positions[:spans[-1, 1]]  # the rows are packed one behind the other from slot 0
         for window in ((None, self.window) if self.window else (None,)):
+            # a fed slot needs the blocks from its oldest visible key's to its own
+            first = 0 if window is None else (fed - (window - 1)).clip(0) // bt
+            live += int((fed // bt - first + 1).sum())
             longest = 0
             for a in range(0, len(order), g):
                 trips = int(llama.walk_blocks(oldest[a:a + g], newest[a:a + g], bt, window)[1])
@@ -524,6 +543,7 @@ class ServingBackend(StepBackend):
             else:
                 self.last_window_blocks = longest
         self.last_attn_rows = (rows, slots)
+        self.last_attn_live = live
 
     # ------------------------------------------------------------------
     # live KV-page migration (serving/migration.py, docs/PROTOCOL.md §Page
@@ -538,7 +558,7 @@ class ServingBackend(StepBackend):
         list; record ``i`` is the page ORDINAL within it (the receiver maps
         ordinals onto its own freshly allocated arena blocks).  Blocking
         (device reads); call from an executor thread."""
-        self.spec.require_whole_row("page export (migration, hibernation)")
+        self.spec.require_page_records("page export (migration, hibernation)")
         if end_tok <= start_tok:
             return []
         self._ensure()
@@ -566,7 +586,7 @@ class ServingBackend(StepBackend):
         """Scatter migrated page records into freshly allocated arena
         blocks (``pages``, the receiving session's page list).  Blocking;
         call from an executor thread."""
-        self.spec.require_whole_row("page import (migration, hibernation)")
+        self.spec.require_page_records("page import (migration, hibernation)")
         if not records:
             return
         self._ensure()
@@ -598,7 +618,8 @@ class ServingBackend(StepBackend):
     def copy_page(self, src: int, dst: int) -> None:
         """Duplicate physical page ``src`` into ``dst`` on device — the
         copy-on-write half of prefix sharing (docs/SERVING.md §Prefix
-        cache and tiering).  The engine calls this before any position
+        cache and tiering), in every arena the whole-row kind has (K and V
+        by head, or the one latent array).  The engine calls this before any position
         inside a shared page would be written: the writer gets its own
         copy, every other table keeps attending to the original.  One
         cached executable serves every CoW (traced page indices).
@@ -608,9 +629,8 @@ class ServingBackend(StepBackend):
         from ..models import llama
 
         with self._dev_lock:
-            self._k_pages, self._v_pages = llama.copy_kv_page(
-                self._k_pages, self._v_pages, src, dst
-            )
+            self._arenas[self._row_kind] = llama.copy_page(
+                self._arenas[self._row_kind], src, dst)
 
     # ------------------------------------------------------------------
     # compat conveniences over step() — tests and benches drive these; the

@@ -42,7 +42,7 @@ from ..protocol.types import OP_SERVING_PREFILL, SPAN_ERROR, Span
 from ..utils.eager import eager
 from ..utils.ids import fast_id
 from .backend import STEP_PHASES, StepBackend, StepEntry, step_phase
-from .modelspec import require_whole_row
+from .modelspec import require_page_records
 from .pager import CacheExhausted, PageAllocator
 from .prefixcache import PrefixCache, PrefixNode
 from .tiering import SessionTiering
@@ -157,6 +157,14 @@ class ServingStats:
     # over steps, by one full and one window layer together: x a block's
     # bytes x the layers of the kind, what the attention read
     attn_rows_gathered: int = 0
+    # query slots those walks computed, a block each, and the FED slots among
+    # them that needed their block: the rest is what the tiles pad (a tile's
+    # empty slots, a short tile walked to its group's longest)
+    attn_slots_computed: int = 0
+    attn_slots_live: int = 0
+    # bytes of the whole-row kind's pages behind a step's rows, summed over
+    # steps: what the rows' cache is (all layers, every arena of the kind)
+    kv_bytes_behind_rows: int = 0
     # a model with window layers (docs/SERVING.md §Two kinds of page): blocks
     # its window layers' walk read, summed over steps; ring slots written
     # again after a lap (a page's worth of the row fell out of the window);
@@ -327,6 +335,11 @@ class ServingEngine:
         # what assumes ONE kind of page per session — the prefix cache,
         # hibernation, live migration — is off for such a model
         self.kv_whole_row = backend.kv_whole_row
+        # what carries a page as K and V records by head — hibernation and
+        # live migration — is off for a model whose page is another thing (a
+        # latent page); prefix sharing is not: copying a page needs no record
+        self.kv_by_head = backend.kv_by_head
+        self.kv_portable = self.kv_whole_row and self.kv_by_head
         # prefix cache + session tiering (docs/SERVING.md §Prefix cache and
         # tiering): the radix index over cached full-page prefixes, and the
         # hibernate/restore machinery that tiers idle resident state to the
@@ -343,7 +356,7 @@ class ServingEngine:
                 export_page=self._export_prefix_page,
                 metrics=metrics,
             )
-            if self.prefix is not None else None
+            if self.prefix is not None and self.kv_portable else None
         )
         # speculative decoding (docs/SERVING.md §Speculative decoding):
         # the self-speculative drafter proposes k tokens per decoding
@@ -1382,6 +1395,10 @@ class ServingEngine:
         self.stats.attn_blocks_total += of
         kv_rows, q_rows = self.backend.last_attn_rows
         self.stats.attn_rows_gathered += kv_rows
+        self.stats.attn_slots_computed += q_rows
+        self.stats.attn_slots_live += self.backend.last_attn_live
+        self.stats.kv_bytes_behind_rows += self.backend.page_bytes * sum(
+            self.allocator.pages_for(sess.pos) for sess, _, _, _ in rows)
         attrs = {
             "occupancy": str(len(rows)),
             "live_tokens": str(sum(chunk for _, chunk, _, _ in rows)),
@@ -1393,6 +1410,7 @@ class ServingEngine:
             attrs["kv_blocks"] = f"{walked}/{of}"
             attrs["kv_rows"] = str(kv_rows)
             attrs["q_rows"] = str(q_rows)
+            attrs["q_live"] = str(self.backend.last_attn_live)
         if self.ring_pages:
             attrs["window_blocks"] = str(self._count_window(rows, pos_before))
         counters = self.backend.last_counters
@@ -1448,7 +1466,7 @@ class ServingEngine:
         resumes prefill on the target).  Drain uses :meth:`session_ids`
         instead and ignores immunity (a draining worker must move
         everything)."""
-        if not self.kv_whole_row:
+        if not self.kv_portable:
             return []  # its pages cannot be shipped: nothing is movable
         now = time.monotonic()
         cands = [
@@ -1463,8 +1481,8 @@ class ServingEngine:
         """The session's immutable metadata (the migration hello frame);
         None when it is not actively decoding here."""
         sess = self._active.get(job_id)
-        if sess is None or sess.cancelled or not self.kv_whole_row:
-            # a model with window layers is never offered for migration:
+        if sess is None or sess.cancelled or not self.kv_portable:
+            # a model with window layers or latent pages is never offered for migration:
             # the drain falls back to a scheduler requeue (re-prefill)
             return None
         req = sess.req
@@ -1499,7 +1517,7 @@ class ServingEngine:
     ) -> list[dict]:
         """Page records covering positions ``[start_tok, end_tok)`` at
         their true lengths."""
-        require_whole_row(self.kv_whole_row, "page export (migration, hibernation)")
+        require_page_records(self.kv_whole_row, self.kv_by_head, "page export (migration, hibernation)")
         sess = self._active.get(job_id)
         if sess is None:
             return []
@@ -1547,7 +1565,7 @@ class ServingEngine:
         :meth:`restore_hibernated` later owns the token stream and the
         terminal result.  False when the session is not live here (or
         tiering is disabled)."""
-        require_whole_row(self.kv_whole_row, "hibernation")
+        require_page_records(self.kv_whole_row, self.kv_by_head, "hibernation")
         if self.tiering is None:
             return False
         meta = self.describe_session(job_id)
@@ -1651,7 +1669,7 @@ class ServingEngine:
         future (token list).  ``origin="hibernate"`` (the
         :meth:`restore_hibernated` path) books the adoption under the
         hibernate counters instead of the migration ones."""
-        require_whole_row(self.kv_whole_row, "adopting a migrated or hibernated session")
+        require_page_records(self.kv_whole_row, self.kv_by_head, "adopting a migrated or hibernated session")
         if self._closed:
             raise RuntimeError("serving engine is stopped")
         if job_id in self._active or any(

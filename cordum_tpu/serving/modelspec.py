@@ -8,7 +8,9 @@ page arenas, the program — and states what the family's cache can do.
 The program's signature is ``(params, *arenas, tokens, positions, *tables,
 token_seq, out_idx) -> (out, *arenas)``: one int32 page table ``[S+1,
 width]`` per KIND of page (the whole-row kind first; a family with window
-layers adds the ring kind), an arena pair per kind, and ``out`` int32
+layers adds the ring kind), the kind's arenas as the family states them
+(``ModelSpec.arenas``: K and V by head for grouped-query attention, ONE
+latent array for latent attention), and ``out`` int32
 ``[T + prod(aux_shape)]`` — the per-slot next tokens, then whatever counters
 the family returns in the same transfer.  The backend does not call it with
 those operands one by one: ``make_ragged_program`` wraps it as ``(params,
@@ -38,6 +40,23 @@ def require_whole_row(whole_row: bool, feature: str) -> None:
             "layers in page rings (ModelSpec.window)")
 
 
+def require_page_records(whole_row: bool, by_head: bool, feature: str) -> None:
+    """Refuse ``feature``, which carries a session's pages as K and V records
+    of whole rows, for a model whose pages are rings or are not K and V by
+    head."""
+    require_whole_row(whole_row, feature)
+    if not by_head:
+        raise UnsupportedForModel(
+            f"{feature} carries a page as K and V records by head; this model's "
+            "page is another thing (ModelSpec.arenas: a latent page has no heads "
+            "and no V)")
+
+
+def kv_pair(n_kv_heads: int, head_dim: int) -> tuple[tuple[int, ...], ...]:
+    """One kind's arenas as K and V by head: two of ``[kvh, hd]`` a slot."""
+    return ((n_kv_heads, head_dim), (n_kv_heads, head_dim))
+
+
 @dataclass(frozen=True)
 class ModelSpec:
     family: str
@@ -46,7 +65,8 @@ class ModelSpec:
     max_seq_len: int
     #: ``(key) -> params``
     init_params: Callable[[Any], Any]
-    #: ``(num_pages, page_size, window_pages) -> arenas`` (a K, V pair per kind)
+    #: ``(num_pages, page_size, window_pages) -> arenas``, flat, in the
+    #: order of ``arenas``
     init_arenas: Callable[[int, int, int], tuple]
     #: ``(sample_logits) -> ragged_program``, the function that is jitted:
     #: ``(params, *arenas, tokens, positions, *tables, token_seq, out_idx)
@@ -54,11 +74,19 @@ class ModelSpec:
     #: unpacks the step's packed feed into them)
     program: Callable[[bool], Callable[..., tuple]]
     #: the window of the family's window layers; None when every layer sees
-    #: the whole row.  THE capability: with a window, a sequence's pages are
-    #: of two kinds (whole-row tables and bounded rings), and what assumes
-    #: one kind — prefix sharing, hibernation, live migration, the
-    #: tensor-parallel gang — refuses the family (:meth:`require_whole_row`)
+    #: the whole row.  A capability (``kv_whole_row``): with a window, a
+    #: sequence's pages are of two kinds (whole-row tables and bounded
+    #: rings), and what assumes one kind — prefix sharing, hibernation, live
+    #: migration, the tensor-parallel gang — refuses the family
+    #: (:meth:`require_whole_row`, :meth:`require_page_records`)
     window: Optional[int] = None
+    #: per KIND of page (the whole-row kind first), the trailing shape of
+    #: each of its arenas behind ``[layers, pages, page_size]``: ``kv_pair``
+    #: for K and V by head, ``((640,),)`` for one latent array.  The
+    #: backend's page copy maps over a kind's list whatever its length; what
+    #: carries a page as K and V records (migration, hibernation, the gang's
+    #: head sharding) asks :attr:`kv_by_head` (:meth:`require_page_records`)
+    arenas: tuple[tuple[tuple[int, ...], ...], ...] = ()
     #: shape of the int32 counters behind the tokens in ``out``
     aux_shape: tuple[int, ...] = ()
     #: ``(aux, live_tokens) -> {ServingStats field: this step's addend}``:
@@ -71,11 +99,19 @@ class ModelSpec:
 
     @property
     def n_arenas(self) -> int:
-        """Arena arrays the program takes and returns: a K, V pair per kind."""
-        return 2 if self.window is None else 4
+        """Arena arrays the program takes and returns, all kinds together."""
+        return sum(len(kind) for kind in self.arenas)
+
+    @property
+    def kv_by_head(self) -> bool:
+        """Every kind of page is a K, V pair of ``[kvh, hd]`` slots."""
+        return all(len(kind) == 2 and all(len(a) == 2 for a in kind) for kind in self.arenas)
 
     def require_whole_row(self, feature: str) -> None:
         require_whole_row(self.kv_whole_row, feature)
+
+    def require_page_records(self, feature: str) -> None:
+        require_page_records(self.kv_whole_row, self.kv_by_head, feature)
 
 
 def spec_for(model: Any) -> ModelSpec:

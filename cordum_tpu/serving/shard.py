@@ -182,7 +182,7 @@ class ShardedServingBackend(LlamaServingBackend):
     def __init__(self, cfg: Any = None, *, rank: int = 0, tp: int = 1,
                  sample_output: Optional[bool] = None, **kw: Any) -> None:
         super().__init__(cfg, **kw)
-        self.spec.require_whole_row("the tensor-parallel serving gang")
+        self.spec.require_page_records("the tensor-parallel serving gang (arenas sharded by head)")
         self.rank = int(rank)
         self.tp = max(1, int(tp))
         self.heads = heads_for_rank(self.cfg.n_kv_heads, self.tp, self.rank)

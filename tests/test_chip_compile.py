@@ -135,6 +135,42 @@ def test_page_programs_compile_on_the_real_arena(one_chip, name):
     assert device_bytes(compiled) <= HBM_BYTES
 
 
+def test_the_latent_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
+    """The axk1 cell's ragged program at the benchmark's sizes (``benchmarks/
+    configs/a.x-k1-ep16.json``): weights and the latent arena fit, donation
+    is real, and the arena is neither copied nor laid out anew.  A latent
+    arena 576 wide (a shape that needs padding to the 128-lane tile) was laid
+    out pages-minor by the compiler and copied whole twice a step: 18.28 of
+    15.75 GB; the arena is 640 wide for that reason (``Axk1Config.
+    latent_width``).  The in-place page copy behind copy-on-write holds no
+    second arena."""
+    from benchmarks.families import axk1 as fam
+    from benchmarks.harness import cells
+
+    doc = dict(cells.load_config("a.x-k1-ep16"))
+    cfg, pool = fam.program_config(doc), doc["pool"]
+    params = jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip),
+        fam.param_shapes(doc), is_leaf=lambda x: isinstance(x, tuple))
+    arena = jax.ShapeDtypeStruct(
+        (cfg.n_layers, pool["pages"], pool["page_size"], cfg.latent_width), cfg.dtype,
+        sharding=one_chip)
+    assert cfg.latent_width == 640 and arena.shape[1] * pool["page_size"] == 16 * cfg.max_seq_len
+    layout = FeedLayout(pool["max_sessions"] + pool["prefill_budget"], pool["max_sessions"],
+                        (cfg.max_seq_len // pool["page_size"],))
+    feed = jax.ShapeDtypeStruct((layout.size,), jnp.int32, sharding=one_chip)
+    program = make_ragged_program(cfg, layout, sample_logits=True, donate=True)
+    compiled = program.lower(params, arena, feed).compile()
+    ma = compiled.memory_analysis()
+    arena_bytes = arena.size * arena.dtype.itemsize
+    assert ma.alias_size_in_bytes >= arena_bytes
+    assert ma.temp_size_in_bytes < 0.5e9  # no copy of the 4 GB arena among the temporaries
+    assert 12.0e9 < device_bytes(compiled) <= 0.85 * HBM_BYTES
+    pid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    copied = llama._copy_page_in_place.lower(arena, pid, pid).compile().memory_analysis()
+    assert copied.alias_size_in_bytes >= arena_bytes and copied.temp_size_in_bytes < 0.1e9
+
+
 def test_reference_forward_fits_beside_the_serving_state(one_chip):
     cfg = smoke_cfg()
     params, arena, _, _ = serving_shapes(
