@@ -36,7 +36,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .llama import attn_block_pages, init_kv_pages, paged_attention, rms_norm, rope
+from .llama import arena_pos_bytes, attn_block_pages, init_kv_pages, paged_attention, rms_norm, rope
 
 Params = dict
 
@@ -276,7 +276,12 @@ def ragged_step(
     page_idx = page_tables[token_seq, positions // ps]  # [T]
     wpage_idx = window_tables[token_seq, (positions // ps) % ring]
     slot = positions % ps
-    block_pages = attn_block_pages(ps, page_tables.shape[1])
+    # a block a kind of page, each from its own arenas' bytes a position
+    block_pages, wblock_pages = (
+        attn_block_pages(ps, page_tables.shape[1],
+                         arena_pos_bytes((kp.shape[3:], vp.shape[3:]), kp.dtype.itemsize),
+                         h, kvh, hd)
+        for kp, vp in ((k_pages, v_pages), (wk_pages, wv_pages)))
     arena_layer = {li: n for kind in (cfg.full_layers, cfg.window_layers)
                    for n, li in enumerate(kind)}
     counts = []
@@ -302,7 +307,7 @@ def ragged_step(
                 wk_pages = wk_pages.at[ai, wpage_idx, slot].set(k)
                 wv_pages = wv_pages.at[ai, wpage_idx, slot].set(v)
             attn = paged_attention(q, wk_pages, wv_pages, ai, window_tables, token_seq,
-                                   positions, block_pages, window=cfg.window)
+                                   positions, wblock_pages, window=cfg.window)
         else:
             with jax.named_scope("kv_write"):
                 k_pages = k_pages.at[ai, page_idx, slot].set(k)
@@ -363,7 +368,7 @@ def serving_spec(cfg: AfmoeConfig) -> Any:
         init_params=lambda key: init_params(key, cfg),
         init_arenas=lambda n, ps, w: init_arenas(cfg, n, ps, w),
         program=program, window=cfg.window,
-        arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),) * 2,
+        arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),) * 2, value_dim=cfg.head_dim,
         aux_shape=(cfg.n_expert_layers, cfg.experts_held),
         count_aux=lambda counts, live: step_counters(cfg, counts, live),
     )

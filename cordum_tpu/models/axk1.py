@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from .afmoe import check_groups, expert_layer, step_counters
-from .llama import attn_block_pages, paged_attention, rms_norm
+from .llama import arena_pos_bytes, attn_block_pages, paged_attention, rms_norm
 
 Params = dict
 LANES = 128  # a TPU tile's minor dimension
@@ -251,7 +251,10 @@ def ragged_step(
     live = token_seq < page_tables.shape[0] - 1  # the last row is the padding row
     page_idx = page_tables[token_seq, positions // ps]  # [T]
     slot = positions % ps
-    block_pages = attn_block_pages(ps, page_tables.shape[1])
+    # one shared key head under the h query heads, values ``rank`` wide
+    block_pages = attn_block_pages(
+        ps, page_tables.shape[1],
+        arena_pos_bytes((c_pages.shape[3:],), c_pages.dtype.itemsize), h, 1, rank)
     scale = cfg.softmax_scale
     counts = []
     dt = params["embed"].dtype
@@ -319,7 +322,7 @@ def serving_spec(cfg: Axk1Config) -> Any:
         family="axk1", cfg=cfg, vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
         init_params=lambda key: init_params(key, cfg),
         init_arenas=lambda n, ps, _w: init_arenas(cfg, n, ps),
-        program=program, arenas=(((cfg.latent_width,),),),
+        program=program, arenas=(((cfg.latent_width,),),), value_dim=cfg.kv_rank,
         aux_shape=(cfg.n_expert_layers, cfg.experts_held),
         count_aux=lambda counts, live: step_counters(cfg, counts, live),
     )

@@ -386,13 +386,33 @@ def scatter_kv_pages(
     return k_pages, v_pages
 
 
-#: token positions one block of :func:`paged_attention`'s walk aims at, and
-#: the least number of blocks a page table is cut into.  Chosen on the chip
-#: (PERF.md §6, PR 25): a block costs about 7 us beyond its bytes, so the
-#: walk's granularity — the last block is half empty on average — weighs
-#: more than the count of blocks, down to 64 positions at T = 32
+#: token positions the least block of :func:`paged_attention`'s walk aims
+#: at, and the least number of blocks a page table is cut into.  Chosen on
+#: the chip (PERF.md section 6, PR 25) for K and V by head, where a block's
+#: bytes outweigh the walk's state two to eight times: a block costs about
+#: 7 us beyond its bytes, so the walk's granularity (the last block is half
+#: empty on average) weighs more than the count of blocks, down to 64
+#: positions at T = 32.  That reasoning holds while a trip's gather is the
+#: larger part of what it moves; :func:`attn_block_pages` grows the block
+#: where it is not
 ATTN_BLOCK_TOKENS = 128
 ATTN_MIN_BLOCKS = 8
+#: a trip reads a block of keys and rewrites the tile's float32 accumulator
+#: (``acc * alpha + p @ v``: read and written once a trip, whatever the
+#: block's length).  The block doubles from ``ATTN_BLOCK_TOKENS`` until its
+#: gathered bytes are at least this many times the accumulator's.  K and V
+#: by head at 2-8 query heads a K/V head gather 8-2 times the state at 128
+#: positions and stay there; a latent page (1280 B a position under 64 heads
+#: x 512 values: 160 KiB gathered against 512 KiB rewritten, a tile) grows
+#: to 512.  Measured on the chip at fixed steps of the latent program
+#: (PERF.md section 6, PR 31; wall ms), blocks of 128 / 256 / 512 / 1024
+#: positions: a 48-slot chunk at depth 8k and two decode rows 31.1 / 30.0 /
+#: 28.8 / 28.9, fifteen decode rows and a chunk at 16k 65.2 / 59.7 / 54.2 /
+#: 54.3, five decode rows alone 22.6 / 20.9 / 19.3 / 19.1, a chunk at 24k and
+#: eight decode rows 57.9 / 53.0 / 48.1 / 48.0, a chunk at depth 0 alone
+#: 19.5 / 19.8 / 20.1 / 20.5 (one trip either way, and a longer one costs
+#: more): a ratio of 2 (blocks of 1024) gains nothing over 1 and loses there
+ATTN_STATE_RATIO = 1
 
 
 def window_ring_pages(window: int, page_size: int, max_batch_tokens: int) -> int:
@@ -404,12 +424,33 @@ def window_ring_pages(window: int, page_size: int, max_batch_tokens: int) -> int
     return (window + max_batch_tokens + page_size - 3) // page_size + 1
 
 
-def attn_block_pages(page_size: int, pages_per_seq: int) -> int:
+def attn_block_pages(page_size: int, pages_per_seq: int, pos_bytes: int,
+                     n_heads: int, n_kv_heads: int, v_dim: int) -> int:
     """Pages in one block of :func:`paged_attention`'s walk over a page
-    table ``pages_per_seq`` wide — derived from the shapes alone, so the
-    backend counts blocks on the host exactly as the program walks them."""
-    return max(1, min(ATTN_BLOCK_TOKENS // page_size,
-                      -(-pages_per_seq // ATTN_MIN_BLOCKS)))
+    table ``pages_per_seq`` wide, for ONE kind of page: ``pos_bytes`` is what
+    a position holds in all the kind's arenas of a layer
+    (:func:`arena_pos_bytes`), ``n_heads`` / ``n_kv_heads`` / ``v_dim`` what
+    the walk accumulates (``v_dim`` wide values under ``n_heads`` query
+    heads).  The least power-of-two multiple of ``ATTN_BLOCK_TOKENS``
+    positions whose gathered bytes are ``ATTN_STATE_RATIO`` times a tile's
+    float32 accumulator, then at most an ``ATTN_MIN_BLOCKS``-th of the
+    table — from the shapes alone, so the backend counts blocks on the host
+    exactly as the program walks them."""
+    if pos_bytes <= 0 or v_dim <= 0:
+        raise ValueError(f"a position of {pos_bytes} bytes under values {v_dim} wide: "
+                         "the rule needs both shapes")
+    state = attn_tile_slots(n_heads // n_kv_heads) * n_heads * v_dim * 4
+    tokens = ATTN_BLOCK_TOKENS
+    while tokens * pos_bytes < ATTN_STATE_RATIO * state:
+        tokens *= 2
+    return max(1, min(tokens // page_size, -(-pages_per_seq // ATTN_MIN_BLOCKS)))
+
+
+def arena_pos_bytes(shapes: Any, itemsize: int) -> int:
+    """Bytes one position holds in a layer of one kind of page: ``shapes``
+    are the trailing shapes of the kind's arenas behind ``[layers, pages,
+    page_size]`` (``ModelSpec.arenas[kind]``, or ``a.shape[3:]`` of each)."""
+    return sum(math.prod(shape) for shape in shapes) * itemsize
 
 
 #: query slots a tile of :func:`paged_attention`'s walk holds, and tiles a
@@ -670,7 +711,7 @@ def serving_spec(cfg: LlamaConfig) -> Any:
         family="llama", cfg=cfg, vocab_size=cfg.vocab_size, max_seq_len=cfg.max_seq_len,
         init_params=lambda key: init_params(key, cfg),
         init_arenas=lambda n, ps, _w: init_kv_pages(cfg, n, ps),
-        program=program, arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),),
+        program=program, arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),), value_dim=cfg.head_dim,
     )
 
 
@@ -747,7 +788,10 @@ def ragged_step(
     pos2 = positions[:, None]  # [T, 1]
     page_idx = page_tables[token_seq, positions // ps]  # [T] — each token's own page
     slot = positions % ps
-    block_pages = attn_block_pages(ps, page_tables.shape[1])
+    block_pages = attn_block_pages(
+        ps, page_tables.shape[1],
+        arena_pos_bytes((k_pages.shape[3:], v_pages.shape[3:]), k_pages.dtype.itemsize),
+        h, kvh, hd)
     # the named scopes are metadata only: they label the operations in a
     # device trace (per-kernel time by scope) and change none of them
     with jax.named_scope("embed"):

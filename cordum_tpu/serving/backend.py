@@ -111,7 +111,7 @@ class StepBackend:
 
     # the static shapes, fixed at construction
     SHAPES = ("num_pages", "page_size", "max_context", "max_seqs",
-              "max_batch_tokens", "ring_pages", "num_window_pages")
+              "max_batch_tokens", "ring_pages", "num_window_pages", "attn_block_tokens")
     num_pages: int
     page_size: int
     max_context: int
@@ -119,6 +119,9 @@ class StepBackend:
     max_batch_tokens: int
     ring_pages: int = 0  # a window layer's ring, pages a sequence
     num_window_pages: int = 0  # that kind's pool
+    # positions a block of the attention's walk over whole rows holds: the
+    # unit of ``last_attn_blocks`` (0: a backend that walks nothing)
+    attn_block_tokens: int = 0
     # the one capability: every layer's pages cover the whole row under one
     # table — what prefix sharing, hibernation, migration and the gang
     # assume.  False for a model with window layers (``ModelSpec.window``).
@@ -325,11 +328,19 @@ class ServingBackend(StepBackend):
         # group of tiles to its longest one, all of which follows from the
         # rows' buffer slots and positions, which the host knows from the
         # entries it packs (``_count_walk``)
-        bp = llama.attn_block_pages(self.page_size, self.pages_per_seq)
-        self._attn_block_tokens = bp * self.page_size
         # the walk's tile follows the query heads a K/V head, as the program's
-        self._tile_slots = llama.attn_tile_slots(self.cfg.n_heads // self.cfg.n_kv_heads)
-        self._attn_blocks_total = -(-self.pages_per_seq // bp)
+        h, kvh = self.cfg.n_heads, self.cfg.n_kv_heads
+        self._tile_slots = llama.attn_tile_slots(h // kvh)
+        # and its block, a kind of page, what a trip gathers beside what it
+        # rewrites: the program's own rule over the shapes the spec states
+        itemsize = np.dtype(self.cfg.dtype).itemsize
+        self._block_tokens = tuple(
+            self.page_size * llama.attn_block_pages(
+                self.page_size, self.pages_per_seq,
+                llama.arena_pos_bytes(kind, itemsize), h, kvh, self.spec.value_dim)
+            for kind in self.spec.arenas)
+        self.attn_block_tokens = self._block_tokens[0]
+        self._attn_blocks_total = -(-self.pages_per_seq * self.page_size // self.attn_block_tokens)
         # the counters the program returned behind the tokens
         # (``spec.aux_shape``; None where the family returns none);
         # ``last_counters`` is what the family says they add to
@@ -525,10 +536,10 @@ class ServingBackend(StepBackend):
         hi = np.minimum(lo + w, np.repeat(spans[:, 1], -(-(spans[:, 1] - spans[:, 0]) // w)))
         order = llama.walk_order(positions[hi - 1], np.ones(len(lo), bool))
         oldest, newest = positions[lo][order], positions[hi - 1][order]
-        bt = self._attn_block_tokens
         rows = slots = live = 0
         fed = positions[:spans[-1, 1]]  # the rows are packed one behind the other from slot 0
-        for window in ((None, self.window) if self.window else (None,)):
+        # a kind of page after the other: whole rows, then the window's rings
+        for bt, window in zip(self._block_tokens, (None, self.window)):
             # a fed slot needs the blocks from its oldest visible key's to its own
             first = 0 if window is None else (fed - (window - 1)).clip(0) // bt
             live += int((fed // bt - first + 1).sum())

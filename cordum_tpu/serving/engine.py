@@ -1408,6 +1408,7 @@ class ServingEngine:
         }
         if of:
             attrs["kv_blocks"] = f"{walked}/{of}"
+            attrs["kv_block_tokens"] = str(self.backend.attn_block_tokens)
             attrs["kv_rows"] = str(kv_rows)
             attrs["q_rows"] = str(q_rows)
             attrs["q_live"] = str(self.backend.last_attn_live)

@@ -87,6 +87,11 @@ class ModelSpec:
     #: carries a page as K and V records (migration, hibernation, the gang's
     #: head sharding) asks :attr:`kv_by_head` (:meth:`require_page_records`)
     arenas: tuple[tuple[tuple[int, ...], ...], ...] = ()
+    #: the width of a value as the attention's walk accumulates it: the head
+    #: dimension for K and V by head, the latent's rank for a latent page
+    #: (a key's leading columns are its value).  With ``arenas`` it is what
+    #: the backend hands ``llama.attn_block_pages``, as the program does
+    value_dim: int = 0
     #: shape of the int32 counters behind the tokens in ``out``
     aux_shape: tuple[int, ...] = ()
     #: ``(aux, live_tokens) -> {ServingStats field: this step's addend}``:

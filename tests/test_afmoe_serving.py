@@ -51,6 +51,15 @@ def backend_for(cfg, params, *, max_seqs=4, budget=12, pages=160):
                           max_batch_tokens=max_seqs + budget, params=params)
 
 
+def block_tokens(cfg, be):
+    """Positions a block of the walk holds, by the program's rule over the
+    family's shapes (float32 K and V by head, both kinds alike)."""
+    bt = PS * llama.attn_block_pages(PS, be.pages_per_seq, 2 * cfg.n_kv_heads * cfg.head_dim * 4,
+                                     cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
+    assert be.attn_block_tokens == bt
+    return bt
+
+
 def gaps(cfg, params, seq, preds):
     """Reference's best logit minus its logit of the program's prediction
     after every position of ``seq``."""
@@ -182,7 +191,7 @@ def test_the_host_counts_the_trips_the_program_walks(rows):
     be = backend_for(cfg, afmoe.init_params(jax.random.PRNGKey(3), cfg))
     book = Rows(be, len(rows))
     be.step([book.entry(i, [1 + i] * n, start) for i, (start, n) in enumerate(rows)])
-    bt = llama.attn_block_pages(PS, be.pages_per_seq) * PS
+    bt = block_tokens(cfg, be)
     w, g = llama.ATTN_TILE_SLOTS, llama.ATTN_GROUP_TILES
     tiles = sorted(((s + k, min(s + k + w, s + n) - 1) for s, n in rows for k in range(0, n, w)),
                    key=lambda tile: -tile[1])
@@ -312,7 +321,7 @@ async def test_engine_serves_mixed_rows_bounded_and_counted(held):
         assert 0 < st.moe_assignments_here < st.moe_assignments
     # the window layers' walk is bounded by the window and a step's buffer,
     # the full layer's is not
-    bt = llama.attn_block_pages(PS, be.pages_per_seq) * PS
+    bt = block_tokens(cfg, be)
     assert max(b for _, _, _, b in seen) <= (cfg.window + be.max_batch_tokens) // bt + 2
     assert st.attn_blocks_walked > st.window_blocks_walked
     assert st.attn_rows_gathered == sum(seen_rows) > 0

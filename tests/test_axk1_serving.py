@@ -96,11 +96,12 @@ def feed(be, seqs, chunks):
 
 
 @pytest.mark.parametrize("case", ["chunks-straddle-pages", "one-token-chunks-then-decode",
-                                  "short-and-long-rows-in-one-step", "narrow-tiles"])
+                                  "short-and-long-rows-in-one-step", "narrow-tiles",
+                                  "a-grown-block"])
 def test_paged_prefill_and_decode_equal_the_published_reference(case):
     """Chunked prefill then decode through the latent pages, absorbed, equals
     the reference's full forward in the published form."""
-    cfg = tiny()
+    cfg, pages = tiny(), 160
     rng = np.random.default_rng(5)
     if case == "short-and-long-rows-in-one-step":
         lens, chunks = [150, 9, 70, 33], [[6, 3, 6, 2] * 6, [3], [5] * 9, [1, 4, 4]]
@@ -111,20 +112,35 @@ def test_paged_prefill_and_decode_equal_the_published_reference(case):
         cfg = tiny(n_heads=64, nope_dim=4, rope_dim=2, v_dim=4)
         assert llama.attn_tile_slots(cfg.n_heads // cfg.n_kv_heads) == 4
         lens, chunks = [90, 30, 11], [[6, 7, 5, 6, 6] * 3, [5, 6], [4, 3, 4]]
+    elif case == "a-grown-block":
+        # 512 B a position under a tile of 4 slots x 64 heads x 96 values
+        # (96 KiB of float32): the walk's block doubles to 256 positions.
+        # One row crosses it, one stays inside the first
+        cfg = tiny(n_heads=64, kv_rank=96, nope_dim=4, rope_dim=2, v_dim=4, max_seq_len=2048)
+        lens, chunks, pages = [290, 20], [[12] * 23, [4] * 5], 520
     else:
         lens, chunks = [120], [[1] * 40]
     params = axk1.init_params(jax.random.PRNGKey(3), cfg)
-    be = backend_for(cfg, params)
+    be = backend_for(cfg, params, pages=pages)
+    assert be.attn_block_tokens == (256 if case == "a-grown-block" else 32)
     seqs = [[int(t) for t in rng.integers(0, cfg.vocab_size, n)] for n in lens]
     preds = feed(be, seqs, chunks)
     assert be.compiled_programs() == 1
     # the cache keeps (c | kr) a token and layer, and nothing by head
-    assert [a.shape for a in be._arenas] == [(cfg.n_layers, 160, PS, cfg.latent_width)]
+    assert [a.shape for a in be._arenas] == [(cfg.n_layers, pages, PS, cfg.latent_width)]
     assert (cfg.latent_dim, cfg.latent_width) == (cfg.kv_rank + cfg.rope_dim, 128)  # zeros to the tile
     for seq, p in zip(seqs, preds):
         assert len(p) == len(seq)
         g = gaps(cfg, params, seq, p)
         assert g.max() < GAP, (case, float(g.max()), int(g.argmax()))
+    if case == "a-grown-block":
+        # the host counts that walk in the program's unit: both rows' last
+        # positions again, two tiles in one group of 8 walked to the longer
+        # row's second block
+        be.step([entry(be, i, seq[-1:], len(seq) - 1) for i, seq in enumerate(seqs)])
+        w, g = llama.attn_tile_slots(cfg.n_heads), llama.ATTN_GROUP_TILES
+        assert be.last_attn_blocks == (2, 2048 // 256)
+        assert be.last_attn_rows == (g * 2, g * w * 2) and be.last_attn_live == 2 + 1
 
 
 def test_absorbed_equals_published_at_float32():
@@ -388,7 +404,9 @@ async def test_the_counters_equal_a_host_recount():
     st = eng.stats
     assert st.prefix_hits == 1 and st.prefix_hit_tokens == (60 + 8 - 1) // PS * PS == 64
     assert st.prefill_tokens == 60 + (98 - 64) + 6 + 100
-    bt = llama.attn_block_pages(PS, be.pages_per_seq) * PS
+    bt = PS * llama.attn_block_pages(PS, be.pages_per_seq, cfg.latent_width * 4,
+                                     cfg.n_heads, 1, cfg.kv_rank)
+    assert be.attn_block_tokens == bt
     w, g = llama.attn_tile_slots(cfg.n_heads), llama.ATTN_GROUP_TILES
     computed = live = held = 0
     for rows, _, _, _ in seen:

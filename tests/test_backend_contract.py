@@ -81,6 +81,9 @@ def test_every_backend_keeps_the_contract(kind):
     assert type(be.last_step_compiled) is bool
     walked, of = be.last_attn_blocks
     assert type(walked) is int and type(of) is int and walked <= of
+    # a backend that counts blocks says how long one is
+    assert (be.attn_block_tokens > 0) == (of > 0)
+    assert of == 0 or of * be.attn_block_tokens >= be.max_context
     assert (be.last_window_blocks > 0) == bool(ring)
     if be.kv_whole_row:
         # page 1 holds row 0's three positions; a copy of it is the same

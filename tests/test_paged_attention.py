@@ -25,16 +25,18 @@ NUM_PAGES = 40
 
 
 def dense_reference(q, k_pages, v_pages, layer, tables, token_seq, positions,
-                    window=None):
+                    window=None, scale=None):
     """Per slot and head, softmax(q . K / sqrt(hd)) . V over the slot's own
     visible positions ([0, position], or the last ``window`` of them), in
     float64, K and V read page by page out of the slot's table row (a ring
-    under a window: logical page n in slot n % ring)."""
+    under a window: logical page n in slot n % ring).  ``scale`` where it is
+    not ``1 / sqrt(hd)``; the values may be narrower than the keys."""
     q, kp, vp = (np.asarray(x, np.float64) for x in (q, k_pages, v_pages))
     t, h, hd = q.shape
     ps = kp.shape[2]
     rep = h // kp.shape[3]
-    out = np.zeros((t, h, hd))
+    scale = 1 / math.sqrt(hd) if scale is None else scale
+    out = np.zeros((t, h, vp.shape[-1]))
     for i in range(t):
         row = np.asarray(tables[token_seq[i]])
         hi = int(positions[i]) + 1
@@ -43,7 +45,7 @@ def dense_reference(q, k_pages, v_pages, layer, tables, token_seq, positions,
         pages = row[(at // ps) % len(row)] if window else row[at // ps]
         k, v = kp[layer][pages, at % ps], vp[layer][pages, at % ps]
         for j in range(h):
-            s = k[:, j // rep] @ q[i, j] / math.sqrt(hd)
+            s = k[:, j // rep] @ q[i, j] * scale
             p = np.exp(s - s.max())
             out[i, j] = (p / p.sum()) @ v[:, j // rep]
     return out
@@ -195,16 +197,62 @@ def test_one_rule_for_the_traced_bound_and_the_host_count(window):
         assert list(first) == [5, 0, 1, 0] and trips == 4
 
 
-@pytest.mark.parametrize("page_size,pages_per_seq,want", [
-    (16, 128, 8),  # context 2048: blocks of 128 positions
-    (16, 32, 4),  # context 512: an eighth of the table
-    (16, 256, 8),
-    (8, 16, 2),  # tiny(): eight blocks of 16 positions
-    (4, 4, 1),
-    (512, 8, 1),  # a page longer than a block
+KV_BY_HEAD = ((8, 128), (8, 128))  # K and V of 8 heads of 128: 4096 B a position in bfloat16
+
+
+@pytest.mark.parametrize("page_size,pages_per_seq,arenas,itemsize,heads,kvh,v_dim,want", [
+    (16, 128, KV_BY_HEAD, 2, 32, 8, 128, 8),  # Mistral, context 2048: blocks of 128 positions
+    (16, 32, KV_BY_HEAD, 2, 16, 8, 128, 4),  # InternLM2, context 512: an eighth of the table
+    (16, 256, KV_BY_HEAD, 2, 32, 8, 128, 8),
+    (8, 16, ((2, 16), (2, 16)), 4, 4, 2, 16, 2),  # tiny(): eight blocks of 16 positions
+    (4, 4, ((2, 16), (2, 16)), 4, 4, 2, 16, 1),
+    (512, 8, KV_BY_HEAD, 2, 32, 8, 128, 1),  # a page longer than a block
+    # Trinity, both kinds of page (48 query heads: a tile's state is 192 KiB
+    # against a block of 512 KiB), and InternLM2 at a context past the cap
+    (16, 1024, KV_BY_HEAD, 2, 48, 8, 128, 8),
+    (16, 2048, KV_BY_HEAD, 2, 16, 8, 128, 8),
+    # the latent arena of ``axk1-docsessions-open``: 1280 B a position under
+    # 4 slots x 64 heads x 512 values in float32 (512 KiB): 128 positions
+    # gather 160 KiB, 256 gather 320, 512 gather 640
+    (16, 2048, ((640,),), 2, 64, 1, 512, 32),
+    # one K/V head of 128 under 32 query heads (512 B a position, a state of
+    # 128 KiB): thin pages under fat tiles double the block once, whatever
+    # the family; a short table still caps it at an eighth
+    (16, 512, ((1, 128), (1, 128)), 2, 32, 1, 128, 16),
+    (16, 64, ((1, 128), (1, 128)), 2, 32, 1, 128, 8),
+    (8, 32, ((128,),), 4, 64, 1, 96, 4),  # the serving tests' latent config, capped
 ])
-def test_block_follows_from_the_shapes(page_size, pages_per_seq, want):
-    assert llama.attn_block_pages(page_size, pages_per_seq) == want
+def test_block_follows_from_the_shapes(page_size, pages_per_seq, arenas, itemsize, heads, kvh,
+                                       v_dim, want):
+    pos_bytes = llama.arena_pos_bytes(arenas, itemsize)
+    assert llama.attn_block_pages(page_size, pages_per_seq, pos_bytes, heads, kvh, v_dim) == want
+
+
+def test_a_latent_walk_at_a_grown_block_matches_the_reference():
+    """A latent arena whose rule gives blocks of 256 positions (4 pages of
+    64): rows that cross several blocks, end inside one, end on a block's
+    last position and are shorter than one, and a chunk across a boundary,
+    against the dense float64 softmax over one shared key whose leading
+    columns are the value."""
+    ps, width, vd, h, per = 64, 12, 8, 64, 32
+    bp = llama.attn_block_pages(ps, per, llama.arena_pos_bytes(((width,),), 4), h, 1, vd)
+    assert bp == 4
+    rows = [(700, 1), (37, 1), (255, 1), (256, 1), (250, 12), (0, 5)]
+    rng = np.random.default_rng(31)
+    arena = rng.normal(size=(1, 1 + len(rows) * per, ps, width)).astype(np.float32)
+    tables = np.zeros((len(rows) + 1, per), np.int32)
+    tables[:-1] = 1 + rng.permutation(len(rows) * per).reshape(len(rows), per)
+    t_buf = 24
+    token_seq, positions = packed(rows, t_buf, len(rows))
+    fed = token_seq < len(rows)
+    q = rng.normal(size=(t_buf, h, width)).astype(np.float32)
+    got = np.asarray(llama.paged_attention(
+        jnp.asarray(q), jnp.asarray(arena), None, 0, jnp.asarray(tables),
+        jnp.asarray(token_seq), jnp.asarray(positions), bp, v_dim=vd, scale=0.3))
+    assert got.shape == (t_buf, h, vd) and np.isfinite(got).all()
+    want = dense_reference(q, arena[..., None, :], arena[..., None, :vd], 0, tables,
+                           token_seq, positions, scale=0.3)
+    np.testing.assert_allclose(got[fed], want[fed], atol=1e-5, rtol=1e-5)
 
 
 # ---------------------------------------------------------------- the program
@@ -294,17 +342,22 @@ def walk_gathers(jaxpr):
     for eqn in all_eqns(jaxpr):
         if eqn.primitive.name == "while":
             for inner in all_eqns(eqn.params["body_jaxpr"].jaxpr):
-                if inner.primitive.name == "gather" and inner.outvars[0].aval.ndim == 5:
+                if inner.primitive.name == "gather" and inner.outvars[0].aval.ndim >= 4:
                     found[id(inner)] = tuple(inner.outvars[0].aval.shape)
     return list(found.values())
 
 
-@pytest.mark.parametrize("name", ["mistral-7b-v0.3", "internlm2-1.8b",
-                                  "trinity-large-preview-ep8"])
-def test_the_walk_gathers_a_block_a_table_row_in_every_configuration(name):
+@pytest.mark.parametrize("name,block_pages", [
+    # K and V by head: the blocks of 128 positions PR 25 chose, so the three
+    # programs lower to the text they lowered to before the rule saw bytes
+    ("mistral-7b-v0.3", 8), ("internlm2-1.8b", 4), ("trinity-large-preview-ep8", 8),
+    ("a.x-k1-ep16", 32),  # the latent page: 512 positions
+])
+def test_the_walk_gathers_a_block_a_table_row_in_every_configuration(name, block_pages):
     """The serving program of each benchmark configuration at its cell's
     pool, traced over shapes (nothing is built or compiled): every gather of
-    pages inside a walk has S rows, never T, in full and window layers."""
+    pages inside a walk has S rows, never T, in full and window layers, and
+    is ``block_pages`` pages long — read off the program, not off the rule."""
     import importlib
     import json
     import pathlib
@@ -328,12 +381,18 @@ def test_the_walk_gathers_a_block_a_table_row_in_every_configuration(name):
     closed = jax.make_jaxpr(program)(
         params, *arenas, jax.ShapeDtypeStruct((layout.size,), jnp.int32))
     shapes = walk_gathers(closed.jaxpr)
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
-    bp = llama.attn_block_pages(ps, widths[0])
-    # K and V of a group of tiles: never a gather a buffer slot, and ONE
-    # walk traced a kind of page, whatever the number of layers
-    assert set(shapes) == {(llama.ATTN_GROUP_TILES, bp, ps, kvh, hd)}
-    assert llama.ATTN_GROUP_TILES < t_buf and len(shapes) == 2 * len(widths)
+    # a group of tiles' block of each arena of a kind: never a gather a
+    # buffer slot, and ONE walk traced a kind of page, whatever the number
+    # of layers
+    assert set(shapes) == {(llama.ATTN_GROUP_TILES, block_pages, ps, *a)
+                           for kind in spec.arenas for a in kind}
+    assert llama.ATTN_GROUP_TILES < t_buf and len(shapes) == spec.n_arenas
+    # and the backend counts in the unit the program walks in
+    from cordum_tpu.serving.backend import ServingBackend
+
+    be = ServingBackend(cfg, num_pages=pool["pages"], page_size=ps, max_seqs=s_rows,
+                        max_batch_tokens=t_buf)
+    assert be.attn_block_tokens == block_pages * ps and set(be._block_tokens) == {block_pages * ps}
 
 
 def test_the_walk_over_slots_would_be_seen(monkeypatch):
@@ -442,6 +501,7 @@ def test_engine_counts_the_walk_and_stamps_the_step_span(backend, monkeypatch, p
         for sp in steps:
             n = int(sp.trace_id.rsplit("-", 1)[1])
             assert sp.attrs["kv_blocks"] == f"{seen[n][0]}/8"
+            assert sp.attrs["kv_block_tokens"] == "16"  # the unit of those blocks
             assert (sp.attrs["kv_rows"], sp.attrs["q_rows"]) == tuple(map(str, rows[n]))
 
     asyncio.run(main())
