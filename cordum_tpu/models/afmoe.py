@@ -22,7 +22,11 @@ seam, §Two kinds of page, §The expert layer):
     expert.  No capacity, so no token is ever dropped; what absent experts
     would add is left out (their chips add it in a deployment), the weights
     still normalised over all ``top_k`` selected.  The shared expert is
-    whole on every chip.
+    whole on every chip.  Other families' routers are the same code under
+    other settings (``models/axk1.py``: groups; ``models/longcat.py``:
+    softmax scores, no shared expert, and ``n_identity`` identity experts
+    behind the real ones in the router, whose picks add ``w x m`` and take
+    no row of the grouped products).
 
 The training-side ``models/moe.py`` is a different layer (capacity-bounded
 one-hot dispatch that drops tokens); nothing here uses it.
@@ -39,6 +43,9 @@ import jax.numpy as jnp
 from .llama import arena_pos_bytes, attn_block_pages, init_kv_pages, paged_attention, rms_norm, rope
 
 Params = dict
+#: numbers behind a layer's counts where the router has identity experts
+#: (:func:`expert_layer`): picks of them, most and fewest real picks a token
+IDENTITY_COUNTS = 3
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 
@@ -66,6 +73,8 @@ class AfmoeConfig:
     n_group: int = 1
     topk_group: int = 1
     n_shared: int = 1
+    n_identity: int = 0  # identity experts behind the real ones in the router (:func:`route`)
+    route_score: str = "sigmoid"
     route_scale: float = 2.448
     route_norm: bool = True
     rope_theta: float = 10000.0
@@ -82,7 +91,7 @@ class AfmoeConfig:
                 f"held of {self.n_experts}")
         if self.n_heads % self.n_kv_heads or not 0 <= self.n_dense_layers <= self.n_layers:
             raise ValueError("heads must group evenly; dense layers lead")
-        check_groups(self)
+        check_routing(self)
 
     @property
     def window_layers(self) -> tuple[int, ...]:
@@ -100,8 +109,12 @@ class AfmoeConfig:
         return serving_spec(self)
 
 
-def check_groups(cfg: Any) -> None:
-    """Refuse routing groups :func:`route` cannot select under."""
+def check_routing(cfg: Any) -> None:
+    """Refuse a router :func:`route` cannot select under."""
+    if cfg.route_score not in ("sigmoid", "softmax"):
+        raise ValueError(f"scores by {cfg.route_score!r}: sigmoid or softmax")
+    if cfg.n_identity < 0 or (cfg.n_identity and cfg.n_group > 1):
+        raise ValueError("identity experts lie behind the real ones, outside any group")
     if cfg.n_experts % cfg.n_group or not 1 <= cfg.topk_group <= cfg.n_group:
         raise ValueError(f"{cfg.n_experts} experts in {cfg.n_group} groups, "
                          f"{cfg.topk_group} kept")
@@ -167,22 +180,29 @@ def init_arenas(cfg: AfmoeConfig, num_pages: int, page_size: int, window_pages: 
 
 
 def route(m: jax.Array, layer: Params, cfg: Any) -> tuple[jax.Array, jax.Array]:
-    """Every token over ALL ``n_experts``, in float32: ``(sel [T, k] expert
+    """Every token over the WHOLE router, in float32: ``(sel [T, k] expert
     ids, w [T, k] weights)``.  The selection bias, where the layer has one,
-    takes part in the selection only; the weights are the selected sigmoid
-    scores, normalised over the k selected (held here or not) and scaled.
+    takes part in the selection only; the weights are the selected scores
+    (``cfg.route_score``: each expert's sigmoid, or a softmax over the
+    router's width), normalised over the k selected (held here or not) where
+    ``cfg.route_norm`` says so, and scaled.
 
     THE selection code of every sparse family (``cfg``: an ``AfmoeConfig``,
-    or another family's config with the same routing fields).  With
-    ``cfg.n_group`` > 1 the selection is group-limited: the experts lie in
-    ``n_group`` equal groups, a group's score is the sum of its two best
-    experts', and a token picks its ``top_k`` among the experts of its
-    ``topk_group`` best groups only, so its experts span at most that many
-    groups (in a deployment: chips).  One group is no limit and the program
-    it always was."""
-    scores = jax.nn.sigmoid(jnp.matmul(
+    or another family's config with the same routing fields).  The router is
+    ``n_experts + n_identity`` wide: ids below ``n_experts`` are real
+    experts, the ``n_identity`` behind them return their input
+    (:func:`expert_layer`).  With ``cfg.n_group`` > 1 the selection is
+    group-limited: the experts lie in ``n_group`` equal groups, a group's
+    score is the sum of its two best experts', and a token picks its
+    ``top_k`` among the experts of its ``topk_group`` best groups only, so
+    its experts span at most that many groups (in a deployment: chips).
+    Sigmoid scores, one group and no identity experts is the program it
+    always was."""
+    logits = jnp.matmul(
         m.astype(jnp.float32), layer["router"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.softmax(logits, axis=-1) if cfg.route_score == "softmax"
+              else jax.nn.sigmoid(logits))
     pick = scores + layer["router_bias"] if "router_bias" in layer else scores
     if cfg.n_group > 1:
         with jax.named_scope("moe_group_select"):
@@ -204,15 +224,26 @@ def expert_layer(
     m: jax.Array, layer: Params, cfg: Any, live: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
     """This chip's part of the expert layer for ``m`` [T, d]: the shared
-    expert plus the weighted outputs of the HELD experts, float32 [T, d];
-    and the assignments each held expert got, int32 [experts_held].  Slots
-    with ``live`` false (buffer padding) route nowhere.  Dropless: every
-    assignment to a held expert is computed, whatever the imbalance — the
-    grouped products run over all ``T * top_k`` assignment rows, sorted by
-    expert, with the rows of experts held elsewhere behind the last group."""
+    expert (where the family has one) plus the weighted outputs of the HELD
+    real experts, float32 [T, d]; and the assignments each held expert got,
+    int32 [experts_held].  Slots with ``live`` false (buffer padding) route
+    nowhere.  Dropless: every assignment to a held expert is computed,
+    whatever the imbalance — the grouped products run over all ``T * top_k``
+    assignment rows, sorted by expert, with the rows of experts held
+    elsewhere (and of identity experts) behind the last group.
+
+    With ``cfg.n_identity`` identity experts in the router a pick of one adds
+    ``w x m`` (``m`` as it came, float32 in the step) and costs neither a row
+    of the grouped products nor a weight read.  That part needs no weights
+    and no exchange: every chip computes it alike for its own tokens, so it
+    is counted ONCE when the chips' shares are added up, as a shared expert
+    is.  ``IDENTITY_COUNTS`` numbers then ride behind the counts (int32
+    [experts_held + 3]): picks of identity experts by live tokens, and the
+    most and the fewest REAL experts a live token picked."""
     t, k, held = m.shape[0], cfg.top_k, cfg.experts_held
     with jax.named_scope("moe_route"):
         sel, w = route(m, layer, cfg)  # from m as it came: float32 in the step
+    m_in = m
     m = m.astype(layer["e_gate"].dtype)
     with jax.named_scope("moe_sort"):
         local = sel - cfg.first_expert
@@ -226,16 +257,29 @@ def expert_layer(
         up = jax.lax.ragged_dot(xs, layer["e_up"], counts)
         ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, layer["e_down"], counts,
                                 preferred_element_type=jnp.float32)
-    with jax.named_scope("moe_shared"):
-        shared = jnp.matmul(
-            jax.nn.silu(m @ layer["s_gate"]) * (m @ layer["s_up"]), layer["s_down"],
-            preferred_element_type=jnp.float32)
+    if cfg.n_shared:
+        with jax.named_scope("moe_shared"):
+            shared = jnp.matmul(
+                jax.nn.silu(m @ layer["s_gate"]) * (m @ layer["s_up"]), layer["s_down"],
+                preferred_element_type=jnp.float32)
     with jax.named_scope("moe_combine"):
         # back to assignment order; rows past the last group (experts held
-        # elsewhere, padding) hold nothing the grouped product defines
+        # elsewhere, identity experts, padding) hold nothing the grouped
+        # product defines
         ys = ys[jnp.argsort(order)].reshape(t, k, -1)
-        routed = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
-    return shared + routed, counts
+        out = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
+    if cfg.n_shared:
+        out = shared + out
+    if cfg.n_identity:
+        with jax.named_scope("moe_identity"):
+            zero = sel >= cfg.n_experts  # [T, k]: the router's ids behind the real experts
+            out = out + (jnp.sum(jnp.where(zero, w, 0.0), axis=1, keepdims=True)
+                         * m_in.astype(jnp.float32))
+            real = k - jnp.sum(zero, axis=1, dtype=jnp.int32)  # [T]
+            counts = jnp.concatenate([counts, jnp.stack([
+                jnp.sum(zero & live[:, None], dtype=jnp.int32),
+                jnp.max(jnp.where(live, real, 0)), jnp.min(jnp.where(live, real, k))])])
+    return out, counts
 
 
 # ---------------------------------------------------------------------------
@@ -337,17 +381,24 @@ def ragged_step(
 
 
 def step_counters(cfg: Any, counts: Any, live_tokens: int) -> dict[str, int]:
-    """What one step's ``counts`` (int [expert layers, experts held], as
-    :func:`ragged_step` returned them) add to ``ServingStats``: assignments
-    the router made, those to experts held here, held experts that got a
-    token, and the busiest held expert's tokens, each summed over the
-    expert layers."""
-    return {
+    """What one step's ``counts`` (int [expert layers, experts held (+ 3)],
+    as :func:`expert_layer` returned them a layer) add to ``ServingStats``:
+    assignments the router made, those to experts held here, held experts
+    that got a token, and the busiest held expert's tokens, each summed over
+    the expert layers; with identity experts in the router also the picks of
+    them, and the most and the fewest real experts a live token picked (a
+    layer, summed over the layers: a reader divides)."""
+    held = counts[:, :cfg.experts_held]
+    out = {
         "moe_assignments": live_tokens * cfg.top_k * counts.shape[0],
-        "moe_assignments_here": int(counts.sum()),
-        "moe_experts_touched": int((counts > 0).sum()),
-        "moe_max_expert_load": int(counts.max(axis=1).sum()) if counts.size else 0,
+        "moe_assignments_here": int(held.sum()),
+        "moe_experts_touched": int((held > 0).sum()),
+        "moe_max_expert_load": int(held.max(axis=1).sum()) if held.size else 0,
     }
+    if cfg.n_identity:
+        zero, most, fewest = (int(n) for n in counts[:, cfg.experts_held:].sum(axis=0))
+        out.update(moe_zero_assignments=zero, moe_real_picks_max=most, moe_real_picks_min=fewest)
+    return out
 
 
 def serving_spec(cfg: AfmoeConfig) -> Any:
@@ -374,5 +425,5 @@ def serving_spec(cfg: AfmoeConfig) -> Any:
     )
 
 
-__all__ = ["AfmoeConfig", "check_groups", "init_params", "init_arenas", "route", "expert_layer",
-           "ragged_step", "serving_spec", "step_counters", "SLIDING", "FULL"]
+__all__ = ["AfmoeConfig", "IDENTITY_COUNTS", "check_routing", "init_params", "init_arenas", "route",
+           "expert_layer", "ragged_step", "serving_spec", "step_counters", "SLIDING", "FULL"]

@@ -28,6 +28,9 @@ What the block has that ``models/llama`` and ``models/afmoe`` have not
     selection code and THE expert layer of both sparse families
     (``afmoe.route``, ``afmoe.expert_layer``), nothing of them copied.
 
+The latent-attention sublayer is ONE function (:func:`mla_sublayer`) that
+``models/longcat.py`` calls too, with its own rotation and rank scalings.
+
 Pre-norm residual block, no bias anywhere, untied head, one leading dense
 layer.  The residual stream is float32 as in ``models/afmoe`` (the router
 reads it unrounded); every matrix product takes its inputs in ``cfg.dtype``.
@@ -36,12 +39,12 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from .afmoe import check_groups, expert_layer, step_counters
+from .afmoe import check_routing, expert_layer, step_counters
 from .llama import arena_pos_bytes, attn_block_pages, paged_attention, rms_norm
 
 Params = dict
@@ -69,6 +72,8 @@ class Axk1Config:
     n_group: int = 4
     topk_group: int = 2
     n_shared: int = 1
+    n_identity: int = 0  # the router holds real experts only (``afmoe.route``)
+    route_score: str = "sigmoid"
     route_scale: float = 2.5
     route_norm: bool = True
     rope_theta: float = 10000.0
@@ -91,7 +96,7 @@ class Axk1Config:
                 f"held of {self.n_experts}")
         if not 0 <= self.n_dense_layers <= self.n_layers or self.rope_dim % 2:
             raise ValueError("dense layers lead; the rotated part pairs its dimensions")
-        check_groups(self)
+        check_routing(self)
 
     @property
     def n_kv_heads(self) -> int:
@@ -157,17 +162,24 @@ def yarn_inv_freq(cfg: Axk1Config) -> jax.Array:
     return extra / cfg.rope_factor * ramp + extra * (1 - ramp)
 
 
-def rope(x: jax.Array, positions: jax.Array, cfg: Axk1Config) -> jax.Array:
-    """x: [T, ..., rope_dim] rotated at ``positions`` [T], half-split pairing
-    (dimension i with i + d/2), cos and sin scaled by YaRN's ``mscale /
-    mscale_all_dim`` ratio (1 in the published configuration)."""
-    ang = positions.astype(jnp.float32)[:, None] * yarn_inv_freq(cfg)[None, :]
+def rotate(x: jax.Array, ang: jax.Array, ratio: float = 1.0) -> jax.Array:
+    """x: [T, ..., rope_dim] rotated by the angles ``ang`` [T, rope_dim / 2]
+    (position x inverse frequency), half-split pairing (dimension i with i +
+    d/2), cos and sin scaled by ``ratio``."""
     ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
-    ratio = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
-             / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
     cos, sin = jnp.cos(ang) * ratio, jnp.sin(ang) * ratio
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+
+
+def rope(x: jax.Array, positions: jax.Array, cfg: Axk1Config) -> jax.Array:
+    """:func:`rotate` at ``positions`` [T] under YaRN: its inverse
+    frequencies, cos and sin scaled by the ``mscale / mscale_all_dim`` ratio
+    (1 in the published configuration)."""
+    ang = positions.astype(jnp.float32)[:, None] * yarn_inv_freq(cfg)[None, :]
+    ratio = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+             / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return rotate(x, ang, ratio)
 
 
 # ---------------------------------------------------------------------------
@@ -226,6 +238,87 @@ def init_arenas(cfg: Axk1Config, num_pages: int, page_size: int) -> tuple[jax.Ar
 # ---------------------------------------------------------------------------
 
 
+class WalkRows(NamedTuple):
+    """What every latent-attention sublayer of one step shares: where each
+    buffer slot's latent is written and how its row is walked."""
+    positions: jax.Array  # [T]
+    token_seq: jax.Array  # [T] table row of each slot
+    page_tables: jax.Array  # [S+1, P]
+    page_idx: jax.Array  # [T] the page a slot's latent is written to
+    slot: jax.Array  # [T] and the slot in it
+    block_pages: int  # pages a trip of the walk gathers
+    c_pad: jax.Array  # [T, zeros] from a latent's numbers to the arena's whole tiles
+    q_pad: jax.Array  # [T, h, zeros] beside every query (they add nothing to a score)
+
+
+def walk_rows(c_pages: jax.Array, positions: jax.Array, page_tables: jax.Array,
+              token_seq: jax.Array, cfg: Any, dt: Any) -> WalkRows:
+    """The step's :class:`WalkRows` over the latent arena ``c_pages`` [rows,
+    N, ps, latent_width] (a row a latent-attention sublayer)."""
+    t_buf, ps = positions.shape[0], c_pages.shape[2]
+    # one shared key head under the h query heads, values ``kv_rank`` wide
+    block_pages = attn_block_pages(
+        ps, page_tables.shape[1],
+        arena_pos_bytes((c_pages.shape[3:],), c_pages.dtype.itemsize), cfg.n_heads, 1, cfg.kv_rank)
+    zeros = c_pages.shape[3] - cfg.latent_dim
+    return WalkRows(
+        positions, token_seq, page_tables, page_tables[token_seq, positions // ps],
+        positions % ps, block_pages, jnp.zeros((t_buf, zeros), dt),
+        jnp.zeros((t_buf, cfg.n_heads, zeros), dt))
+
+
+def mla_sublayer(
+    a: jax.Array,
+    layer: Params,
+    c_pages: jax.Array,
+    row: int,
+    rows: WalkRows,
+    cfg: Any,
+    rope_fn: Callable[[jax.Array, jax.Array], jax.Array],
+    *,
+    q_scale: float = 1.0,
+    kv_scale: float = 1.0,
+) -> tuple[jax.Array, jax.Array]:
+    """One latent-attention sublayer in absorbed form over row ``row`` of the
+    latent arena: ``a`` [T, d] (normed, in the weights' dtype) -> ``(the
+    sublayer's output [T, d], c_pages)``.  THE sublayer of every
+    latent-attention family: ``cfg`` gives the widths (``n_heads``,
+    ``nope_dim``, ``rope_dim``, ``v_dim``, ``kv_rank``, ``latent_dim``),
+    ``norm_eps`` and ``softmax_scale``; ``rope_fn(x, positions)`` rotates the
+    family's way (YaRN here, plain in ``models/longcat.py``); ``q_scale``
+    multiplies the query behind ``wqb`` and ``kv_scale`` the normed kv latent
+    before it is cached and expanded (LongCat's rank scalings; 1 is no
+    operation at all).  Every token's ``(c | kr)`` is written at ``(page_idx,
+    slot)`` before the walk, so a chunk's later tokens see its earlier ones."""
+    t_buf = a.shape[0]
+    h, nope, rd, vd, rank = cfg.n_heads, cfg.nope_dim, cfg.rope_dim, cfg.v_dim, cfg.kv_rank
+    with jax.named_scope("mla_q_proj"):
+        cq = rms_norm(a @ layer["wqa"], layer["q_norm"], cfg.norm_eps)
+        q = (cq @ layer["wqb"]).reshape(t_buf, h, nope + rd)
+        if q_scale != 1.0:
+            q = q * q_scale
+        q_rope = rope_fn(q[..., nope:], rows.positions)
+    with jax.named_scope("mla_kv_proj"):
+        ckr = a @ layer["wkva"]  # [T, rank + rd]
+        c = rms_norm(ckr[:, :rank], layer["kv_norm"], cfg.norm_eps)
+        if kv_scale != 1.0:
+            c = c * kv_scale
+        latent = jnp.concatenate([c, rope_fn(ckr[:, rank:], rows.positions), rows.c_pad], axis=-1)
+    with jax.named_scope("kv_write"):
+        c_pages = c_pages.at[row, rows.page_idx, rows.slot].set(latent)
+    wkvb = layer["wkvb"].reshape(rank, h, nope + vd)
+    with jax.named_scope("mla_absorb_q"):
+        ql = jnp.einsum("thn,chn->thc", q[..., :nope], wkvb[..., :nope])  # [T, h, rank]
+    # one shared key head under the h query heads; a key's leading
+    # ``rank`` columns are its value
+    ol = paged_attention(
+        jnp.concatenate([ql, q_rope, rows.q_pad], axis=-1), c_pages, None, row, rows.page_tables,
+        rows.token_seq, rows.positions, rows.block_pages, v_dim=rank, scale=cfg.softmax_scale)
+    with jax.named_scope("mla_absorb_out"):
+        o = jnp.einsum("thc,chv->thv", ol, wkvb[..., nope:])  # [T, h, vd]
+    return o.reshape(t_buf, h * vd) @ layer["wo"], c_pages
+
+
 def ragged_step(
     params: Params,
     c_pages: jax.Array,
@@ -240,53 +333,21 @@ def ragged_step(
 ) -> tuple[jax.Array, jax.Array]:
     """One ragged mixed prefill+decode step (the contract of
     ``llama.ragged_step``) over the latent arena ``c_pages`` [L, N, ps,
-    latent_width].  Every token's ``(c | kr)`` is written at ``(table[row][pos
-    // ps], pos % ps)`` before the walk, so a chunk's later tokens see its
-    earlier ones.  Returns ``(out, c_pages)``, ``out`` int32 [T + expert
+    latent_width].  Returns ``(out, c_pages)``, ``out`` int32 [T + expert
     layers x experts_held]: the per-slot next-token argmax, then the
     assignments each held expert got in each expert layer."""
     t_buf = tokens.shape[0]
-    h, nope, rd, vd, rank = cfg.n_heads, cfg.nope_dim, cfg.rope_dim, cfg.v_dim, cfg.kv_rank
-    ps = c_pages.shape[2]
     live = token_seq < page_tables.shape[0] - 1  # the last row is the padding row
-    page_idx = page_tables[token_seq, positions // ps]  # [T]
-    slot = positions % ps
-    # one shared key head under the h query heads, values ``rank`` wide
-    block_pages = attn_block_pages(
-        ps, page_tables.shape[1],
-        arena_pos_bytes((c_pages.shape[3:],), c_pages.dtype.itemsize), h, 1, rank)
-    scale = cfg.softmax_scale
     counts = []
     dt = params["embed"].dtype
-    # zeros from a latent's 576 numbers to the arena's whole tiles, beside
-    # every stored row and every query (they add nothing to a score)
-    zeros = c_pages.shape[3] - cfg.latent_dim
-    c_pad, q_pad = jnp.zeros((t_buf, zeros), dt), jnp.zeros((t_buf, h, zeros), dt)
+    rows = walk_rows(c_pages, positions, page_tables, token_seq, cfg, dt)
+    rope_fn = lambda x, pos: rope(x, pos, cfg)  # noqa: E731
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(jnp.float32)  # [T, d], float32 throughout
     for li, layer in enumerate(params["layers"]):
         a = rms_norm(x, layer["norm_in"], cfg.norm_eps).astype(dt)
-        with jax.named_scope("mla_q_proj"):
-            cq = rms_norm(a @ layer["wqa"], layer["q_norm"], cfg.norm_eps)
-            q = (cq @ layer["wqb"]).reshape(t_buf, h, nope + rd)
-            q_rope = rope(q[..., nope:], positions, cfg)
-        with jax.named_scope("mla_kv_proj"):
-            ckr = a @ layer["wkva"]  # [T, rank + rd]
-            c = rms_norm(ckr[:, :rank], layer["kv_norm"], cfg.norm_eps)
-            latent = jnp.concatenate([c, rope(ckr[:, rank:], positions, cfg), c_pad], axis=-1)
-        with jax.named_scope("kv_write"):
-            c_pages = c_pages.at[li, page_idx, slot].set(latent)
-        wkvb = layer["wkvb"].reshape(rank, h, nope + vd)
-        with jax.named_scope("mla_absorb_q"):
-            ql = jnp.einsum("thn,chn->thc", q[..., :nope], wkvb[..., :nope])  # [T, h, rank]
-        # one shared key head under the h query heads; a key's leading
-        # ``rank`` columns are its value
-        ol = paged_attention(
-            jnp.concatenate([ql, q_rope, q_pad], axis=-1), c_pages, None, li, page_tables,
-            token_seq, positions, block_pages, v_dim=rank, scale=scale)
-        with jax.named_scope("mla_absorb_out"):
-            o = jnp.einsum("thc,chv->thv", ol, wkvb[..., nope:])  # [T, h, vd]
-        x = x + o.reshape(t_buf, h * vd) @ layer["wo"]
+        o, c_pages = mla_sublayer(a, layer, c_pages, li, rows, cfg, rope_fn)
+        x = x + o
         m = rms_norm(x, layer["norm_post"], cfg.norm_eps)  # float32
         if li < cfg.n_dense_layers:
             with jax.named_scope("mlp"):
@@ -328,5 +389,5 @@ def serving_spec(cfg: Axk1Config) -> Any:
     )
 
 
-__all__ = ["Axk1Config", "init_params", "init_arenas", "ragged_step", "rope", "serving_spec",
-           "yarn_inv_freq", "yarn_mscale"]
+__all__ = ["Axk1Config", "WalkRows", "init_params", "init_arenas", "mla_sublayer", "ragged_step",
+           "rope", "rotate", "serving_spec", "walk_rows", "yarn_inv_freq", "yarn_mscale"]

@@ -181,6 +181,12 @@ class ServingStats:
     moe_assignments_here: int = 0
     moe_experts_touched: int = 0
     moe_max_expert_load: int = 0
+    # a router with identity (zero-compute) experts: picks of them by live
+    # tokens, and the most and the fewest REAL experts a live token picked,
+    # a layer and step (summed: divide by layers x steps)
+    moe_zero_assignments: int = 0
+    moe_real_picks_max: int = 0
+    moe_real_picks_min: int = 0
     # per-step wall time (seconds), capped ring for inter-token p50/p99
     step_seconds: deque = field(default_factory=lambda: deque(maxlen=4096))
     # submit → first sampled token (seconds), capped ring for TTFT p50
@@ -1422,6 +1428,10 @@ class ServingEngine:
                 setattr(self.stats, name, getattr(self.stats, name) + n)
             attrs["moe_here"] = str(counters["moe_assignments_here"])
             attrs["moe_touched"] = str(counters["moe_experts_touched"])
+            if "moe_zero_assignments" in counters:
+                attrs["moe_zero"] = str(counters["moe_zero_assignments"])
+                attrs["moe_real_picks"] = (f"{counters['moe_real_picks_min']}-"
+                                           f"{counters['moe_real_picks_max']}")
         if self.speculative:
             attrs["drafted"] = str(step_drafted)
             attrs["accepted"] = str(step_accepted)

@@ -171,6 +171,44 @@ def test_the_latent_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
     assert copied.alias_size_in_bytes >= arena_bytes and copied.temp_size_in_bytes < 0.1e9
 
 
+def test_the_shortcut_connected_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
+    """The longcat cell's ragged program at the benchmark's sizes (``benchmarks/
+    configs/longcat-flash-chat-ep32.json``: published widths, four layers of
+    two latent-attention sublayers, 16 of 512 real experts held, 32 sessions
+    x 8192 positions): 10.35 GB of weights and the 2.68 GB arena of EIGHT rows
+    (a row a sublayer) fit, donation is real, and the arena is neither copied
+    nor laid out anew (PERF.md section 4 has the reading)."""
+    from benchmarks.families import longcat as fam
+    from benchmarks.harness import cells
+
+    doc = dict(cells.load_config("longcat-flash-chat-ep32"))
+    cfg, pool = fam.program_config(doc), doc["pool"]
+    shapes = fam.param_shapes(doc)
+    params = jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip),
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    for layer in params["layers"]:  # the selection bias is float32
+        layer["router_bias"] = jax.ShapeDtypeStruct(
+            layer["router_bias"].shape, jnp.float32, sharding=one_chip)
+    arena = jax.ShapeDtypeStruct(
+        (cfg.n_sublayers, pool["pages"], pool["page_size"], cfg.latent_width), cfg.dtype,
+        sharding=one_chip)
+    assert (cfg.n_sublayers, cfg.latent_width) == (8, 640)
+    assert arena.shape[1] * pool["page_size"] == pool["max_sessions"] * cfg.max_seq_len
+    layout = FeedLayout(pool["max_sessions"] + pool["prefill_budget"], pool["max_sessions"],
+                        (cfg.max_seq_len // pool["page_size"],))
+    feed = jax.ShapeDtypeStruct((layout.size,), jnp.int32, sharding=one_chip)
+    program = make_ragged_program(cfg, layout, sample_logits=True, donate=True)
+    compiled = program.lower(params, arena, feed).compile()
+    ma = compiled.memory_analysis()
+    arena_bytes = arena.size * arena.dtype.itemsize
+    assert ma.alias_size_in_bytes >= arena_bytes
+    assert ma.temp_size_in_bytes < 0.5e9  # no copy of the 2.7 GB arena among the temporaries
+    assert 13.0e9 < device_bytes(compiled) <= 0.9 * HBM_BYTES
+    print(f"longcat step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
+
+
 def test_reference_forward_fits_beside_the_serving_state(one_chip):
     cfg = smoke_cfg()
     params, arena, _, _ = serving_shapes(
