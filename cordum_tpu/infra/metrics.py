@@ -457,6 +457,12 @@ class Metrics:
             "cordum_serving_sessions_retired_total",
             "Sessions retired from the decode loop, by reason",
         )
+        self.serving_stream_packets = Counter(
+            "cordum_serving_stream_packets_total",
+            "Stream packets (one session's new tokens of one step) the step "
+            "loop published, by whether the next step was already on the "
+            "device (behind_step = true | false)",
+        )
         self.serving_sessions = Gauge(
             "cordum_serving_active_sessions",
             "Sessions currently in the decode set",
@@ -765,6 +771,7 @@ class Metrics:
             self.serving_prefill,
             self.serving_admitted,
             self.serving_retired,
+            self.serving_stream_packets,
             self.serving_sessions,
             self.serving_kv_pages_in_use,
             self.serving_compiles,

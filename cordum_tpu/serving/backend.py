@@ -156,6 +156,12 @@ class StepBackend:
     # step — the serving-gang leader broadcasts it so followers replay the
     # identical program against their head shards
     on_step: Optional[Callable[[list["StepEntry"]], None]] = None
+    # called once a step, from the step's own thread, the moment the step is
+    # fed: its program is on the device and nothing of it needs the
+    # interpreter until the result is back.  The engine starts what may
+    # hold the interpreter (publishing the last step's tokens) only then.
+    # A backend with no feed of its own calls it on entry
+    on_dispatched: Optional[Callable[[], None]] = None
 
     def stamp_whole_call(self, t0: int) -> None:
         """``last_phases`` of a step with no pack, dispatch or unpack of its
@@ -496,6 +502,8 @@ class ServingBackend(StepBackend):
                 nxt, *self._arenas = self._ragged_jit(
                     self._params, *self._arenas, feed)
                 nxt.copy_to_host_async()
+            if self.on_dispatched is not None:
+                self.on_dispatched()
             with step_phase("wait", n_step, marks):
                 out = np.asarray(nxt)
         # out is [T] per-position predictions: a sampled entry's token is

@@ -39,7 +39,7 @@ from ..infra import logging as logx
 from ..infra.metrics import Metrics
 from ..obs.tracer import Tracer
 from ..protocol.types import OP_SERVING_PREFILL, SPAN_ERROR, Span
-from ..utils.eager import eager
+from ..utils.eager import eager, eager_gather
 from ..utils.ids import fast_id
 from .backend import STEP_PHASES, StepBackend, StepEntry, step_phase
 from .modelspec import require_page_records
@@ -187,6 +187,11 @@ class ServingStats:
     moe_zero_assignments: int = 0
     moe_real_picks_max: int = 0
     moe_real_picks_min: int = 0
+    # stream packets the step loop handed to the sinks (a session's new
+    # tokens of one step; replays of a carried prefix are not among them),
+    # and those of them published with the NEXT step already on the device
+    stream_packets: int = 0
+    stream_packets_behind_step: int = 0
     # per-step wall time (seconds), capped ring for inter-token p50/p99
     step_seconds: deque = field(default_factory=lambda: deque(maxlen=4096))
     # submit → first sampled token (seconds), capped ring for TTFT p50
@@ -244,6 +249,9 @@ class _Session:
     # and the tokens the drafter planned for the upcoming step
     accept_ewma: float = 1.0
     draft_plan: list[int] = field(default_factory=list)
+    # stream packets of this session that a step booked and nobody was told
+    # yet: its future does not resolve, and it is not quiesced, before 0
+    unsent: int = 0
     enqueued_at: float = field(default_factory=time.monotonic)
     enqueued_ns: int = field(default_factory=time.time_ns)  # same instant, wall
     # open until the first token; None for migrated-in, resumed and restored
@@ -384,8 +392,24 @@ class ServingEngine:
         self._closed = False
         # job ids riding the step currently on the device: a migration
         # freeze is complete only once the in-flight step (which may still
-        # produce one token for the session) has scattered its results
+        # produce one token for the session) has scattered its results and
+        # that token's packet is out (``_Session.unsent``)
         self._in_step: frozenset[str] = frozenset()
+        # the last step's stream packets ``(session, tokens, count, done)``,
+        # in row order, and the retired sessions whose futures wait for
+        # them: both are told once the NEXT step is on the device (or
+        # before the loop parks), never between two steps.  The lock makes
+        # ``stop()`` wait for a publish the loop is in the middle of
+        self._unsent: list[tuple[_Session, list[int], int, bool]] = []
+        self._unresolved: list[tuple[_Session, Optional[BaseException]]] = []
+        self._publishing = asyncio.Lock()
+        self._packets_at_close = (0, 0)  # the two stream counters, last cycle
+        # set, through the loop, by the executor thread once the step it was
+        # handed is fed to the device (``StepBackend.on_dispatched``) or has
+        # returned: from then on the loop may hold the interpreter
+        self._fed = asyncio.Event()
+        self._tell_fed: Callable[[], None] = lambda: None
+        self._crash_publish: Optional[asyncio.Task] = None
         # names this worker's step traces (``step-<worker_id>-<n>``); the
         # owning worker sets it when it attaches the engine
         self.worker_id = ""
@@ -579,6 +603,10 @@ class ServingEngine:
                 f"decode loop crashed: {exc}"
             ))
         self._pending.clear()
+        if self._unsent:
+            # the crash fell between a step's bookkeeping and its publish:
+            # the tokens still go out, and the futures behind them resolve
+            self._crash_publish = asyncio.ensure_future(self._publish_unsent(False))
 
     def _gauge(self) -> None:
         if self.metrics is not None:
@@ -692,7 +720,8 @@ class ServingEngine:
                 # offset 0 — consumers dedupe by offset, so a client that
                 # saw the original stream skips it and one that missed
                 # packets in the crash window backfills
-                asyncio.ensure_future(self._emit(sess, list(sess.out_tokens)))
+                asyncio.ensure_future(self._emit(
+                    sess, list(sess.out_tokens), len(sess.out_tokens), sess.done))
             if sess.done:
                 # the crash landed after the final token: nothing left to
                 # decode — finish straight from the resume prefix
@@ -873,7 +902,8 @@ class ServingEngine:
 
     async def _flush_spans(self) -> None:
         """Publish what was recorded since the last flush.  Called only
-        with a step on the device, before the loop parks, and from
+        with a step on the device (after that step's turn to tell the last
+        one's tokens: ``_publish_unsent``), before the loop parks, and from
         ``stop()``."""
         if not self._spans or self.tracer is None:
             return
@@ -881,15 +911,49 @@ class ServingEngine:
         for sp in spans:
             await self.tracer.emit(sp)
 
-    async def _emit(self, sess: _Session, new_tokens: list[int]) -> None:
+    async def _emit(
+        self, sess: _Session, new_tokens: list[int], n_generated: int, done: bool
+    ) -> None:
+        """One stream packet to the session's sink: ``new_tokens`` end at
+        ``n_generated`` of its output."""
         if sess.on_tokens is None:
             return
         try:
-            await sess.on_tokens(new_tokens, len(sess.out_tokens), sess.done)
+            await sess.on_tokens(new_tokens, n_generated, done)
         except Exception as e:  # noqa: BLE001 - streaming is best-effort
             logx.warn("token stream sink failed", job_id=sess.job_id, err=str(e))
 
+    async def _publish_unsent(self, behind_step: bool) -> None:
+        """Tell what the last step's bookkeeping (``_scatter``) left untold:
+        its stream packets, in row order, then the futures of the sessions
+        it retired: a future never resolves before its session's last
+        packet went out.  ``behind_step``: the next step is on the device,
+        so none of this holds it up; the loop calls it with False only when
+        there is no next step to hand over (drained, or every row parked),
+        ``stop()`` before it evicts."""
+        async with self._publishing:
+            while self._unsent:
+                packets, self._unsent = self._unsent, []
+                # each sink runs to its first real suspension in row order;
+                # a bus that delivers at publish never suspends one
+                await eager_gather([self._emit(*pkt) for pkt in packets])
+                for sess, _, _, _ in packets:
+                    sess.unsent -= 1
+                n = len(packets)
+                self.stats.stream_packets += n
+                self.stats.stream_packets_behind_step += n if behind_step else 0
+                if self.metrics is not None:
+                    self.metrics.serving_stream_packets.inc(
+                        float(n), behind_step=str(behind_step).lower())
+            resolved, self._unresolved = self._unresolved, []
+            for sess, error in resolved:
+                self._resolve(sess, error)
+
     def _retire(self, sess: _Session, error: Optional[BaseException] = None) -> None:
+        """Take ``sess`` out of the engine: everything the next ``_admit``
+        and ``_assemble`` must see happens here and now; its future
+        resolves now too unless a stream packet of it is still untold, in
+        which case ``_publish_unsent`` resolves it behind that packet."""
         if (
             error is None and self.prefix is not None
             and not self._closed and not sess.cancelled and sess.pages
@@ -914,8 +978,6 @@ class ServingEngine:
             self.stats.retired += 1
             if self.metrics is not None:
                 self.metrics.serving_retired.inc(reason="finished")
-            if not sess.future.done():
-                sess.future.set_result(list(sess.out_tokens))
         else:
             if isinstance(error, SessionCancelled):
                 reason = "cancelled"
@@ -933,8 +995,19 @@ class ServingEngine:
                 reason = "failed"
             if self.metrics is not None:
                 self.metrics.serving_retired.inc(reason=reason)
-            if not sess.future.done():
-                sess.future.set_exception(error)
+        if sess.unsent:
+            self._unresolved.append((sess, error))
+        else:
+            self._resolve(sess, error)
+
+    @staticmethod
+    def _resolve(sess: _Session, error: Optional[BaseException]) -> None:
+        if sess.future.done():
+            return
+        if error is None:
+            sess.future.set_result(list(sess.out_tokens))
+        else:
+            sess.future.set_exception(error)
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1148,7 +1221,16 @@ class ServingEngine:
 
         One cycle is six contiguous phases (``backend.STEP_PHASES``):
         ``assemble`` here, ``pack``/``dispatch``/``wait``/``unpack`` inside
-        ``backend.step``, ``emit`` here again."""
+        ``backend.step``, ``emit`` here again.  ``emit`` is bookkeeping
+        only (``_scatter``): what a step's tokens make of the sessions and
+        the allocators, which the next ``assemble`` reads.  Telling anybody
+        (the riders' stream packets, the finishers' futures) is nothing the
+        next step waits for, so it happens after that step's hand-over,
+        while the device runs it (``_publish_unsent``), and before the loop
+        parks where there is no next step."""
+        loop = asyncio.get_running_loop()
+        self._tell_fed = lambda: loop.call_soon_threadsafe(self._fed.set)
+        self.backend.on_dispatched = self._tell_fed
         while not self._closed:
             n_step = self.stats.steps
             marks = [time.time_ns()]
@@ -1166,8 +1248,18 @@ class ServingEngine:
                     t0 = time.monotonic()
                     self._in_step = frozenset(s.job_id for s, _, _, _ in rows)
                     # handed to the executor before anything is published:
-                    # the flush below runs while the device does
+                    # the publish and the flush below run while the device does
+                    self._fed.clear()
                     done, step_call = eager(self._run_step(entries))
+            in_backend = bool(entries) and not done
+            if in_backend:
+                # the executor thread packs and dispatches in Python: what
+                # follows would hold the interpreter against it, and the
+                # program would start late
+                await self._fed.wait()
+            # the last step's tokens and finishers: behind this step if there
+            # is one, else now, before the loop parks or polls
+            await self._publish_unsent(behind_step=in_backend)
             if not self._active:
                 self._gauge()
                 if not self._pending:
@@ -1185,6 +1277,10 @@ class ServingEngine:
             if not entries:  # defensive: all rows parked past the budget
                 await asyncio.sleep(0.001)
                 continue
+            # yield once: the deliveries the publish queued, and intake,
+            # cancel and heartbeat tasks, run even under a saturated decode
+            # set (and when the step came back at once)
+            await asyncio.sleep(0)
             await self._flush_spans()
             results, step_err = step_call if done else await step_call
             if step_err is not None:
@@ -1209,11 +1305,8 @@ class ServingEngine:
             marks.append(time.time_ns())
             dt = time.monotonic() - t0
             with step_phase("emit", n_step, marks):
-                attrs = await self._scatter(rows, results, dt)
+                attrs = self._scatter(rows, results, dt)
                 self._gauge()
-                # yield to the loop so intake/cancel/heartbeat tasks run
-                # between steps even under a saturated decode set
-                await asyncio.sleep(0)
             self._cycle_closed(n_step, marks, attrs)
 
     async def _run_step(
@@ -1222,26 +1315,39 @@ class ServingEngine:
         """``backend.step`` off the loop; a whole-step failure comes back as
         a value, so the hand-over and the await share one error path."""
         try:
-            return await self.run_blocking(self.backend.step, entries), None
+            return await self.run_blocking(self._step_in_thread, entries), None
         except Exception as e:  # noqa: BLE001 - whole-step failure
             return [], e
 
-    async def _scatter(
+    def _step_in_thread(self, entries: list[StepEntry]) -> list[Any]:
+        """``backend.step`` (whatever stands there when the step runs), on
+        the executor thread.  A step that ends without having said it was
+        fed (it raised first, or its backend says nothing) says so here:
+        the loop waits for that word before it awaits the step itself."""
+        try:
+            return self.backend.step(entries)
+        finally:
+            if not self._fed.is_set():
+                self._tell_fed()
+
+    def _scatter(
         self,
         rows: list[tuple[_Session, int, bool, list[int]]],
         results: list[Any],
         dt: float,
     ) -> dict[str, str]:
-        """Scatter one step's results back to its riders, stream the new
-        tokens, retire the finishers.  ``dt`` is the whole ``backend.step``
-        wall.  Returns the cycle's ``step`` span attrs."""
+        """Scatter one step's results back to its riders and retire the
+        finishers: bookkeeping alone, nothing here awaits.  A rider's new
+        tokens go on ``_unsent`` as one stream packet, stamped with the
+        count and the ``done`` they were booked at; a finisher's pages are
+        free when this returns, its future waits for its packet
+        (``_retire``).  ``dt`` is the whole ``backend.step`` wall.  Returns
+        the cycle's ``step`` span attrs."""
         generated = 0
         prefill_fed = 0
         retired_this_step = 0
         step_drafted = 0
         step_accepted = 0
-        emits = []
-        retires = []
         pos_before = [sess.pos for sess, _, _, _ in rows] if self.ring_pages else []
         for (sess, chunk, samples, drafted), tok in zip(rows, results):
             if sess.ttft is not None:
@@ -1298,7 +1404,7 @@ class ServingEngine:
                 generated += len(burst)
                 if first:
                     self._first_token(sess)
-                emits.append(self._emit(sess, burst))
+                self._book_packet(sess, burst)
             else:
                 if sess.prefilled:
                     sess.pos += 1  # decode row: wrote its token at pos
@@ -1317,15 +1423,19 @@ class ServingEngine:
                         # (resume prefixes pre-populate out_tokens, so
                         # migrated/resumed sessions never land here)
                         self._first_token(sess)
-                    emits.append(self._emit(sess, [t]))
+                    self._book_packet(sess, [t])
             if sess.done or sess.cancelled:
                 retired_this_step += 1
-                # deferred below the emit gather: the future must not
-                # resolve before the session's final token packet is
-                # delivered, or a submitter that stops the engine the
-                # moment submit() returns races the stream's tail (the
-                # exactly-once contract spec bursts lean on)
-                retires.append(sess)
+                # the future must not resolve before the session's final
+                # token packet is delivered, or a submitter that stops the
+                # engine the moment submit() returns races the stream's
+                # tail (the exactly-once contract spec bursts lean on):
+                # _retire leaves it to the publish of that packet
+                self._retire(
+                    sess,
+                    error=SessionCancelled(sess.job_id)
+                    if sess.cancelled else None,
+                )
             elif (
                 self.on_prefill_done is not None
                 and not sess.handoff_signaled
@@ -1382,16 +1492,8 @@ class ServingEngine:
                     items=generated, tokens=generated,
                     compiled=compiled,
                 )
-        if emits:
-            await asyncio.gather(*emits)
-        for sess in retires:
-            self._retire(
-                sess,
-                error=SessionCancelled(sess.job_id)
-                if sess.cancelled else None,
-            )
-        # every token of this step is appended AND emitted: a freeze
-        # waiting on wait_quiesced() now sees a fully consistent session
+        # every token of this step is appended; a freeze waiting on
+        # wait_quiesced() also waits until the session's packet is out
         self._in_step = frozenset()
         if self.metrics is not None:
             self.metrics.serving_batch_occupancy.observe(float(len(rows)))
@@ -1435,7 +1537,21 @@ class ServingEngine:
         if self.speculative:
             attrs["drafted"] = str(step_drafted)
             attrs["accepted"] = str(step_accepted)
+        # of the stream packets published since the last cycle closed (the
+        # step before this one's), those that went out behind this step
+        behind, told = self.stats.stream_packets_behind_step, self.stats.stream_packets
+        behind0, told0 = self._packets_at_close
+        attrs["published_behind"] = f"{behind - behind0}/{told - told0}"
+        self._packets_at_close = (behind, told)
         return attrs
+
+    def _book_packet(self, sess: _Session, new_tokens: list[int]) -> None:
+        """One step's new tokens of ``sess`` as a stream packet to tell
+        later (``_publish_unsent``), as the session stands now."""
+        if sess.on_tokens is None:
+            return
+        sess.unsent += 1
+        self._unsent.append((sess, new_tokens, len(sess.out_tokens), sess.done))
 
     def _count_window(
         self, rows: list[tuple[_Session, int, bool, list[int]]], pos_before: list[int],
@@ -1554,9 +1670,16 @@ class ServingEngine:
 
     async def wait_quiesced(self, job_id: str) -> None:
         """Block until the in-flight step (which may still produce one
-        token for a just-frozen session) has scattered its results."""
-        while job_id in self._in_step:
+        token for a just-frozen session) has scattered its results AND
+        every token appended to the session has been published (a step's
+        packets go out behind the next hand-over, whether or not the
+        session rides that step)."""
+        while job_id in self._in_step or self._untold(job_id):
             await asyncio.sleep(0.002)
+
+    def _untold(self, job_id: str) -> bool:
+        sess = self._active.get(job_id)
+        return sess is not None and sess.unsent > 0
 
     def complete_migration(self, job_id: str) -> bool:
         """The target committed: retire locally as migrated — the waiter
@@ -1733,7 +1856,8 @@ class ServingEngine:
             # replay the carried tokens at offset 0: dedupe-by-offset makes
             # it a no-op for clients that saw them and a backfill for
             # clients that lost packets in the handover window
-            asyncio.ensure_future(self._emit(sess, list(sess.out_tokens)))
+            asyncio.ensure_future(self._emit(
+                sess, list(sess.out_tokens), len(sess.out_tokens), sess.done))
         if sess.done:
             self._retire(sess)
         else:
@@ -1759,6 +1883,8 @@ class ServingEngine:
             except Exception as e:  # noqa: BLE001 - logged, never swallowed
                 logx.warn("tiering sweep crashed during shutdown", err=str(e))
             self._tiering_task = None
+        # tokens a step booked go out before their sessions' cancellations
+        await self._publish_unsent(behind_step=False)
         for sess in list(self._pending):
             if not sess.future.done():
                 sess.future.set_exception(SessionCancelled(sess.job_id))
@@ -1775,4 +1901,5 @@ class ServingEngine:
             except Exception as e:  # noqa: BLE001 - logged, never swallowed
                 logx.warn("decode loop crashed during shutdown", err=str(e))
             self._loop_task = None
+        self.backend.on_dispatched = None  # the loop's; it spoke to this event loop
         await self._flush_spans()

@@ -281,6 +281,8 @@ class ServingGangGroup(StepBackend):
     # -- lock-step execution -------------------------------------------
     def step(self, entries: list[StepEntry]) -> list[Any]:
         t0 = time.time_ns()
+        if self.on_dispatched is not None:
+            self.on_dispatched()  # no one feed: each rank's step feeds and waits in its turn
         with self._lock:
             res = self.leader.step(entries)
             for follower in self.ranks[1:]:

@@ -55,9 +55,9 @@ class FakeBackend(StepBackend):
         t0 = time.time_ns()
         n_step = self.steps
         self.steps += 1
-        stall = self.step_delay + self.slow_at.get(n_step, 0.0)
-        if stall:
-            time.sleep(stall)
+        if self.on_dispatched is not None:
+            self.on_dispatched()  # no feed of its own: fed on entry
+        self.device(n_step)
         if n_step in self.fail_at:
             raise RuntimeError("poisoned")
         # the static-shape contract the real backend enforces
@@ -92,6 +92,12 @@ class FakeBackend(StepBackend):
             self.on_step(entries)
         self.stamp_whole_call(t0)
         return out
+
+    def device(self, n_step):
+        """Where the step's device time passes."""
+        stall = self.step_delay + self.slow_at.get(n_step, 0.0)
+        if stall:
+            time.sleep(stall)
 
     def copy_page(self, src, dst):
         self.arena[dst] = list(self._row(src))

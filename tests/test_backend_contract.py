@@ -51,7 +51,7 @@ def test_every_backend_keeps_the_contract(kind):
     def check_members():
         for name, hint in hints.items():
             value = getattr(be, name)
-            if name == "on_step":
+            if name in ("on_step", "on_dispatched"):
                 assert value is None or callable(value)
             else:
                 assert isinstance(value, typing.get_origin(hint) or hint), name
@@ -68,13 +68,17 @@ def test_every_backend_keeps_the_contract(kind):
                       window_pages=list(range(1, 1 + ring))),
             StepEntry(tokens=[7], start=0, pages=[3, 4],
                       window_pages=list(range(1 + ring, 1 + 2 * ring)))]
-    tapped = []
+    tapped, fed = [], []
     be.on_step = tapped.append
+    be.on_dispatched = lambda: fed.append(time.time_ns())
     before = time.time_ns()
     out = be.step(rows)
     after = time.time_ns()
     assert len(out) == 2 and all(type(t) is int for t in out)
     assert tapped == [rows]
+    # said once a step: after its dispatch, or on entry where there is none
+    assert len(fed) == 1 and before <= fed[0] <= be.last_phases[3]
+    assert be.last_phases[2] <= fed[0] or be.last_phases[0] == be.last_phases[2]
     check_members()
     assert len(be.last_phases) == 5
     assert [before, *be.last_phases, after] == sorted([before, *be.last_phases, after])

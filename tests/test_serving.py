@@ -507,6 +507,276 @@ async def test_engine_stop_evicts_everything():
         await eng.submit(GenRequest(prompt=[1]), job_id="late")
 
 
+# ------------------------------------------- the order of a cycle
+# (docs/SERVING.md §The continuous-batching step loop: bookkeeping, assemble,
+# hand-over, publish, resolve, await)
+
+
+class GatedBackend(FakeBackend):
+    """A fake whose every step, once fed, waits in ``backend.step`` for the
+    test's leave (``go``); ``free()`` lets all later steps through."""
+
+    def __init__(self, **kw):
+        import threading
+
+        super().__init__(**kw)
+        self.go = threading.Semaphore(0)
+        self.gated = True
+
+    def free(self):
+        self.gated = False
+        self.go.release()
+
+    def device(self, n_step):
+        if self.gated:
+            assert self.go.acquire(timeout=20), "the test never let the step go"
+            if not self.gated:
+                self.go.release()  # pass the leave on to the next step
+        super().device(n_step)
+
+
+class CycleLog:
+    """The order of hand-overs, returns, stream packets (as their sink
+    returns, after an optional sleep) and resolved submits of one engine."""
+
+    def __init__(self, sink_sleep=0.0):
+        self.events = []
+        self.packets = {}  # job -> [(tokens, n_generated, done)]
+        self.sink_sleep = sink_sleep
+        self.hold = {}  # job -> (packet ordinal, asyncio.Event) the sink waits for
+
+    async def run_blocking(self, fn, *args):
+        self.events.append("handed")
+        try:
+            return await asyncio.get_running_loop().run_in_executor(None, fn, *args)
+        finally:
+            self.events.append("returned")
+
+    def sink(self, job):
+        async def on_tokens(tokens, n_generated, done):
+            mine = self.packets.setdefault(job, [])
+            held = self.hold.get(job)
+            if held is not None and held[0] == len(mine):
+                await held[1].wait()
+            elif self.sink_sleep:
+                await asyncio.sleep(self.sink_sleep)
+            mine.append((list(tokens), n_generated, done))
+            self.events.append(("packet", job, n_generated))
+        return on_tokens
+
+    async def submit(self, eng, job, prompt, n_new):
+        try:
+            out = await eng.submit(GenRequest(prompt=prompt, max_new_tokens=n_new),
+                                   job_id=job, on_tokens=self.sink(job))
+        except Exception as e:  # noqa: BLE001 - the order of the error is the subject
+            self.events.append(("error", job, type(e).__name__))
+            raise
+        self.events.append(("resolved", job, len(out["tokens"])))
+        return out
+
+    def streamed(self, job):
+        """The job's stream, assembled: offsets contiguous and in order."""
+        out = []
+        for tokens, n_generated, _ in self.packets.get(job, []):
+            assert n_generated - len(tokens) == len(out), (job, self.packets[job])
+            out.extend(tokens)
+        return out
+
+
+async def until(cond, timeout=10.0):
+    t0 = asyncio.get_running_loop().time()
+    while not cond():
+        assert asyncio.get_running_loop().time() - t0 < timeout, "never happened"
+        await asyncio.sleep(0.002)
+
+
+async def test_a_steps_packets_go_out_while_the_next_step_is_in_the_backend():
+    """Step N's packet is published after step N+1's hand-over and before
+    its return, and counted as behind a step; the last step's, with no
+    successor, before the loop parks, and not counted."""
+    from cordum_tpu.infra.metrics import Metrics
+
+    log, be, metrics = CycleLog(), GatedBackend(num_pages=64), Metrics()
+    eng = ServingEngine(be, run_blocking=log.run_blocking, max_sessions=4, metrics=metrics)
+    job = asyncio.ensure_future(log.submit(eng, "a", [1, 2, 3], 3))
+    await until(lambda: log.events == ["handed"])
+    be.go.release()  # step 0 samples the first token; step 1 then waits in the backend
+    await until(lambda: ("packet", "a", 1) in log.events)
+    assert log.events == ["handed", "returned", "handed", ("packet", "a", 1)]
+    assert (eng.stats.stream_packets, eng.stats.stream_packets_behind_step) == (1, 1)
+    be.free()
+    out = await asyncio.wait_for(job, timeout=10)
+    assert out["tokens"] == log.streamed("a") == fake_ref([1, 2, 3], 3)
+    assert log.events[4:] == ["returned", "handed", ("packet", "a", 2), "returned",
+                              ("packet", "a", 3), ("resolved", "a", 3)]
+    assert (eng.stats.stream_packets, eng.stats.stream_packets_behind_step) == (3, 2)
+    # a session of one step has no step to hide behind
+    log.events.clear()
+    await asyncio.wait_for(log.submit(eng, "b", [4, 5], 1), timeout=10)
+    assert log.events == ["handed", "returned", ("packet", "b", 1), ("resolved", "b", 1)]
+    assert (eng.stats.stream_packets, eng.stats.stream_packets_behind_step) == (4, 2)
+    assert metrics.serving_stream_packets.value(behind_step="true") == 2
+    assert metrics.serving_stream_packets.value(behind_step="false") == 2
+    await eng.stop()
+
+
+@pytest.mark.parametrize("kind", ["says", "mute"])
+async def test_the_publish_waits_until_the_step_is_fed_or_has_returned(kind):
+    """The executor thread feeds the device in Python, so the loop holds the
+    last step's packets back until the backend says the step is fed
+    (``StepBackend.on_dispatched``); behind a backend that never says so,
+    until the step has returned."""
+    import time
+
+    log = CycleLog()
+
+    class Feeding(FakeBackend):
+        def step(self, entries):
+            hook, self.on_dispatched = self.on_dispatched, None
+            try:
+                log.events.append("feeding")
+                time.sleep(0.01)
+                if kind == "says":
+                    log.events.append("fed")
+                    hook()
+                out = super().step(entries)
+                log.events.append("result")
+                return out
+            finally:
+                self.on_dispatched = hook
+
+    eng = ServingEngine(Feeding(num_pages=64, step_delay=0.02),
+                        run_blocking=log.run_blocking, max_sessions=4)
+    out = await asyncio.wait_for(log.submit(eng, "a", [1, 2, 3], 3), timeout=10)
+    assert out["tokens"] == log.streamed("a") == fake_ref([1, 2, 3], 3)
+    cycle = ["handed", "feeding", "fed", ("packet", "a", 1), "result", "returned"]
+    if kind == "mute":
+        cycle = ["handed", "feeding", "result", ("packet", "a", 1), "returned"]
+    assert log.events[log.events.index("returned") + 1:][:len(cycle)] == cycle
+    await eng.stop()
+
+
+@pytest.mark.parametrize("kind", ["one-step", "many-steps", "bursts"])
+async def test_a_future_never_resolves_before_its_last_packets_sink_returned(kind):
+    """With a sink that sleeps: when ``submit`` returns, the packet that
+    carried the last token has left its sink; offsets are contiguous, in
+    order and exactly-once per job."""
+    from .test_speculative import cut2_drafter
+
+    log = CycleLog(sink_sleep=0.004)
+    n_new = 1 if kind == "one-step" else 14
+    eng = ServingEngine(
+        FakeBackend(num_pages=64, step_delay=0.001), run_blocking=log.run_blocking,
+        max_sessions=4, speculative=kind == "bursts", drafter=cut2_drafter)
+    prompts = {f"j{i}": [i + 1, 7, i + 2] for i in range(3)}
+    outs = await asyncio.wait_for(asyncio.gather(*[
+        log.submit(eng, job, prompt, n_new) for job, prompt in prompts.items()]), timeout=20)
+    for (job, prompt), out in zip(prompts.items(), outs):
+        assert out["tokens"] == log.streamed(job) == fake_ref(prompt, n_new)
+        assert log.packets[job][-1][1:] == (n_new, True)
+        assert [done for _, _, done in log.packets[job][:-1]] == [False] * (len(log.packets[job]) - 1)
+        last = log.events.index(("packet", job, n_new))
+        assert last < log.events.index(("resolved", job, n_new))
+    if kind == "bursts":
+        assert eng.stats.accepted_tokens > 0
+        assert any(len(t) > 1 for j in prompts for t, _, _ in log.packets[j])
+    assert eng.stats.stream_packets == sum(len(p) for p in log.packets.values())
+    await eng.stop()
+
+
+async def test_wait_quiesced_waits_for_the_frozen_sessions_pending_packet():
+    """A session frozen while its step is in the backend gets that step's
+    token; ``wait_quiesced`` returns only once the packet is out (behind a
+    step the session does not ride), and ``export_state`` then equals what
+    was streamed."""
+    log, be = CycleLog(), GatedBackend(num_pages=64)
+    eng = ServingEngine(be, run_blocking=log.run_blocking, max_sessions=4)
+    jobs = [asyncio.ensure_future(log.submit(eng, j, p, 40))
+            for j, p in (("a", [1, 2, 3]), ("b", [9, 8]))]
+    for k in range(3):
+        await until(lambda: log.events.count("handed") == k + 1)
+        be.go.release()
+        await until(lambda: log.events.count("returned") == k + 1)
+    await until(lambda: log.events.count("handed") == 4)
+    # a step with both rows is in the backend: freeze a, hold its next packet
+    n_before, b_before = len(log.packets["a"]), len(log.packets["b"])
+    gate = asyncio.Event()
+    log.hold["a"] = (n_before, gate)
+    assert eng.freeze_session("a")
+    quiesced = asyncio.ensure_future(eng.wait_quiesced("a"))
+    be.free()
+    # the next step is handed over with b alone, and b's packet goes out behind it
+    await until(lambda: len(log.packets["b"]) == b_before + 1 and len(be.seen) == 5)
+    assert log.events.count("handed") == 5 and [k for _, _, k, _ in be.seen[4]] == ["decode"]
+    await asyncio.sleep(0.02)
+    assert not quiesced.done() and len(log.packets["a"]) == n_before
+    assert len(eng.export_state("a")["out_tokens"]) == n_before + 1  # booked, untold
+    gate.set()
+    await asyncio.wait_for(quiesced, timeout=10)
+    assert eng.export_state("a")["out_tokens"] == log.streamed("a")
+    assert len(log.packets["a"]) == n_before + 1
+    eng.unfreeze_session("a")
+    for job, prompt, out in zip(("a", "b"), ([1, 2, 3], [9, 8]),
+                                await asyncio.wait_for(asyncio.gather(*jobs), timeout=20)):
+        assert out["tokens"] == log.streamed(job) == fake_ref(prompt, 40)
+    await eng.stop()
+
+
+async def test_a_failing_step_delivers_the_step_before_it_first():
+    """Step 2 raises: the tokens of steps 0 and 1 reach the sink before the
+    riders get the error."""
+    log = CycleLog(sink_sleep=0.003)
+    eng = ServingEngine(FakeBackend(num_pages=64, fail_at={2}),
+                        run_blocking=log.run_blocking, max_sessions=4)
+    with pytest.raises(RuntimeError):
+        await asyncio.wait_for(log.submit(eng, "a", [1, 2, 3], 8), timeout=10)
+    assert log.streamed("a") == fake_ref([1, 2, 3], 2)
+    assert [e for e in log.events if isinstance(e, tuple)] == [
+        ("packet", "a", 1), ("packet", "a", 2), ("error", "a", "RuntimeError")]
+    await eng.stop()
+
+
+async def test_stop_delivers_pending_packets_before_the_cancellations():
+    """``stop()`` while a step's packet is on its way through a slow sink:
+    the packet leaves the sink, then the session is cancelled."""
+    log, be = CycleLog(), FakeBackend(num_pages=64, step_delay=0.002)
+    eng = ServingEngine(be, run_blocking=log.run_blocking, max_sessions=4)
+    gate = asyncio.Event()
+    log.hold["a"] = (2, gate)
+    job = asyncio.ensure_future(log.submit(eng, "a", [1, 2, 3], 30))
+    await until(lambda: len(log.packets.get("a", [])) == 2 and eng._active["a"].unsent)
+    stopping = asyncio.ensure_future(eng.stop())
+    await asyncio.sleep(0.02)
+    assert not stopping.done() and not job.done()
+    gate.set()
+    await asyncio.wait_for(stopping, timeout=10)
+    with pytest.raises(SessionCancelled):
+        await asyncio.wait_for(job, timeout=5)
+    told = [e for e in log.events if isinstance(e, tuple)]
+    assert told[-2:] == [("packet", "a", 3), ("error", "a", "SessionCancelled")]
+    assert log.streamed("a") == fake_ref([1, 2, 3], 30)[:3]
+    assert eng.allocator.used_pages == 0
+
+
+async def test_a_finishers_pages_are_admitted_into_by_the_very_next_assemble():
+    """The pool holds one session: the second is admitted by the assemble
+    right after the first's last step, whose packet is told only behind the
+    second's first step."""
+    log, be = CycleLog(sink_sleep=0.01), FakeBackend(num_pages=3, page_size=4)
+    eng = ServingEngine(be, run_blocking=log.run_blocking, max_sessions=4,
+                        prefix_cache=False)
+    outs = await asyncio.wait_for(asyncio.gather(
+        log.submit(eng, "a", [1, 2, 3], 4), log.submit(eng, "b", [5, 6, 7], 4)), timeout=20)
+    assert [o["tokens"] for o in outs] == [fake_ref([1, 2, 3], 4), fake_ref([5, 6, 7], 4)]
+    assert eng.stats.admission_waits >= 1
+    # a: prefill + 3 decode rows = steps 0-3; b's prefill is step 4
+    assert [[(phase, start) for _, start, phase, _ in step] for step in be.seen[3:5]] == [
+        [("decode", 5)], [("prefill", 0)]]
+    handed = [i for i, e in enumerate(log.events) if e == "handed"]
+    assert handed[4] < log.events.index(("packet", "a", 4)) < log.events.index(("resolved", "a", 4))
+    await eng.stop()
+
+
 # ------------------------------------------------------- session affinity
 
 
@@ -968,11 +1238,13 @@ async def test_migrated_in_and_resumed_sessions_get_no_ttft_spans():
     assert len(eng.stats.ttft_seconds) == 1
 
 
-@pytest.mark.parametrize("kind", ["stamping", "plain"])
+@pytest.mark.parametrize("kind", ["stamping", "plain", "streaming"])
 async def test_sampled_cycle_children_are_contiguous_and_sum_to_step(kind, llama_env):
     """A kept cycle is a trace of its own: root ``step`` with six contiguous
     children that sum to it exactly.  The real backend stamps its four
-    phases; a backend that stamps nothing reads as one ``wait``."""
+    phases; a backend that stamps nothing reads as one ``wait``.  Sessions
+    that stream (their packets told behind the next step, through a sink
+    that sleeps) add no span and no phase."""
     from cordum_tpu.serving.backend import STEP_PHASES
 
     sink = await SpanSink().listen()
@@ -981,7 +1253,14 @@ async def test_sampled_cycle_children_are_contiguous_and_sum_to_step(kind, llama
     else:
         be = FakeBackend(num_pages=64, step_delay=0.004)
     eng = traced_engine(sink, be, max_sessions=4)
-    await generate(eng, 3, new=8)
+    if kind == "streaming":
+        log = CycleLog(sink_sleep=0.001)
+        await asyncio.gather(*[log.submit(eng, f"j{i}", [i + 1] * 5, 8) for i in range(3)])
+        assert eng.stats.stream_packets == 24
+        assert eng.stats.stream_packets_behind_step >= 21  # all but the last step's
+    else:
+        await generate(eng, 3, new=8)
+        assert eng.stats.stream_packets == 0
     await eng.stop()
     await sink.bus.drain()
     traces = sink.step_traces()
@@ -998,9 +1277,12 @@ async def test_sampled_cycle_children_are_contiguous_and_sum_to_step(kind, llama
             assert a.end_us == b.start_us
         assert sum(c.duration_us for c in children) == root.duration_us
         assert {"occupancy", "live_tokens", "prefill_tokens", "retired",
-                "compiled"} <= set(root.attrs)
+                "compiled", "published_behind"} <= set(root.attrs)
+        behind, told = map(int, root.attrs["published_behind"].split("/"))
+        # what a cycle tells is the step before it, behind its own
+        assert behind == told == (0 if kind != "streaming" or trace_id.endswith("-0") else 3)
         by = {c.name: c.duration_us for c in children}
-        if kind == "plain":
+        if kind != "stamping":
             assert by["step.pack"] == by["step.dispatch"] == by["step.unpack"] == 0
             # the fake's 4 ms sleep lies between the hand-over (stamped by
             # the loop, late under load) and the return
