@@ -473,10 +473,21 @@ class Metrics:
         )
         self.serving_compiles = Counter(
             "cordum_serving_compile_total",
-            "XLA programs compiled by the serving backend, by entry point "
-            "(the ragged mixed prefill+decode entry compiles exactly once "
-            "per process — a higher count is the bucket-recompile cliff "
-            "coming back)",
+            "Compile requests JAX made inside the serving backend's own "
+            "calls, by entry point, counted from JAX's backend-compile events "
+            "whether the persistent cache served them or not (ragged = the "
+            "mixed prefill+decode entry, exactly once per process: a higher "
+            "count is the bucket-recompile cliff coming back; state = the "
+            "weights and arenas; copy_page | gather_page | scatter_page)",
+        )
+        self.startup_phase = Gauge(
+            "cordum_startup_phase_seconds",
+            "Seconds of each phase of this worker process's start-up, set "
+            "once when its first step cycle that sampled a token closed "
+            "(phase = startup | startup.compute | startup.embedder | "
+            "startup.backend | startup.state | startup.weights | "
+            "startup.arenas | startup.program | startup.program.trace | "
+            ".lower | .load | startup.first_step; phases of one name summed)",
         )
         self.session_affinity = Counter(
             "cordum_session_affinity_total",
@@ -775,6 +786,7 @@ class Metrics:
             self.serving_sessions,
             self.serving_kv_pages_in_use,
             self.serving_compiles,
+            self.startup_phase,
             self.session_affinity,
             self.serving_migrations,
             self.serving_migration_pause,

@@ -20,10 +20,17 @@ one transfer in, one call, and one transfer out, started at dispatch.
 Because the shapes never change, XLA compiles exactly **one** program —
 there is no prompt-length bucket ladder, no pow2 batch buckets, and no
 recompile cliff when sessions join or leave (the Ragged Paged Attention
-argument, PAPERS.md).  ``compiled_programs()`` and the
-``cordum_serving_compile_total{entry}`` counter make that a measured
-number, and ``last_step_compiled`` lets the capacity observatory keep
-warmup compiles out of the steady-state throughput rows.
+argument, PAPERS.md).  What the compiler really did is measured, not
+guessed: the backend holds ``obs/startup.py``'s ``backend_call`` open round
+its own jitted calls, so JAX's trace, lowering and backend-compile events of
+THOSE calls (and of nobody else's in the process) become the start-up
+record's ``startup.program`` phases, ``cordum_serving_compile_total{entry}``
+(one count a compile request) and the step's report: ``last_step_compiled``
+(a compile request fell in this step's cycle: the ``step`` span's
+``compiled``, ``compile_ms``, ``cache_hit``, and what lets the capacity
+observatory keep warm-up compiles out of the steady-state throughput rows).
+``compiled_programs()`` is only a count of the shape keys ``step`` has been
+called with (one, by construction).
 
 :meth:`step` is **blocking** (called from the worker's executor threads)
 and serializes page-arena mutations under one lock: the functional
@@ -43,6 +50,8 @@ from types import MappingProxyType
 from typing import Any, Callable, Iterator, Mapping, Optional
 
 import numpy as np
+
+from ..obs import startup
 
 DEFAULT_MAX_SEQS = 16
 
@@ -135,9 +144,16 @@ class StepBackend:
     page_bytes: int = 0
     # the latest step's report, written by ``step`` and read by the engine
     # after the call
-    REPORT = ("last_step_compiled", "last_phases", "last_attn_blocks",
-              "last_window_blocks", "last_attn_rows", "last_attn_live", "last_counters")
-    last_step_compiled: bool = False  # did it pay XLA?
+    REPORT = ("last_step_compiled", "last_compile_ms", "last_cache_hit", "last_phases",
+              "last_attn_blocks", "last_window_blocks", "last_attn_rows", "last_attn_live",
+              "last_counters")
+    # did the compiler run for this backend since the step before returned
+    # (in this step's dispatch, or in a page program the cycle called first:
+    # a first copy-on-write)?  For how long, and did the persistent cache
+    # serve every request of it?  From JAX's own events, not a guess
+    last_step_compiled: bool = False
+    last_compile_ms: float = 0.0
+    last_cache_hit: bool = False
     # its boundaries, ns: (entry, arrays packed, program dispatched, result
     # on the host, return) — the engine splits its step cycle by them
     last_phases: tuple[int, ...] = ()
@@ -327,7 +343,9 @@ class ServingBackend(StepBackend):
         self._arenas: Optional[list] = None
         self._row_kind = slice(0, len(self.spec.arenas[0]))  # the whole-row kind's
         self._ragged_jit: Any = None
-        self._compiled_shapes: set = set()  # observability: program count
+        self._compiled_shapes: set = set()  # the count behind compiled_programs()
+        # compile requests since the last step's report: count, ns, cache hits
+        self._paid = [0, 0, 0]
         self._metrics = metrics
         # ``last_attn_blocks`` / ``last_window_blocks`` / ``last_attn_rows``:
         # ``llama.paged_attention`` cuts the rows into tiles and walks each
@@ -394,10 +412,16 @@ class ServingBackend(StepBackend):
             return
         import jax
 
-        self._params, *arenas = self._make_state(
-            self._params_provider() if self._params_provider is not None else None
-        )
-        self._arenas = list(arenas)
+        with startup.backend_call() as events, startup.phase("startup.state") as state:
+            with startup.phase("startup.weights"):
+                given = self._params_provider() if self._params_provider is not None else None
+                params = jax.block_until_ready(self._make_params(given))
+            with startup.phase("startup.arenas") as made:
+                self._arenas = list(jax.block_until_ready(self._make_arenas()))
+                made["bytes"] = sum(a.nbytes for a in self._arenas)
+            self._params = params
+            state.update(events.counts())
+        self._note_compiles("state", events)
         self.page_bytes = sum(a.nbytes // a.shape[1] for a in self._arenas[self._row_kind])
         # donate the page arenas on real accelerators so the in-place
         # update never copies the arena; CPU jax spams donation warnings
@@ -406,19 +430,48 @@ class ServingBackend(StepBackend):
             donate=jax.default_backend() != "cpu",
         )
 
-    def _make_state(self, params: Any):
-        """``(params, *arenas)``: the weights (``params``, or seeded random
-        ones when None) and the zeroed page arenas, on the default device.
-        ShardedServingBackend overrides it to create them already laid out
-        over the TP mesh."""
+    def _make_params(self, params: Any) -> Any:
+        """The weights on the default device: ``params``, or seeded random
+        ones when None.  ShardedServingBackend overrides this and
+        ``_make_arenas`` to create both already laid out over the TP mesh."""
         import jax
 
         if params is None:
             params = self.spec.init_params(jax.random.PRNGKey(self._seed))
-        return (params, *self.spec.init_arenas(
+        return params
+
+    def _make_arenas(self) -> tuple:
+        """The zeroed page arenas, in the program's argument order."""
+        return tuple(self.spec.init_arenas(
             self.num_pages, self.page_size, self.num_window_pages))
 
+    def _note_compiles(self, entry: str, events: "startup.ProgramEvents") -> None:
+        """Book the compile requests of one backend call: the counter (one a
+        request, whatever the cache did) and the next step's report."""
+        n = events.compiles
+        if not n:
+            return
+        if self._metrics is not None:
+            self._metrics.serving_compiles.inc(float(n), entry=entry)
+        self._paid[0] += n
+        self._paid[1] += events.compile_ns
+        self._paid[2] += events.hits
+
+    @contextlib.contextmanager
+    def _page_program(self, entry: str) -> Iterator[None]:
+        """One of the page programs' calls, under the device lock; what it
+        made the compiler do (its first use) is booked like a step's."""
+        t0 = time.time_ns()
+        with self._dev_lock:
+            with startup.backend_call() as events:
+                yield
+            if events.spans:
+                self._note_compiles(entry, events)
+                startup.program(entry, t0, events)
+
     def compiled_programs(self) -> int:
+        """Shape keys ``step`` has been called with (what the compiler did
+        is the ``step`` span's ``compiled``)."""
         return len(self._compiled_shapes)
 
     def _clamp(self, row: list[int]) -> list[int]:
@@ -485,12 +538,7 @@ class ServingBackend(StepBackend):
                 out_idx[i] = ti + n - 1
                 spans.append((ti, ti + n))
                 ti += n
-            shape_key = ("ragged", t_buf, s_rows, self.pages_per_seq)
-            self.last_step_compiled = shape_key not in self._compiled_shapes
-            if self.last_step_compiled:
-                self._compiled_shapes.add(shape_key)
-                if self._metrics is not None:
-                    self._metrics.serving_compiles.inc(entry="ragged")
+            self._compiled_shapes.add(("ragged", t_buf, s_rows, self.pages_per_seq))
         with contextlib.ExitStack() as held:
             # dispatch opens before the lock is taken, so a wait for it
             # shows there; the lock is held until the result is on the host
@@ -499,13 +547,22 @@ class ServingBackend(StepBackend):
                 # numpy straight into the call: its one transfer rides the
                 # call's own path; the result's copy back starts now, so
                 # ``wait`` finds it on the host when the program ends
-                nxt, *self._arenas = self._ragged_jit(
-                    self._params, *self._arenas, feed)
+                with startup.backend_call() as events:
+                    nxt, *self._arenas = self._ragged_jit(
+                        self._params, *self._arenas, feed)
                 nxt.copy_to_host_async()
             if self.on_dispatched is not None:
                 self.on_dispatched()
             with step_phase("wait", n_step, marks):
                 out = np.asarray(nxt)
+            if events.spans:  # the call traced or compiled: never in a warm window
+                self._note_compiles("ragged", events)
+                startup.program("ragged", marks[1], events, ran_until_ns=marks[3])
+            n, ns, hits = self._paid
+            self.last_step_compiled = n > 0
+            if n:
+                self.last_compile_ms, self.last_cache_hit = ns / 1e6, hits >= n
+                self._paid = [0, 0, 0]
         # out is [T] per-position predictions: a sampled entry's token is
         # the prediction after its LAST fed slot (== out_idx[i], the same
         # value the old sequence-final projection produced); a draft row
@@ -591,7 +648,7 @@ class ServingBackend(StepBackend):
         # invalidates the arena buffers it was handed, so the gather must
         # not overlap a step's jit call (page CONTENT below end_tok is
         # stable either way — steps only write at the current positions)
-        with self._dev_lock:
+        with self._page_program("gather_page"):
             blocks = llama.gather_kv_pages(
                 self._k_pages, self._v_pages, [pages[o] for o in ords], used
             )
@@ -629,7 +686,7 @@ class ServingBackend(StepBackend):
             v = np.frombuffer(rec["v"], np.float32).reshape(shape)
             ids.append(pages[o])
             blocks.append((k, v))
-        with self._dev_lock:
+        with self._page_program("scatter_page"):
             self._k_pages, self._v_pages = llama.scatter_kv_pages(
                 self._k_pages, self._v_pages, ids, blocks
             )
@@ -647,7 +704,7 @@ class ServingBackend(StepBackend):
         self._ensure()
         from ..models import llama
 
-        with self._dev_lock:
+        with self._page_program("copy_page"):
             self._arenas[self._row_kind] = llama.copy_page(
                 self._arenas[self._row_kind], src, dst)
 

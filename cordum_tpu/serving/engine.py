@@ -37,6 +37,7 @@ from typing import Any, Awaitable, Callable, Optional
 
 from ..infra import logging as logx
 from ..infra.metrics import Metrics
+from ..obs import startup
 from ..obs.tracer import Tracer
 from ..protocol.types import OP_SERVING_PREFILL, SPAN_ERROR, Span
 from ..utils.eager import eager, eager_gather
@@ -418,6 +419,7 @@ class ServingEngine:
         self._spans: list[Span] = []
         self._cycle_ns: deque[int] = deque(maxlen=STEP_MEDIAN_WINDOW)
         self._kept_cycle_ns = 0  # start of the last cycle kept as a trace
+        self._startup_told = False  # the start-up record went out (or was not ours)
 
     # ------------------------------------------------------------------
     def parts(self, payload: Any) -> Optional[GenRequest]:
@@ -874,9 +876,11 @@ class ServingEngine:
                 self.metrics.serving_step_phase.observe((b - a) / 1e9, phase=name)
         dur = c1 - c0
         recent = self._cycle_ns
-        keep = c0 - self._kept_cycle_ns >= STEP_SAMPLE_PERIOD_NS or (
-            len(recent) == recent.maxlen
-            and dur > STEP_STALL_FACTOR * statistics.median(recent)
+        keep = (
+            c0 - self._kept_cycle_ns >= STEP_SAMPLE_PERIOD_NS
+            or "compile_ms" in attrs  # the compiler ran: never in steady state
+            or (len(recent) == recent.maxlen
+                and dur > STEP_STALL_FACTOR * statistics.median(recent))
         )
         recent.append(dur)
         if not keep:
@@ -899,6 +903,34 @@ class ServingEngine:
             "step", trace_id=trace_id, span_id=root_id,
             start_us=us[0], end_us=us[-1], attrs=attrs,
         ))
+
+    def _tell_startup(self, end_ns: int) -> None:
+        """The first cycle that returned a sampled token has closed at
+        ``end_ns``: close the process's start-up record (``obs/startup.py``)
+        and hand it on once: the seconds a phase name on the gauge, and the
+        phases as trace ``startup-<worker_id>``, children first and the root
+        last, to go out with the next flush, behind a step."""
+        self._startup_told = True
+        record = startup.close(end_ns, worker_id=self.worker_id)
+        if not record:
+            return
+        if self.metrics is not None:
+            seconds: dict[str, float] = {}
+            for ph in record:
+                seconds[ph.name] = seconds.get(ph.name, 0.0) + ph.seconds
+            for name, secs in seconds.items():
+                self.metrics.startup_phase.set(secs, phase=name)
+        tr = self.tracer
+        if tr is None or not tr.listening():
+            return
+        ids = {ph.id: fast_id() for ph in record}
+        for ph in record:
+            self._spans.append(tr.record(
+                ph.name, trace_id=f"startup-{self.worker_id}", span_id=ids[ph.id],
+                parent_span_id=ids.get(ph.parent, ""),
+                start_us=ph.start_ns // 1000, end_us=ph.end_ns // 1000,
+                attrs={k: str(v) for k, v in ph.attrs.items()},
+            ))
 
     async def _flush_spans(self) -> None:
         """Publish what was recorded since the last flush.  Called only
@@ -1308,6 +1340,8 @@ class ServingEngine:
                 attrs = self._scatter(rows, results, dt)
                 self._gauge()
             self._cycle_closed(n_step, marks, attrs)
+            if not self._startup_told and any(r is not None for r in results):
+                self._tell_startup(marks[-1])
 
     async def _run_step(
         self, entries: list[StepEntry]
@@ -1514,6 +1548,9 @@ class ServingEngine:
             "retired": str(retired_this_step),
             "compiled": str(self.backend.last_step_compiled).lower(),
         }
+        if self.backend.last_step_compiled:
+            attrs["compile_ms"] = f"{self.backend.last_compile_ms:.3f}"
+            attrs["cache_hit"] = str(self.backend.last_cache_hit).lower()
         if of:
             attrs["kv_blocks"] = f"{walked}/{of}"
             attrs["kv_block_tokens"] = str(self.backend.attn_block_tokens)

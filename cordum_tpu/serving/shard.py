@@ -192,9 +192,9 @@ class ShardedServingBackend(LlamaServingBackend):
         self.sample_output = (self.rank == 0) if sample_output is None else bool(sample_output)
         self.mesh: Any = None
 
-    def _make_state(self, params: Any):
-        """Weights and arenas laid out over the TP mesh.  Seeded weights and
-        the zeroed arenas are CREATED sharded (``jit`` with
+    def _make_params(self, params: Any) -> Any:
+        """The weights laid out over the TP mesh.  Seeded weights (and the
+        zeroed arenas, below) are CREATED sharded (``jit`` with
         ``out_shardings``): building them on one device first would put the
         whole model there, which at real widths no single chip holds."""
         import jax
@@ -204,23 +204,28 @@ class ShardedServingBackend(LlamaServingBackend):
 
         self.mesh = mesh = rank_mesh(self.tp)
         cfg = self.cfg
-        if params is None:
-            shardings = jax.tree.map(
-                lambda s: NamedSharding(mesh, s), llama.param_specs(cfg))
+        if params is not None:
+            return llama.shard_params(params, cfg, mesh)
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(mesh, s), llama.param_specs(cfg))
 
-            def sharded_init(key):
-                return llama.init_params(key, cfg)
+        def sharded_init(key):
+            return llama.init_params(key, cfg)
 
-            params = jax.jit(sharded_init, out_shardings=shardings)(
-                jax.random.PRNGKey(self._seed))
-        else:
-            params = llama.shard_params(params, cfg, mesh)
-        arena = NamedSharding(mesh, llama.KV_ARENA_SPEC)
-        k_pages, v_pages = jax.jit(
-            lambda: llama.init_kv_pages(cfg, self.num_pages, self.page_size),
+        return jax.jit(sharded_init, out_shardings=shardings)(
+            jax.random.PRNGKey(self._seed))
+
+    def _make_arenas(self) -> tuple:
+        import jax
+        from jax.sharding import NamedSharding
+
+        from ..models import llama
+
+        arena = NamedSharding(self.mesh, llama.KV_ARENA_SPEC)
+        return jax.jit(
+            lambda: llama.init_kv_pages(self.cfg, self.num_pages, self.page_size),
             out_shardings=(arena, arena),
         )()
-        return params, k_pages, v_pages
 
     def export_kv(self, pages: list[int], start_tok: int, end_tok: int) -> list[dict]:
         """This rank's head slice of every page record.  A gang's full
@@ -290,7 +295,11 @@ class ServingGangGroup(StepBackend):
         self._forward(self.REPORT)
         # any rank paying XLA makes the step a warmup step for the
         # capacity observatory's steady-state filter
-        self.last_step_compiled = any(r.last_step_compiled for r in self.ranks)
+        paid = [r for r in self.ranks if r.last_step_compiled]
+        self.last_step_compiled = bool(paid)
+        if paid:
+            self.last_compile_ms = sum(r.last_compile_ms for r in paid)
+            self.last_cache_hit = all(r.last_cache_hit for r in paid)
         if self.on_step is not None:
             self.on_step(entries)
         self.stamp_whole_call(t0)

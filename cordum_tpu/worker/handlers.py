@@ -24,6 +24,7 @@ from typing import Any, Optional
 import numpy as np
 
 from ..infra import logging as logx
+from ..obs import startup
 from .runtime import JobContext, Worker
 
 
@@ -76,16 +77,19 @@ class TPUCompute:
     """
 
     def __init__(self, *, tp: int = 1, embedder_cfg=None, llama_cfg=None, seed: int = 0):
-        import jax
+        with startup.phase("startup.compute"):
+            import jax
 
-        from ..models.embedder import Embedder, EmbedderConfig
-        from ..models import llama as llama_mod
-        from ..parallel.mesh import simple_mesh
+            from ..models.embedder import Embedder, EmbedderConfig
+            from ..models import llama as llama_mod
+            from ..parallel.mesh import simple_mesh
 
-        self.jax = jax
-        n_dev = len(jax.devices())
-        self.mesh = simple_mesh(min(tp, n_dev) if n_dev % min(tp, n_dev) == 0 else 1)
-        self.embedder = Embedder(embedder_cfg or EmbedderConfig(), seed=seed, mesh=self.mesh)
+            self.jax = jax
+            n_dev = len(jax.devices())
+            self.mesh = simple_mesh(min(tp, n_dev) if n_dev % min(tp, n_dev) == 0 else 1)
+            with startup.phase("startup.embedder"):
+                self.embedder = Embedder(
+                    embedder_cfg or EmbedderConfig(), seed=seed, mesh=self.mesh)
         self.llama_cfg = llama_cfg or llama_mod.LlamaConfig.tiny()
         self._llama_params = None
         self._llama_fwd = None
@@ -444,33 +448,34 @@ def make_serving_engine(
         compute._ensure_llama()
         return compute._llama_params
 
-    backend = ServingBackend(
-        model if model is not None else compute.llama_cfg,
-        num_pages=cache_pages,
-        page_size=page_size,
-        max_seqs=max_sessions,
-        max_batch_tokens=max_sessions + max(1, prefill_budget),
-        params=params,
-        # another model's weights are never the compute's llama ones
-        params_provider=params_provider if model is None else None,
-        metrics=metrics,
-    )
-    engine = ServingEngine(
-        backend,
-        run_blocking=worker.run_in_executor,
-        max_sessions=max_sessions,
-        max_new_tokens_cap=max_new_tokens,
-        max_concurrent_prefills=max_concurrent_prefills,
-        handoff_threshold_tokens=handoff_tokens,
-        prefix_cache=prefix_cache,
-        hibernate_after_s=hibernate_after_s,
-        speculative=speculative,
-        # draft_k == 0 means "engine default" so config files can omit it
-        **({"draft_k": draft_k} if draft_k > 0 else {}),
-        metrics=metrics,
-        tracer=worker.tracer,
-        capacity=worker.capacity,
-    )
+    with startup.phase("startup.backend"):
+        backend = ServingBackend(
+            model if model is not None else compute.llama_cfg,
+            num_pages=cache_pages,
+            page_size=page_size,
+            max_seqs=max_sessions,
+            max_batch_tokens=max_sessions + max(1, prefill_budget),
+            params=params,
+            # another model's weights are never the compute's llama ones
+            params_provider=params_provider if model is None else None,
+            metrics=metrics,
+        )
+        engine = ServingEngine(
+            backend,
+            run_blocking=worker.run_in_executor,
+            max_sessions=max_sessions,
+            max_new_tokens_cap=max_new_tokens,
+            max_concurrent_prefills=max_concurrent_prefills,
+            handoff_threshold_tokens=handoff_tokens,
+            prefix_cache=prefix_cache,
+            hibernate_after_s=hibernate_after_s,
+            speculative=speculative,
+            # draft_k == 0 means "engine default" so config files can omit it
+            **({"draft_k": draft_k} if draft_k > 0 else {}),
+            metrics=metrics,
+            tracer=worker.tracer,
+            capacity=worker.capacity,
+        )
     if cold_tier == "statebus" and engine.tiering is not None:
         # journal hibernated sessions through the statebus KV so they
         # survive a restart; cmd.worker awaits arena.load() post-start

@@ -53,6 +53,16 @@ def _syncsan_zero_reports():
             "\n".join(str(r) for r in reps)
 
 
+@pytest.fixture(autouse=True)
+def _fresh_startup_record():
+    """The start-up record is the process's (``obs/startup.py``): a test
+    starts with an empty, open one, whatever the tests before it stamped."""
+    from cordum_tpu.obs import startup
+
+    startup.reset()
+    yield
+
+
 @pytest.fixture
 def kv():
     from cordum_tpu.infra.kv import MemoryKV
