@@ -564,7 +564,8 @@ def paged_attention(
       from ``positions``;
     * **a group's walk ends at its longest tile** (:func:`walk_blocks`) — a
       traced trip count on static shapes: one program, and blocks past it
-      are never read.
+      are never read (the latent form's kernel ends each TILE at its own:
+      below).
 
     A masked key scores ``-1e30``, not ``-inf``.  A slot whose first walked
     blocks hold none of its visible keys (under a window the TILE starts the
@@ -597,7 +598,25 @@ def paged_attention(
     columns of its key, so a trip's ONE gather feeds both products and the
     accumulators are ``v_dim`` wide; returns [T, h, v_dim].  ``scale`` is the
     softmax scale where it is not ``1 / sqrt(hd)``.  With a V arena and no
-    scale the program is the one it was."""
+    scale the program is the one it was.
+
+    **Two walks behind the latent form** (``models/latent_walk.py``): where
+    the program is LOWERED for the TPU, a group's block walk is one Pallas
+    kernel — a tile's queries, the scores, the mask and the float32 softmax
+    state live in VMEM for the tile's whole walk, a block's pages are copied
+    page by page from the arena (which stays in HBM) into a double-buffered
+    VMEM block, and **each tile ends at its OWN newest block**
+    (``latent_walk.tile_trips``), where the ``jax.numpy`` walk drags every
+    tile of a group to the longest.  Everything round it is shared: the
+    tiles, their order, the groups, the loop over the groups that hold a live
+    tile, the scatter back to buffer slots, the block as the counted unit.
+    ``jax.lax.platform_dependent`` chooses, by the arena's form (``v_pages is
+    None``, no window) and the lowering platform alone: every other platform
+    (the CPU's tests and float32 references) and every other form (K and V
+    by head, the window's ring) keeps the ``jax.numpy`` walk below, the
+    latter byte for byte the program it was.  Same numerics in both:
+    operands in the arena's dtype, float32 scores and state, probabilities
+    cast to the arena's dtype, a masked key ``-1e30``."""
     t, h, hd = q.shape
     ps = k_pages.shape[2]
     kvh = k_pages.shape[3] if k_pages.ndim == 5 else 1
@@ -637,13 +656,18 @@ def paged_attention(
         ring = tab.shape[1]
         lane = jnp.arange(bp, dtype=itype)
     offs = jnp.arange(bt, dtype=itype)
+    latent = v_pages is None and window is None
+    if latent:  # imported here: Pallas costs a second that the other forms' programs never pay
+        from . import latent_walk
     # each tile's queries [tiles, kvh, slots x rep, hd] and their positions
     qt = q.reshape(t, kvh, rep, hd)[slots].transpose(0, 2, 1, 3, 4).reshape(
         n_tiles, kvh, w * rep, hd)
-    pt = jnp.repeat(positions[slots], rep, axis=1)[:, :, None]  # [tiles, slots x rep, 1]
+    pslot = positions[slots]  # [tiles, slots]
+    pt = jnp.repeat(pslot, rep, axis=1)[:, :, None]  # [tiles, slots x rep, 1]
 
-    def group(i, out):
-        lo = i * g
+    def walk_jnp(lo, out):
+        """One group's walk as ``jax.numpy``, every tile to the group's
+        longest, its outputs written behind ``out``'s slot ``lo * w``."""
         qc, pc, tab_c = (jax.lax.dynamic_slice_in_dim(x, lo, g) for x in (qt, pt, tab))
         first, trips = walk_blocks(jax.lax.dynamic_slice_in_dim(oldest, lo, g),
                                    jax.lax.dynamic_slice_in_dim(newest, lo, g), bt, window)
@@ -685,6 +709,25 @@ def paged_attention(
         done = (acc / l[..., None]).astype(q.dtype).reshape(g, kvh, w, rep, vd)
         return jax.lax.dynamic_update_slice_in_dim(
             out, done.transpose(0, 2, 1, 3, 4).reshape(g * w, h, vd), lo * w, axis=0)
+
+    def walk_kernel(lo, out):
+        """The same group through ``latent_walk``'s kernel: every tile to
+        its OWN end, its queries read from and its outputs written into the
+        step's whole arrays in place (one key head: a tile's rows are its
+        slots x heads as ``out`` has them)."""
+        tab_c, new_c, live_c, pslot_c = (
+            jax.lax.dynamic_slice_in_dim(x, lo, g) for x in (tab, newest, live, pslot))
+        return latent_walk.walk_group(
+            qt[:, 0], pslot_c, k_pages, layer, tab_c, latent_walk.tile_trips(new_c, live_c, bt),
+            out.reshape(n_tiles, w * h, vd), lo, block_pages=bp, v_dim=vd, scale=scale,
+        ).reshape(out.shape)
+
+    def group(i, out):
+        lo = i * g
+        if latent:  # two walks, chosen where the program is lowered
+            return jax.lax.platform_dependent(
+                lo, out, default=walk_jnp, **{latent_walk.PLATFORM: walk_kernel})
+        return walk_jnp(lo, out)
 
     # the groups that hold a live tile (they come first), each to its own end
     walked = (jnp.sum(live, dtype=itype) + (g - 1)) // g

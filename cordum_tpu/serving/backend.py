@@ -142,6 +142,11 @@ class StepBackend:
     kv_by_head: bool = True
     # bytes one page of the whole-row kind holds, all its arenas and layers
     page_bytes: int = 0
+    # which walk the step program holds: the kernel's name where its
+    # attention walks the pages with one ("latent_walk": a latent arena in a
+    # program lowered for the TPU, ``models/latent_walk.py``), "" where the
+    # walk is ``jax.numpy``'s.  Known once the state is on its device
+    walk_kernel: str = ""
     # the latest step's report, written by ``step`` and read by the engine
     # after the call
     REPORT = ("last_step_compiled", "last_compile_ms", "last_cache_hit", "last_phases",
@@ -420,6 +425,18 @@ class ServingBackend(StepBackend):
                 self._arenas = list(jax.block_until_ready(self._make_arenas()))
                 made["bytes"] = sum(a.nbytes for a in self._arenas)
             self._params = params
+            # the walk the program will hold is chosen where it is lowered:
+            # for the platform the arenas live on, by the arena's form
+            if len(self.spec.arenas[0]) == 1 and self.window is None:  # one latent array a layer
+                # the kernel's module imports Pallas, a second or more that
+                # only this form pays: stamped, so that the record says so
+                with startup.phase("startup.walk_kernel") as walk:
+                    from ..models import latent_walk
+
+                    platform = next(iter(self._arenas[0].devices())).platform
+                    if latent_walk.holds_kernel(platform, latent=True):
+                        self.walk_kernel = latent_walk.KERNEL_NAME
+                    walk["walk_kernel"] = self.walk_kernel or "none"
             state.update(events.counts())
         self._note_compiles("state", events)
         self.page_bytes = sum(a.nbytes // a.shape[1] for a in self._arenas[self._row_kind])
@@ -452,7 +469,9 @@ class ServingBackend(StepBackend):
         if not n:
             return
         if self._metrics is not None:
-            self._metrics.serving_compiles.inc(float(n), entry=entry)
+            # every series of a backend says which walk its step program holds
+            self._metrics.serving_compiles.inc(
+                float(n), entry=entry, walk_kernel=self.walk_kernel or "none")
         self._paid[0] += n
         self._paid[1] += events.compile_ns
         self._paid[2] += events.hits
@@ -593,7 +612,9 @@ class ServingBackend(StepBackend):
         the host from ``spans`` (int [rows, 2]: each fed row's buffer slots)
         and the packed ``positions``: the rows cut into tiles, the tiles in
         the program's order, each group of them walked by the program's rule
-        (``llama.walk_blocks``)."""
+        (``llama.walk_blocks``: every tile to its group's longest) — or,
+        where the program holds the walk's kernel (``walk_kernel``), each
+        tile to its OWN end (``latent_walk.tile_trips``)."""
         from ..models import llama
 
         w, g = self._tile_slots, llama.ATTN_GROUP_TILES
@@ -608,12 +629,20 @@ class ServingBackend(StepBackend):
             # a fed slot needs the blocks from its oldest visible key's to its own
             first = 0 if window is None else (fed - (window - 1)).clip(0) // bt
             live += int((fed // bt - first + 1).sum())
-            longest = 0
-            for a in range(0, len(order), g):
-                trips = int(llama.walk_blocks(oldest[a:a + g], newest[a:a + g], bt, window)[1])
-                longest = max(longest, trips)
-                rows += g * trips
-                slots += g * w * trips
+            if self.walk_kernel:  # a program with it has no window kind
+                from ..models import latent_walk
+
+                own = latent_walk.tile_trips(newest, np.ones(len(newest), bool), bt)
+                longest = int(own.max())
+                rows += int(own.sum())
+                slots += w * int(own.sum())
+            else:
+                longest = 0
+                for a in range(0, len(order), g):
+                    trips = int(llama.walk_blocks(oldest[a:a + g], newest[a:a + g], bt, window)[1])
+                    longest = max(longest, trips)
+                    rows += g * trips
+                    slots += g * w * trips
             if window is None:
                 self.last_attn_blocks = (longest, self._attn_blocks_total)
             else:

@@ -1557,6 +1557,7 @@ class ServingEngine:
             attrs["kv_rows"] = str(kv_rows)
             attrs["q_rows"] = str(q_rows)
             attrs["q_live"] = str(self.backend.last_attn_live)
+            attrs["walk_kernel"] = self.backend.walk_kernel or "none"
         if self.ring_pages:
             attrs["window_blocks"] = str(self._count_window(rows, pos_before))
         counters = self.backend.last_counters

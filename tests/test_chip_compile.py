@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import chip_smoke
-from cordum_tpu.models import embedder, llama
+from cordum_tpu.models import embedder, latent_walk, llama
 from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
 from cordum_tpu.worker.handlers import make_matmul_program
 
@@ -70,6 +70,13 @@ def device_bytes(compiled) -> int:
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes
             + ma.generated_code_size_in_bytes)
+
+
+def holds_walk_kernel(text: str) -> bool:
+    """Whether a program's text (lowered or compiled) calls the latent
+    walk's kernel: a TPU custom call under the kernel's name."""
+    return any("tpu_custom_call" in line and latent_walk.KERNEL_NAME in line
+               for line in text.splitlines())
 
 
 def smoke_cfg(**kw):
@@ -166,9 +173,42 @@ def test_the_latent_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
     assert ma.alias_size_in_bytes >= arena_bytes
     assert ma.temp_size_in_bytes < 0.5e9  # no copy of the 4 GB arena among the temporaries
     assert 12.0e9 < device_bytes(compiled) <= 0.85 * HBM_BYTES
+    assert holds_walk_kernel(compiled.as_text())  # the walk is the kernel, with the arena in place
     pid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     copied = llama._copy_page_in_place.lower(arena, pid, pid).compile().memory_analysis()
     assert copied.alias_size_in_bytes >= arena_bytes and copied.temp_size_in_bytes < 0.1e9
+
+
+@pytest.mark.parametrize("config", ["mistral-7b-v0.3", "internlm2-1.8b",
+                                    "trinity-large-preview-ep8"])
+def test_a_by_head_program_holds_no_walk_kernel(config):
+    """K and V by head, and the window's rings, keep the ``jax.numpy`` walk
+    whatever the platform: the benchmark's three by-head programs, LOWERED
+    for the TPU at their cells' shapes (nothing compiled), call no kernel of
+    ``latent_walk``; the latent program lowered the same way does, and
+    lowered for the CPU does not."""
+    from benchmarks.harness import cells
+    from cordum_tpu.serving.backend import ServingBackend
+
+    def lowered(name, platform):
+        doc = dict(cells.load_config(name))
+        fam = __import__(f"benchmarks.families.{doc['family']}", fromlist=["x"])
+        cfg, pool = fam.program_config(doc), doc["pool"]
+        be = ServingBackend(cfg, num_pages=pool["pages"], page_size=pool["page_size"],
+                            max_seqs=pool["max_sessions"],
+                            max_batch_tokens=pool["max_sessions"] + pool["prefill_budget"])
+        params = jax.eval_shape(be.spec.init_params, jax.random.PRNGKey(0))
+        arenas = jax.eval_shape(lambda: tuple(be.spec.init_arenas(
+            be.num_pages, be.page_size, be.num_window_pages)))
+        feed = jax.ShapeDtypeStruct((be.feed_layout.size,), jnp.int32)
+        program = make_ragged_program(be.spec, be.feed_layout, sample_logits=True, donate=False)
+        return program.trace(params, *arenas, feed).lower(
+            lowering_platforms=(platform,)).as_text()
+
+    assert not holds_walk_kernel(lowered(config, "tpu"))
+    if config == "trinity-large-preview-ep8":  # once: the rule's other side
+        assert holds_walk_kernel(lowered("a.x-k1-ep16", "tpu"))
+        assert not holds_walk_kernel(lowered("a.x-k1-ep16", "cpu"))
 
 
 def test_the_shortcut_connected_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
@@ -205,6 +245,7 @@ def test_the_shortcut_connected_step_fits_one_chip_and_keeps_its_arena_in_place(
     assert ma.alias_size_in_bytes >= arena_bytes
     assert ma.temp_size_in_bytes < 0.5e9  # no copy of the 2.7 GB arena among the temporaries
     assert 13.0e9 < device_bytes(compiled) <= 0.9 * HBM_BYTES
+    assert holds_walk_kernel(compiled.as_text())  # one kernel for the eight sublayers' walks
     print(f"longcat step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
           f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
 
