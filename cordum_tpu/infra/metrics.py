@@ -471,6 +471,11 @@ class Metrics:
             "cordum_serving_kv_pages_in_use",
             "KV cache pages currently allocated to sessions",
         )
+        self.serving_state_slots = Gauge(
+            "cordum_serving_state_slots",
+            "State slots sessions hold now (a model with recurrent state: "
+            "one slot a session beside its pages, taken at admission)",
+        )
         self.serving_compiles = Counter(
             "cordum_serving_compile_total",
             "Compile requests JAX made inside the serving backend's own "
@@ -785,6 +790,7 @@ class Metrics:
             self.serving_stream_packets,
             self.serving_sessions,
             self.serving_kv_pages_in_use,
+            self.serving_state_slots,
             self.serving_compiles,
             self.startup_phase,
             self.session_affinity,

@@ -278,6 +278,8 @@ def mla_sublayer(
     *,
     q_scale: float = 1.0,
     kv_scale: float = 1.0,
+    direct_q: bool = False,
+    head_gate: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """One latent-attention sublayer in absorbed form over row ``row`` of the
     latent arena: ``a`` [T, d] (normed, in the weights' dtype) -> ``(the
@@ -288,13 +290,20 @@ def mla_sublayer(
     family's way (YaRN here, plain in ``models/longcat.py``); ``q_scale``
     multiplies the query behind ``wqb`` and ``kv_scale`` the normed kv latent
     before it is cached and expanded (LongCat's rank scalings; 1 is no
-    operation at all).  Every token's ``(c | kr)`` is written at ``(page_idx,
+    operation at all).  ``direct_q``: the queries come from ONE matrix
+    ``wq`` [d, h x (nope + rope)], no query latent (``q_lora_rank`` null:
+    ``models/bailing.py``); ``head_gate``: each head's output is multiplied by
+    ``sigmoid(a wg)`` (``wg`` [d, h]) before ``wo``.  Without them the program
+    is the one it was.  Every token's ``(c | kr)`` is written at ``(page_idx,
     slot)`` before the walk, so a chunk's later tokens see its earlier ones."""
     t_buf = a.shape[0]
     h, nope, rd, vd, rank = cfg.n_heads, cfg.nope_dim, cfg.rope_dim, cfg.v_dim, cfg.kv_rank
     with jax.named_scope("mla_q_proj"):
-        cq = rms_norm(a @ layer["wqa"], layer["q_norm"], cfg.norm_eps)
-        q = (cq @ layer["wqb"]).reshape(t_buf, h, nope + rd)
+        if direct_q:
+            q = (a @ layer["wq"]).reshape(t_buf, h, nope + rd)
+        else:
+            cq = rms_norm(a @ layer["wqa"], layer["q_norm"], cfg.norm_eps)
+            q = (cq @ layer["wqb"]).reshape(t_buf, h, nope + rd)
         if q_scale != 1.0:
             q = q * q_scale
         q_rope = rope_fn(q[..., nope:], rows.positions)
@@ -316,6 +325,9 @@ def mla_sublayer(
         rows.token_seq, rows.positions, rows.block_pages, v_dim=rank, scale=cfg.softmax_scale)
     with jax.named_scope("mla_absorb_out"):
         o = jnp.einsum("thc,chv->thv", ol, wkvb[..., nope:])  # [T, h, vd]
+    if head_gate:
+        with jax.named_scope("attn_gate"):
+            o = o * jax.nn.sigmoid(a @ layer["wg"])[..., None]
     return o.reshape(t_buf, h * vd) @ layer["wo"], c_pages
 
 

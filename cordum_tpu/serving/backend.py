@@ -108,6 +108,8 @@ class StepEntry:
     draft: int = 0  # >0: speculative row with this many drafted tokens
     # the session's ring of window-layer pages (a model with window layers)
     window_pages: list[int] = field(default_factory=list)
+    # the session's state slot (a model with recurrent state); 0 is the null slot
+    state_slot: int = 0
 
 
 class StepBackend:
@@ -142,6 +144,14 @@ class StepBackend:
     kv_by_head: bool = True
     # bytes one page of the whole-row kind holds, all its arenas and layers
     page_bytes: int = 0
+    # everything the model keeps of a row lies at a position, in a page.
+    # False for a model with recurrent state (``ModelSpec.init_state``): a
+    # session then also holds one of ``state_slots`` slots (slot 0 is the
+    # null slot of padding rows) of ``state_bytes`` each, all state layers;
+    # what shares, re-feeds or carries positions refuses such a model
+    kv_positional: bool = True
+    state_slots: int = 0
+    state_bytes: int = 0
     # which walk the step program holds: the kernel's name where its
     # attention walks the pages with one ("latent_walk": a latent arena in a
     # program lowered for the TPU, ``models/latent_walk.py``), "" where the
@@ -225,10 +235,18 @@ class FeedLayout:
     tokens: int  # T: slots of the flat token buffer
     seqs: int  # S: sequence rows (a table has one more, the padding row)
     table_widths: tuple[int, ...]  # pages a row, per kind of page
+    # a model with recurrent state: one int32 a table row behind the tables,
+    # the row's state slot (``S + 1``; 0 where the model keeps no state)
+    state_rows: int = 0
 
     @property
     def size(self) -> int:
-        return 3 * self.tokens + self.seqs + (self.seqs + 1) * sum(self.table_widths)
+        return (3 * self.tokens + self.seqs + (self.seqs + 1) * sum(self.table_widths)
+                + self.state_rows)
+
+    def state_slot(self, feed: Any) -> Any:
+        """The rows' state slots ``[S + 1]`` of ``feed``: a view, as :meth:`split`'s."""
+        return feed[self.size - self.state_rows:self.size]
 
     def split(self, feed: Any) -> tuple[Any, Any, Any, Any, list]:
         """``(tokens, positions, token_seq, out_idx, tables)`` of ``feed``
@@ -264,11 +282,13 @@ def make_ragged_program(
     def ragged_program(params, *arenas_feed):
         *arenas, feed = arenas_feed
         tokens, positions, token_seq, out_idx, tables = layout.split(feed)
+        if layout.state_rows:  # the state arrays ride behind the page arenas
+            tables = [*tables, layout.state_slot(feed)]
         return family(params, *arenas, tokens, positions, *tables, token_seq, out_idx)
 
     return jax.jit(
         ragged_program,
-        donate_argnums=tuple(range(1, 1 + spec.n_arenas)) if donate else (),
+        donate_argnums=tuple(range(1, 1 + spec.n_arenas + spec.n_state)) if donate else (),
     )
 
 
@@ -322,6 +342,10 @@ class ServingBackend(StepBackend):
         self.window = self.spec.window
         self.kv_whole_row = self.spec.kv_whole_row
         self.kv_by_head = self.spec.kv_by_head
+        # a model with recurrent state: a slot a sequence row beside the null
+        # slot, so a free row always finds one (as a window's ring)
+        self.kv_positional = self.spec.kv_positional
+        self.state_slots = 0 if self.kv_positional else self.max_seqs + 1
         self.ring_pages = (
             llama.window_ring_pages(self.window, self.page_size, self.max_batch_tokens)
             if self.window else 0
@@ -334,6 +358,7 @@ class ServingBackend(StepBackend):
             self.max_batch_tokens, self.max_seqs,
             (self.pages_per_seq, self.ring_pages) if self.window
             else (self.pages_per_seq,),
+            state_rows=self.max_seqs + 1 if self.state_slots else 0,
         )
         self._seed = seed
         # the weights: ``params`` as given, else what ``params_provider``
@@ -344,7 +369,8 @@ class ServingBackend(StepBackend):
         self._params: Any = None
         # the program's arenas in its argument order, a kind after the other
         # (``spec.arenas``): the whole-row kind's (K and V by head, or one
-        # latent array), then the window kind's where there is one
+        # latent array), then the window kind's where there is one, then the
+        # state arrays (``spec.init_state``), which have no page axis
         self._arenas: Optional[list] = None
         self._row_kind = slice(0, len(self.spec.arenas[0]))  # the whole-row kind's
         self._ragged_jit: Any = None
@@ -402,7 +428,7 @@ class ServingBackend(StepBackend):
 
     def _set_arena(self, i: int, value: Any) -> None:
         if self._arenas is None:
-            self._arenas = [None] * self.spec.n_arenas
+            self._arenas = [None] * (self.spec.n_arenas + self.spec.n_state)
         self._arenas[i] = value
 
     def release_arenas(self) -> None:
@@ -410,7 +436,7 @@ class ServingBackend(StepBackend):
         done serving and wants the device memory back.  A later step would
         find no arena; build a new backend instead."""
         with self._dev_lock:
-            self._arenas = [None] * self.spec.n_arenas
+            self._arenas = [None] * (self.spec.n_arenas + self.spec.n_state)
 
     def _ensure(self) -> None:
         if self._params is not None:
@@ -424,6 +450,10 @@ class ServingBackend(StepBackend):
             with startup.phase("startup.arenas") as made:
                 self._arenas = list(jax.block_until_ready(self._make_arenas()))
                 made["bytes"] = sum(a.nbytes for a in self._arenas)
+                if self.state_slots:
+                    made["state_bytes"] = sum(
+                        a.nbytes for a in self._arenas[self.spec.n_arenas:])
+                    self.state_bytes = made["state_bytes"] // self.state_slots
             self._params = params
             # the walk the program will hold is chosen where it is lowered:
             # for the platform the arenas live on, by the arena's form
@@ -458,9 +488,13 @@ class ServingBackend(StepBackend):
         return params
 
     def _make_arenas(self) -> tuple:
-        """The zeroed page arenas, in the program's argument order."""
-        return tuple(self.spec.init_arenas(
+        """The zeroed page arenas, in the program's argument order, and the
+        zeroed state arrays behind them where the model keeps state."""
+        pages = tuple(self.spec.init_arenas(
             self.num_pages, self.page_size, self.num_window_pages))
+        if not self.state_slots:
+            return pages
+        return pages + tuple(self.spec.init_state(self.state_slots))
 
     def _note_compiles(self, entry: str, events: "startup.ProgramEvents") -> None:
         """Book the compile requests of one backend call: the counter (one a
@@ -529,6 +563,7 @@ class ServingBackend(StepBackend):
             # it when the call returns), packed through its views
             feed = np.zeros((self.feed_layout.size,), np.int32)
             tokens, positions, token_seq, out_idx, tables = self.feed_layout.split(feed)
+            state_slot = self.feed_layout.state_slot(feed)  # empty without state
             # padding tokens map to the padding row (all null pages): their
             # writes land on page 0 and no live sequence's gather can see them
             token_seq[:] = s_rows
@@ -554,6 +589,12 @@ class ServingBackend(StepBackend):
                             "StepEntry.window_pages is empty: this model's window "
                             "layers keep their K and V in a ring of their own")
                     tables[1][i, : len(e.window_pages)] = e.window_pages
+                if self.state_slots:
+                    if not 0 < e.state_slot < self.state_slots:
+                        raise ValueError(
+                            f"StepEntry.state_slot {e.state_slot}: this model keeps a row's "
+                            f"recurrent state in one of the slots 1..{self.state_slots - 1}")
+                    state_slot[i] = e.state_slot
                 out_idx[i] = ti + n - 1
                 spans.append((ti, ti + n))
                 ti += n

@@ -43,8 +43,8 @@ from ..protocol.types import OP_SERVING_PREFILL, SPAN_ERROR, Span
 from ..utils.eager import eager, eager_gather
 from ..utils.ids import fast_id
 from .backend import STEP_PHASES, StepBackend, StepEntry, step_phase
-from .modelspec import require_page_records
-from .pager import CacheExhausted, PageAllocator
+from .modelspec import require_page_records, require_positional
+from .pager import CacheExhausted, PageAllocator, SlotAllocator
 from .prefixcache import PrefixCache, PrefixNode
 from .tiering import SessionTiering
 
@@ -188,6 +188,13 @@ class ServingStats:
     moe_zero_assignments: int = 0
     moe_real_picks_max: int = 0
     moe_real_picks_min: int = 0
+    # a model with recurrent state (docs/SERVING.md §The state slot): the
+    # most state slots sessions held at once; rows that advanced their state
+    # by ONE token, summed over steps (decode rows: a state read and written
+    # for one token), and tokens fed by rows of more than one (prefill chunks)
+    state_slots_peak: int = 0
+    kda_decode_rows: int = 0
+    kda_chunk_tokens: int = 0
     # stream packets the step loop handed to the sinks (a session's new
     # tokens of one step; replays of a carried prefix are not among them),
     # and those of them published with the NEXT step already on the device
@@ -231,6 +238,8 @@ class _Session:
     pages: list[int] = field(default_factory=list)
     # the session's ring of window-layer pages (a model with window layers)
     window_pages: list[int] = field(default_factory=list)
+    # the session's state slot (a model with recurrent state); 0: none
+    state_slot: int = 0
     pos: int = 0  # sequence positions cached so far
     prefill_pos: int = 0  # prompt tokens fed so far (== pos until prefilled)
     last_token: int = 0
@@ -299,9 +308,9 @@ class ServingEngine:
         capacity: Optional[Any] = None,
         handoff_threshold_tokens: int = 0,
         migrate_in_cooldown_s: float = 30.0,
-        prefix_cache: bool = True,
+        prefix_cache: Optional[bool] = None,
         hibernate_after_s: float = 0.0,
-        speculative: bool = False,
+        speculative: Optional[bool] = False,
         draft_k: int = DEFAULT_DRAFT_K,
         drafter: Optional[Callable[[list[int], int], list[int]]] = None,
     ) -> None:
@@ -336,7 +345,9 @@ class ServingEngine:
         # per step; every admitted session must at least fit a decode row
         self.step_tokens = backend.max_batch_tokens
         self.max_sessions = min(
-            self.max_sessions, backend.max_seqs, self.step_tokens
+            self.max_sessions, backend.max_seqs, self.step_tokens,
+            # a session of a model with recurrent state holds a slot of its own
+            *([backend.state_slots - 1] if backend.state_slots else []),
         )
         self.allocator = PageAllocator(backend.num_pages, backend.page_size)
         # a model with window layers keeps a second kind of page, in a ring
@@ -354,7 +365,34 @@ class ServingEngine:
         # live migration — is off for a model whose page is another thing (a
         # latent page); prefix sharing is not: copying a page needs no record
         self.kv_by_head = backend.kv_by_head
-        self.kv_portable = self.kv_whole_row and self.kv_by_head
+        # what shares, re-feeds or carries POSITIONS — the prefix cache,
+        # speculation's verify rows, hibernation, live migration — refuses a
+        # model that also keeps recurrent state: a session of it holds a
+        # state slot beside its pages, from admission to retirement, never
+        # shared and never copied (docs/SERVING.md §The state slot).  Asked
+        # for by name (``prefix_cache=True``, ``speculative=True``) they are
+        # refused; None is "on where the model allows" (the worker's defaults),
+        # resolved here and nowhere else
+        self.kv_positional = backend.kv_positional
+        self.state_allocator: Optional[SlotAllocator] = (
+            SlotAllocator(backend.state_slots) if backend.state_slots else None
+        )
+        if not self.kv_positional:
+            if prefix_cache:
+                require_positional(False, "the prefix cache")
+            if speculative:
+                require_positional(False, "speculative decoding (its verify rows re-feed positions)")
+            if hibernate_after_s > 0:
+                require_positional(False, "hibernation")
+            if prefix_cache is None or speculative is None:
+                logx.info("the prefix cache and the drafter stay off where they were left to the "
+                          "default: this model keeps recurrent state in per-session slots "
+                          "(kv_positional is false)")
+        if prefix_cache is None:
+            prefix_cache = self.kv_positional
+        if speculative is None:
+            speculative = self.kv_positional
+        self.kv_portable = self.kv_whole_row and self.kv_by_head and self.kv_positional
         # prefix cache + session tiering (docs/SERVING.md §Prefix cache and
         # tiering): the radix index over cached full-page prefixes, and the
         # hibernate/restore machinery that tiers idle resident state to the
@@ -614,6 +652,15 @@ class ServingEngine:
         if self.metrics is not None:
             self.metrics.serving_sessions.set(float(len(self._active)))
             self.metrics.serving_kv_pages_in_use.set(float(self.allocator.used_pages))
+            if self.state_allocator is not None:
+                self.metrics.serving_state_slots.set(float(self.state_allocator.used))
+
+    def _require_records(self, feature: str) -> None:
+        """Refuse ``feature``, which carries a session as K and V page
+        records, for a model with recurrent state, window rings or latent
+        pages — each by the capability it lacks."""
+        require_positional(self.kv_positional, feature)
+        require_page_records(self.kv_whole_row, self.kv_by_head, feature)
 
     def _ring_for(self, n_tokens: int) -> int:
         """Window-layer pages a session of ``n_tokens`` positions holds: its
@@ -689,6 +736,11 @@ class ServingEngine:
             except CacheExhausted:
                 self.stats.admission_waits += 1
                 break  # head-of-line waits for a retirement to free pages
+            if self.state_allocator is not None:
+                # cannot run out: a slot a session row, and ``max_sessions``
+                # is at most the slots (the window kind's argument)
+                sess.state_slot = self.state_allocator.alloc(sess.job_id)
+                self.stats.state_slots_peak = self.state_allocator.peak_in_use
             self._pending.popleft()
             sess.pages = pages
             if hit_tokens > 0:
@@ -1005,6 +1057,8 @@ class ServingEngine:
         self.allocator.free(sess.job_id)
         if self.window_allocator is not None:
             self.window_allocator.free(sess.job_id)
+        if self.state_allocator is not None:
+            self.state_allocator.free(sess.job_id)
         self._active.pop(sess.job_id, None)
         if error is None:
             self.stats.retired += 1
@@ -1205,7 +1259,7 @@ class ServingEngine:
                 tokens=[sess.last_token, *plan], start=sess.pos,
                 pages=sess.pages, sample=True, phase="decode",
                 key=sess.job_id, draft=len(plan),
-                window_pages=sess.window_pages,
+                window_pages=sess.window_pages, state_slot=sess.state_slot,
             ))
             rows.append((sess, 1 + len(plan), True, plan))
             budget -= 1 + len(plan)
@@ -1240,6 +1294,7 @@ class ServingEngine:
                 start=sess.prefill_pos, pages=sess.pages,
                 sample=samples, phase="prefill",
                 key=sess.job_id, window_pages=sess.window_pages,
+                state_slot=sess.state_slot,
             ))
             rows.append((sess, chunk, samples, []))
             budget -= chunk
@@ -1560,6 +1615,15 @@ class ServingEngine:
             attrs["walk_kernel"] = self.backend.walk_kernel or "none"
         if self.ring_pages:
             attrs["window_blocks"] = str(self._count_window(rows, pos_before))
+        if self.state_allocator is not None:
+            # every fed row advanced its state slot: by one token (a state
+            # read and written for it) or by a chunk
+            single = sum(1 for _, chunk, _, _ in rows if chunk == 1)
+            fed = sum(chunk for _, chunk, _, _ in rows)
+            self.stats.kda_decode_rows += single
+            self.stats.kda_chunk_tokens += fed - single
+            attrs["state_rows"] = str(len(rows))
+            attrs["kda_tokens"] = str(fed)
         counters = self.backend.last_counters
         if counters:
             # what the model family's program counted this step, named by
@@ -1682,7 +1746,7 @@ class ServingEngine:
     ) -> list[dict]:
         """Page records covering positions ``[start_tok, end_tok)`` at
         their true lengths."""
-        require_page_records(self.kv_whole_row, self.kv_by_head, "page export (migration, hibernation)")
+        self._require_records("page export (migration, hibernation)")
         sess = self._active.get(job_id)
         if sess is None:
             return []
@@ -1737,7 +1801,7 @@ class ServingEngine:
         :meth:`restore_hibernated` later owns the token stream and the
         terminal result.  False when the session is not live here (or
         tiering is disabled)."""
-        require_page_records(self.kv_whole_row, self.kv_by_head, "hibernation")
+        self._require_records("hibernation")
         if self.tiering is None:
             return False
         meta = self.describe_session(job_id)
@@ -1841,7 +1905,7 @@ class ServingEngine:
         future (token list).  ``origin="hibernate"`` (the
         :meth:`restore_hibernated` path) books the adoption under the
         hibernate counters instead of the migration ones."""
-        require_page_records(self.kv_whole_row, self.kv_by_head, "adopting a migrated or hibernated session")
+        self._require_records("adopting a migrated or hibernated session")
         if self._closed:
             raise RuntimeError("serving engine is stopped")
         if job_id in self._active or any(

@@ -19,6 +19,17 @@ searched for, where ``feed`` is the step's ONE packed int32 vector
 (``backend.FeedLayout``: ``[tokens T | positions T | token_seq T | out_idx
 S | a table per kind]``), taken apart inside the jit.  The signature a
 family writes is the one above, unchanged.
+
+A family with **recurrent state** (``ModelSpec.init_state``: linear-attention
+layers, ``models/kda.py``) keeps, beside its pages, arrays with NO page axis
+and no position: ``[state layers, slots, ...]``, addressed by a session's
+**state slot**, which outlives a step.  Its program takes them behind the
+page arenas and one more int32 operand, ``state_slot`` ``[S+1]`` (a table
+row's slot; 0, the null slot, for the padding row and every unused row),
+between the tables and ``token_seq``: ``(params, *arenas, *state, tokens,
+positions, *tables, state_slot, token_seq, out_idx) -> (out, *arenas,
+*state)``.  A row whose first fed position is 0 starts from a zero state,
+whatever its slot held: the program sees that from ``positions``.
 """
 from __future__ import annotations
 
@@ -50,6 +61,18 @@ def require_page_records(whole_row: bool, by_head: bool, feature: str) -> None:
             f"{feature} carries a page as K and V records by head; this model's "
             "page is another thing (ModelSpec.arenas: a latent page has no heads "
             "and no V)")
+
+
+def require_positional(positional: bool, feature: str) -> None:
+    """Refuse ``feature``, which shares, copies, carries or re-feeds
+    POSITIONS of a row, for a model that also keeps recurrent state: a state
+    has no page to share or ship and cannot be un-advanced."""
+    if not positional:
+        raise UnsupportedForModel(
+            f"{feature} needs a cache that is positions in pages and nothing else "
+            "(kv_positional); this model keeps recurrent state in per-session slots "
+            "(ModelSpec.init_state), which has no page to share or carry and "
+            "cannot be rolled back")
 
 
 def kv_pair(n_kv_heads: int, head_dim: int) -> tuple[tuple[int, ...], ...]:
@@ -92,6 +115,17 @@ class ModelSpec:
     #: (a key's leading columns are its value).  With ``arenas`` it is what
     #: the backend hands ``llama.attn_block_pages``, as the program does
     value_dim: int = 0
+    #: ``(slots) -> state arrays``, each ``[state layers, slots, ...]``, in
+    #: the program's argument order behind the page arenas; None for a model
+    #: whose every cached number lies at a position.  A capability
+    #: (``kv_positional``): a state is advanced in place by every step that
+    #: feeds its row, so what shares a prefix's pages, re-feeds positions
+    #: (speculation's verify rows), or carries a session as page records
+    #: (hibernation, migration, the gang) refuses the family
+    #: (:func:`require_positional`)
+    init_state: Optional[Callable[[int], tuple]] = None
+    #: state arrays the program takes and returns behind the page arenas
+    n_state: int = 0
     #: shape of the int32 counters behind the tokens in ``out``
     aux_shape: tuple[int, ...] = ()
     #: ``(aux, live_tokens) -> {ServingStats field: this step's addend}``:
@@ -112,10 +146,17 @@ class ModelSpec:
         """Every kind of page is a K, V pair of ``[kvh, hd]`` slots."""
         return all(len(kind) == 2 and all(len(a) == 2 for a in kind) for kind in self.arenas)
 
+    @property
+    def kv_positional(self) -> bool:
+        """Everything the model keeps of a row lies at a position, in a page."""
+        return self.init_state is None
+
     def require_whole_row(self, feature: str) -> None:
+        require_positional(self.kv_positional, feature)
         require_whole_row(self.kv_whole_row, feature)
 
     def require_page_records(self, feature: str) -> None:
+        require_positional(self.kv_positional, feature)
         require_page_records(self.kv_whole_row, self.kv_by_head, feature)
 
 

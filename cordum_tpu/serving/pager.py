@@ -265,3 +265,57 @@ class PageAllocator:
                     raise PageAccountingError(
                         f"table {owner!r} maps unreferenced page {p}"
                     )
+
+
+class SlotAllocator:
+    """Which **state slot** belongs to which session, for a model that keeps
+    recurrent state beside its pages (docs/SERVING.md §The state slot): one
+    slot a session, taken at admission and given back at retirement.  Slot 0
+    is the null slot (padding rows).  A slot is never shared and never
+    copied: a state is advanced in place by every step that feeds its row,
+    so there is no refcount to keep.  A freed slot goes back dirty: the
+    program starts a row whose first fed position is 0 from zero, whatever
+    its slot held.  Event-loop-confined, like :class:`PageAllocator`."""
+
+    NULL_SLOT = 0
+
+    def __init__(self, num_slots: int) -> None:
+        if num_slots < 2:
+            raise ValueError("need at least 2 state slots (slot 0 is the null slot)")
+        self.num_slots = num_slots
+        self._free: deque[int] = deque(range(1, num_slots))
+        self._owned: dict[str, int] = {}
+        self.peak_in_use = 0
+
+    @property
+    def capacity(self) -> int:
+        return self.num_slots - 1
+
+    @property
+    def used(self) -> int:
+        return len(self._owned)
+
+    def alloc(self, owner: str) -> int:
+        if owner in self._owned:
+            raise PageAccountingError(f"{owner!r} already holds state slot {self._owned[owner]}")
+        if not self._free:
+            raise CacheExhausted(f"no free state slot of {self.capacity}")
+        slot = self._free.popleft()
+        self._owned[owner] = slot
+        self.peak_in_use = max(self.peak_in_use, len(self._owned))
+        return slot
+
+    def free(self, owner: str) -> int:
+        """Give ``owner``'s slot back; 0 when it held none (as a session that
+        was never admitted)."""
+        slot = self._owned.pop(owner, self.NULL_SLOT)
+        if slot:
+            self._free.append(slot)
+        return slot
+
+    def check_consistency(self) -> None:
+        held = sorted(self._owned.values())
+        if len(set(held)) != len(held) or set(held) & set(self._free) or self.NULL_SLOT in held:
+            raise PageAccountingError(f"state slots held {held}, free {sorted(self._free)}")
+        if len(held) + len(self._free) != self.capacity:
+            raise PageAccountingError("a state slot is neither held nor free")

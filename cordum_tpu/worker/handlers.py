@@ -420,9 +420,9 @@ def make_serving_engine(
     max_concurrent_prefills: int = 2,
     prefill_budget: int = 16,
     handoff_tokens: int = 0,
-    prefix_cache: bool = True,
+    prefix_cache: Optional[bool] = None,
     hibernate_after_s: float = 0.0,
-    speculative: bool = True,
+    speculative: Optional[bool] = None,
     draft_k: int = 0,
     cold_tier: str = "",
     model=None,
@@ -440,6 +440,12 @@ def make_serving_engine(
     sequence rows over a flat token buffer of ``max_sessions +
     prefill_budget`` slots, so a full decode set always fits and prefill
     chunks ride the remaining ``prefill_budget`` tokens per step.
+
+    ``prefix_cache`` and ``speculative`` default to None, "on where the model
+    allows", and are handed to the engine as given: it turns them off, with
+    one log line that names the capability, for a model that keeps recurrent
+    state in per-session slots (``kv_positional`` false), and refuses them
+    for such a model when asked for by name (``UnsupportedForModel``).
     """
     from ..serving.backend import ServingBackend
     from ..serving.engine import ServingEngine
@@ -501,9 +507,9 @@ def attach_default_tpu_worker(
     serving_max_new_tokens: int = 64,
     serving_prefill_budget: int = 16,
     serving_handoff_tokens: int = 0,
-    serving_prefix_cache: bool = True,
+    serving_prefix_cache: Optional[bool] = None,
     serving_hibernate_after_s: float = 0.0,
-    serving_speculative: bool = True,
+    serving_speculative: Optional[bool] = None,
     serving_draft_k: int = 0,
     serving_cold_tier: str = "",
     serving_model=None,

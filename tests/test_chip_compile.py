@@ -250,6 +250,50 @@ def test_the_shortcut_connected_step_fits_one_chip_and_keeps_its_arena_in_place(
           f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
 
 
+def test_the_hybrid_step_fits_one_chip_and_keeps_state_and_pages_in_place(one_chip):
+    """The bailing cell's ragged program at the benchmark's sizes (``benchmarks/
+    configs/ling-3.0-flash-ep4.json``: published widths, six KDA layers and one
+    latent layer, 128 of 512 experts held, 64 sessions): 10.34 GB of weights,
+    the 2.01 GB latent arena and 0.85 GB of state slots fit, donation is real
+    for all three cache arrays (none copied, none laid out anew), and the
+    program lowered for the TPU holds both kernels: ``latent_walk`` for the
+    one latent layer, ``kda_step`` once a KDA layer (PERF.md section 4)."""
+    from benchmarks.families import bailing as fam
+    from benchmarks.harness import cells
+    from cordum_tpu.models import kda
+
+    doc = dict(cells.load_config("ling-3.0-flash-ep4"))
+    cfg, pool = fam.program_config(doc), doc["pool"]
+
+    def leaf(path, shape):
+        dtype = jnp.float32 if path[-1].key in fam.FLOAT32 else jnp.bfloat16
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        leaf, fam.param_shapes(doc), is_leaf=lambda x: isinstance(x, tuple))
+    seqs = pool["max_sessions"]
+    spec = cfg.serving_spec()
+    caches = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in jax.eval_shape(
+        lambda: spec.init_arenas(pool["pages"], pool["page_size"], 0) + spec.init_state(seqs + 1))]
+    assert [c.shape for c in caches] == [(1, 98304, 16, 640), (6, 65, 128, 32, 128),
+                                         (6, 65, 3, 12288)]
+    assert caches[1].dtype == jnp.float32
+    layout = FeedLayout(seqs + pool["prefill_budget"], seqs,
+                        (cfg.max_seq_len // pool["page_size"],), state_rows=seqs + 1)
+    feed = jax.ShapeDtypeStruct((layout.size,), jnp.int32, sharding=one_chip)
+    program = make_ragged_program(cfg, layout, sample_logits=True, donate=True)
+    compiled = program.lower(params, *caches, feed).compile()
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in caches)
+    assert ma.alias_size_in_bytes >= cache_bytes > 2.8e9
+    assert ma.temp_size_in_bytes < 0.5e9  # no copy of the state or the arena among the temporaries
+    assert 12.5e9 < device_bytes(compiled) <= 0.9 * HBM_BYTES
+    text = compiled.as_text()
+    assert holds_walk_kernel(text) and kda.KERNEL_NAME in text
+    print(f"bailing step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
+
+
 def test_reference_forward_fits_beside_the_serving_state(one_chip):
     cfg = smoke_cfg()
     params, arena, _, _ = serving_shapes(

@@ -1,0 +1,63 @@
+"""The step programs of the four families the benchmark had before ISSUE 40
+trace to the jaxpr text they had then: a state slot beside the page tables, a
+direct query matrix and a head-wise gate in ``axk1.mla_sublayer`` are
+additions that a program which does not ask for them never sees.  The hashes
+are of the text at tiny sizes on the tree of PR 39 (addresses of function
+objects cut out); a change that means to alter one of these programs updates
+its hash, and says so."""
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from cordum_tpu.models import afmoe, axk1, bailing, llama, longcat
+from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
+from cordum_tpu.serving.modelspec import spec_for
+
+PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
+
+#: sha256 of the jaxpr text, PR 39's tree
+AS_IT_WAS = {
+    "llama": "b6f5236ed6c4e3250c080f30f92c7370102c8a4209ac229fc24fc5988ec38828",
+    "afmoe": "65d7ac5045e83067d596366898c2d8a2be4eb4584c4a4520987b6f886c17979c",
+    "axk1": "bfbb03817b5398ab2289d7895474e584ec30ac247fc1e55fef6de93eca4f213f",
+    "longcat": "7201c2a5de9ec275884f0f3933c34f6e46c33b9c844233c35f85770a2d686b1a",
+}
+CONFIGS = {"llama": llama.LlamaConfig.tiny, "afmoe": afmoe.AfmoeConfig, "axk1": axk1.Axk1Config,
+           "longcat": longcat.LongcatConfig, "bailing": bailing.BailingConfig}
+
+
+def text_of(cfg) -> str:
+    spec = spec_for(cfg)
+    ring = llama.window_ring_pages(spec.window, PS, TOKENS) if spec.window else 0
+    widths = (CONTEXT // PS, ring) if spec.window else (CONTEXT // PS,)
+    layout = FeedLayout(TOKENS, SEQS, widths, state_rows=0 if spec.kv_positional else SEQS + 1)
+    program = make_ragged_program(spec, layout, sample_logits=True, donate=False)
+    params = jax.eval_shape(lambda: spec.init_params(jax.random.PRNGKey(0)))
+    arenas = jax.eval_shape(lambda: tuple(spec.init_arenas(PAGES, PS, SEQS * ring + 1)) + (
+        () if spec.kv_positional else tuple(spec.init_state(SEQS + 1))))
+    feed = jax.ShapeDtypeStruct((layout.size,), jnp.int32)
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(program)(params, *arenas, feed)))
+
+
+@pytest.mark.parametrize("family", sorted(AS_IT_WAS))
+def test_the_program_traces_to_the_text_it_had(family):
+    text = text_of(CONFIGS[family]())
+    assert hashlib.sha256(text.encode()).hexdigest() == AS_IT_WAS[family]
+    assert "kda_step" not in text and "state_slot" not in text
+
+
+def test_the_new_familys_program_holds_what_the_others_lack():
+    """One trace holds both forms of the recurrence (the choice is made where
+    the program is lowered) and the latent walk's, the state's float32 arrays
+    among its operands and results, and a feed one int32 a table row longer."""
+    text = text_of(bailing.BailingConfig())
+    assert "kda_step" in text and "latent_walk" in text and "platform_index" in text
+    cfg = bailing.BailingConfig()
+    state = f"f32[{len(cfg.kda_layers)},{SEQS + 1},{cfg.kda_dk},{cfg.n_heads},{cfg.kda_dv}]"
+    assert text.count(state) >= 2
+    with_state = FeedLayout(TOKENS, SEQS, (CONTEXT // PS,), state_rows=SEQS + 1)
+    assert with_state.size == FeedLayout(TOKENS, SEQS, (CONTEXT // PS,)).size + SEQS + 1
+    assert f"i32[{with_state.size}]" in text

@@ -697,7 +697,12 @@ async def test_wait_quiesced_waits_for_the_frozen_sessions_pending_packet():
         await until(lambda: log.events.count("handed") == k + 1)
         be.go.release()
         await until(lambda: log.events.count("returned") == k + 1)
-    await until(lambda: log.events.count("handed") == 4)
+    # the fourth step is handed over, and the third's packets are out: they go behind the
+    # hand-over only once the executor thread has said the step was fed, which a busy machine
+    # delays past the "handed" mark (the packet counts below would then be one short, and
+    # the hold would catch the third step's packet with the fourth never awaited)
+    await until(lambda: log.events.count("handed") == 4
+                and not eng._untold("a") and not eng._untold("b"))
     # a step with both rows is in the backend: freeze a, hold its next packet
     n_before, b_before = len(log.packets["a"]), len(log.packets["b"])
     gate = asyncio.Event()
