@@ -18,8 +18,10 @@ seam, §Two kinds of page, §The expert layer):
     picks ``top_k`` by score plus a selection-only bias, and this chip
     computes the part of the weighted sum that ITS experts
     (``first_expert .. first_expert + experts_held``) give, as grouped
-    products (``jax.lax.ragged_dot``) over the assignments sorted by
-    expert.  No capacity, so no token is ever dropped; what absent experts
+    products over the assignments sorted by expert (:func:`grouped_products`:
+    one Pallas kernel over the touched experts where the program is lowered
+    for the TPU, ``models/expert_mlp.py``; ``jax.lax.ragged_dot`` on every
+    other platform).  No capacity, so no token is ever dropped; what absent experts
     would add is left out (their chips add it in a deployment), the weights
     still normalised over all ``top_k`` selected.  The shared expert is
     whole on every chip.  Other families' routers are the same code under
@@ -220,6 +222,33 @@ def route(m: jax.Array, layer: Params, cfg: Any) -> tuple[jax.Array, jax.Array]:
     return sel, w * cfg.route_scale
 
 
+def ragged_products(xs: jax.Array, e_gate: jax.Array, e_up: jax.Array, e_down: jax.Array,
+                    counts: jax.Array) -> jax.Array:
+    """The grouped products as three ``jax.lax.ragged_dot`` calls: ``xs`` [R,
+    d] sorted by held expert, ``counts`` [held] rows a group -> float32 [R,
+    d], ``(silu(x Wg) * (x Wu)) Wd`` under each row's expert."""
+    gate = jax.lax.ragged_dot(xs, e_gate, counts)
+    up = jax.lax.ragged_dot(xs, e_up, counts)
+    return jax.lax.ragged_dot(jax.nn.silu(gate) * up, e_down, counts,
+                              preferred_element_type=jnp.float32)
+
+
+def grouped_products(xs: jax.Array, e_gate: jax.Array, e_up: jax.Array, e_down: jax.Array,
+                     counts: jax.Array) -> jax.Array:
+    """The same products by the form the lowering platform holds: one Pallas
+    kernel that reads only the touched experts (``models/expert_mlp.py``)
+    where the program is lowered for the TPU and a block of these experts
+    fits its VMEM, :func:`ragged_products` everywhere else.  The platform
+    and the operands' shapes decide, nothing else."""
+    from . import expert_mlp  # imports Pallas: only a program with an expert layer pays
+
+    if not expert_mlp.fits(*e_gate.shape[1:], e_gate.dtype.itemsize):
+        return ragged_products(xs, e_gate, e_up, e_down, counts)
+    return jax.lax.platform_dependent(
+        xs, e_gate, e_up, e_down, counts,
+        default=ragged_products, **{expert_mlp.PLATFORM: expert_mlp.expert_mlp})
+
+
 def expert_layer(
     m: jax.Array, layer: Params, cfg: Any, live: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
@@ -228,9 +257,9 @@ def expert_layer(
     real experts, float32 [T, d]; and the assignments each held expert got,
     int32 [experts_held].  Slots with ``live`` false (buffer padding) route
     nowhere.  Dropless: every assignment to a held expert is computed,
-    whatever the imbalance — the grouped products run over all ``T * top_k``
-    assignment rows, sorted by expert, with the rows of experts held
-    elsewhere (and of identity experts) behind the last group.
+    whatever the imbalance — the grouped products (:func:`grouped_products`)
+    take all ``T * top_k`` assignment rows, sorted by expert, with the rows
+    of experts held elsewhere (and of identity experts) behind the last group.
 
     With ``cfg.n_identity`` identity experts in the router a pick of one adds
     ``w x m`` (``m`` as it came, float32 in the step) and costs neither a row
@@ -253,10 +282,7 @@ def expert_layer(
         counts = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
         xs = m[order // k]  # [T * k, d], grouped by held expert
     with jax.named_scope("moe_experts"):
-        gate = jax.lax.ragged_dot(xs, layer["e_gate"], counts)
-        up = jax.lax.ragged_dot(xs, layer["e_up"], counts)
-        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, layer["e_down"], counts,
-                                preferred_element_type=jnp.float32)
+        ys = grouped_products(xs, layer["e_gate"], layer["e_up"], layer["e_down"], counts)
     if cfg.n_shared:
         with jax.named_scope("moe_shared"):
             shared = jnp.matmul(
@@ -426,4 +452,5 @@ def serving_spec(cfg: AfmoeConfig) -> Any:
 
 
 __all__ = ["AfmoeConfig", "IDENTITY_COUNTS", "check_routing", "init_params", "init_arenas", "route",
-           "expert_layer", "ragged_step", "serving_spec", "step_counters", "SLIDING", "FULL"]
+           "expert_layer", "grouped_products", "ragged_products", "ragged_step", "serving_spec",
+           "step_counters", "SLIDING", "FULL"]

@@ -157,6 +157,11 @@ class StepBackend:
     # program lowered for the TPU, ``models/latent_walk.py``), "" where the
     # walk is ``jax.numpy``'s.  Known once the state is on its device
     walk_kernel: str = ""
+    # which form the expert layer's grouped products take: the kernel's name
+    # ("expert_mlp": a model with an expert layer whose program is lowered
+    # for the TPU, ``models/expert_mlp.py``), "" where they are
+    # ``jax.lax.ragged_dot``'s or the model has no expert layer
+    expert_kernel: str = ""
     # the latest step's report, written by ``step`` and read by the engine
     # after the call
     REPORT = ("last_step_compiled", "last_compile_ms", "last_cache_hit", "last_phases",
@@ -467,6 +472,17 @@ class ServingBackend(StepBackend):
                     if latent_walk.holds_kernel(platform, latent=True):
                         self.walk_kernel = latent_walk.KERNEL_NAME
                     walk["walk_kernel"] = self.walk_kernel or "none"
+            if getattr(self.cfg, "experts_held", 0):  # the model has an expert layer
+                # as above: the products' form is the lowering platform's, by
+                # the experts' shapes; the module's import is Pallas'
+                with startup.phase("startup.expert_kernel") as made:
+                    from ..models import expert_mlp
+
+                    platform = next(iter(self._arenas[0].devices())).platform
+                    if expert_mlp.holds_kernel(platform, self.cfg.d_model, self.cfg.d_expert,
+                                               np.dtype(self.cfg.dtype).itemsize):
+                        self.expert_kernel = expert_mlp.KERNEL_NAME
+                    made["expert_kernel"] = self.expert_kernel or "none"
             state.update(events.counts())
         self._note_compiles("state", events)
         self.page_bytes = sum(a.nbytes // a.shape[1] for a in self._arenas[self._row_kind])
@@ -503,9 +519,11 @@ class ServingBackend(StepBackend):
         if not n:
             return
         if self._metrics is not None:
-            # every series of a backend says which walk its step program holds
+            # every series of a backend says which walk and which form of
+            # the grouped expert products its step program holds
             self._metrics.serving_compiles.inc(
-                float(n), entry=entry, walk_kernel=self.walk_kernel or "none")
+                float(n), entry=entry, walk_kernel=self.walk_kernel or "none",
+                expert_kernel=self.expert_kernel or "none")
         self._paid[0] += n
         self._paid[1] += events.compile_ns
         self._paid[2] += events.hits
@@ -641,6 +659,15 @@ class ServingBackend(StepBackend):
                 self.last_aux = out[t_buf:].reshape(self.spec.aux_shape)
                 if self.spec.count_aux is not None:
                     self.last_counters = self.spec.count_aux(self.last_aux, ti)
+                    if self.expert_kernel:
+                        # the work items the kernel visited, as its own rule
+                        # makes them from the counts that are here already
+                        from ..models import expert_mlp
+
+                        held = self.last_aux[:, :self.cfg.experts_held]
+                        self.last_counters = {
+                            **self.last_counters,
+                            "moe_kernel_items": int(expert_mlp.item_counts(held).sum())}
             self._count_walk(np.array(spans), positions)
             if self.on_step is not None:
                 self.on_step(entries)

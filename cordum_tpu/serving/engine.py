@@ -182,6 +182,10 @@ class ServingStats:
     moe_assignments_here: int = 0
     moe_experts_touched: int = 0
     moe_max_expert_load: int = 0
+    # work items the grouped products' kernel visited (a touched expert and
+    # a run of its rows, ``models/expert_mlp.py``), summed over steps and
+    # expert layers; stays 0 where the products are ``ragged_dot``'s
+    moe_kernel_items: int = 0
     # a router with identity (zero-compute) experts: picks of them by live
     # tokens, and the most and the fewest REAL experts a live token picked,
     # a layer and step (summed: divide by layers x steps)
@@ -1632,6 +1636,8 @@ class ServingEngine:
                 setattr(self.stats, name, getattr(self.stats, name) + n)
             attrs["moe_here"] = str(counters["moe_assignments_here"])
             attrs["moe_touched"] = str(counters["moe_experts_touched"])
+            attrs["expert_kernel"] = self.backend.expert_kernel or "none"
+            attrs["moe_items"] = str(counters.get("moe_kernel_items", 0))
             if "moe_zero_assignments" in counters:
                 attrs["moe_zero"] = str(counters["moe_zero_assignments"])
                 attrs["moe_real_picks"] = (f"{counters['moe_real_picks_min']}-"

@@ -79,6 +79,14 @@ def holds_walk_kernel(text: str) -> bool:
                for line in text.splitlines())
 
 
+def holds_expert_kernel(text: str) -> bool:
+    """Whether a program's text calls the grouped expert products' kernel."""
+    from cordum_tpu.models import expert_mlp
+
+    return any("tpu_custom_call" in line and expert_mlp.KERNEL_NAME in line
+               for line in text.splitlines())
+
+
 def smoke_cfg(**kw):
     return dataclasses.replace(
         llama.LlamaConfig.llama3_8b(), n_layers=SZ["n_layers"],
@@ -205,10 +213,33 @@ def test_a_by_head_program_holds_no_walk_kernel(config):
         return program.trace(params, *arenas, feed).lower(
             lowering_platforms=(platform,)).as_text()
 
-    assert not holds_walk_kernel(lowered(config, "tpu"))
+    text = lowered(config, "tpu")
+    assert not holds_walk_kernel(text)
+    # the grouped products' kernel: where there is an expert layer, for the TPU
+    assert holds_expert_kernel(text) == (config == "trinity-large-preview-ep8")
     if config == "trinity-large-preview-ep8":  # once: the rule's other side
-        assert holds_walk_kernel(lowered("a.x-k1-ep16", "tpu"))
-        assert not holds_walk_kernel(lowered("a.x-k1-ep16", "cpu"))
+        latent = lowered("a.x-k1-ep16", "tpu")
+        assert holds_walk_kernel(latent) and holds_expert_kernel(latent)
+        on_cpu = lowered("a.x-k1-ep16", "cpu")
+        assert not holds_walk_kernel(on_cpu) and not holds_expert_kernel(on_cpu)
+
+
+@pytest.mark.parametrize("rows,d,fe,held", [
+    (1024, 2560, 768, 128), (512, 7168, 2048, 12), (1536, 6144, 2048, 16), (256, 3072, 3072, 32)],
+    ids=["ling", "axk1", "longcat", "trinity"])
+def test_the_expert_kernel_compiles_at_the_sparse_cells_shapes(one_chip, rows, d, fe, held):
+    """``expert_mlp`` alone at each sparse cell's committed shapes (T x top_k
+    rows, the experts held): Mosaic takes the blocks ``block_width`` derives,
+    the 16-row pieces' copies and the kernel's VMEM under its stated budget."""
+    from cordum_tpu.models import expert_mlp
+
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)  # noqa: E731
+    assert expert_mlp.holds_kernel("tpu", d, fe, 2)
+    compiled = jax.jit(expert_mlp.expert_mlp).lower(
+        shape((rows, d), jnp.bfloat16), shape((held, d, fe), jnp.bfloat16),
+        shape((held, d, fe), jnp.bfloat16), shape((held, fe, d), jnp.bfloat16),
+        shape((held,), jnp.int32)).compile()
+    assert holds_expert_kernel(compiled.as_text())
 
 
 def test_the_shortcut_connected_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
@@ -289,7 +320,7 @@ def test_the_hybrid_step_fits_one_chip_and_keeps_state_and_pages_in_place(one_ch
     assert ma.temp_size_in_bytes < 0.5e9  # no copy of the state or the arena among the temporaries
     assert 12.5e9 < device_bytes(compiled) <= 0.9 * HBM_BYTES
     text = compiled.as_text()
-    assert holds_walk_kernel(text) and kda.KERNEL_NAME in text
+    assert holds_walk_kernel(text) and kda.KERNEL_NAME in text and holds_expert_kernel(text)
     print(f"bailing step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
           f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
 

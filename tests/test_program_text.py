@@ -2,9 +2,13 @@
 trace to the jaxpr text they had then: a state slot beside the page tables, a
 direct query matrix and a head-wise gate in ``axk1.mla_sublayer`` are
 additions that a program which does not ask for them never sees.  The hashes
-are of the text at tiny sizes on the tree of PR 39 (addresses of function
-objects cut out); a change that means to alter one of these programs updates
-its hash, and says so."""
+are of the text at tiny sizes (addresses of function objects cut out); a
+change that means to alter one of these programs updates its hash, and says
+so: ``llama``'s is the text on the tree of PR 39 still; ``afmoe``'s,
+``axk1``'s and ``longcat``'s changed with ISSUE 41, whose expert layer hands
+BOTH forms of the grouped products to the lowering (``ragged_dot`` and the
+``expert_mlp`` kernel with its work list, ``models/expert_mlp.py``), so both
+are in the trace; a program without an expert layer holds neither."""
 import hashlib
 import re
 
@@ -18,12 +22,12 @@ from cordum_tpu.serving.modelspec import spec_for
 
 PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
 
-#: sha256 of the jaxpr text, PR 39's tree
+#: sha256 of the jaxpr text: PR 39's tree (llama), PR 41's (the three sparse families)
 AS_IT_WAS = {
     "llama": "b6f5236ed6c4e3250c080f30f92c7370102c8a4209ac229fc24fc5988ec38828",
-    "afmoe": "65d7ac5045e83067d596366898c2d8a2be4eb4584c4a4520987b6f886c17979c",
-    "axk1": "bfbb03817b5398ab2289d7895474e584ec30ac247fc1e55fef6de93eca4f213f",
-    "longcat": "7201c2a5de9ec275884f0f3933c34f6e46c33b9c844233c35f85770a2d686b1a",
+    "afmoe": "ac8a4f1e447b7059c93d0652c4643e930dc91dcbe0fbbce52d86e0778f141ee7",
+    "axk1": "dc5522dfaaf71aa57a0142aa9378bcda99ddab191aedeb6ab295b221287189b2",
+    "longcat": "2b508f8fe8fefb380ba03563ea32191691167b2510d5a4ab0ad0cf28174be0ae",
 }
 CONFIGS = {"llama": llama.LlamaConfig.tiny, "afmoe": afmoe.AfmoeConfig, "axk1": axk1.Axk1Config,
            "longcat": longcat.LongcatConfig, "bailing": bailing.BailingConfig}
@@ -47,6 +51,8 @@ def test_the_program_traces_to_the_text_it_had(family):
     text = text_of(CONFIGS[family]())
     assert hashlib.sha256(text.encode()).hexdigest() == AS_IT_WAS[family]
     assert "kda_step" not in text and "state_slot" not in text
+    # the grouped products' two forms, where there is an expert layer and only there
+    assert ("expert_mlp" in text) == ("ragged_dot" in text) == (family != "llama")
 
 
 def test_the_new_familys_program_holds_what_the_others_lack():

@@ -275,7 +275,7 @@ def test_ragged_single_program_no_recompile_cliff(llama_env):
     for width in (1, 2, 4, 3):  # ragged join/leave widths, incl. non-pow2
         be.decode([(t, p, pg) for t, p, pg in sessions[:width]])
     assert be.compiled_programs() == 1
-    assert metrics.serving_compiles.value(entry="ragged", walk_kernel="none") == 1
+    assert metrics.serving_compiles.value(entry="ragged", walk_kernel="none", expert_kernel="none") == 1
     assert be.last_step_compiled is False  # steady state by now
 
 

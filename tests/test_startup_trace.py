@@ -71,8 +71,14 @@ class Served:
         return self.spans
 
     def compile_events(self):
-        return sum(self.metrics.serving_compiles.value(entry=e, walk_kernel="none")
+        return sum(self.compiles(e)
                    for e in ("state", "ragged", "copy_page", "gather_page", "scatter_page"))
+
+    def compiles(self, entry):
+        """The compile counter's series of this backend (a llama program:
+        neither kernel) for ``entry``."""
+        return self.metrics.serving_compiles.value(
+            entry=entry, walk_kernel="none", expert_kernel="none")
 
 
 async def test_record_holds_every_phase_once_and_closes_with_the_first_token():
@@ -149,8 +155,8 @@ async def test_step_reads_compiled_from_the_compilers_own_events():
     async with Served(pages=107) as s:
         prompt = list(range(1, 33))  # exactly two pages
         out1 = await s.generate(prompt, "a")
-        assert s.metrics.serving_compiles.value(entry="ragged", walk_kernel="none") == 1
-        assert s.metrics.serving_compiles.value(entry="copy_page", walk_kernel="none") == 0
+        assert s.compiles("ragged") == 1
+        assert s.compiles("copy_page") == 0
         # the same prompt again hits both pages and re-feeds its last token,
         # which writes into a shared page: the first copy-on-write, and so
         # the first use of the page copy program, inside that cycle
@@ -168,8 +174,8 @@ async def test_step_reads_compiled_from_the_compilers_own_events():
         again = steps[n_before].attrs  # always kept, whatever the sampling period says
         assert again["compiled"] == "true" and float(again["compile_ms"]) > 0
         assert [n for n, sp in steps.items() if sp.attrs["compiled"] == "true"] == [0, n_before]
-        assert s.metrics.serving_compiles.value(entry="copy_page", walk_kernel="none") == 1
-        assert s.metrics.serving_compiles.value(entry="ragged", walk_kernel="none") == 1
+        assert s.compiles("copy_page") == 1
+        assert s.compiles("ragged") == 1
         assert s.engine.backend.compiled_programs() == 1
         assert not s.engine.backend.last_step_compiled
 
