@@ -162,6 +162,10 @@ class StepBackend:
     # for the TPU, ``models/expert_mlp.py``), "" where they are
     # ``jax.lax.ragged_dot``'s or the model has no expert layer
     expert_kernel: str = ""
+    # which form a state-space mixer's recurrence takes: the kernel's name
+    # ("ssd_step": a model with such a mixer whose program is lowered for the
+    # TPU, ``models/ssd.py``), "" where it is ``jax.numpy``'s or the model has none
+    state_kernel: str = ""
     # the latest step's report, written by ``step`` and read by the engine
     # after the call
     REPORT = ("last_step_compiled", "last_compile_ms", "last_cache_hit", "last_phases",
@@ -483,6 +487,15 @@ class ServingBackend(StepBackend):
                                                np.dtype(self.cfg.dtype).itemsize):
                         self.expert_kernel = expert_mlp.KERNEL_NAME
                     made["expert_kernel"] = self.expert_kernel or "none"
+            if getattr(self.cfg, "ssm_heads", 0):  # the model has a state-space mixer
+                # as above: the recurrence's form is the lowering platform's
+                with startup.phase("startup.ssd_kernel") as made:
+                    from ..models import ssd
+
+                    platform = next(iter(self._arenas[0].devices())).platform
+                    if ssd.holds_kernel(platform):
+                        self.state_kernel = ssd.KERNEL_NAME
+                    made["ssd_kernel"] = self.state_kernel or "none"
             state.update(events.counts())
         self._note_compiles("state", events)
         self.page_bytes = sum(a.nbytes // a.shape[1] for a in self._arenas[self._row_kind])

@@ -199,6 +199,13 @@ class ServingStats:
     state_slots_peak: int = 0
     kda_decode_rows: int = 0
     kda_chunk_tokens: int = 0
+    # what a family's program counted of its own state arrays
+    # (``ModelSpec.count_aux``), a state layer's, summed over steps: rows
+    # that advanced a state, tokens through the recurrence, and rows that
+    # started from zeros (a session's first chunk, whatever its slot held)
+    state_rows_advanced: int = 0
+    state_tokens_scanned: int = 0
+    state_rows_fresh: int = 0
     # stream packets the step loop handed to the sinks (a session's new
     # tokens of one step; replays of a carried prefix are not among them),
     # and those of them published with the NEXT step already on the device
@@ -1631,9 +1638,14 @@ class ServingEngine:
         counters = self.backend.last_counters
         if counters:
             # what the model family's program counted this step, named by
-            # the family (``ModelSpec.count_aux``): the expert layer's four
+            # the family (``ModelSpec.count_aux``): the expert layer's four,
+            # a state family's three
             for name, n in counters.items():
                 setattr(self.stats, name, getattr(self.stats, name) + n)
+        if "state_rows_fresh" in counters:
+            attrs["state_fresh"] = str(counters["state_rows_fresh"])
+            attrs["state_kernel"] = self.backend.state_kernel or "none"
+        if "moe_assignments_here" in counters:
             attrs["moe_here"] = str(counters["moe_assignments_here"])
             attrs["moe_touched"] = str(counters["moe_experts_touched"])
             attrs["expert_kernel"] = self.backend.expert_kernel or "none"

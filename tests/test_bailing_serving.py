@@ -347,22 +347,43 @@ def test_the_engine_serves_it_and_turns_slots_over():
     assert st.prefix_hits == 0 and st.drafted_tokens == 0
 
 
-def test_a_step_entry_without_a_slot_is_refused():
-    cfg = tiny()
-    be = backend_for(cfg, bailing.init_params(jax.random.PRNGKey(3), cfg))
+def state_family(family):
+    """``(cfg, params)`` of a tiny model of a family that keeps recurrent
+    state: this file's, or the one whose every layer holds pages AND a state."""
+    if family == "bailing":
+        cfg = tiny()
+        return cfg, bailing.init_params(jax.random.PRNGKey(3), cfg)
+    from cordum_tpu.models import falcon_h1
+
+    cfg = falcon_h1.FalconH1Config(dtype=jnp.float32)
+    return cfg, falcon_h1.init_params(jax.random.PRNGKey(3), cfg)
+
+
+STATE_FAMILIES = ["bailing", "falcon_h1"]
+
+
+@pytest.mark.parametrize("family", STATE_FAMILIES)
+def test_a_step_entry_without_a_slot_is_refused(family):
+    cfg, params = state_family(family)
+    be = backend_for(cfg, params)
     with pytest.raises(ValueError, match="state_slot"):
         be.step([entry(be, 0, [1, 2, 3], 0, slot=0)])
     with pytest.raises(ValueError, match="state_slot"):
         be.step([entry(be, 0, [1, 2, 3], 0, slot=5)])
 
 
+@pytest.mark.parametrize("family", STATE_FAMILIES)
 @pytest.mark.parametrize("feature", ["prefix cache", "speculation", "hibernation", "migration",
                                      "gang"])
-def test_what_shares_refeeds_or_carries_positions_refuses_the_family(feature):
-    """Each by the NEW capability (``kv_positional``), loudly."""
-    cfg = tiny()
-    assert not spec_for(cfg).kv_positional and spec_for(cfg).kv_whole_row
-    be = backend_for(cfg, bailing.init_params(jax.random.PRNGKey(3), cfg))
+def test_what_shares_refeeds_or_carries_positions_refuses_the_family(feature, family):
+    """Each by the ONE capability (``kv_positional``), loudly: the second
+    family's pages alone would pass every other test (K and V by head, whole
+    rows), so ``kv_positional`` is asked first."""
+    cfg, params = state_family(family)
+    spec = spec_for(cfg)
+    assert not spec.kv_positional and spec.kv_whole_row
+    assert spec.kv_by_head == (family == "falcon_h1")
+    be = backend_for(cfg, params)
 
     async def engine(**kw):
         return ServingEngine(be, run_blocking=run_blocking, **kw)
@@ -405,7 +426,8 @@ def test_what_shares_refeeds_or_carries_positions_refuses_the_family(feature):
             ShardedServingBackend(cfg, tp=2, num_pages=16, page_size=PS)
 
 
-def test_the_workers_defaults_meet_the_capability(caplog):
+@pytest.mark.parametrize("family", STATE_FAMILIES)
+def test_the_workers_defaults_meet_the_capability(caplog, family):
     """``make_serving_engine`` hands its defaults (None) on and the engine
     resolves them: the drafter and the prefix cache stay off with ONE log
     line that names the capability; either asked for by name is refused."""
@@ -415,8 +437,7 @@ def test_the_workers_defaults_meet_the_capability(caplog):
     from cordum_tpu.worker.handlers import TPUCompute, make_serving_engine
     from cordum_tpu.worker.runtime import Worker
 
-    cfg = tiny()
-    params = bailing.init_params(jax.random.PRNGKey(3), cfg)
+    cfg, params = state_family(family)
 
     async def build(**kw):
         worker = Worker(bus=LoopbackBus(sync=True), store=MemoryStore(MemoryKV()),

@@ -325,6 +325,53 @@ def test_the_hybrid_step_fits_one_chip_and_keeps_state_and_pages_in_place(one_ch
           f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
 
 
+def test_the_state_space_step_fits_one_chip_and_keeps_state_and_pages_in_place(one_chip):
+    """The falcon_h1 cell's ragged program at the benchmark's sizes (``benchmarks/
+    configs/falcon-h1-34b-pp8.json``: published widths, 9 layers each with a
+    mixer AND attention, an eighth of the vocabulary, 80 sessions): 8.41 GB of
+    weights, 2.26 GB of K and V pages and 3.08 GB of state slots fit, donation
+    is real for all four cache arrays, and the program lowered for the TPU
+    holds ``ssd_step`` (ONE kernel body, called a layer: the recurrence is
+    jitted with the layer a traced operand) and no other kernel."""
+    from benchmarks.families import falcon_h1 as fam
+    from benchmarks.harness import cells
+    from cordum_tpu.models import ssd
+
+    doc = dict(cells.load_config("falcon-h1-34b-pp8"))
+    cfg, pool = fam.program_config(doc), doc["pool"]
+
+    def leaf(path, shape):
+        dtype = jnp.float32 if path[-1].key in fam.FLOAT32 else jnp.bfloat16
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        leaf, fam.param_shapes(doc), is_leaf=lambda x: isinstance(x, tuple))
+    seqs = pool["max_sessions"]
+    spec = cfg.serving_spec()
+    caches = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in jax.eval_shape(
+        lambda: tuple(spec.init_arenas(pool["pages"], pool["page_size"], 0))
+        + spec.init_state(seqs + 1))]
+    assert [c.shape for c in caches] == [(9, 7680, 16, 4, 128)] * 2 + [
+        (9, 81, 256, 32, 128), (9, 81, 3, 5120)]
+    assert caches[2].dtype == jnp.float32
+    layout = FeedLayout(seqs + pool["prefill_budget"], seqs,
+                        (cfg.max_seq_len // pool["page_size"],), state_rows=seqs + 1)
+    feed = jax.ShapeDtypeStruct((layout.size,), jnp.int32, sharding=one_chip)
+    program = make_ragged_program(cfg, layout, sample_logits=True, donate=True)
+    compiled = program.lower(params, *caches, feed).compile()
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(c.size * c.dtype.itemsize for c in caches)
+    assert ma.alias_size_in_bytes >= cache_bytes > 5.3e9
+    assert ma.temp_size_in_bytes < 0.5e9  # no copy of the state or of an arena among the temporaries
+    assert 13.5e9 < device_bytes(compiled) <= 0.9 * HBM_BYTES
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and ssd.KERNEL_NAME in ln]
+    assert calls and not holds_walk_kernel(text) and not holds_expert_kernel(text)
+    print(f"falcon_h1 step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB, "
+          f"{len(calls)} ssd_step call lines")
+
+
 def test_reference_forward_fits_beside_the_serving_state(one_chip):
     cfg = smoke_cfg()
     params, arena, _, _ = serving_shapes(

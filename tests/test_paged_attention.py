@@ -81,7 +81,7 @@ def attend(q, k_pages, v_pages, layer, tables, token_seq, positions, block_pages
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("rep", [1, 2, 4, 8])
+@pytest.mark.parametrize("rep", [1, 2, 4, 5, 8])
 def test_matches_dense_reference(rep, dtype, tol):
     q, k_pages, v_pages, tables, token_seq, positions = arena_and_rows(rep, dtype)
     fed = token_seq < len(tables) - 1

@@ -1,5 +1,7 @@
-"""The step programs of the four families the benchmark had before ISSUE 40
-trace to the jaxpr text they had then: a state slot beside the page tables, a
+"""The step programs of the families the benchmark had before ISSUE 42 trace to
+the jaxpr text they had (``bailing``'s: the text on the tree of PR 41; the
+sixth family, ``falcon_h1``, touches none of them).  For the four the
+benchmark had before ISSUE 40: a state slot beside the page tables, a
 direct query matrix and a head-wise gate in ``axk1.mla_sublayer`` are
 additions that a program which does not ask for them never sees.  The hashes
 are of the text at tiny sizes (addresses of function objects cut out); a
@@ -16,14 +18,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from cordum_tpu.models import afmoe, axk1, bailing, llama, longcat
+from cordum_tpu.models import afmoe, axk1, bailing, falcon_h1, llama, longcat
 from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
 from cordum_tpu.serving.modelspec import spec_for
 
 PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
 
-#: sha256 of the jaxpr text: PR 39's tree (llama), PR 41's (the three sparse families)
+#: sha256 of the jaxpr text: PR 39's tree (llama), PR 41's (the four sparse families)
 AS_IT_WAS = {
+    "bailing": "6551ba3ad256442012da20d044e23b1f8fb8f683eea087f0e6467d7adefce633",
     "llama": "b6f5236ed6c4e3250c080f30f92c7370102c8a4209ac229fc24fc5988ec38828",
     "afmoe": "ac8a4f1e447b7059c93d0652c4643e930dc91dcbe0fbbce52d86e0778f141ee7",
     "axk1": "dc5522dfaaf71aa57a0142aa9378bcda99ddab191aedeb6ab295b221287189b2",
@@ -50,7 +53,7 @@ def text_of(cfg) -> str:
 def test_the_program_traces_to_the_text_it_had(family):
     text = text_of(CONFIGS[family]())
     assert hashlib.sha256(text.encode()).hexdigest() == AS_IT_WAS[family]
-    assert "kda_step" not in text and "state_slot" not in text
+    assert ("kda_step" in text) == (family == "bailing") and "ssd_step" not in text
     # the grouped products' two forms, where there is an expert layer and only there
     assert ("expert_mlp" in text) == ("ragged_dot" in text) == (family != "llama")
 
@@ -66,4 +69,21 @@ def test_the_new_familys_program_holds_what_the_others_lack():
     assert text.count(state) >= 2
     with_state = FeedLayout(TOKENS, SEQS, (CONTEXT // PS,), state_rows=SEQS + 1)
     assert with_state.size == FeedLayout(TOKENS, SEQS, (CONTEXT // PS,)).size + SEQS + 1
+    assert f"i32[{with_state.size}]" in text
+
+
+def test_the_state_space_familys_program_holds_pages_and_state_in_every_layer():
+    """One trace holds both forms of the mixer's recurrence (the choice is
+    made where the program is lowered) under ONE jitted function that every
+    layer calls, K and V arenas by head AND the state's float32 array among
+    its operands and results, no latent walk and no expert layer."""
+    cfg = falcon_h1.FalconH1Config()
+    text = text_of(cfg)
+    assert "ssd_step" in text and "platform_index" in text and "name=recurrence" in text
+    assert not any(w in text for w in ("kda_step", "latent_walk", "expert_mlp", "ragged_dot"))
+    state = (f"f32[{cfg.n_layers},{SEQS + 1},{cfg.ssm_state},{cfg.ssm_heads},"
+             f"{cfg.ssm_head_dim}]")
+    pages = f"bf16[{cfg.n_layers},{PAGES},{PS},{cfg.n_kv_heads},{cfg.head_dim}]"
+    assert text.count(state) >= 2 and text.count(pages) >= 4
+    with_state = FeedLayout(TOKENS, SEQS, (CONTEXT // PS,), state_rows=SEQS + 1)
     assert f"i32[{with_state.size}]" in text
