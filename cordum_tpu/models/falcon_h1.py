@@ -232,9 +232,8 @@ def ragged_step(
             mid = (jax.nn.silu(gate * cfg.mlp_multipliers[0]) * up).astype(dt)
             x = x + jnp.dot(mid, layer["w_down"],
                             preferred_element_type=jnp.float32) * cfg.mlp_multipliers[1]
-    fed = srows.n > 0
-    counters = jnp.stack([jnp.sum(fed), jnp.sum(srows.n), jnp.sum(fed & srows.fresh)]
-                         ).astype(jnp.int32)
+    counters = jnp.stack([srows.fed, jnp.sum(srows.n), jnp.sum((srows.n > 0) & srows.fresh),
+                          kda.rows_prefetched(srows)]).astype(jnp.int32)
     if not sample_logits:
         return jnp.concatenate([jnp.zeros((t_buf,), jnp.int32), counters]), k_pages, v_pages, \
             state, tail
@@ -249,16 +248,16 @@ def ragged_step(
 
 def step_counters(counts: Any, live: int) -> dict[str, int]:
     """``ServingStats`` addends of one step's counters (``ragged_step``'s
-    three, as ``ModelSpec.count_aux`` hands them over)."""
-    rows, tokens, fresh = (int(n) for n in counts)
+    four, as ``ModelSpec.count_aux`` hands them over)."""
+    rows, tokens, fresh, prefetched = (int(n) for n in counts)
     return {"state_rows_advanced": rows, "state_tokens_scanned": tokens,
-            "state_rows_fresh": fresh}
+            "state_rows_fresh": fresh, "state_rows_prefetched": prefetched}
 
 
 def serving_spec(cfg: FalconH1Config) -> Any:
     """The family's specification for the serving backend
     (``serving/modelspec.py``): one kind of page, K and V by head, in every
-    layer, two state arrays in slots, three counters behind the tokens."""
+    layer, two state arrays in slots, four counters behind the tokens."""
     from ..serving.modelspec import ModelSpec, kv_pair
 
     def program(sample_logits):
@@ -274,7 +273,7 @@ def serving_spec(cfg: FalconH1Config) -> Any:
         init_arenas=lambda n, ps, _w: init_kv_pages(cfg, n, ps),
         program=program, arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),), value_dim=cfg.head_dim,
         init_state=lambda slots: init_state(cfg, slots), n_state=2,
-        aux_shape=(3,), count_aux=step_counters,
+        aux_shape=(4,), count_aux=step_counters,
     )
 
 

@@ -40,10 +40,23 @@ state a token.  Two forms, chosen where the program is LOWERED
 (``jax.lax.platform_dependent``, as ``models/latent_walk.py``'s walk):
 
 * :func:`rows_kernel` — a Pallas TPU kernel, :data:`KERNEL_NAME` in the
-  lowered program and in a device trace: grid = the table's rows, one after
-  the other; a live row's ``S`` (2 MiB at the published widths) is copied
-  from the state array (which stays in HBM) into VMEM, advanced by the row's
-  tokens there and copied back; rows that feed nothing cost a grid step;
+  lowered program and in a device trace.  Only the token body is this
+  module's (:func:`_kernel`); everything round it is
+  ``models/row_pipeline.py``'s, shared with ``ssd_step``.  **The grid** is
+  the table's rows (static); grid step ``j`` advances the ``j``-th FED row of
+  the step's work list (:class:`StateRows` ``work`` / ``fed``, made in
+  :func:`state_rows`), the steps behind the list do nothing.  **The
+  buffers**: two state buffers in VMEM, 2 MiB each at the published widths
+  (``row_pipeline.BUFFERS``, or as many as :data:`VMEM_BUDGET_BYTES` holds
+  beside the operands) and a DMA semaphore a buffer and direction; while a
+  row's tokens run in one buffer, the next fed row's ``S`` is on its way
+  from the state array (which stays in HBM) into the other.  **Waited for**:
+  a row's read before its first token (the step's first fed row's is the
+  only read nothing hides; a ``fresh`` row fills its buffer with zeros and
+  reads nothing), a buffer's write-back before that buffer is filled again,
+  every write-back before the call returns.  **One slot, one row**: a row's
+  read starts before the rows ahead of it are written back, so a step never
+  holds one slot in two fed rows (:class:`StateRows`);
 * :func:`rows_jnp` — ``jax.numpy``, every other platform (the CPU's tests
   and references): the rows side by side, a round a token of the longest.
 """
@@ -62,7 +75,9 @@ PLATFORM = "tpu"
 #: the kernel's name in the lowered program (its custom call) and in a trace
 KERNEL_NAME = "kda_step"
 #: VMEM the kernel may ask for: the five operands and the output whole (2 MiB
-#: each at 128 buffer slots), a row's state, and what the pipeline doubles
+#: each at 128 buffer slots, and Pallas' pipeline holds each twice: 24 MiB)
+#: and the row pipeline's state buffers (two of 2 MiB: 28 MiB in all;
+#: ``row_pipeline.depth_for`` gives fewer where fewer fit)
 VMEM_BUDGET_BYTES = 48 * 1024 * 1024
 #: accumulators the two reductions over the key channels are spread over
 #: (a chain of 128 dependent adds would wait for each add's latency)
@@ -78,13 +93,20 @@ def init_state(n_layers: int, slots: int, heads: int, dk: int, dv: int, conv_wid
 
 class StateRows(NamedTuple):
     """What every KDA layer of one step shares: where each table row's tokens
-    lie in the buffer and which slot holds its state."""
+    lie in the buffer, which slot holds its state, and the kernel's work
+    list.  **A step never holds one slot in two fed rows** (a slot is a
+    session's, a session one table row; rows that feed nothing name the null
+    slot 0, which no fed row may name): the kernel's pipeline starts a row's
+    read before the rows ahead of it are written back
+    (``models/row_pipeline.py``)."""
     token_seq: jax.Array  # [T] table row of each buffer slot
     offset: jax.Array  # [T] a slot's place in its row's run (0: the row's first fed token)
     lo: jax.Array  # [R] a row's first buffer slot (T for a row that feeds nothing)
     n: jax.Array  # [R] tokens the row feeds (0 for the padding row)
     fresh: jax.Array  # [R] the row's first fed position is 0: it starts from zeros
     slot: jax.Array  # [R] the row's state slot (0: the null slot)
+    work: jax.Array  # [R] the table rows that feed, in table order; behind the ``fed``-th: 0
+    fed: jax.Array  # [] how many rows feed
 
 
 def state_rows(positions: jax.Array, token_seq: jax.Array, state_slot: jax.Array) -> StateRows:
@@ -97,9 +119,18 @@ def state_rows(positions: jax.Array, token_seq: jax.Array, state_slot: jax.Array
     lo = jnp.full((r,), t_buf, jnp.int32).at[token_seq].min(at)
     n = jnp.where(live_row, jnp.zeros((r,), jnp.int32).at[token_seq].add(1), 0)
     first = positions[jnp.minimum(lo, t_buf - 1)]  # [R] the row's first fed position
-    fresh = (first == 0) | (n == 0)
-    return StateRows(token_seq, at - lo[token_seq], lo, n, fresh,
-                     jnp.where(n > 0, state_slot, 0))
+    feeds = n > 0
+    (work,) = jnp.nonzero(feeds, size=r, fill_value=0)
+    return StateRows(token_seq, at - lo[token_seq], lo, n, (first == 0) | ~feeds,
+                     jnp.where(feeds, state_slot, 0), work.astype(jnp.int32),
+                     jnp.sum(feeds, dtype=jnp.int32))
+
+
+def rows_prefetched(rows: StateRows) -> jax.Array:
+    """Fed rows whose state the kernel's pipeline reads ahead of their turn:
+    the carried (not ``fresh``) fed rows behind the work list's first."""
+    carried = (rows.n > 0) & ~rows.fresh
+    return jnp.sum(carried, dtype=jnp.int32) - carried[rows.work[0]].astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -163,97 +194,50 @@ def rows_jnp(q, k, kb, eg, v, state, layer, rows: StateRows):
     return o, state.at[layer, rows.slot].set(s)
 
 
-def _kernel(lo_ref, n_ref, slot_ref, fresh_ref, layer_ref, q_ref, k_ref, kb_ref, eg_ref, v_ref,
-            state_in, o_ref, state_out, s_ref, sem, *, dk: int):
-    """One table row's tokens through its state, in VMEM.  ``s_ref`` [d_k,
-    heads, d_v]: the row's ``S``; a token is two passes over its slabs."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def _kernel(*refs, dk: int):
+    """A token's two passes over its row's state: ``s_ref`` [d_k, heads,
+    d_v], the row's ``S`` in one of the pipeline's VMEM buffers.  Everything
+    round it (which row, which buffer, what is copied when) is
+    ``row_pipeline.pipeline``'s."""
+    from . import row_pipeline
 
-    i = pl.program_id(0)
+    lists, (q_ref, k_ref, kb_ref, eg_ref, v_ref, state_in, o_ref, *rest) = (
+        refs[:row_pipeline.N_LISTS], refs[row_pipeline.N_LISTS:])
+    add, mul, column = jax.lax.add, jax.lax.mul, row_pipeline.column  # cheap to trace
 
-    @pl.when(i == 0)
-    def _():  # buffer slots no row feeds read zeros, not what VMEM held
-        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    def token(s_ref, at):
+        kt, kbt, qt, egt, vt = k_ref[at], kb_ref[at], q_ref[at], eg_ref[at], v_ref[at]
+        acc = [jnp.zeros(vt.shape, jnp.float32) for _ in range(ACCUMULATORS)]
+        for c in range(dk):  # S_d = Diag(exp g) S; u = S_d^T k
+            sd = mul(s_ref[c], column(egt, c))
+            s_ref[c] = sd
+            acc[c % ACCUMULATORS] = add(acc[c % ACCUMULATORS], mul(column(kt, c), sd))
+        d = vt - sum(acc[1:], acc[0])
+        acc = [jnp.zeros(vt.shape, jnp.float32) for _ in range(ACCUMULATORS)]
+        for c in range(dk):  # S = S_d + (beta k)(v - u)^T; o = S^T q
+            s2 = add(s_ref[c], mul(column(kbt, c), d))
+            s_ref[c] = s2
+            acc[c % ACCUMULATORS] = add(acc[c % ACCUMULATORS], mul(column(qt, c), s2))
+        o_ref[at] = sum(acc[1:], acc[0])
 
-    n = n_ref[i]
-
-    @pl.when(n > 0)
-    def _():
-        layer, slot, lo = layer_ref[0], slot_ref[i], lo_ref[i]
-
-        @pl.when(fresh_ref[i] == 0)
-        def _():
-            cp = pltpu.make_async_copy(state_in.at[layer, slot], s_ref, sem.at[0])
-            cp.start()
-            cp.wait()
-
-        @pl.when(fresh_ref[i] != 0)
-        def _():
-            s_ref[...] = jnp.zeros(s_ref.shape, s_ref.dtype)
-
-        def token(t, carry):
-            at = lo + t
-            kt, kbt, qt, egt, vt = k_ref[at], kb_ref[at], q_ref[at], eg_ref[at], v_ref[at]
-            acc = [jnp.zeros(vt.shape, jnp.float32) for _ in range(ACCUMULATORS)]
-            for c in range(dk):  # S_d = Diag(exp g) S; u = S_d^T k
-                sd = s_ref[c] * egt[:, c:c + 1]
-                s_ref[c] = sd
-                acc[c % ACCUMULATORS] = acc[c % ACCUMULATORS] + kt[:, c:c + 1] * sd
-            d = vt - sum(acc[1:], acc[0])
-            acc = [jnp.zeros(vt.shape, jnp.float32) for _ in range(ACCUMULATORS)]
-            for c in range(dk):  # S = S_d + (beta k)(v - u)^T; o = S^T q
-                s2 = s_ref[c] + kbt[:, c:c + 1] * d
-                s_ref[c] = s2
-                acc[c % ACCUMULATORS] = acc[c % ACCUMULATORS] + qt[:, c:c + 1] * s2
-            o_ref[at] = sum(acc[1:], acc[0])
-            return carry
-
-        jax.lax.fori_loop(0, n, token, 0)
-        cp = pltpu.make_async_copy(s_ref, state_out.at[layer, slot], sem.at[0])
-        cp.start()
-        cp.wait()
+    row_pipeline.pipeline(*lists, state_in, o_ref, *rest, token)
 
 
 def rows_kernel(q, k, kb, eg, v, state, layer, rows: StateRows):
     """The same as :func:`rows_jnp` through the Pallas kernel: the operands
-    whole in VMEM (fetched once: their block does not move), the state array
-    in HBM and updated in place (the result aliases it)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    whole in VMEM, the state array in HBM, the fed rows' states through the
+    pipeline's buffers (``row_pipeline.advance_rows``)."""
+    from . import row_pipeline
 
-    t_buf, h, dk = q.shape
-    dv = v.shape[2]
-    r = rows.n.shape[0]
-    need = (2 * 5 * t_buf * h * max(dk, dv) + 2 * t_buf * h * dv + dk * h * dv) * 4
-    if need > VMEM_BUDGET_BYTES:
-        raise ValueError(f"the recurrence's kernel needs {need} bytes of VMEM for a buffer of "
-                         f"{t_buf} slots: over {VMEM_BUDGET_BYTES}")
-    whole = lambda *shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))  # noqa: E731
-    o, state = pl.pallas_call(
-        partial(_kernel, dk=dk),
-        out_shape=(jax.ShapeDtypeStruct((t_buf, h, dv), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(r,),
-            in_specs=[whole(t_buf, h, dk), whole(t_buf, h, dk), whole(t_buf, h, dk),
-                      whole(t_buf, h, dk), whole(t_buf, h, dv),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=(whole(t_buf, h, dv), pl.BlockSpec(memory_space=pl.ANY)),
-            scratch_shapes=[pltpu.VMEM((dk, h, dv), jnp.float32),
-                            pltpu.SemaphoreType.DMA((1,))]),
-        # ``state`` (operand 10, behind the five prefetched and the five
-        # blocked) is the second result
-        input_output_aliases={10: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",), vmem_limit_bytes=VMEM_BUDGET_BYTES),
-        name=KERNEL_NAME,
-    )(jnp.minimum(rows.lo, t_buf - 1), rows.n, rows.slot, rows.fresh.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, k, kb, eg, v, state)
-    return o, state
+    return row_pipeline.advance_rows(
+        partial(_kernel, dk=q.shape[2]), (q, k, kb, eg, v), v.shape[2], state, layer, rows,
+        name=KERNEL_NAME, vmem_budget=VMEM_BUDGET_BYTES)
 
 
+# jitted, with the layer a traced operand (as ``ssd.recurrence``): the KDA
+# layers of a step program trace and lower ONE recurrence (the kernel unrolls
+# two passes over ``d_k`` slabs)
+@jax.jit
 def recurrence(q, k, kb, eg, v, state, layer, rows: StateRows):
     """The step's recurrences by the form the lowering platform holds."""
     with jax.named_scope(KERNEL_NAME):
@@ -298,4 +282,4 @@ def kda_sublayer(a: jax.Array, layer: dict, state: jax.Array, tail: jax.Array, r
 
 
 __all__ = ["KERNEL_NAME", "PLATFORM", "StateRows", "init_state", "kda_sublayer", "recurrence",
-           "rows_jnp", "rows_kernel", "short_conv", "state_rows"]
+           "rows_jnp", "rows_kernel", "rows_prefetched", "short_conv", "state_rows"]

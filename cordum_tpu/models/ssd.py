@@ -43,10 +43,22 @@ is a later change, and :func:`recurrence` is where it goes.  Two forms,
 chosen where the program is LOWERED (``jax.lax.platform_dependent``):
 
 * :func:`rows_kernel`: a Pallas TPU kernel, :data:`KERNEL_NAME` in the
-  lowered program and in a device trace: grid = the table's rows, one after
-  the other; a live row's ``S`` is copied from the state array (which stays in
-  HBM) into VMEM, advanced by the row's tokens there and copied back; rows
-  that feed nothing cost a grid step;
+  lowered program and in a device trace.  Only the token body is this
+  module's (:func:`_kernel`); everything round it is
+  ``models/row_pipeline.py``'s, shared with ``kda_step``.  **The grid** is
+  the table's rows (static); grid step ``j`` advances the ``j``-th FED row of
+  the step's work list (``StateRows.work`` / ``fed``), the steps behind the
+  list do nothing.  **The buffers**: two 4 MiB state buffers in VMEM
+  (``row_pipeline.BUFFERS``, or as many as :data:`VMEM_BUDGET_BYTES` holds
+  beside the operands) and a DMA semaphore a buffer and direction; while a
+  row's tokens run in one buffer, the next fed row's ``S`` is on its way
+  from the state array (which stays in HBM) into the other.  **Waited for**:
+  a row's read before its first token (the step's first fed row's is the
+  only read nothing hides; a ``fresh`` row fills its buffer with zeros and
+  reads nothing), a buffer's write-back before that buffer is filled again,
+  every write-back before the call returns.  **One slot, one row**: a row's
+  read starts before the rows ahead of it are written back, so a step never
+  holds one slot in two fed rows (``kda.StateRows``);
 * :func:`rows_jnp`: ``jax.numpy``, every other platform (the CPU's tests and
   references) and the kernel's reference in tests.
 """
@@ -67,7 +79,9 @@ PLATFORM = "tpu"
 #: the kernel's name in the lowered program (its custom call) and in a trace
 KERNEL_NAME = "ssd_step"
 #: VMEM the kernel may ask for: the four operands and the output whole
-#: (fetched once, but the pipeline holds each twice), and a row's state
+#: (fetched once, but Pallas' pipeline holds each twice: 47.7 MB at a buffer
+#: of 208 slots) and the row pipeline's state buffers (two of 4 MiB: 56.1
+#: MB in all; ``row_pipeline.depth_for`` gives fewer where fewer fit)
 VMEM_BUDGET_BYTES = 100 * 1024 * 1024
 #: accumulators the read-out's sum over the state channels is spread over
 ACCUMULATORS = 4
@@ -121,91 +135,41 @@ def rows_jnp(dtx, da, b, c, state, layer, rows: StateRows):
     return o, state.at[layer, rows.slot].set(s)
 
 
-def _kernel(lo_ref, n_ref, slot_ref, fresh_ref, layer_ref, dtx_ref, da_ref, b_ref, c_ref,
-            state_in, o_ref, state_out, s_ref, sem, *, d_state: int):
-    """One table row's tokens through its state, in VMEM.  ``s_ref``
-    [d_state, heads, head_dim]: the row's ``S``; a token is one pass over its
-    slabs."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+def _kernel(*refs, d_state: int):
+    """A token's pass over its row's state: ``s_ref`` [d_state, heads,
+    head_dim], the row's ``S`` in one of the pipeline's VMEM buffers; a token
+    is one pass over its slabs.  Everything round it (which row, which
+    buffer, what is copied when) is ``row_pipeline.pipeline``'s."""
+    from . import row_pipeline
 
-    i = pl.program_id(0)
+    lists, (dtx_ref, da_ref, b_ref, c_ref, state_in, o_ref, *rest) = (
+        refs[:row_pipeline.N_LISTS], refs[row_pipeline.N_LISTS:])
+    add, mul, column = jax.lax.add, jax.lax.mul, row_pipeline.column  # cheap to trace
 
-    @pl.when(i == 0)
-    def _():  # buffer slots no row feeds read zeros, not what VMEM held
-        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    def token(s_ref, at):
+        xt, dat, bt, ct = dtx_ref[at], da_ref[at], b_ref[at], c_ref[at]
+        acc = [jnp.zeros(xt.shape, jnp.float32) for _ in range(ACCUMULATORS)]
+        for ch in range(d_state):  # S = exp(dt A) S + (dt x) B^T; y = S C
+            s2 = add(mul(s_ref[ch], dat), mul(column(bt, ch), xt))
+            s_ref[ch] = s2
+            acc[ch % ACCUMULATORS] = add(acc[ch % ACCUMULATORS], mul(column(ct, ch), s2))
+        o_ref[at] = sum(acc[1:], acc[0])
 
-    n = n_ref[i]
-
-    @pl.when(n > 0)
-    def _():
-        layer, slot, lo = layer_ref[0], slot_ref[i], lo_ref[i]
-
-        @pl.when(fresh_ref[i] == 0)
-        def _():
-            cp = pltpu.make_async_copy(state_in.at[layer, slot], s_ref, sem.at[0])
-            cp.start()
-            cp.wait()
-
-        @pl.when(fresh_ref[i] != 0)
-        def _():
-            s_ref[...] = jnp.zeros(s_ref.shape, s_ref.dtype)
-
-        def token(t, carry):
-            at = lo + t
-            xt, dat, bt, ct = dtx_ref[at], da_ref[at], b_ref[at], c_ref[at]
-            acc = [jnp.zeros(xt.shape, jnp.float32) for _ in range(ACCUMULATORS)]
-            for ch in range(d_state):  # S = exp(dt A) S + (dt x) B^T; y = S C
-                s2 = s_ref[ch] * dat + bt[:, ch:ch + 1] * xt
-                s_ref[ch] = s2
-                acc[ch % ACCUMULATORS] = acc[ch % ACCUMULATORS] + ct[:, ch:ch + 1] * s2
-            o_ref[at] = sum(acc[1:], acc[0])
-            return carry
-
-        jax.lax.fori_loop(0, n, token, 0)
-        cp = pltpu.make_async_copy(s_ref, state_out.at[layer, slot], sem.at[0])
-        cp.start()
-        cp.wait()
+    row_pipeline.pipeline(*lists, state_in, o_ref, *rest, token)
 
 
 def rows_kernel(dtx, da, b, c, state, layer, rows: StateRows):
     """The same as :func:`rows_jnp` through the Pallas kernel: the operands
-    whole in VMEM (fetched once: their block does not move; the decay spread
-    over a head's lanes, as the kernel multiplies it), the state array in HBM
-    and updated in place (the result aliases it)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    whole in VMEM (the decay spread over a head's lanes, as the kernel
+    multiplies it), the state array in HBM, the fed rows' states through the
+    pipeline's buffers (``row_pipeline.advance_rows``)."""
+    from . import row_pipeline
 
     t_buf, h, p = dtx.shape
-    n_state = b.shape[2]
-    r = rows.n.shape[0]
-    need = (2 * (3 * t_buf * h * p + 2 * t_buf * h * n_state) + n_state * h * p) * 4
-    if need > VMEM_BUDGET_BYTES:
-        raise ValueError(f"the recurrence's kernel needs {need} bytes of VMEM for a buffer of "
-                         f"{t_buf} slots: over {VMEM_BUDGET_BYTES}")
-    whole = lambda *shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))  # noqa: E731
-    o, state = pl.pallas_call(
-        partial(_kernel, d_state=n_state),
-        out_shape=(jax.ShapeDtypeStruct((t_buf, h, p), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(r,),
-            in_specs=[whole(t_buf, h, p), whole(t_buf, h, p), whole(t_buf, h, n_state),
-                      whole(t_buf, h, n_state), pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=(whole(t_buf, h, p), pl.BlockSpec(memory_space=pl.ANY)),
-            scratch_shapes=[pltpu.VMEM((n_state, h, p), jnp.float32),
-                            pltpu.SemaphoreType.DMA((1,))]),
-        # ``state`` (operand 9, behind the five prefetched and the four
-        # blocked) is the second result
-        input_output_aliases={9: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",), vmem_limit_bytes=VMEM_BUDGET_BYTES),
-        name=KERNEL_NAME,
-    )(jnp.minimum(rows.lo, t_buf - 1), rows.n, rows.slot, rows.fresh.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), dtx,
-      jnp.broadcast_to(da[:, :, None], (t_buf, h, p)), b, c, state)
-    return o, state
+    return row_pipeline.advance_rows(
+        partial(_kernel, d_state=b.shape[2]),
+        (dtx, jnp.broadcast_to(da[:, :, None], (t_buf, h, p)), b, c), p, state, layer, rows,
+        name=KERNEL_NAME, vmem_budget=VMEM_BUDGET_BYTES)
 
 
 # jitted, with the layer a traced operand: the layers of a step program trace

@@ -629,6 +629,11 @@ class ServingBackend(StepBackend):
                 out_idx[i] = ti + n - 1
                 spans.append((ti, ti + n))
                 ti += n
+            if self.state_slots and len(set(state_slot[:len(entries)].tolist())) < len(entries):
+                # the recurrence kernels' pipeline reads a row's state before
+                # the rows ahead of it are written back (models/row_pipeline.py)
+                raise ValueError("two StepEntry rows name one state_slot: a slot is one "
+                                 "session's, a session one row of a step")
             self._compiled_shapes.add(("ragged", t_buf, s_rows, self.pages_per_seq))
         with contextlib.ExitStack() as held:
             # dispatch opens before the lock is taken, so a wait for it

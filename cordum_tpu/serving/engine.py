@@ -201,11 +201,14 @@ class ServingStats:
     kda_chunk_tokens: int = 0
     # what a family's program counted of its own state arrays
     # (``ModelSpec.count_aux``), a state layer's, summed over steps: rows
-    # that advanced a state, tokens through the recurrence, and rows that
-    # started from zeros (a session's first chunk, whatever its slot held)
+    # that advanced a state, tokens through the recurrence, rows that
+    # started from zeros (a session's first chunk, whatever its slot held),
+    # and rows whose state the kernel's pipeline read ahead of their turn
+    # (carried rows behind the step's first fed row: ``models/row_pipeline.py``)
     state_rows_advanced: int = 0
     state_tokens_scanned: int = 0
     state_rows_fresh: int = 0
+    state_rows_prefetched: int = 0
     # stream packets the step loop handed to the sinks (a session's new
     # tokens of one step; replays of a carried prefix are not among them),
     # and those of them published with the NEXT step already on the device
@@ -1639,11 +1642,12 @@ class ServingEngine:
         if counters:
             # what the model family's program counted this step, named by
             # the family (``ModelSpec.count_aux``): the expert layer's four,
-            # a state family's three
+            # a state family's four
             for name, n in counters.items():
                 setattr(self.stats, name, getattr(self.stats, name) + n)
         if "state_rows_fresh" in counters:
             attrs["state_fresh"] = str(counters["state_rows_fresh"])
+            attrs["state_prefetched"] = str(counters["state_rows_prefetched"])
             attrs["state_kernel"] = self.backend.state_kernel or "none"
         if "moe_assignments_here" in counters:
             attrs["moe_here"] = str(counters["moe_assignments_here"])

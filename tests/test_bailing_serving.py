@@ -221,32 +221,105 @@ def test_a_rows_state_is_the_same_however_its_tokens_are_split_over_steps(split)
         np.testing.assert_allclose(np.asarray(s), np.asarray(s64), rtol=1e-5, atol=1e-7)
 
 
-def test_the_kernel_is_the_recurrence_of_the_jnp_form():
-    """The Pallas kernel (interpreted here; lowered for the TPU on the chip)
-    against ``rows_jnp``: decode rows and chunks in one step, a fresh row, a
-    row that feeds nothing, the padding behind them; it never writes a slot
-    no row names."""
-    from jax.experimental.pallas import tpu as pltpu
+#: the row tables the kernel's pipeline can get wrong, over five table rows and
+#: the padding row, seven slots, 24 buffer slots: a fed row is (table row,
+#: tokens, slot, first position)
+ROW_TABLES = {
+    "decode_rows_and_chunks": [(0, 7, 3, 0), (1, 1, 5, 11), (3, 9, 2, 4), (4, 1, 6, 0)],
+    "one_fed_row": [(2, 3, 4, 9)],
+    "empty_rows_before_between_behind": [(1, 2, 3, 5), (3, 1, 6, 2)],
+    "fresh_between_carried": [(0, 1, 1, 8), (1, 4, 2, 0), (2, 1, 3, 3)],
+    "last_table_row_fed": [(0, 1, 1, 3), (4, 5, 2, 6)],
+    "every_row_fed": [(0, 1, 1, 4), (1, 2, 2, 0), (2, 1, 3, 7), (3, 3, 4, 2), (4, 1, 5, 9)],
+    "every_row_fresh": [(0, 2, 6, 0), (1, 1, 5, 0), (2, 3, 4, 0), (3, 1, 3, 0), (4, 1, 2, 0)],
+    "no_row_fed": [],
+    "chunk_between_decode_rows": [(0, 1, 1, 20), (1, 1, 2, 31), (2, 17, 3, 5), (3, 1, 4, 12),
+                                  (4, 1, 5, 2)],
+}
+T_BUF, S_ROWS, SLOTS = 24, 5, 7
 
-    t, h, dk, dv, s_rows, slots = 24, 8, 16, 128, 5, 7
-    q, k, kb, eg, v = kda_inputs(t, h, dk, dv, seed=1)
-    state = jnp.asarray(np.random.default_rng(2).standard_normal((2, slots, dk, h, dv)), jnp.float32)
+
+def rows_of(plan, t=T_BUF, s_rows=S_ROWS):
+    """``StateRows`` of a step and its live buffer slots: ``plan`` = (table
+    row, tokens, slot, first position) a fed row."""
     token_seq, positions = np.full(t, s_rows, np.int32), np.zeros(t, np.int32)
     slot_of = np.zeros(s_rows + 1, np.int32)
     at = 0
-    for row, n, slot, start in [(0, 7, 3, 0), (1, 1, 5, 11), (3, 9, 2, 4), (4, 1, 6, 0)]:
+    for row, n, slot, start in plan:
         token_seq[at:at + n], positions[at:at + n], slot_of[row] = row, start + np.arange(n), slot
         at += n
-    rows = kda.state_rows(jnp.asarray(positions), jnp.asarray(token_seq), jnp.asarray(slot_of))
-    assert rows.n.tolist() == [7, 1, 0, 9, 1, 0] and rows.fresh.tolist()[:2] == [True, False]
-    o1, s1 = kda.rows_jnp(q, k, kb, eg, v, state, 1, rows)
+    return kda.state_rows(jnp.asarray(positions), jnp.asarray(token_seq), jnp.asarray(slot_of)), at
+
+
+def holds_the_jnp_form(jnp_form, kernel_form, operands, state, plan):
+    """``kernel_form`` (a recurrence's Pallas kernel, interpreted here; lowered
+    for the TPU on the chip) against ``jnp_form`` over the step ``plan``
+    describes: the same ``o`` and zeros behind the live slots, the same state
+    in every named slot, every other slot (the null slot and the other layer
+    among them) left bit for bit as it was."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, at = rows_of(plan)
+    assert int(rows.fed) == len(plan) and rows.work.tolist()[:len(plan)] == [p[0] for p in plan]
+    o1, s1 = jnp_form(*operands, state, 1, rows)
     with pltpu.force_tpu_interpret_mode():
-        o2, s2 = kda.rows_kernel(q, k, kb, eg, v, state, 1, rows)
+        o2, s2 = kernel_form(*operands, state, 1, rows)
     np.testing.assert_allclose(np.asarray(o2), np.asarray(o1), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(s2[:, 1:]), np.asarray(s1[:, 1:]), rtol=1e-5, atol=1e-6)
     assert (np.asarray(o2[at:]) == 0).all()  # buffer slots no row feeds read zeros
     untouched = np.asarray(s2) == np.asarray(state)
-    assert untouched[0].all() and untouched[1, [0, 1, 4]].all()  # the other layer, unnamed slots
+    unnamed = sorted(set(range(state.shape[1])) - {p[2] for p in plan})
+    assert untouched[0].all() and untouched[1, unnamed].all()
+    changed = [p[2] for p in plan if not untouched[1, p[2]].all()]
+    assert changed == [p[2] for p in plan]  # and every fed row's slot was written
+
+
+@pytest.mark.parametrize("table", sorted(ROW_TABLES))
+def test_the_kernel_is_the_recurrence_of_the_jnp_form(table):
+    """The Pallas kernel against ``rows_jnp`` over the row tables its pipeline
+    can get wrong (``ROW_TABLES``): decode rows and chunks in one step, fresh
+    rows, rows that feed nothing before, between and behind the fed ones, more
+    fed rows than the pipeline has buffers, none at all; it never writes a
+    slot no row names."""
+    h, dk, dv = 8, 16, 128
+    state = jnp.asarray(np.random.default_rng(2).standard_normal((2, SLOTS, dk, h, dv)),
+                        jnp.float32)
+    holds_the_jnp_form(kda.rows_jnp, kda.rows_kernel, kda_inputs(T_BUF, h, dk, dv, seed=1), state,
+                       ROW_TABLES[table])
+
+
+def test_the_first_table_describes_what_it_says():
+    rows, at = rows_of(ROW_TABLES["decode_rows_and_chunks"])
+    assert rows.n.tolist() == [7, 1, 0, 9, 1, 0] and rows.fresh.tolist()[:2] == [True, False]
+    assert rows.work.tolist() == [0, 1, 3, 4, 0, 0] and int(rows.fed) == 4 and at == 18
+    # carried fed rows behind the first fed row: rows 1 and 3 (row 4 is fresh)
+    assert int(kda.rows_prefetched(rows)) == 2
+    assert int(kda.rows_prefetched(rows_of([])[0])) == 0
+    assert int(kda.rows_prefetched(rows_of(ROW_TABLES["one_fed_row"])[0])) == 0
+    assert int(kda.rows_prefetched(rows_of(ROW_TABLES["every_row_fed"])[0])) == 3
+
+
+@pytest.mark.parametrize("wanted,fit,depth", [(2, 3, 2), (2, 2, 2), (3, 7, 3), (3, 2, 2), (4, 4, 4)])
+def test_the_pipeline_takes_the_buffers_its_budget_holds(wanted, fit, depth, monkeypatch):
+    """How many state buffers the kernel's pipeline gets is read from the
+    state's bytes and the kernel's VMEM budget (at most ``BUFFERS``), the same
+    results whatever it comes to; a budget that holds fewer than two is
+    refused."""
+    from cordum_tpu.models import row_pipeline
+
+    h, dk, dv = 8, 16, 128
+    operands = kda_inputs(T_BUF, h, dk, dv, seed=3)
+    held = 2 * 4 * T_BUF * h * (4 * dk + 2 * dv)  # five operands and the output, twice
+    monkeypatch.setattr(row_pipeline, "BUFFERS", wanted)
+    monkeypatch.setattr(kda, "VMEM_BUDGET_BYTES", held + fit * 4 * dk * h * dv + 100)
+    assert row_pipeline.depth_for(4 * dk * h * dv, held, kda.VMEM_BUDGET_BYTES) == depth
+    state = jnp.asarray(np.random.default_rng(4).standard_normal((2, SLOTS, dk, h, dv)),
+                        jnp.float32)
+    holds_the_jnp_form(kda.rows_jnp, kda.rows_kernel, operands, state,
+                       ROW_TABLES["chunk_between_decode_rows"])
+    monkeypatch.setattr(kda, "VMEM_BUDGET_BYTES", held + 4 * dk * h * dv + 100)
+    with pytest.raises(ValueError, match="two row states"):
+        kda.rows_kernel(*operands, state, 1, rows_of(ROW_TABLES["one_fed_row"])[0])
 
 
 def test_a_reused_slot_starts_from_zero():
@@ -370,6 +443,17 @@ def test_a_step_entry_without_a_slot_is_refused(family):
         be.step([entry(be, 0, [1, 2, 3], 0, slot=0)])
     with pytest.raises(ValueError, match="state_slot"):
         be.step([entry(be, 0, [1, 2, 3], 0, slot=5)])
+
+
+@pytest.mark.parametrize("family", STATE_FAMILIES)
+def test_a_step_that_names_a_slot_twice_is_refused(family):
+    """A slot is one session's and a session one row of a step: the
+    recurrence kernels' pipeline reads a row's state before the rows ahead of
+    it are written back (``models/row_pipeline.py``)."""
+    cfg, params = state_family(family)
+    be = backend_for(cfg, params)
+    with pytest.raises(ValueError, match="one state_slot"):
+        be.step([entry(be, 0, [1, 2, 3], 0, slot=2), entry(be, 1, [4], 0, slot=2)])
 
 
 @pytest.mark.parametrize("family", STATE_FAMILIES)

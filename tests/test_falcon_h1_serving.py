@@ -23,7 +23,8 @@ from benchmarks.families import falcon_h1_reference as ref_mod
 from cordum_tpu.models import falcon_h1, kda, llama, ssd
 from cordum_tpu.serving.backend import ServingBackend, StepEntry
 from cordum_tpu.serving.engine import GenRequest, ServingEngine
-from tests.test_bailing_serving import entry, run_blocking
+from tests.test_bailing_serving import (ROW_TABLES, SLOTS, T_BUF, entry, holds_the_jnp_form,
+                                        rows_of, run_blocking)
 
 # float32 program against the float32 "highest" reference, logits of spread
 # about 0.5 here: both round at 1e-7 relative, the recurrence and the softmax
@@ -248,17 +249,6 @@ def ssd_inputs(t, h, p, n, seed=0):
             jnp.asarray(rng.standard_normal((t, h, n)), jnp.float32))
 
 
-def rows_of(plan, t, s_rows):
-    """``StateRows`` of a step: ``plan`` = (table row, tokens, slot, start)."""
-    token_seq, positions = np.full(t, s_rows, np.int32), np.zeros(t, np.int32)
-    slot_of = np.zeros(s_rows + 1, np.int32)
-    at = 0
-    for row, n, slot, start in plan:
-        token_seq[at:at + n], positions[at:at + n], slot_of[row] = row, start + np.arange(n), slot
-        at += n
-    return kda.state_rows(jnp.asarray(positions), jnp.asarray(token_seq), jnp.asarray(slot_of)), at
-
-
 @pytest.mark.parametrize("split", [1, 7, 16, 17, 64])
 def test_a_rows_state_and_tail_are_the_same_however_its_tokens_are_split_over_steps(split):
     """64 tokens of one row fed ``split`` at a time through the whole mixer
@@ -285,26 +275,17 @@ def test_a_rows_state_and_tail_are_the_same_however_its_tokens_are_split_over_st
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-6)
 
 
-def test_the_kernel_is_the_recurrence_of_the_jnp_form():
-    """The Pallas kernel (interpreted here; lowered for the TPU on the chip)
-    against ``rows_jnp``: decode rows and chunks in one step, a fresh row, a
-    row that feeds nothing, the padding behind them; it never writes a slot
-    no row names."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    t, h, p, n, s_rows, slots = 24, 8, 128, 16, 5, 7
-    dtx, da, b, c = ssd_inputs(t, h, p, n, seed=1)
-    state = jnp.asarray(np.random.default_rng(2).standard_normal((2, slots, n, h, p)), jnp.float32)
-    rows, at = rows_of([(0, 7, 3, 0), (1, 1, 5, 11), (3, 9, 2, 4), (4, 1, 6, 0)], t, s_rows)
-    assert rows.n.tolist() == [7, 1, 0, 9, 1, 0] and rows.fresh.tolist()[:2] == [True, False]
-    o1, s1 = ssd.rows_jnp(dtx, da, b, c, state, 1, rows)
-    with pltpu.force_tpu_interpret_mode():
-        o2, s2 = ssd.rows_kernel(dtx, da, b, c, state, 1, rows)
-    np.testing.assert_allclose(np.asarray(o2), np.asarray(o1), rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(s2[:, 1:]), np.asarray(s1[:, 1:]), rtol=1e-5, atol=1e-6)
-    assert (np.asarray(o2[at:]) == 0).all()  # buffer slots no row feeds read zeros
-    untouched = np.asarray(s2) == np.asarray(state)
-    assert untouched[0].all() and untouched[1, [0, 1, 4]].all()  # the other layer, unnamed slots
+@pytest.mark.parametrize("table", sorted(ROW_TABLES))
+def test_the_kernel_is_the_recurrence_of_the_jnp_form(table):
+    """The Pallas kernel against ``rows_jnp`` over the row tables its pipeline
+    can get wrong (``tests/test_bailing_serving.py``'s ``ROW_TABLES``, the
+    same pipeline under another token body): decode rows and chunks in one
+    step, fresh rows, rows that feed nothing, more fed rows than buffers,
+    none at all; it never writes a slot no row names."""
+    h, p, n = 8, 128, 16
+    state = jnp.asarray(np.random.default_rng(2).standard_normal((2, SLOTS, n, h, p)), jnp.float32)
+    holds_the_jnp_form(ssd.rows_jnp, ssd.rows_kernel, ssd_inputs(T_BUF, h, p, n, seed=1), state,
+                       ROW_TABLES[table])
 
 
 def test_the_recurrence_is_the_references_scan():
@@ -410,10 +391,13 @@ def test_eighty_sessions_turn_over_sixteen_slots_without_a_stale_state():
 
 async def test_the_step_span_and_the_startup_record_say_how_the_recurrence_engages(monkeypatch):
     """``StepBackend.state_kernel``, the ``step`` span's ``state_kernel`` /
-    ``state_fresh`` / ``state_rows`` / ``kda_tokens`` and the
-    ``startup.ssd_kernel`` phase: ``none`` on the CPU (the arenas' platform
-    holds the ``jax.numpy`` form), the kernel's name under a backend that
-    reports it (as one on the TPU does); no expert layer's attribute."""
+    ``state_fresh`` / ``state_prefetched`` / ``state_rows`` / ``kda_tokens``
+    and the ``startup.ssd_kernel`` phase: ``none`` on the CPU (the arenas'
+    platform holds the ``jax.numpy`` form), the kernel's name under a backend
+    that reports it (as one on the TPU does); no expert layer's attribute.
+    ``state_prefetched`` counts the rows the kernel's pipeline reads ahead:
+    none while one session decodes alone, the carried rows behind the step's
+    first fed row once two decode side by side."""
     from cordum_tpu.infra.bus import LoopbackBus
     from cordum_tpu.infra.metrics import Metrics
     from cordum_tpu.obs import startup
@@ -451,11 +435,19 @@ async def test_the_step_span_and_the_startup_record_say_how_the_recurrence_engag
                                                            "startup.walk_kernel")]
     be.state_kernel = ssd.KERNEL_NAME  # as a backend whose arenas live on the TPU reports
     await generate("b")
+    assert eng.stats.state_rows_prefetched == 0  # a step of one fed row reads nothing ahead
+    await asyncio.gather(generate("c"), generate("d"))
     await eng.stop()
     await bus.drain()
     steps = sorted((s for s in spans if s.name == "step"), key=lambda s: s.start_us)
-    assert steps and all({"state_kernel", "state_fresh", "state_rows", "kda_tokens"} <= set(s.attrs)
+    assert steps and all({"state_kernel", "state_fresh", "state_prefetched", "state_rows",
+                          "kda_tokens"} <= set(s.attrs)
                          and "moe_here" not in s.attrs for s in steps)
     assert {s.attrs["state_kernel"] for s in steps} == {"none", ssd.KERNEL_NAME}
-    assert sum(int(s.attrs["state_fresh"]) for s in steps) == 2 == eng.stats.state_rows_fresh
+    assert sum(int(s.attrs["state_fresh"]) for s in steps) == 4 == eng.stats.state_rows_fresh
+    ahead = [int(s.attrs["state_prefetched"]) for s in steps]
+    assert sum(ahead) == eng.stats.state_rows_prefetched > 0
+    for s, n in zip(steps, ahead):  # the carried rows, less the first fed row where it is one
+        carried = int(s.attrs["state_rows"]) - int(s.attrs["state_fresh"])
+        assert max(carried - 1, 0) <= n <= carried and n < int(s.attrs["state_rows"])
     assert ssd.holds_kernel("tpu") and not ssd.holds_kernel("cpu")

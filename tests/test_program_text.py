@@ -1,16 +1,22 @@
 """The step programs of the families the benchmark had before ISSUE 42 trace to
-the jaxpr text they had (``bailing``'s: the text on the tree of PR 41; the
-sixth family, ``falcon_h1``, touches none of them).  For the four the
-benchmark had before ISSUE 40: a state slot beside the page tables, a
-direct query matrix and a head-wise gate in ``axk1.mla_sublayer`` are
-additions that a program which does not ask for them never sees.  The hashes
-are of the text at tiny sizes (addresses of function objects cut out); a
-change that means to alter one of these programs updates its hash, and says
-so: ``llama``'s is the text on the tree of PR 39 still; ``afmoe``'s,
-``axk1``'s and ``longcat``'s changed with ISSUE 41, whose expert layer hands
-BOTH forms of the grouped products to the lowering (``ragged_dot`` and the
-``expert_mlp`` kernel with its work list, ``models/expert_mlp.py``), so both
-are in the trace; a program without an expert layer holds neither."""
+the jaxpr text they had (the sixth family, ``falcon_h1``, touches none of
+them).  For the four the benchmark had before ISSUE 40: a state slot beside
+the page tables, a direct query matrix and a head-wise gate in
+``axk1.mla_sublayer`` are additions that a program which does not ask for
+them never sees.  The hashes are of the text at tiny sizes (addresses of
+function objects cut out); a change that means to alter one of these programs
+updates its hash, and says so: ``llama``'s is the text on the tree of PR 39
+still; ``afmoe``'s, ``axk1``'s and ``longcat``'s changed with ISSUE 41, whose
+expert layer hands BOTH forms of the grouped products to the lowering
+(``ragged_dot`` and the ``expert_mlp`` kernel with its work list,
+``models/expert_mlp.py``), so both are in the trace; a program without an
+expert layer holds neither.  ``bailing``'s is the text on the tree of ISSUE
+43: its ``kda_step`` kernel is the token body under the row pipeline
+(``models/row_pipeline.py``: a work list of the fed rows in
+``kda.state_rows``, two state buffers, a semaphore a buffer and direction),
+and ``kda.recurrence`` is jitted with the layer a traced operand, as
+``ssd.recurrence`` is, so the KDA layers trace and lower one kernel; the four
+others lower no recurrence kernel and keep theirs."""
 import hashlib
 import re
 
@@ -24,9 +30,9 @@ from cordum_tpu.serving.modelspec import spec_for
 
 PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
 
-#: sha256 of the jaxpr text: PR 39's tree (llama), PR 41's (the four sparse families)
+#: sha256 of the jaxpr text: PR 39's tree (llama), PR 41's (afmoe, axk1, longcat), PR 43's (bailing)
 AS_IT_WAS = {
-    "bailing": "6551ba3ad256442012da20d044e23b1f8fb8f683eea087f0e6467d7adefce633",
+    "bailing": "61b1448bb9d1572be43f70b2e86c68325cb869b6ebc67b458d2b7a3f8841a919",
     "llama": "b6f5236ed6c4e3250c080f30f92c7370102c8a4209ac229fc24fc5988ec38828",
     "afmoe": "ac8a4f1e447b7059c93d0652c4643e930dc91dcbe0fbbce52d86e0778f141ee7",
     "axk1": "dc5522dfaaf71aa57a0142aa9378bcda99ddab191aedeb6ab295b221287189b2",
