@@ -39,9 +39,11 @@ value product, a masked key scores ``-1e30``, ``scale`` as given.
 
 The module imports Pallas, a second or more of imports (1.45 s on the
 chip's host, PERF.md section 6, PR 39), so nothing imports it at its own
-import: ``llama.paged_attention`` does where it traces the latent form and
-a backend where it holds a latent arena, and a program over K and V by head
-never pays for it.  The loops over a block's pages are ``fori_loop``s of
+import: ``llama.paged_attention`` does where it traces a form that has a
+kernel and a backend where it holds such an arena (``models/head_walk.py``,
+the kernel for K and V by head, imports this module's shared rules:
+:func:`tile_trips`, :func:`page_loop`, the VMEM budget).  The loops over a
+block's pages are ``fori_loop``s of
 :data:`PAGE_UNROLL` copies a pass, not Python's over all 32: unrolled in
 Python they made the step program's trace 1.3 s and its executable's load
 1.1 s longer on that host, and ``setup_s`` is judged.
@@ -93,6 +95,19 @@ def tile_trips(newest: Any, live: Any, block_tokens: int) -> Any:
     return xp.where(live, newest // block_tokens + 1, 0)
 
 
+def page_loop(block_pages: int, copy: Any) -> None:
+    """``copy(p)`` for every page of a block, inside a kernel: a loop in the
+    kernel (the step program's trace and the executable do not grow with the
+    block) of :data:`PAGE_UNROLL` pages a pass."""
+    k = math.gcd(block_pages, PAGE_UNROLL)
+
+    def some(c, _):
+        for u in range(k):
+            copy(c * k + u)
+
+    jax.lax.fori_loop(0, block_pages // k, some, None)
+
+
 def vmem_bytes(rows: int, width: int, v_dim: int, block_tokens: int, itemsize: int) -> int:
     """What the kernel keeps in VMEM: the double-buffered block, the blocked
     queries and output (two buffers each), the float32 state (a ``[rows,
@@ -124,17 +139,7 @@ def _kernel(at_ref, trips_ref, src_ref, pos_ref, tab_ref, q_ref, arena_ref, _, o
     def page(p):  # where page p of a block lands in a half
         return pl.ds(pl.multiple_of(p * ps, ps), ps)
 
-    def pages(copy):
-        # ``copy(p)`` for every page of a block: a loop in the kernel (the
-        # step program's trace and the executable do not grow with the
-        # block) of PAGE_UNROLL pages a pass
-        k = math.gcd(bp, PAGE_UNROLL)
-
-        def some(c, _):
-            for u in range(k):
-                copy(c * k + u)
-
-        jax.lax.fori_loop(0, bp // k, some, None)
+    pages = partial(page_loop, bp)
 
     def start(tile, j, half):
         # block j of a tile: its pages, one copy each, into one half

@@ -388,13 +388,16 @@ def scatter_kv_pages(
 
 #: token positions the least block of :func:`paged_attention`'s walk aims
 #: at, and the least number of blocks a page table is cut into.  Chosen on
-#: the chip (PERF.md section 6, PR 25) for K and V by head, where a block's
-#: bytes outweigh the walk's state two to eight times: a block costs about
-#: 7 us beyond its bytes, so the walk's granularity (the last block is half
-#: empty on average) weighs more than the count of blocks, down to 64
-#: positions at T = 32.  That reasoning holds while a trip's gather is the
-#: larger part of what it moves; :func:`attn_block_pages` grows the block
-#: where it is not
+#: the chip (PERF.md section 6, PR 25) for K and V by head under the
+#: ``jax.numpy`` walk (``walk_jnp``), where a block's bytes outweigh the
+#: walk's state two to eight times: a block of THAT walk costs about 7 us
+#: beyond its bytes, so the walk's granularity (the last block is half empty
+#: on average) weighs more than the count of blocks, down to 64 positions at
+#: T = 32.  That reasoning holds while a trip's gather is the larger part of
+#: what it moves; :func:`attn_block_pages` grows the block where it is not.
+#: The walks' kernels (``models/head_walk.py``, ``models/latent_walk.py``)
+#: keep a trip's state in VMEM and walk the same blocks: the block stays the
+#: unit both the program and the host count in
 ATTN_BLOCK_TOKENS = 128
 ATTN_MIN_BLOCKS = 8
 #: a trip reads a block of keys and rewrites the tile's float32 accumulator
@@ -564,8 +567,7 @@ def paged_attention(
       from ``positions``;
     * **a group's walk ends at its longest tile** (:func:`walk_blocks`) — a
       traced trip count on static shapes: one program, and blocks past it
-      are never read (the latent form's kernel ends each TILE at its own:
-      below).
+      are never read (the walks' kernels end each TILE at its own: below).
 
     A masked key scores ``-1e30``, not ``-inf``.  A slot whose first walked
     blocks hold none of its visible keys (under a window the TILE starts the
@@ -600,23 +602,28 @@ def paged_attention(
     softmax scale where it is not ``1 / sqrt(hd)``.  With a V arena and no
     scale the program is the one it was.
 
-    **Two walks behind the latent form** (``models/latent_walk.py``): where
-    the program is LOWERED for the TPU, a group's block walk is one Pallas
-    kernel — a tile's queries, the scores, the mask and the float32 softmax
-    state live in VMEM for the tile's whole walk, a block's pages are copied
-    page by page from the arena (which stays in HBM) into a double-buffered
-    VMEM block, and **each tile ends at its OWN newest block**
-    (``latent_walk.tile_trips``), where the ``jax.numpy`` walk drags every
-    tile of a group to the longest.  Everything round it is shared: the
-    tiles, their order, the groups, the loop over the groups that hold a live
-    tile, the scatter back to buffer slots, the block as the counted unit.
-    ``jax.lax.platform_dependent`` chooses, by the arena's form (``v_pages is
-    None``, no window) and the lowering platform alone: every other platform
-    (the CPU's tests and float32 references) and every other form (K and V
-    by head, the window's ring) keeps the ``jax.numpy`` walk below, the
-    latter byte for byte the program it was.  Same numerics in both:
-    operands in the arena's dtype, float32 scores and state, probabilities
-    cast to the arena's dtype, a masked key ``-1e30``."""
+    **Two walks behind either form of arena** (``models/latent_walk.py``,
+    ``models/head_walk.py``): where the program is LOWERED for the TPU, a
+    group's block walk is one Pallas kernel — a tile's queries, the scores,
+    the mask and the float32 softmax state live in VMEM for the tile's whole
+    walk, a block's pages are copied page by page from the arena (both
+    arenas by head; they stay in HBM) into a VMEM block of a few buffers, and
+    **each tile ends at its OWN newest block** (``latent_walk.tile_trips``),
+    where the ``jax.numpy`` walk drags every tile of a group to the longest.
+    Everything round it is shared: the tiles, their order, the groups, the
+    loop over the groups that hold a live tile, the gather of the tiles'
+    queries, the scatter back to buffer slots, the block as the counted unit.
+    ``jax.lax.platform_dependent`` chooses, by what the code can observe
+    alone: the arena's form (one latent array, ``v_pages is None``: the
+    latent kernel; K and V by head: the by-head kernel; either with no
+    window), the lowering platform, and for K and V by head that the program
+    is not partitioned over a mesh (``head_walk.mesh_devices`` of the traced
+    arena: the tensor-parallel gang shards the arenas by head, and a Pallas
+    call is one device's).  Every other platform (the CPU's tests and float32
+    references), the window's ring and a program over a mesh keep the
+    ``jax.numpy`` walk below, byte for byte the program it was.  Same numerics
+    in all: operands in the arena's dtype, float32 scores and state,
+    probabilities cast to the arena's dtype, a masked key ``-1e30``."""
     t, h, hd = q.shape
     ps = k_pages.shape[2]
     kvh = k_pages.shape[3] if k_pages.ndim == 5 else 1
@@ -657,8 +664,15 @@ def paged_attention(
         lane = jnp.arange(bp, dtype=itype)
     offs = jnp.arange(bt, dtype=itype)
     latent = v_pages is None and window is None
-    if latent:  # imported here: Pallas costs a second that the other forms' programs never pay
+    if latent:  # imported here: Pallas costs a second that a program with neither kernel never pays
         from . import latent_walk
+    # K and V by head: the kernel where a TPU's lowering of THIS program would hold it
+    by_head = False
+    if v_pages is not None and window is None:
+        from . import head_walk
+
+        by_head = head_walk.holds_kernel(head_walk.PLATFORM, True, None,
+                                         head_walk.mesh_devices(k_pages))
     # each tile's queries [tiles, kvh, slots x rep, hd] and their positions
     qt = q.reshape(t, kvh, rep, hd)[slots].transpose(0, 2, 1, 3, 4).reshape(
         n_tiles, kvh, w * rep, hd)
@@ -722,11 +736,27 @@ def paged_attention(
             out.reshape(n_tiles, w * h, vd), lo, block_pages=bp, v_dim=vd, scale=scale,
         ).reshape(out.shape)
 
+    def walk_heads(lo, out):
+        """The same group through ``head_walk``'s kernel: every tile to its
+        OWN end, its queries read from the step's whole array in place; the
+        group's outputs come back a K/V head's rows together and are laid
+        out by slot as ``walk_jnp`` lays its own."""
+        tab_c, new_c, live_c, pslot_c = (
+            jax.lax.dynamic_slice_in_dim(x, lo, g) for x in (tab, newest, live, pslot))
+        done = head_walk.walk_group(
+            qt, pslot_c, k_pages, v_pages, layer, tab_c, head_walk.tile_trips(new_c, live_c, bt),
+            lo, block_pages=bp, scale=scale).reshape(g, kvh, w, rep, vd)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, done.transpose(0, 2, 1, 3, 4).reshape(g * w, h, vd), lo * w, axis=0)
+
     def group(i, out):
         lo = i * g
         if latent:  # two walks, chosen where the program is lowered
             return jax.lax.platform_dependent(
                 lo, out, default=walk_jnp, **{latent_walk.PLATFORM: walk_kernel})
+        if by_head:
+            return jax.lax.platform_dependent(
+                lo, out, default=walk_jnp, **{head_walk.PLATFORM: walk_heads})
         return walk_jnp(lo, out)
 
     # the groups that hold a live tile (they come first), each to its own end

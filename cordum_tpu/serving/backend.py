@@ -152,10 +152,13 @@ class StepBackend:
     kv_positional: bool = True
     state_slots: int = 0
     state_bytes: int = 0
-    # which walk the step program holds: the kernel's name where its
-    # attention walks the pages with one ("latent_walk": a latent arena in a
-    # program lowered for the TPU, ``models/latent_walk.py``), "" where the
-    # walk is ``jax.numpy``'s.  Known once the state is on its device
+    # which walk the step program holds over its whole-row kind of page: the
+    # kernel's name where its attention walks them with one, in a program
+    # lowered for the TPU ("latent_walk": a latent arena,
+    # ``models/latent_walk.py``; "head_walk": K and V by head in a program
+    # that is one device's, ``models/head_walk.py``), "" where the walk is
+    # ``jax.numpy``'s, as a window kind's rings always are.  Known once the
+    # state is on its device
     walk_kernel: str = ""
     # which form the expert layer's grouped products take: the kernel's name
     # ("expert_mlp": a model with an expert layer whose program is lowered
@@ -465,16 +468,26 @@ class ServingBackend(StepBackend):
                     self.state_bytes = made["state_bytes"] // self.state_slots
             self._params = params
             # the walk the program will hold is chosen where it is lowered:
-            # for the platform the arenas live on, by the arena's form
-            if len(self.spec.arenas[0]) == 1 and self.window is None:  # one latent array a layer
-                # the kernel's module imports Pallas, a second or more that
-                # only this form pays: stamped, so that the record says so
+            # for the platform the arenas live on, by the whole-row kind's
+            # form (one latent array a layer, or K and V by head; a window
+            # kind's rings keep the ``jax.numpy`` walk)
+            latent = len(self.spec.arenas[0]) == 1 and self.window is None
+            if latent or self.kv_by_head:
+                # a kernel's module imports Pallas, a second or more: stamped,
+                # so that the record says so
                 with startup.phase("startup.walk_kernel") as walk:
-                    from ..models import latent_walk
-
                     platform = next(iter(self._arenas[0].devices())).platform
-                    if latent_walk.holds_kernel(platform, latent=True):
-                        self.walk_kernel = latent_walk.KERNEL_NAME
+                    if latent:
+                        from ..models import latent_walk
+
+                        if latent_walk.holds_kernel(platform, latent=True):
+                            self.walk_kernel = latent_walk.KERNEL_NAME
+                    else:
+                        from ..models import head_walk
+
+                        if head_walk.holds_kernel(platform, True, None,
+                                                  head_walk.mesh_devices(self._arenas[0])):
+                            self.walk_kernel = head_walk.KERNEL_NAME
                     walk["walk_kernel"] = self.walk_kernel or "none"
             if getattr(self.cfg, "experts_held", 0):  # the model has an expert layer
                 # as above: the products' form is the lowering platform's, by
@@ -697,10 +710,12 @@ class ServingBackend(StepBackend):
         """The step's walk as ``llama.paged_attention`` makes it, counted on
         the host from ``spans`` (int [rows, 2]: each fed row's buffer slots)
         and the packed ``positions``: the rows cut into tiles, the tiles in
-        the program's order, each group of them walked by the program's rule
-        (``llama.walk_blocks``: every tile to its group's longest) — or,
-        where the program holds the walk's kernel (``walk_kernel``), each
-        tile to its OWN end (``latent_walk.tile_trips``)."""
+        the program's order, and a KIND of page after the other by the rule
+        of that kind's walk: each tile to its OWN end
+        (``latent_walk.tile_trips``) where the kind's walk is a kernel (the
+        whole-row kind's under ``walk_kernel``), each group of tiles to its
+        longest (``llama.walk_blocks``) where it is ``jax.numpy``'s (the
+        window kind's rings always)."""
         from ..models import llama
 
         w, g = self._tile_slots, llama.ATTN_GROUP_TILES
@@ -715,7 +730,7 @@ class ServingBackend(StepBackend):
             # a fed slot needs the blocks from its oldest visible key's to its own
             first = 0 if window is None else (fed - (window - 1)).clip(0) // bt
             live += int((fed // bt - first + 1).sum())
-            if self.walk_kernel:  # a program with it has no window kind
+            if self.walk_kernel and window is None:
                 from ..models import latent_walk
 
                 own = latent_walk.tile_trips(newest, np.ones(len(newest), bool), bt)

@@ -79,6 +79,14 @@ def holds_walk_kernel(text: str) -> bool:
                for line in text.splitlines())
 
 
+def holds_head_kernel(text: str) -> bool:
+    """Whether a program's text calls the by-head walk's kernel."""
+    from cordum_tpu.models import head_walk
+
+    return any("tpu_custom_call" in line and head_walk.KERNEL_NAME in line
+               for line in text.splitlines())
+
+
 def holds_expert_kernel(text: str) -> bool:
     """Whether a program's text calls the grouped expert products' kernel."""
     from cordum_tpu.models import expert_mlp
@@ -189,12 +197,14 @@ def test_the_latent_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
 
 @pytest.mark.parametrize("config", ["mistral-7b-v0.3", "internlm2-1.8b",
                                     "trinity-large-preview-ep8"])
-def test_a_by_head_program_holds_no_walk_kernel(config):
-    """K and V by head, and the window's rings, keep the ``jax.numpy`` walk
-    whatever the platform: the benchmark's three by-head programs, LOWERED
-    for the TPU at their cells' shapes (nothing compiled), call no kernel of
-    ``latent_walk``; the latent program lowered the same way does, and
-    lowered for the CPU does not."""
+def test_a_by_head_program_holds_the_by_head_walks_kernel(config):
+    """K and V by head with no window walk their pages with ``head_walk``'s
+    kernel where the program is lowered for the TPU (ISSUE 44), never with
+    the latent form's: the benchmark's three by-head programs, LOWERED at
+    their cells' shapes (nothing compiled); Trinity's holds it for its full
+    layers beside the ``jax.numpy`` walk of its rings.  Lowered for the CPU
+    they call no kernel; the latent program holds its own kernel on the TPU
+    and none on the CPU."""
     from benchmarks.harness import cells
     from cordum_tpu.serving.backend import ServingBackend
 
@@ -214,12 +224,14 @@ def test_a_by_head_program_holds_no_walk_kernel(config):
             lowering_platforms=(platform,)).as_text()
 
     text = lowered(config, "tpu")
-    assert not holds_walk_kernel(text)
+    assert holds_head_kernel(text) and not holds_walk_kernel(text)
+    assert not holds_head_kernel(lowered(config, "cpu"))
     # the grouped products' kernel: where there is an expert layer, for the TPU
     assert holds_expert_kernel(text) == (config == "trinity-large-preview-ep8")
     if config == "trinity-large-preview-ep8":  # once: the rule's other side
         latent = lowered("a.x-k1-ep16", "tpu")
         assert holds_walk_kernel(latent) and holds_expert_kernel(latent)
+        assert not holds_head_kernel(latent)
         on_cpu = lowered("a.x-k1-ep16", "cpu")
         assert not holds_walk_kernel(on_cpu) and not holds_expert_kernel(on_cpu)
 
@@ -332,7 +344,9 @@ def test_the_state_space_step_fits_one_chip_and_keeps_state_and_pages_in_place(o
     weights, 2.26 GB of K and V pages and 3.08 GB of state slots fit, donation
     is real for all four cache arrays, and the program lowered for the TPU
     holds ``ssd_step`` (ONE kernel body, called a layer: the recurrence is
-    jitted with the layer a traced operand) and no other kernel."""
+    jitted with the layer a traced operand), the by-head walk's kernel
+    (``head_walk``, ISSUE 44: both arenas stay in place under it) and no
+    other kernel."""
     from benchmarks.families import falcon_h1 as fam
     from benchmarks.harness import cells
     from cordum_tpu.models import ssd
@@ -367,6 +381,7 @@ def test_the_state_space_step_fits_one_chip_and_keeps_state_and_pages_in_place(o
     text = compiled.as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and ssd.KERNEL_NAME in ln]
     assert calls and not holds_walk_kernel(text) and not holds_expert_kernel(text)
+    assert holds_head_kernel(text)
     print(f"falcon_h1 step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
           f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB, "
           f"{len(calls)} ssd_step call lines")
@@ -427,6 +442,9 @@ def test_sharded_ragged_step_full_depth_on_four_chips(topo):
     assert per_device <= 0.95 * HBM_BYTES
     assert per_device < 0.5 * weight_bytes  # no device holds the whole model
     text = compiled.as_text()
+    # a program partitioned over a mesh keeps the ``jax.numpy`` walk: a
+    # Pallas call is one device's
+    assert not holds_head_kernel(text)
     # row-parallel wo and w_down end in a sum over tp, in every layer
     assert text.count(" all-reduce(") + text.count(" all-reduce-start(") >= 2 * cfg.n_layers
 

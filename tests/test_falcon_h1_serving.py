@@ -431,8 +431,10 @@ async def test_the_step_span_and_the_startup_record_say_how_the_recurrence_engag
     phase = [p for p in startup.phases() if p.name == "startup.ssd_kernel"]
     assert len(phase) == 1 and phase[0].attrs["ssd_kernel"] == "none"
     assert [p.name for p in startup.phases() if p.id == phase[0].parent] == ["startup.state"]
-    assert not [p for p in startup.phases() if p.name in ("startup.expert_kernel",
-                                                           "startup.walk_kernel")]
+    # K and V by head: the walk's kernel is asked for too, and the CPU holds none
+    walk = [p for p in startup.phases() if p.name == "startup.walk_kernel"]
+    assert len(walk) == 1 and walk[0].attrs["walk_kernel"] == "none"
+    assert not [p for p in startup.phases() if p.name == "startup.expert_kernel"]
     be.state_kernel = ssd.KERNEL_NAME  # as a backend whose arenas live on the TPU reports
     await generate("b")
     assert eng.stats.state_rows_prefetched == 0  # a step of one fed row reads nothing ahead

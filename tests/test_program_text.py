@@ -5,8 +5,13 @@ the page tables, a direct query matrix and a head-wise gate in
 ``axk1.mla_sublayer`` are additions that a program which does not ask for
 them never sees.  The hashes are of the text at tiny sizes (addresses of
 function objects cut out); a change that means to alter one of these programs
-updates its hash, and says so: ``llama``'s is the text on the tree of PR 39
-still; ``afmoe``'s, ``axk1``'s and ``longcat``'s changed with ISSUE 41, whose
+updates its hash, and says so: ``llama``'s and ``afmoe``'s changed ON
+PURPOSE with ISSUE 44, whose ``paged_attention`` hands BOTH walks over K and V
+by head to the lowering (``walk_jnp`` and the ``head_walk`` kernel,
+``models/head_walk.py``: ``afmoe``'s full layers; its window layers' rings
+keep ``walk_jnp`` alone), so both are in the trace; the three latent programs
+hold ``latent_walk`` as they did and their text is the string it was.
+``axk1``'s and ``longcat``'s are ISSUE 41's, whose
 expert layer hands BOTH forms of the grouped products to the lowering
 (``ragged_dot`` and the ``expert_mlp`` kernel with its work list,
 ``models/expert_mlp.py``), so both are in the trace; a program without an
@@ -30,11 +35,11 @@ from cordum_tpu.serving.modelspec import spec_for
 
 PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
 
-#: sha256 of the jaxpr text: PR 39's tree (llama), PR 41's (afmoe, axk1, longcat), PR 43's (bailing)
+#: sha256 of the jaxpr text: PR 44's tree (llama, afmoe), PR 41's (axk1, longcat), PR 43's (bailing)
 AS_IT_WAS = {
     "bailing": "61b1448bb9d1572be43f70b2e86c68325cb869b6ebc67b458d2b7a3f8841a919",
-    "llama": "b6f5236ed6c4e3250c080f30f92c7370102c8a4209ac229fc24fc5988ec38828",
-    "afmoe": "ac8a4f1e447b7059c93d0652c4643e930dc91dcbe0fbbce52d86e0778f141ee7",
+    "llama": "7c24797e0e81a1d624d7c9f78f6ee45ebc3763a0e78890394292bd291fa24ef3",
+    "afmoe": "61ba09b08bb56661244de4b70fd7727f8a0d65092f77d838808439fb90670dee",
     "axk1": "dc5522dfaaf71aa57a0142aa9378bcda99ddab191aedeb6ab295b221287189b2",
     "longcat": "2b508f8fe8fefb380ba03563ea32191691167b2510d5a4ab0ad0cf28174be0ae",
 }
@@ -62,6 +67,9 @@ def test_the_program_traces_to_the_text_it_had(family):
     assert ("kda_step" in text) == (family == "bailing") and "ssd_step" not in text
     # the grouped products' two forms, where there is an expert layer and only there
     assert ("expert_mlp" in text) == ("ragged_dot" in text) == (family != "llama")
+    # the walk's kernel of the arena's form: K and V by head, or one latent array
+    assert ("head_walk" in text) == (family in ("llama", "afmoe"))
+    assert ("latent_walk" in text) == (family not in ("llama", "afmoe"))
 
 
 def test_the_new_familys_program_holds_what_the_others_lack():
@@ -82,10 +90,12 @@ def test_the_state_space_familys_program_holds_pages_and_state_in_every_layer():
     """One trace holds both forms of the mixer's recurrence (the choice is
     made where the program is lowered) under ONE jitted function that every
     layer calls, K and V arenas by head AND the state's float32 array among
-    its operands and results, no latent walk and no expert layer."""
+    its operands and results, the by-head walk's kernel beside ``walk_jnp``,
+    no latent walk and no expert layer."""
     cfg = falcon_h1.FalconH1Config()
     text = text_of(cfg)
     assert "ssd_step" in text and "platform_index" in text and "name=recurrence" in text
+    assert "head_walk" in text
     assert not any(w in text for w in ("kda_step", "latent_walk", "expert_mlp", "ragged_dot"))
     state = (f"f32[{cfg.n_layers},{SEQS + 1},{cfg.ssm_state},{cfg.ssm_heads},"
              f"{cfg.ssm_head_dim}]")
