@@ -491,8 +491,7 @@ class Metrics:
             "once when its first step cycle that sampled a token closed "
             "(phase = startup | startup.compute | startup.embedder | "
             "startup.backend | startup.state | startup.weights | "
-            "startup.arenas | startup.walk_kernel | startup.expert_kernel | startup.ssd_kernel | "
-            "startup.program | "
+            "startup.arenas | startup.kernels | startup.program | "
             "startup.program.trace | "
             ".lower | .load | startup.first_step; phases of one name summed)",
         )

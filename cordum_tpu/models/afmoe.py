@@ -11,7 +11,7 @@ seam, §Two kinds of page, §The expert layer):
     rotates q and k (RoPE) and sees the last ``window`` keys, a full layer
     has no positional encoding and sees the whole row.  Each kind has its
     own pair of page arenas: the full kind's page tables reach the whole
-    context, the window kind's are rings (``llama.window_ring_pages``), so a
+    context, the window kind's are rings (``attention.window_ring_pages``), so a
     window layer holds a bounded number of pages per sequence;
   * **a dropless expert layer that is told which experts it holds** — the
     sigmoid router scores every token over all ``n_experts`` in float32,
@@ -37,12 +37,15 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Mapping
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from .llama import arena_pos_bytes, attn_block_pages, init_kv_pages, paged_attention, rms_norm, rope
+from .attention import (arena_pos_bytes, attn_block_pages, init_kv_pages, paged_attention,
+                        walk_label)
+from .llama import rms_norm, rope
 
 Params = dict
 #: numbers behind a layer's counts where the router has identity experts
@@ -427,6 +430,42 @@ def step_counters(cfg: Any, counts: Any, live_tokens: int) -> dict[str, int]:
     return out
 
 
+def expert_label(cfg: Any, platform: str) -> dict[str, str]:
+    """The ``expert`` role of a sparse family's ``ModelSpec.kernels``: the name
+    of the kernel :func:`grouped_products` hands a lowering for ``platform``
+    at ``cfg``'s expert shapes, "" where the products are ``ragged_dot``'s.
+    The kernel's module imports Pallas: here, at start-up, not in a step."""
+    from . import expert_mlp
+
+    held = expert_mlp.holds_kernel(platform, cfg.d_model, cfg.d_expert,
+                                   np.dtype(cfg.dtype).itemsize)
+    return {"expert": expert_mlp.KERNEL_NAME if held else ""}
+
+
+def step_report(cfg: Any, counts: Any, live_tokens: int,
+                kernels: Mapping[str, str]) -> tuple[dict[str, int], dict[str, str]]:
+    """``ModelSpec.count_aux`` of every sparse family: one step's
+    :func:`step_counters`, with the work items the grouped products' kernel
+    visited where the program holds it (``kernels``: as its own rule makes
+    them from the counts that are here already), and what the ``step`` span
+    says of them."""
+    counters = step_counters(cfg, counts, live_tokens)
+    if kernels.get("expert"):
+        from . import expert_mlp
+
+        counters["moe_kernel_items"] = int(
+            expert_mlp.item_counts(counts[:, :cfg.experts_held]).sum())
+    attrs = {"moe_here": str(counters["moe_assignments_here"]),
+             "moe_touched": str(counters["moe_experts_touched"]),
+             "expert_kernel": kernels.get("expert") or "none",
+             "moe_items": str(counters.get("moe_kernel_items", 0))}
+    if cfg.n_identity:
+        attrs["moe_zero"] = str(counters["moe_zero_assignments"])
+        attrs["moe_real_picks"] = (f"{counters['moe_real_picks_min']}-"
+                                   f"{counters['moe_real_picks_max']}")
+    return counters, attrs
+
+
 def serving_spec(cfg: AfmoeConfig) -> Any:
     """The family's specification for the serving backend
     (``serving/modelspec.py``): two kinds of page, the experts' counts
@@ -447,10 +486,14 @@ def serving_spec(cfg: AfmoeConfig) -> Any:
         program=program, window=cfg.window,
         arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),) * 2, value_dim=cfg.head_dim,
         aux_shape=(cfg.n_expert_layers, cfg.experts_held),
-        count_aux=lambda counts, live: step_counters(cfg, counts, live),
+        count_aux=lambda counts, live, kernels: step_report(cfg, counts, live, kernels),
+        # the full layers' whole rows are K and V by head; the window's rings
+        # keep the ``jax.numpy`` walk
+        kernels=lambda platform, mesh_devices: {
+            **walk_label(platform, True, mesh_devices), **expert_label(cfg, platform)},
     )
 
 
 __all__ = ["AfmoeConfig", "IDENTITY_COUNTS", "check_routing", "init_params", "init_arenas", "route",
-           "expert_layer", "grouped_products", "ragged_products", "ragged_step", "serving_spec",
-           "step_counters", "SLIDING", "FULL"]
+           "expert_label", "expert_layer", "grouped_products", "ragged_products", "ragged_step",
+           "serving_spec", "step_counters", "step_report", "SLIDING", "FULL"]

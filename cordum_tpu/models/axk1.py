@@ -16,7 +16,7 @@ What the block has that ``models/llama`` and ``models/afmoe`` have not
     K and V by head.  With ``wkvb`` split per head into ``Wuk_h`` and
     ``Wuv_h``: ``ql_h = q_nope_h Wuk_h^T``, the score of head ``h`` is ``s
     (ql_h | q_rope_h) . (c | kr)``, ``ol_h = softmax(score_h) c`` and ``o_h =
-    ol_h Wuv_h``: the walk (``llama.paged_attention``) sees one shared key
+    ol_h Wuv_h``: the walk (``attention.paged_attention``) sees one shared key
     head 576 wide under all query heads and takes a key's leading 512
     columns as its value.  Identical to the published form in exact
     arithmetic (``benchmarks/families/axk1_reference.py`` is the published
@@ -44,8 +44,9 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .afmoe import check_routing, expert_layer, step_counters
-from .llama import arena_pos_bytes, attn_block_pages, paged_attention, rms_norm
+from .afmoe import check_routing, expert_label, expert_layer, step_report
+from .attention import arena_pos_bytes, attn_block_pages, paged_attention, walk_label
+from .llama import rms_norm
 
 Params = dict
 LANES = 128  # a TPU tile's minor dimension
@@ -379,6 +380,13 @@ def ragged_step(
     return jnp.concatenate([nxt, tail]), c_pages
 
 
+def held_kernels(cfg: Any, platform: str, mesh_devices: int) -> dict[str, str]:
+    """``ModelSpec.kernels`` of a family with ONE latent arena and an expert
+    layer (this one, ``models/longcat.py``, ``models/bailing.py``): the latent
+    walk's kernel and the grouped products', each by its own rule."""
+    return {**walk_label(platform, False, mesh_devices), **expert_label(cfg, platform)}
+
+
 def serving_spec(cfg: Axk1Config) -> Any:
     """The family's specification for the serving backend
     (``serving/modelspec.py``): one kind of page with ONE latent arena, the
@@ -397,9 +405,11 @@ def serving_spec(cfg: Axk1Config) -> Any:
         init_arenas=lambda n, ps, _w: init_arenas(cfg, n, ps),
         program=program, arenas=(((cfg.latent_width,),),), value_dim=cfg.kv_rank,
         aux_shape=(cfg.n_expert_layers, cfg.experts_held),
-        count_aux=lambda counts, live: step_counters(cfg, counts, live),
+        count_aux=lambda counts, live, kernels: step_report(cfg, counts, live, kernels),
+        kernels=lambda platform, mesh_devices: held_kernels(cfg, platform, mesh_devices),
     )
 
 
-__all__ = ["Axk1Config", "WalkRows", "init_params", "init_arenas", "mla_sublayer", "ragged_step",
+__all__ = ["Axk1Config", "WalkRows", "held_kernels", "init_params", "init_arenas", "mla_sublayer",
+           "ragged_step",
            "rope", "rotate", "serving_spec", "walk_rows", "yarn_inv_freq", "yarn_mscale"]

@@ -39,8 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from . import kda
-from .afmoe import check_routing, expert_layer, step_counters
-from .axk1 import LANES, mla_sublayer, rotate, walk_rows
+from .afmoe import check_routing, expert_layer, step_report
+from .axk1 import LANES, held_kernels, mla_sublayer, rotate, walk_rows
 from .llama import rms_norm
 
 Params = dict
@@ -289,7 +289,11 @@ def serving_spec(cfg: BailingConfig) -> Any:
         program=program, arenas=(((cfg.latent_width,),),), value_dim=cfg.kv_rank,
         init_state=lambda slots: init_state(cfg, slots), n_state=2,
         aux_shape=(cfg.n_expert_layers, cfg.experts_held),
-        count_aux=lambda counts, live: step_counters(cfg, counts, live),
+        count_aux=lambda counts, live, kernels: step_report(cfg, counts, live, kernels),
+        # the walk and the grouped products, as the backend stated them before
+        # the specification did; the recurrence's ``kda_step`` is chosen at
+        # trace time like them and has no label yet (ROADMAP, named debts)
+        kernels=lambda platform, mesh_devices: held_kernels(cfg, platform, mesh_devices),
     )
 
 

@@ -44,8 +44,9 @@ ends with its own expert's product.  Rows no item visited hold nothing.
 
 The module imports Pallas (a second or more of imports, PERF.md section 6,
 PR 39), so nothing imports it at its own import: ``afmoe.expert_layer`` does
-where it traces the products and a backend where its model has an expert
-layer (``startup.expert_kernel``); ``models/llama.py`` never does.  Which
+where it traces the products and a sparse family's ``ModelSpec.kernels``
+(``afmoe.expert_label``, under a backend's ``startup.kernels`` phase);
+``models/llama.py`` never does.  Which
 form a program holds is decided where it is LOWERED
 (``jax.lax.platform_dependent`` in ``afmoe.grouped_products``):
 :data:`PLATFORM` gets this kernel, every other platform ``ragged_dot``;

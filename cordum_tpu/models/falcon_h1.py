@@ -7,7 +7,7 @@ state slot, "pages AND a state in one layer"):
   * **a state-space mixer and attention side by side in EVERY layer** — both
     branches read the SAME normed input and their outputs are summed into
     the residual stream: grouped-query attention over K and V pages by head
-    (``llama.paged_attention``, here at 5 query heads a K/V head) and a
+    (``attention.paged_attention``, here at 5 query heads a K/V head) and a
     Mamba-2 mixer (``models/ssd.py``) whose cache is a float32 state a row;
   * **so a row keeps two kinds of cache in every layer** — K/V PAGES
     (``[layers, pages, page_size, kv heads, head_dim]`` twice, as
@@ -32,14 +32,15 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Mapping
 
 import jax
 import jax.numpy as jnp
 
 from . import kda, ssd
-from .llama import (arena_pos_bytes, attn_block_pages, init_kv_pages, paged_attention, rms_norm,
-                    rope)
+from .attention import (arena_pos_bytes, attn_block_pages, init_kv_pages, paged_attention,
+                        walk_label)
+from .llama import rms_norm, rope
 
 Params = dict
 
@@ -247,11 +248,30 @@ def ragged_step(
 
 
 def step_counters(counts: Any, live: int) -> dict[str, int]:
-    """``ServingStats`` addends of one step's counters (``ragged_step``'s
-    four, as ``ModelSpec.count_aux`` hands them over)."""
+    """``ServingStats.model`` addends of one step's counters (``ragged_step``'s
+    four, as the program returned them)."""
     rows, tokens, fresh, prefetched = (int(n) for n in counts)
     return {"state_rows_advanced": rows, "state_tokens_scanned": tokens,
             "state_rows_fresh": fresh, "state_rows_prefetched": prefetched}
+
+
+def step_report(counts: Any, live: int,
+                kernels: Mapping[str, str]) -> tuple[dict[str, int], dict[str, str]]:
+    """``ModelSpec.count_aux``: one step's :func:`step_counters` and what the
+    ``step`` span says of them, with the form the recurrence takes in this
+    backend's program (``kernels``)."""
+    counters = step_counters(counts, live)
+    return counters, {"state_fresh": str(counters["state_rows_fresh"]),
+                      "state_prefetched": str(counters["state_rows_prefetched"]),
+                      "state_kernel": kernels.get("state") or "none"}
+
+
+def held_kernels(platform: str, mesh_devices: int) -> dict[str, str]:
+    """``ModelSpec.kernels``: the by-head walk's kernel and the mixer's
+    recurrence, each by its own rule (``ssd.holds_kernel`` imports Pallas
+    where it holds: here, at start-up)."""
+    return {**walk_label(platform, True, mesh_devices),
+            "state": ssd.KERNEL_NAME if ssd.holds_kernel(platform) else ""}
 
 
 def serving_spec(cfg: FalconH1Config) -> Any:
@@ -273,9 +293,9 @@ def serving_spec(cfg: FalconH1Config) -> Any:
         init_arenas=lambda n, ps, _w: init_kv_pages(cfg, n, ps),
         program=program, arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),), value_dim=cfg.head_dim,
         init_state=lambda slots: init_state(cfg, slots), n_state=2,
-        aux_shape=(4,), count_aux=step_counters,
+        aux_shape=(4,), count_aux=step_report, kernels=held_kernels,
     )
 
 
-__all__ = ["FalconH1Config", "init_params", "init_state", "ragged_step", "serving_spec", "spread",
-           "step_counters"]
+__all__ = ["FalconH1Config", "held_kernels", "init_params", "init_state", "ragged_step",
+           "serving_spec", "spread", "step_counters", "step_report"]

@@ -1,7 +1,7 @@
 """The walk over K and V pages by head of one group of tiles as ONE Pallas TPU
 kernel (docs/SERVING.md §The ragged entry point; ROADMAP S2 step 1).
 
-``llama.paged_attention`` over two arenas ``[rows, N, ps, kvh, hd]`` (K and V
+``attention.paged_attention`` over two arenas ``[rows, N, ps, kvh, hd]`` (K and V
 by head, no window) walks a group of ``ATTN_GROUP_TILES`` tiles block by
 block.  As ``jax.numpy`` that walk gathers a trip's pages into a new HBM
 array and reads them back, makes five passes over the float32 scores through
@@ -9,8 +9,8 @@ HBM, reads and rewrites the group's accumulator whatever the block's length
 and drags all eight tiles to the group's longest row: 3.8 ms of a 36 ms
 step for 0.36 ms of bytes in the one cell the device binds, 1.4 ms under
 this kernel (PERF.md section 6, PR 44).  This kernel is ``models/latent_walk.py``'s for the other form of
-arena, and shares its rules by import (:func:`latent_walk.tile_trips`, the
-page loop, the VMEM budget):
+arena, and shares by import what the two have in common in the kernel body
+(the platform, the page loop, the VMEM budget):
 
 * **grid = the tiles of the group**; a tile's queries ``[kvh, slots x rep,
   hd]`` are resident for its whole walk, its slots' positions and its table
@@ -40,13 +40,14 @@ float32 scores and state, probabilities cast to the arena's dtype for the
 value product, a masked key scores ``-1e30``, ``scale`` as given.
 
 Which walk a program holds is decided where it is LOWERED
-(``jax.lax.platform_dependent`` in ``llama.paged_attention``), by
+(``jax.lax.platform_dependent`` in ``attention.paged_attention``), by
 :func:`holds_kernel`: the arena's form, the lowering platform, and that the
 program is not partitioned over a mesh (a Pallas call is one device's; the
 tensor-parallel gang shards the arenas by head and keeps the ``jax.numpy``
-walk).  The host counts the walk by the same rule
-(``ServingBackend._count_walk``).  The module imports Pallas, so nothing
-imports it at its own import (``models/latent_walk.py`` says why).
+walk).  ``attention.walk_kernel`` asks it for the trace and for the host
+alike, and the host counts the walk by the same rule
+(``attention.count_walk``).  The module imports Pallas, so nothing imports it
+at its own import (``models/latent_walk.py`` says why).
 """
 from __future__ import annotations
 
@@ -59,7 +60,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .latent_walk import PLATFORM, VMEM_BUDGET_BYTES, page_loop
-from .latent_walk import tile_trips  # noqa: F401 - the one trips rule, under this module's name too
 
 #: the kernel's name in the lowered program (its custom call) and in a trace
 KERNEL_NAME = "head_walk"
@@ -79,16 +79,6 @@ def holds_kernel(platform: str, by_head: bool, window: Optional[int], mesh_devic
     device's (``mesh_devices``: the devices of the mesh the arenas are laid
     out over, 0 or 1 where there is none) — nothing else."""
     return by_head and window is None and mesh_devices <= 1 and platform == PLATFORM
-
-
-def mesh_devices(arena: Any) -> int:
-    """Devices of the mesh ``arena`` is laid out over, for
-    :func:`holds_kernel`: of a traced operand (its type carries the mesh of
-    the sharding it was given, through every jit) or of an array on its
-    devices."""
-    if isinstance(arena, jax.core.Tracer):
-        return jax.typeof(arena).sharding.mesh.size
-    return len(arena.devices())
 
 
 def vmem_bytes(kvh: int, rows: int, hd: int, block_tokens: int, itemsize: int) -> int:
@@ -239,7 +229,7 @@ def walk_group(q: jax.Array, q_pos: jax.Array, k_arena: jax.Array, v_arena: jax.
     are read in place); q_pos: int32 ``[G, slots]``, each slot's position;
     k_arena / v_arena: ``[arena rows, N, ps, kvh, hd]``; row: the arena row (a
     traced int); tab: int32 ``[G, P]``, each tile's table row, ``P`` a whole
-    number of blocks; trips: int32 ``[G]`` (``latent_walk.tile_trips``).
+    number of blocks; trips: int32 ``[G]`` (``attention.tile_trips``).
     Returns the group's outputs ``[G, kvh, rows, hd]`` in q's dtype, an idle
     tile's zeros."""
     _, kvh, rows, hd = q.shape

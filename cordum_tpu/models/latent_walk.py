@@ -1,7 +1,7 @@
 """The latent walk of one group of tiles as ONE Pallas TPU kernel
 (docs/SERVING.md §The ragged entry point; ROADMAP S2 step 2, the latent form).
 
-``llama.paged_attention`` over a latent arena (``v_pages is None``: one
+``attention.paged_attention`` over a latent arena (``v_pages is None``: one
 arena ``[rows, N, ps, width]``, one shared key head under all the query
 heads, a key's leading ``v_dim`` columns its value) walks a group of
 ``ATTN_GROUP_TILES`` tiles block by block.  As ``jax.numpy`` that walk
@@ -39,20 +39,24 @@ value product, a masked key scores ``-1e30``, ``scale`` as given.
 
 The module imports Pallas, a second or more of imports (1.45 s on the
 chip's host, PERF.md section 6, PR 39), so nothing imports it at its own
-import: ``llama.paged_attention`` does where it traces a form that has a
-kernel and a backend where it holds such an arena (``models/head_walk.py``,
-the kernel for K and V by head, imports this module's shared rules:
-:func:`tile_trips`, :func:`page_loop`, the VMEM budget).  The loops over a
+import: ``attention.walk_kernel`` does, for ``attention.paged_attention``
+where it traces a form that has a kernel and for a family's
+``ModelSpec.kernels`` where a backend holds such an arena
+(``models/head_walk.py``, the kernel for K and V by head, imports what the two
+kernels share in the kernel body: :data:`PLATFORM`, :func:`page_loop`, the
+VMEM budget).  The loops over a
 block's pages are ``fori_loop``s of
 :data:`PAGE_UNROLL` copies a pass, not Python's over all 32: unrolled in
 Python they made the step program's trace 1.3 s and its executable's load
 1.1 s longer on that host, and ``setup_s`` is judged.
 
 Which of the two walks a program holds is decided where the program is
-LOWERED (``jax.lax.platform_dependent`` in ``llama.paged_attention``):
+LOWERED (``jax.lax.platform_dependent`` in ``attention.paged_attention``):
 :data:`PLATFORM` gets this kernel, every other platform the ``jax.numpy``
-walk; :func:`holds_kernel` is the same rule for the host, which counts the
-walk as its program makes it (``ServingBackend._count_walk``).
+walk; :func:`holds_kernel` is that rule, which ``attention.walk_kernel`` asks
+for the trace and for the host alike (the host counts the walk as its program
+makes it, each tile to its own end: ``attention.tile_trips``,
+``attention.count_walk``).
 """
 from __future__ import annotations
 
@@ -62,7 +66,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -85,14 +88,6 @@ def holds_kernel(platform: str, latent: bool) -> bool:
     """Whether a step program lowered for ``platform`` walks its pages with
     this kernel: the arena's form and the platform, nothing else."""
     return latent and platform == PLATFORM
-
-
-def tile_trips(newest: Any, live: Any, block_tokens: int) -> Any:
-    """Blocks each tile of the kernel walks (numpy or jax int arrays, one
-    entry a tile): to the block of its own newest slot, none for an idle
-    tile — the kernel's loop bound and the host's count alike."""
-    xp = np if isinstance(newest, np.ndarray) else jnp
-    return xp.where(live, newest // block_tokens + 1, 0)
 
 
 def page_loop(block_pages: int, copy: Any) -> None:
@@ -215,7 +210,7 @@ def walk_group(q: jax.Array, q_pos: jax.Array, arena: jax.Array, row: Any, tab: 
     product rows a slot); arena: ``[arena rows, N, ps, width]``; row: the
     arena row (a traced int); tab: int32 ``[G, P]``, each tile's table row,
     ``P`` a whole number of blocks; trips: int32 ``[G]``
-    (:func:`tile_trips`); out: ``[tiles, rows, v_dim]`` in q's dtype.
+    (``attention.tile_trips``); out: ``[tiles, rows, v_dim]`` in q's dtype.
     Returns ``out`` with the group's tiles written (in place: the result
     aliases it), the others as they were."""
     n_tiles, rows, width = q.shape

@@ -48,8 +48,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .afmoe import IDENTITY_COUNTS, check_routing, expert_layer, step_counters
-from .axk1 import LANES, mla_sublayer, rotate, walk_rows
+from .afmoe import IDENTITY_COUNTS, check_routing, expert_layer, step_report
+from .axk1 import LANES, held_kernels, mla_sublayer, rotate, walk_rows
 from .llama import rms_norm
 
 Params = dict
@@ -292,7 +292,8 @@ def serving_spec(cfg: LongcatConfig) -> Any:
         init_arenas=lambda n, ps, _w: init_arenas(cfg, n, ps),
         program=program, arenas=(((cfg.latent_width,),),), value_dim=cfg.kv_rank,
         aux_shape=(cfg.n_layers, cfg.experts_held + IDENTITY_COUNTS),
-        count_aux=lambda counts, live: step_counters(cfg, counts, live),
+        count_aux=lambda counts, live, kernels: step_report(cfg, counts, live, kernels),
+        kernels=lambda platform, mesh_devices: held_kernels(cfg, platform, mesh_devices),
     )
 
 

@@ -90,7 +90,7 @@ ACCUMULATORS = 4
 def holds_kernel(platform: str) -> bool:
     """Whether a program lowered for ``platform`` advances the state with the
     kernel.  Where it does, Pallas is imported here, a second or more that the
-    first lowering would pay unseen (``startup.ssd_kernel`` stamps this call)."""
+    first lowering would pay unseen (``startup.kernels`` stamps this call)."""
     if platform != PLATFORM:
         return False
     from jax.experimental.pallas import tpu  # noqa: F401

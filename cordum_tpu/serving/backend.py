@@ -1,9 +1,12 @@
 """The XLA half of the serving path: ONE ragged mixed prefill+decode entry.
 
 One backend per worker process owns the KV-page arenas and a single jitted
-program, both supplied by a model specification (``serving/modelspec.py``:
-the llama family's ``init_kv_pages`` and ``ragged_step`` are the first; a
-family with window layers brings a second kind of page, held in rings).
+program, both supplied by a model specification (``serving/modelspec.py``: a
+dense family's K and V pages by head and its ragged step are the first; a
+family with window layers brings a second kind of page, held in rings).  The
+page programs and the rules of the attention's walk, which every family
+shares, come from ``models/attention.py``: the one module of ``models/`` this
+one imports.
 Every device call — a decode step over the live
 sessions, a chunk of some prompt's prefill, or any mix of the two — flows
 through :meth:`step` with the same static operand shapes:
@@ -152,28 +155,17 @@ class StepBackend:
     kv_positional: bool = True
     state_slots: int = 0
     state_bytes: int = 0
-    # which walk the step program holds over its whole-row kind of page: the
-    # kernel's name where its attention walks them with one, in a program
-    # lowered for the TPU ("latent_walk": a latent arena,
-    # ``models/latent_walk.py``; "head_walk": K and V by head in a program
-    # that is one device's, ``models/head_walk.py``), "" where the walk is
-    # ``jax.numpy``'s, as a window kind's rings always are.  Known once the
-    # state is on its device
-    walk_kernel: str = ""
-    # which form the expert layer's grouped products take: the kernel's name
-    # ("expert_mlp": a model with an expert layer whose program is lowered
-    # for the TPU, ``models/expert_mlp.py``), "" where they are
-    # ``jax.lax.ragged_dot``'s or the model has no expert layer
-    expert_kernel: str = ""
-    # which form a state-space mixer's recurrence takes: the kernel's name
-    # ("ssd_step": a model with such a mixer whose program is lowered for the
-    # TPU, ``models/ssd.py``), "" where it is ``jax.numpy``'s or the model has none
-    state_kernel: str = ""
+    # which kernels the lowered step program holds, by role (``walk``: the
+    # attention's walk over the whole-row kind of page; ``expert``; ``state``),
+    # as the model's specification states them for the platform the arenas
+    # live on (``ModelSpec.kernels``): a role that is absent or "" is the
+    # ``jax.numpy`` form.  Known once the state is on its device
+    kernels: Mapping[str, str] = MappingProxyType({})
     # the latest step's report, written by ``step`` and read by the engine
     # after the call
     REPORT = ("last_step_compiled", "last_compile_ms", "last_cache_hit", "last_phases",
               "last_attn_blocks", "last_window_blocks", "last_attn_rows", "last_attn_live",
-              "last_counters")
+              "last_counters", "last_attrs")
     # did the compiler run for this backend since the step before returned
     # (in this step's dispatch, or in a page program the cycle called first:
     # a first copy-on-write)?  For how long, and did the persistent cache
@@ -189,12 +181,14 @@ class StepBackend:
     # computed), blocks of them, by one full and one window layer together;
     # the query slots among those that were FED and needed their block (the
     # rest is what the tiles pad); what the family's program counted, under
-    # the ``ServingStats`` names it adds to
+    # the family's names (``ModelSpec.count_aux``: the addends of
+    # ``ServingStats.model``), and the ``step`` span's attributes of them
     last_attn_blocks: tuple[int, int] = (0, 0)
     last_window_blocks: int = 0
     last_attn_rows: tuple[int, int] = (0, 0)
     last_attn_live: int = 0
     last_counters: Mapping[str, int] = MappingProxyType({})
+    last_attrs: Mapping[str, str] = MappingProxyType({})
     # observation tap: called with the entry list after every successful
     # step — the serving-gang leader broadcasts it so followers replay the
     # identical program against their head shards
@@ -326,11 +320,15 @@ class ServingBackend(StepBackend):
     ) -> None:
         # lazy model import keeps this module (and the engine importing it
         # for StepEntry) jax-free until a real backend is constructed
-        from ..models import llama
+        from ..models import attention
         from .modelspec import spec_for
 
-        # ``cfg``: a ModelSpec, a family's config object, or None (tiny llama)
-        self.spec = spec_for(llama.LlamaConfig.tiny() if cfg is None else cfg)
+        if cfg is None:  # the default model of a backend built with none
+            from ..models.llama import LlamaConfig
+
+            cfg = LlamaConfig.tiny()
+        # ``cfg``: a ModelSpec or a family's config object
+        self.spec = spec_for(cfg)
         self.cfg = self.spec.cfg
         self.page_size = max(1, page_size)
         self.num_pages = max(2, num_pages)
@@ -359,7 +357,7 @@ class ServingBackend(StepBackend):
         self.kv_positional = self.spec.kv_positional
         self.state_slots = 0 if self.kv_positional else self.max_seqs + 1
         self.ring_pages = (
-            llama.window_ring_pages(self.window, self.page_size, self.max_batch_tokens)
+            attention.window_ring_pages(self.window, self.page_size, self.max_batch_tokens)
             if self.window else 0
         )
         self.num_window_pages = (
@@ -391,27 +389,27 @@ class ServingBackend(StepBackend):
         self._paid = [0, 0, 0]
         self._metrics = metrics
         # ``last_attn_blocks`` / ``last_window_blocks`` / ``last_attn_rows``:
-        # ``llama.paged_attention`` cuts the rows into tiles and walks each
+        # ``attention.paged_attention`` cuts the rows into tiles and walks each
         # group of tiles to its longest one, all of which follows from the
         # rows' buffer slots and positions, which the host knows from the
-        # entries it packs (``_count_walk``)
+        # entries it packs (``attention.count_walk``)
         # the walk's tile follows the query heads a K/V head, as the program's
         h, kvh = self.cfg.n_heads, self.cfg.n_kv_heads
-        self._tile_slots = llama.attn_tile_slots(h // kvh)
+        self._tile_slots = attention.attn_tile_slots(h // kvh)
         # and its block, a kind of page, what a trip gathers beside what it
         # rewrites: the program's own rule over the shapes the spec states
         itemsize = np.dtype(self.cfg.dtype).itemsize
         self._block_tokens = tuple(
-            self.page_size * llama.attn_block_pages(
+            self.page_size * attention.attn_block_pages(
                 self.page_size, self.pages_per_seq,
-                llama.arena_pos_bytes(kind, itemsize), h, kvh, self.spec.value_dim)
+                attention.arena_pos_bytes(kind, itemsize), h, kvh, self.spec.value_dim)
             for kind in self.spec.arenas)
         self.attn_block_tokens = self._block_tokens[0]
         self._attn_blocks_total = -(-self.pages_per_seq * self.page_size // self.attn_block_tokens)
         # the counters the program returned behind the tokens
         # (``spec.aux_shape``; None where the family returns none);
-        # ``last_counters`` is what the family says they add to
-        # ``ServingStats`` (``spec.count_aux``)
+        # ``last_counters`` and ``last_attrs`` are what the family says of
+        # them (``spec.count_aux``)
         self.last_aux: Any = None
         self._steps_done = 0  # numbers the host annotations
         # page-arena mutation lock: steps read-modify-write the K/V arrays
@@ -467,48 +465,18 @@ class ServingBackend(StepBackend):
                         a.nbytes for a in self._arenas[self.spec.n_arenas:])
                     self.state_bytes = made["state_bytes"] // self.state_slots
             self._params = params
-            # the walk the program will hold is chosen where it is lowered:
-            # for the platform the arenas live on, by the whole-row kind's
-            # form (one latent array a layer, or K and V by head; a window
-            # kind's rings keep the ``jax.numpy`` walk)
-            latent = len(self.spec.arenas[0]) == 1 and self.window is None
-            if latent or self.kv_by_head:
-                # a kernel's module imports Pallas, a second or more: stamped,
-                # so that the record says so
-                with startup.phase("startup.walk_kernel") as walk:
-                    platform = next(iter(self._arenas[0].devices())).platform
-                    if latent:
-                        from ..models import latent_walk
+            # which kernels the lowered program will hold is the model's to
+            # say (``ModelSpec.kernels``), for the platform the arenas live on
+            # and the devices they are laid out over.  The kernels' modules
+            # import Pallas, a second or more: stamped, so that the record
+            # says so
+            with startup.phase("startup.kernels") as held:
+                from ..models import attention
 
-                        if latent_walk.holds_kernel(platform, latent=True):
-                            self.walk_kernel = latent_walk.KERNEL_NAME
-                    else:
-                        from ..models import head_walk
-
-                        if head_walk.holds_kernel(platform, True, None,
-                                                  head_walk.mesh_devices(self._arenas[0])):
-                            self.walk_kernel = head_walk.KERNEL_NAME
-                    walk["walk_kernel"] = self.walk_kernel or "none"
-            if getattr(self.cfg, "experts_held", 0):  # the model has an expert layer
-                # as above: the products' form is the lowering platform's, by
-                # the experts' shapes; the module's import is Pallas'
-                with startup.phase("startup.expert_kernel") as made:
-                    from ..models import expert_mlp
-
-                    platform = next(iter(self._arenas[0].devices())).platform
-                    if expert_mlp.holds_kernel(platform, self.cfg.d_model, self.cfg.d_expert,
-                                               np.dtype(self.cfg.dtype).itemsize):
-                        self.expert_kernel = expert_mlp.KERNEL_NAME
-                    made["expert_kernel"] = self.expert_kernel or "none"
-            if getattr(self.cfg, "ssm_heads", 0):  # the model has a state-space mixer
-                # as above: the recurrence's form is the lowering platform's
-                with startup.phase("startup.ssd_kernel") as made:
-                    from ..models import ssd
-
-                    platform = next(iter(self._arenas[0].devices())).platform
-                    if ssd.holds_kernel(platform):
-                        self.state_kernel = ssd.KERNEL_NAME
-                    made["ssd_kernel"] = self.state_kernel or "none"
+                self.kernels = dict(self.spec.kernels(
+                    next(iter(self._arenas[0].devices())).platform,
+                    attention.mesh_devices(self._arenas[0])))
+                held.update({role: name or "none" for role, name in self.kernels.items()})
             state.update(events.counts())
         self._note_compiles("state", events)
         self.page_bytes = sum(a.nbytes // a.shape[1] for a in self._arenas[self._row_kind])
@@ -548,8 +516,8 @@ class ServingBackend(StepBackend):
             # every series of a backend says which walk and which form of
             # the grouped expert products its step program holds
             self._metrics.serving_compiles.inc(
-                float(n), entry=entry, walk_kernel=self.walk_kernel or "none",
-                expert_kernel=self.expert_kernel or "none")
+                float(n), entry=entry, walk_kernel=self.kernels.get("walk") or "none",
+                expert_kernel=self.kernels.get("expert") or "none")
         self._paid[0] += n
         self._paid[1] += events.compile_ns
         self._paid[2] += events.hits
@@ -689,67 +657,22 @@ class ServingBackend(StepBackend):
                 # the family's counters rode behind the tokens, one transfer
                 self.last_aux = out[t_buf:].reshape(self.spec.aux_shape)
                 if self.spec.count_aux is not None:
-                    self.last_counters = self.spec.count_aux(self.last_aux, ti)
-                    if self.expert_kernel:
-                        # the work items the kernel visited, as its own rule
-                        # makes them from the counts that are here already
-                        from ..models import expert_mlp
+                    self.last_counters, self.last_attrs = self.spec.count_aux(
+                        self.last_aux, ti, self.kernels)
+            # the walk as the program made it: each tile to its own end where
+            # the whole-row kind's walk is a kernel
+            from ..models import attention
 
-                        held = self.last_aux[:, :self.cfg.experts_held]
-                        self.last_counters = {
-                            **self.last_counters,
-                            "moe_kernel_items": int(expert_mlp.item_counts(held).sum())}
-            self._count_walk(np.array(spans), positions)
+            walked, self.last_window_blocks, self.last_attn_rows, self.last_attn_live = (
+                attention.count_walk(np.array(spans), positions, self._tile_slots,
+                                     self._block_tokens, self.window,
+                                     bool(self.kernels.get("walk"))))
+            self.last_attn_blocks = (walked, self._attn_blocks_total)
             if self.on_step is not None:
                 self.on_step(entries)
         self._steps_done = n_step + 1
         self.last_phases = tuple(marks)
         return res
-
-    def _count_walk(self, spans: Any, positions: Any) -> None:
-        """The step's walk as ``llama.paged_attention`` makes it, counted on
-        the host from ``spans`` (int [rows, 2]: each fed row's buffer slots)
-        and the packed ``positions``: the rows cut into tiles, the tiles in
-        the program's order, and a KIND of page after the other by the rule
-        of that kind's walk: each tile to its OWN end
-        (``latent_walk.tile_trips``) where the kind's walk is a kernel (the
-        whole-row kind's under ``walk_kernel``), each group of tiles to its
-        longest (``llama.walk_blocks``) where it is ``jax.numpy``'s (the
-        window kind's rings always)."""
-        from ..models import llama
-
-        w, g = self._tile_slots, llama.ATTN_GROUP_TILES
-        lo = np.concatenate([np.arange(a, b, w) for a, b in spans])  # a tile's first slot
-        hi = np.minimum(lo + w, np.repeat(spans[:, 1], -(-(spans[:, 1] - spans[:, 0]) // w)))
-        order = llama.walk_order(positions[hi - 1], np.ones(len(lo), bool))
-        oldest, newest = positions[lo][order], positions[hi - 1][order]
-        rows = slots = live = 0
-        fed = positions[:spans[-1, 1]]  # the rows are packed one behind the other from slot 0
-        # a kind of page after the other: whole rows, then the window's rings
-        for bt, window in zip(self._block_tokens, (None, self.window)):
-            # a fed slot needs the blocks from its oldest visible key's to its own
-            first = 0 if window is None else (fed - (window - 1)).clip(0) // bt
-            live += int((fed // bt - first + 1).sum())
-            if self.walk_kernel and window is None:
-                from ..models import latent_walk
-
-                own = latent_walk.tile_trips(newest, np.ones(len(newest), bool), bt)
-                longest = int(own.max())
-                rows += int(own.sum())
-                slots += w * int(own.sum())
-            else:
-                longest = 0
-                for a in range(0, len(order), g):
-                    trips = int(llama.walk_blocks(oldest[a:a + g], newest[a:a + g], bt, window)[1])
-                    longest = max(longest, trips)
-                    rows += g * trips
-                    slots += g * w * trips
-            if window is None:
-                self.last_attn_blocks = (longest, self._attn_blocks_total)
-            else:
-                self.last_window_blocks = longest
-        self.last_attn_rows = (rows, slots)
-        self.last_attn_live = live
 
     # ------------------------------------------------------------------
     # live KV-page migration (serving/migration.py, docs/PROTOCOL.md §Page
@@ -768,7 +691,7 @@ class ServingBackend(StepBackend):
         if end_tok <= start_tok:
             return []
         self._ensure()
-        from ..models import llama
+        from ..models import attention
 
         ps = self.page_size
         first, last = start_tok // ps, -(-end_tok // ps)
@@ -779,7 +702,7 @@ class ServingBackend(StepBackend):
         # not overlap a step's jit call (page CONTENT below end_tok is
         # stable either way — steps only write at the current positions)
         with self._page_program("gather_page"):
-            blocks = llama.gather_kv_pages(
+            blocks = attention.gather_kv_pages(
                 self._k_pages, self._v_pages, [pages[o] for o in ords], used
             )
         return [
@@ -796,7 +719,7 @@ class ServingBackend(StepBackend):
         if not records:
             return
         self._ensure()
-        from ..models import llama
+        from ..models import attention
 
         if any("heads" in rec for rec in records):
             # per-rank records from a serving-gang source (docs/SERVING.md
@@ -817,7 +740,7 @@ class ServingBackend(StepBackend):
             ids.append(pages[o])
             blocks.append((k, v))
         with self._page_program("scatter_page"):
-            self._k_pages, self._v_pages = llama.scatter_kv_pages(
+            self._k_pages, self._v_pages = attention.scatter_kv_pages(
                 self._k_pages, self._v_pages, ids, blocks
             )
 
@@ -832,10 +755,10 @@ class ServingBackend(StepBackend):
         Blocking; call from an executor thread."""
         self.spec.require_whole_row("page copy (prefix sharing)")
         self._ensure()
-        from ..models import llama
+        from ..models import attention
 
         with self._page_program("copy_page"):
-            self._arenas[self._row_kind] = llama.copy_page(
+            self._arenas[self._row_kind] = attention.copy_page(
                 self._arenas[self._row_kind], src, dst)
 
     # ------------------------------------------------------------------

@@ -31,7 +31,7 @@ from __future__ import annotations
 import asyncio
 import statistics
 import time
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Any, Awaitable, Callable, Optional
 
@@ -174,41 +174,18 @@ class ServingStats:
     window_pages_reused: int = 0
     kv_pages_held_full: int = 0
     kv_pages_held_window: int = 0
-    # a model with an expert layer (docs/SERVING.md §The expert layer),
-    # summed over steps and expert layers: assignments the router made
-    # (live tokens x top_k), those to experts held here, held experts that
-    # got at least one token, and the busiest held expert's tokens
-    moe_assignments: int = 0
-    moe_assignments_here: int = 0
-    moe_experts_touched: int = 0
-    moe_max_expert_load: int = 0
-    # work items the grouped products' kernel visited (a touched expert and
-    # a run of its rows, ``models/expert_mlp.py``), summed over steps and
-    # expert layers; stays 0 where the products are ``ragged_dot``'s
-    moe_kernel_items: int = 0
-    # a router with identity (zero-compute) experts: picks of them by live
-    # tokens, and the most and the fewest REAL experts a live token picked,
-    # a layer and step (summed: divide by layers x steps)
-    moe_zero_assignments: int = 0
-    moe_real_picks_max: int = 0
-    moe_real_picks_min: int = 0
     # a model with recurrent state (docs/SERVING.md §The state slot): the
     # most state slots sessions held at once; rows that advanced their state
     # by ONE token, summed over steps (decode rows: a state read and written
     # for one token), and tokens fed by rows of more than one (prefill chunks)
     state_slots_peak: int = 0
-    kda_decode_rows: int = 0
-    kda_chunk_tokens: int = 0
-    # what a family's program counted of its own state arrays
-    # (``ModelSpec.count_aux``), a state layer's, summed over steps: rows
-    # that advanced a state, tokens through the recurrence, rows that
-    # started from zeros (a session's first chunk, whatever its slot held),
-    # and rows whose state the kernel's pipeline read ahead of their turn
-    # (carried rows behind the step's first fed row: ``models/row_pipeline.py``)
-    state_rows_advanced: int = 0
-    state_tokens_scanned: int = 0
-    state_rows_fresh: int = 0
-    state_rows_prefetched: int = 0
+    state_decode_rows: int = 0
+    state_chunk_tokens: int = 0
+    # what the model family's program counted of its own layers, summed over
+    # steps under the names the family gives them (``ModelSpec.count_aux``:
+    # an expert layer's assignments, a recurrence's rows, ...; the engine
+    # knows none of them).  A name nobody counted reads 0
+    model: Counter = field(default_factory=Counter)
     # stream packets the step loop handed to the sinks (a session's new
     # tokens of one step; replays of a carried prefix are not among them),
     # and those of them published with the NEXT step already on the device
@@ -1626,7 +1603,7 @@ class ServingEngine:
             attrs["kv_rows"] = str(kv_rows)
             attrs["q_rows"] = str(q_rows)
             attrs["q_live"] = str(self.backend.last_attn_live)
-            attrs["walk_kernel"] = self.backend.walk_kernel or "none"
+            attrs["walk_kernel"] = self.backend.kernels.get("walk") or "none"
         if self.ring_pages:
             attrs["window_blocks"] = str(self._count_window(rows, pos_before))
         if self.state_allocator is not None:
@@ -1634,30 +1611,15 @@ class ServingEngine:
             # read and written for it) or by a chunk
             single = sum(1 for _, chunk, _, _ in rows if chunk == 1)
             fed = sum(chunk for _, chunk, _, _ in rows)
-            self.stats.kda_decode_rows += single
-            self.stats.kda_chunk_tokens += fed - single
+            self.stats.state_decode_rows += single
+            self.stats.state_chunk_tokens += fed - single
             attrs["state_rows"] = str(len(rows))
-            attrs["kda_tokens"] = str(fed)
-        counters = self.backend.last_counters
-        if counters:
-            # what the model family's program counted this step, named by
-            # the family (``ModelSpec.count_aux``): the expert layer's four,
-            # a state family's four
-            for name, n in counters.items():
-                setattr(self.stats, name, getattr(self.stats, name) + n)
-        if "state_rows_fresh" in counters:
-            attrs["state_fresh"] = str(counters["state_rows_fresh"])
-            attrs["state_prefetched"] = str(counters["state_rows_prefetched"])
-            attrs["state_kernel"] = self.backend.state_kernel or "none"
-        if "moe_assignments_here" in counters:
-            attrs["moe_here"] = str(counters["moe_assignments_here"])
-            attrs["moe_touched"] = str(counters["moe_experts_touched"])
-            attrs["expert_kernel"] = self.backend.expert_kernel or "none"
-            attrs["moe_items"] = str(counters.get("moe_kernel_items", 0))
-            if "moe_zero_assignments" in counters:
-                attrs["moe_zero"] = str(counters["moe_zero_assignments"])
-                attrs["moe_real_picks"] = (f"{counters['moe_real_picks_min']}-"
-                                           f"{counters['moe_real_picks_max']}")
+            attrs["state_tokens"] = str(fed)
+        # what the model family's program counted this step and what it says
+        # of that on the span, both under the family's own names
+        # (``ModelSpec.count_aux``)
+        self.stats.model.update(self.backend.last_counters)
+        attrs.update(self.backend.last_attrs)
         if self.speculative:
             attrs["drafted"] = str(step_drafted)
             attrs["accepted"] = str(step_accepted)

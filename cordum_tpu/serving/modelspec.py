@@ -3,7 +3,10 @@
 :class:`~cordum_tpu.serving.backend.ServingBackend` knows no model family:
 it packs host arrays, calls ONE jitted program and keeps what the program
 returns.  A :class:`ModelSpec` supplies the rest — the weights' init, the
-page arenas, the program — and states what the family's cache can do.
+page arenas, the program — and states what the family's cache can do,
+which kernels its lowered program holds (``kernels``) and what its
+program's counters are called (``count_aux``): the backend and the engine
+hand both on under the family's names and know none of them.
 
 The program's signature is ``(params, *arenas, tokens, positions, *tables,
 token_seq, out_idx) -> (out, *arenas)``: one int32 page table ``[S+1,
@@ -34,7 +37,7 @@ whatever its slot held: the program sees that from ``positions``.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Mapping, Optional
 
 
 class UnsupportedForModel(RuntimeError):
@@ -113,7 +116,7 @@ class ModelSpec:
     #: the width of a value as the attention's walk accumulates it: the head
     #: dimension for K and V by head, the latent's rank for a latent page
     #: (a key's leading columns are its value).  With ``arenas`` it is what
-    #: the backend hands ``llama.attn_block_pages``, as the program does
+    #: the backend hands ``attention.attn_block_pages``, as the program does
     value_dim: int = 0
     #: ``(slots) -> state arrays``, each ``[state layers, slots, ...]``, in
     #: the program's argument order behind the page arenas; None for a model
@@ -128,9 +131,25 @@ class ModelSpec:
     n_state: int = 0
     #: shape of the int32 counters behind the tokens in ``out``
     aux_shape: tuple[int, ...] = ()
-    #: ``(aux, live_tokens) -> {ServingStats field: this step's addend}``:
-    #: the family names what its counters count, once, for every reader
-    count_aux: Optional[Callable[[Any, int], dict[str, int]]] = None
+    #: ``(aux, live_tokens, kernels) -> (counters, attrs)``: the family names
+    #: what its counters count, once, for every reader.  ``counters`` is this
+    #: step's addend to ``ServingStats.model`` under the family's own names
+    #: (and ``backend.last_counters``), ``attrs`` the ``step`` span's
+    #: attributes of them (``backend.last_attrs``); ``kernels`` is what
+    #: :attr:`kernels` returned for this backend
+    count_aux: Optional[Callable[[Any, int, Mapping[str, str]],
+                                 tuple[dict[str, int], dict[str, str]]]] = None
+    #: ``(platform, mesh_devices) -> {role: kernel's name}``: for the
+    #: platform the arenas live on and the number of devices the program is
+    #: partitioned over, the kernel the LOWERED step program holds in each
+    #: role the family has, among ``walk`` (the attention's walk over the
+    #: whole-row kind of page), ``expert`` (the expert layer's grouped
+    #: products), ``state`` (a recurrence over state slots).  A role that is
+    #: absent or "" is the ``jax.numpy`` / ``ragged_dot`` form.  Built from
+    #: the kernel modules' own ``holds_kernel``, the predicate the trace-time
+    #: choice uses, so the rule is written once a kernel; the kernels'
+    #: modules (Pallas) are imported inside this call
+    kernels: Callable[[str, int], Mapping[str, str]] = lambda platform, mesh_devices: {}
 
     @property
     def kv_whole_row(self) -> bool:

@@ -219,11 +219,11 @@ class ShardedServingBackend(LlamaServingBackend):
         import jax
         from jax.sharding import NamedSharding
 
-        from ..models import llama
+        from ..models import attention
 
-        arena = NamedSharding(self.mesh, llama.KV_ARENA_SPEC)
+        arena = NamedSharding(self.mesh, attention.KV_ARENA_SPEC)
         return jax.jit(
-            lambda: llama.init_kv_pages(self.cfg, self.num_pages, self.page_size),
+            lambda: attention.init_kv_pages(self.cfg, self.num_pages, self.page_size),
             out_shardings=(arena, arena),
         )()
 

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from benchmarks.families import afmoe_reference as ref_mod
-from cordum_tpu.models import afmoe, llama
+from cordum_tpu.models import afmoe, attention, llama
 from cordum_tpu.serving.backend import ServingBackend, StepEntry
 from cordum_tpu.serving.engine import GenRequest, ServingEngine
 from cordum_tpu.serving.modelspec import UnsupportedForModel, spec_for
@@ -54,7 +54,7 @@ def backend_for(cfg, params, *, max_seqs=4, budget=12, pages=160):
 def block_tokens(cfg, be):
     """Positions a block of the walk holds, by the program's rule over the
     family's shapes (float32 K and V by head, both kinds alike)."""
-    bt = PS * llama.attn_block_pages(PS, be.pages_per_seq, 2 * cfg.n_kv_heads * cfg.head_dim * 4,
+    bt = PS * attention.attn_block_pages(PS, be.pages_per_seq, 2 * cfg.n_kv_heads * cfg.head_dim * 4,
                                      cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
     assert be.attn_block_tokens == bt
     return bt
@@ -165,13 +165,13 @@ def test_paged_attention_with_a_window_reads_a_ring(window, ring_pages, block_pa
             vp[0, tables[i, (p // ps) % ring_pages], p % ps] = v[p]
         want.append(dense_window_attention(q, k, v, window)[-1])
         qs.append(q[-1])
-    got = llama.paged_attention(
+    got = attention.paged_attention(
         jnp.asarray(np.stack(qs)), jnp.asarray(kp), jnp.asarray(vp), 0,
         jnp.asarray(np.concatenate([tables, tables[:1] * 0])),  # and the padding row
         jnp.arange(len(lens), dtype=jnp.int32),
         jnp.asarray([n - 1 for n in lens], jnp.int32), block_pages, window=window)
     np.testing.assert_allclose(np.asarray(got), np.stack(want), atol=2e-5)
-    assert llama.window_ring_pages(window, ps, 1) <= ring_pages
+    assert attention.window_ring_pages(window, ps, 1) <= ring_pages
 
 
 @pytest.mark.parametrize("rows", [
@@ -192,10 +192,10 @@ def test_the_host_counts_the_trips_the_program_walks(rows):
     book = Rows(be, len(rows))
     be.step([book.entry(i, [1 + i] * n, start) for i, (start, n) in enumerate(rows)])
     bt = block_tokens(cfg, be)
-    w, g = llama.ATTN_TILE_SLOTS, llama.ATTN_GROUP_TILES
+    w, g = attention.ATTN_TILE_SLOTS, attention.ATTN_GROUP_TILES
     tiles = sorted(((s + k, min(s + k + w, s + n) - 1) for s, n in rows for k in range(0, n, w)),
                    key=lambda tile: -tile[1])
-    assert len(tiles) <= llama.attn_tiles(be.max_batch_tokens, be.max_seqs)
+    assert len(tiles) <= attention.attn_tiles(be.max_batch_tokens, be.max_seqs)
     full = [max(hi // bt for _, hi in tiles[a:a + g]) + 1 for a in range(0, len(tiles), g)]
     ring = [max(hi // bt - max(lo - cfg.window + 1, 0) // bt for lo, hi in tiles[a:a + g]) + 1
             for a in range(0, len(tiles), g)]
@@ -310,15 +310,15 @@ async def test_engine_serves_mixed_rows_bounded_and_counted(held):
     eng.window_allocator.check_consistency()
     assert eng.allocator.used_pages == 0 and eng.window_allocator.used_pages == 0
     layers = cfg.n_expert_layers
-    assert st.moe_assignments == sum(t for t, _, _, _ in seen) * cfg.top_k * layers
-    assert st.moe_assignments_here == sum(int(c.sum()) for _, c, _, _ in seen)
-    assert st.moe_experts_touched == sum(int((c > 0).sum()) for _, c, _, _ in seen)
-    assert st.moe_max_expert_load == sum(int(c.max(axis=1).sum()) for _, c, _, _ in seen)
+    assert st.model["moe_assignments"] == sum(t for t, _, _, _ in seen) * cfg.top_k * layers
+    assert st.model["moe_assignments_here"] == sum(int(c.sum()) for _, c, _, _ in seen)
+    assert st.model["moe_experts_touched"] == sum(int((c > 0).sum()) for _, c, _, _ in seen)
+    assert st.model["moe_max_expert_load"] == sum(int(c.max(axis=1).sum()) for _, c, _, _ in seen)
     assert st.window_blocks_walked == sum(b for _, _, _, b in seen)
     if held == 16:
-        assert st.moe_assignments_here == st.moe_assignments  # all experts are here
+        assert st.model["moe_assignments_here"] == st.model["moe_assignments"]  # all experts are here
     else:
-        assert 0 < st.moe_assignments_here < st.moe_assignments
+        assert 0 < st.model["moe_assignments_here"] < st.model["moe_assignments"]
     # the window layers' walk is bounded by the window and a step's buffer,
     # the full layer's is not
     bt = block_tokens(cfg, be)

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from benchmarks.families import axk1_reference as ref_mod
-from cordum_tpu.models import afmoe, axk1, llama
+from cordum_tpu.models import afmoe, attention, axk1, llama
 from cordum_tpu.serving.backend import ServingBackend, StepEntry
 from cordum_tpu.serving.engine import GenRequest, ServingEngine
 from cordum_tpu.serving.modelspec import UnsupportedForModel, spec_for
@@ -110,7 +110,7 @@ def test_paged_prefill_and_decode_equal_the_published_reference(case):
     elif case == "narrow-tiles":
         # 64 query heads over the one key head: tiles of 4 slots, not 8
         cfg = tiny(n_heads=64, nope_dim=4, rope_dim=2, v_dim=4)
-        assert llama.attn_tile_slots(cfg.n_heads // cfg.n_kv_heads) == 4
+        assert attention.attn_tile_slots(cfg.n_heads // cfg.n_kv_heads) == 4
         lens, chunks = [90, 30, 11], [[6, 7, 5, 6, 6] * 3, [5, 6], [4, 3, 4]]
     elif case == "a-grown-block":
         # 512 B a position under a tile of 4 slots x 64 heads x 96 values
@@ -138,7 +138,7 @@ def test_paged_prefill_and_decode_equal_the_published_reference(case):
         # positions again, two tiles in one group of 8 walked to the longer
         # row's second block
         be.step([entry(be, i, seq[-1:], len(seq) - 1) for i, seq in enumerate(seqs)])
-        w, g = llama.attn_tile_slots(cfg.n_heads), llama.ATTN_GROUP_TILES
+        w, g = attention.attn_tile_slots(cfg.n_heads), attention.ATTN_GROUP_TILES
         assert be.last_attn_blocks == (2, 2048 // 256)
         assert be.last_attn_rows == (g * 2, g * w * 2) and be.last_attn_live == 2 + 1
 
@@ -167,7 +167,7 @@ def test_absorbed_equals_published_at_float32():
     tables = np.zeros((2, 16), np.int32)
     tables[0, :pages] = 1 + np.arange(pages)
     f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
-    ol = llama.paged_attention(
+    ol = attention.paged_attention(
         f32(np.concatenate([ql, q_rope], -1)), f32(arena), None, 0, jnp.asarray(tables),
         jnp.zeros((t,), jnp.int32), jnp.arange(t, dtype=jnp.int32), 2, v_dim=rank, scale=scale)
     assert ol.shape == (t, h, rank)
@@ -404,10 +404,10 @@ async def test_the_counters_equal_a_host_recount():
     st = eng.stats
     assert st.prefix_hits == 1 and st.prefix_hit_tokens == (60 + 8 - 1) // PS * PS == 64
     assert st.prefill_tokens == 60 + (98 - 64) + 6 + 100
-    bt = PS * llama.attn_block_pages(PS, be.pages_per_seq, cfg.latent_width * 4,
+    bt = PS * attention.attn_block_pages(PS, be.pages_per_seq, cfg.latent_width * 4,
                                      cfg.n_heads, 1, cfg.kv_rank)
     assert be.attn_block_tokens == bt
-    w, g = llama.attn_tile_slots(cfg.n_heads), llama.ATTN_GROUP_TILES
+    w, g = attention.attn_tile_slots(cfg.n_heads), attention.ATTN_GROUP_TILES
     computed = live = held = 0
     for rows, _, _, _ in seen:
         tiles = sorted((min(s + k + w, s + n) - 1 for s, n in rows for k in range(0, n, w)),
@@ -421,10 +421,10 @@ async def test_the_counters_equal_a_host_recount():
     assert be.page_bytes == cfg.n_layers * PS * cfg.latent_width * 4
     assert st.kv_bytes_behind_rows == held * be.page_bytes
     layers = cfg.n_expert_layers
-    assert st.moe_assignments == sum(n for rows, _, _, _ in seen for _, n in rows) * cfg.top_k * layers
-    assert st.moe_assignments_here == sum(int(c.sum()) for _, c, _, _ in seen)
-    assert st.moe_experts_touched == sum(int((c > 0).sum()) for _, c, _, _ in seen)
-    assert 0 < st.moe_assignments_here < st.moe_assignments
+    assert st.model["moe_assignments"] == sum(n for rows, _, _, _ in seen for _, n in rows) * cfg.top_k * layers
+    assert st.model["moe_assignments_here"] == sum(int(c.sum()) for _, c, _, _ in seen)
+    assert st.model["moe_experts_touched"] == sum(int((c > 0).sum()) for _, c, _, _ in seen)
+    assert 0 < st.model["moe_assignments_here"] < st.model["moe_assignments"]
 
 
 async def test_what_cannot_carry_a_latent_page_refuses():

@@ -135,3 +135,167 @@ async def test_the_fake_and_the_real_backend_see_the_same_engine(kind):
     }
     eng.allocator.check_consistency()
     await eng.stop()
+
+
+# ----------------------------------------------------------------- the seam
+#: what names a model family or a kernel's module: neither the engine nor the
+#: backend says any of them (``serving/modelspec.py``: the model's
+#: specification states its kernels and names its counters)
+FAMILY_WORDS = ("moe_", "kda_", "ssd", "afmoe", "axk1", "bailing", "longcat", "falcon",
+                "latent_walk", "head_walk", "expert_mlp")
+
+
+def test_the_engine_and_the_backend_name_no_family_and_no_kernel():
+    import ast
+    import dataclasses
+    import inspect
+    import io
+    import tokenize
+
+    from cordum_tpu.serving import backend, engine
+    from cordum_tpu.serving.engine import ServingStats
+
+    for module in (engine, backend):
+        source = inspect.getsource(module)
+        code = " ".join(tok.string for tok in tokenize.generate_tokens(io.StringIO(source).readline)
+                        if tok.type != tokenize.COMMENT)
+        assert not [w for w in FAMILY_WORDS if w in code], module.__name__
+        assert "getattr(self.cfg" not in code  # no probe of a model's shape
+        # what the module imports of ``cordum_tpu.models``, wherever in it
+        taken = set()
+        for node in ast.walk(ast.parse(source)):
+            if isinstance(node, ast.ImportFrom) and (node.module or "").split(".")[0] == "models":
+                taken |= {f"{node.module}.{a.name}" for a in node.names}
+            assert not (isinstance(node, ast.Import)
+                        and any(".models" in a.name for a in node.names))
+        # the walk's own module, and the default model of a backend built with none
+        assert taken == ({"models.attention", "models.llama.LlamaConfig"} if module is backend
+                         else set())
+    hints = typing.get_type_hints(StepBackend)
+    assert {"kernels", "last_attrs", "last_counters"} <= set(hints)
+    assert not {"walk_kernel", "expert_kernel", "state_kernel"} & set(dir(StepBackend))
+    assert "last_attrs" in StepBackend.REPORT
+    fields = {f.name for f in dataclasses.fields(ServingStats)}
+    assert "model" in fields and {"state_slots_peak", "state_decode_rows",
+                                  "state_chunk_tokens"} <= fields
+    assert not [f for f in fields if f.startswith(("moe_", "kda_", "state_rows_"))]
+
+
+@pytest.mark.parametrize("kind", ["fake", "real"])
+async def test_a_made_up_family_needs_no_edit_of_the_engine_or_the_backend(kind, monkeypatch):
+    """A specification made up here, with a counter, a span attribute and a
+    kernel label of its own, reaches ``ServingStats.model``, the ``step`` span
+    and (through a real backend) the ``startup.kernels`` phase."""
+    import dataclasses
+
+    from cordum_tpu.infra.bus import LoopbackBus
+    from cordum_tpu.obs import startup
+    from cordum_tpu.obs.tracer import Tracer
+    from cordum_tpu.protocol import subjects as subj
+    from cordum_tpu.serving import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "STEP_SAMPLE_PERIOD_NS", 0)  # every cycle a ``step`` trace
+    if kind == "fake":
+        be = FakeBackend(max_context=64, **SHAPES)
+        inner = be.step
+
+        def step(entries):  # a backend's report of a step, under names nobody declared
+            fed = sum(len(e.tokens) for e in entries)
+            be.last_counters = {"made_up_fed": fed, "made_up_sevens": 7}
+            be.last_attrs = {"made_up": f"{fed}/made_up_step"}
+            return inner(entries)
+
+        be.step = step
+    else:
+        import jax.numpy as jnp
+
+        from cordum_tpu.models import llama
+
+        base = llama.LlamaConfig.tiny().serving_spec()
+
+        def program(sample_logits):
+            dense = base.program(sample_logits)
+
+            def ragged_program(p, kp, vp, toks, pos, pt, ts, oi):
+                out, kp, vp = dense(p, kp, vp, toks, pos, pt, ts, oi)
+                fed = jnp.sum(ts < pt.shape[0] - 1, dtype=jnp.int32)
+                return jnp.concatenate([out, jnp.stack([fed, jnp.int32(7)])]), kp, vp
+
+            return ragged_program
+
+        def count_aux(aux, live_tokens, kernels):
+            assert int(aux[0]) == live_tokens
+            return ({"made_up_fed": int(aux[0]), "made_up_sevens": int(aux[1])},
+                    {"made_up": f"{int(aux[0])}/{kernels['state']}"})
+
+        spec = dataclasses.replace(
+            base, family="made-up", program=program, aux_shape=(2,), count_aux=count_aux,
+            kernels=lambda platform, mesh_devices: {
+                "walk": "", "state": "made_up_step", "where": f"{platform}-{mesh_devices}"})
+        be = ServingBackend(spec, num_pages=37, page_size=4, max_seqs=4, max_batch_tokens=8,
+                            max_context=64)
+    bus, spans = LoopbackBus(), []
+
+    async def on_span(subject, pkt):
+        spans.append(pkt.span)
+
+    await bus.subscribe(subj.TRACE_SPAN, on_span)
+    eng = ServingEngine(be, run_blocking=run_blocking, tracer=Tracer("worker", bus),
+                        max_new_tokens_cap=16)
+    eng.worker_id = "w-made-up"
+    out = await eng.submit(GenRequest(prompt=list(range(10, 23)), max_new_tokens=5, stream=False),
+                           job_id="a", trace_id="tr-a", parent_span_id="ex-a")
+    assert len(out["tokens"]) == 5
+    await eng.stop()
+    await bus.drain()
+    st = eng.stats
+    # 13 prompt tokens and the four sampled ones that were fed back
+    assert st.model == {"made_up_fed": 13 + 4, "made_up_sevens": 7 * st.steps}
+    assert st.model["a name nobody counted"] == 0
+    steps = [s for s in spans if s.name == "step"]
+    assert len(steps) == st.steps
+    assert sum(int(s.attrs["made_up"].split("/")[0]) for s in steps) == 13 + 4
+    assert {s.attrs["made_up"].split("/")[1] for s in steps} == {"made_up_step"}
+    if kind == "fake":
+        # a backend that counts no blocks says nothing of a walk
+        assert all("walk_kernel" not in s.attrs for s in steps)
+        return
+    assert {s.attrs["walk_kernel"] for s in steps} == {"none"}
+    assert be.kernels == {"walk": "", "state": "made_up_step", "where": "cpu-1"}
+    phase = [p for p in startup.phases() if p.name == "startup.kernels"]
+    assert len(phase) == 1
+    assert phase[0].attrs == {"walk": "none", "state": "made_up_step", "where": "cpu-1"}
+    assert [p.name for p in startup.phases() if p.id == phase[0].parent] == ["startup.state"]
+
+
+# a chunk of 20 at position 37, decode rows at 5, 63 and 90, a draft row of 1 + 3 at 48
+WALK_ROWS = [(37, 20), (5, 1), (63, 1), (90, 1), (48, 4)]
+
+
+@pytest.mark.parametrize("tile_slots, block_tokens, window, own_ends, want", [
+    # what the backend's own method reported for this step before the count
+    # left it for ``attention.count_walk`` (run on the tree of PR 44 with these shapes):
+    # (longest walk over whole rows, over rings, (table rows gathered, query
+    # slots computed), fed slots that needed their block)
+    (8, (16,), None, False, (6, 0, (48, 384), 96)),
+    (8, (16,), None, True, (6, 0, (26, 208), 96)),
+    (4, (32,), None, False, (3, 0, (32, 128), 54)),
+    (4, (32,), None, True, (3, 0, (18, 72), 54)),
+    (8, (16, 8), 24, False, (6, 5, (88, 704), 197)),
+    (8, (16, 8), 24, True, (6, 5, (66, 528), 197)),
+])
+def test_the_hosts_count_of_a_mixed_step_is_what_it_was(tile_slots, block_tokens, window,
+                                                        own_ends, want):
+    import numpy as np
+
+    from cordum_tpu.models import attention
+
+    spans, positions, lo = [], np.zeros(32, np.int32), 0
+    for start, n in WALK_ROWS:
+        positions[lo:lo + n] = np.arange(start, start + n)
+        spans.append((lo, lo + n))
+        lo += n
+    got = attention.count_walk(np.array(spans), positions, tile_slots, block_tokens, window,
+                               own_ends)
+    assert got == want
+    assert all(type(n) is int for n in (got[0], got[1], *got[2], got[3]))

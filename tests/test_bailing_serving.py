@@ -415,8 +415,8 @@ def test_the_engine_serves_it_and_turns_slots_over():
     assert st.state_slots_peak == 3 and eng.state_allocator.used == 0
     eng.state_allocator.check_consistency()
     eng.allocator.check_consistency()
-    assert st.kda_chunk_tokens + st.kda_decode_rows == st.prefill_tokens + st.decoded_tokens - 6
-    assert st.kda_decode_rows >= 6 * 5 and st.moe_assignments > 0
+    assert st.state_chunk_tokens + st.state_decode_rows == st.prefill_tokens + st.decoded_tokens - 6
+    assert st.state_decode_rows >= 6 * 5 and st.model["moe_assignments"] > 0
     assert st.prefix_hits == 0 and st.drafted_tokens == 0
 
 

@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import chip_smoke
-from cordum_tpu.models import embedder, latent_walk, llama
+from cordum_tpu.models import attention, embedder, latent_walk, llama
 from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
 from cordum_tpu.worker.handlers import make_matmul_program
 
@@ -148,7 +148,7 @@ def test_page_programs_compile_on_the_real_arena(one_chip, name):
     block = jax.ShapeDtypeStruct(shape[:1] + shape[2:], cfg.dtype, sharding=one_chip)
     args = {"_gather_page": (arena, pid), "_scatter_page": (arena, pid, block),
             "_copy_page": (arena, pid, pid)}[name]
-    compiled = getattr(llama, name).lower(*args).compile()
+    compiled = getattr(attention, name).lower(*args).compile()
     arena_bytes = arena.size * arena.dtype.itemsize
     ma = compiled.memory_analysis()
     if name != "_gather_page":
@@ -191,7 +191,7 @@ def test_the_latent_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
     assert 12.0e9 < device_bytes(compiled) <= 0.85 * HBM_BYTES
     assert holds_walk_kernel(compiled.as_text())  # the walk is the kernel, with the arena in place
     pid = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    copied = llama._copy_page_in_place.lower(arena, pid, pid).compile().memory_analysis()
+    copied = attention._copy_page_in_place.lower(arena, pid, pid).compile().memory_analysis()
     assert copied.alias_size_in_bytes >= arena_bytes and copied.temp_size_in_bytes < 0.1e9
 
 
@@ -433,7 +433,7 @@ def test_sharded_ragged_step_full_depth_on_four_chips(topo):
     pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), llama.param_specs(cfg))
     params, arena, layout, feed = serving_shapes(
         cfg, SZ["tp_pages"], cfg.max_seq_len, pshard,
-        NamedSharding(mesh, llama.KV_ARENA_SPEC), NamedSharding(mesh, P()))
+        NamedSharding(mesh, attention.KV_ARENA_SPEC), NamedSharding(mesh, P()))
     program = make_ragged_program(cfg, layout, sample_logits=True, donate=True)
     compiled = program.lower(params, arena, arena, feed).compile()
     weight_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))

@@ -194,9 +194,10 @@ def test_a_program_without_an_expert_layer_never_imports_the_module():
 
 
 async def test_the_counter_says_how_the_products_engage(monkeypatch):
-    """``StepBackend.expert_kernel``, the ``step`` span's ``expert_kernel`` and
-    ``moe_items``, ``ServingStats.moe_kernel_items``, the label on
-    ``cordum_serving_compile_total`` and the ``startup.expert_kernel`` phase:
+    """``StepBackend.kernels``' ``expert`` role, the ``step`` span's
+    ``expert_kernel`` and ``moe_items``, ``ServingStats.model``'s
+    ``moe_kernel_items``, the label on ``cordum_serving_compile_total`` and the
+    ``startup.kernels`` phase:
     ``none`` / 0 on the CPU (the arenas' platform holds ``ragged_dot``), and
     under a backend that reports the kernel (as one on the TPU does) the items
     the kernel's own rule makes of each step's counts."""
@@ -211,7 +212,7 @@ async def test_the_counter_says_how_the_products_engage(monkeypatch):
     from cordum_tpu.serving.backend import ServingBackend, StepBackend
     from cordum_tpu.serving.engine import GenRequest, ServingEngine
 
-    assert StepBackend.expert_kernel == ""
+    assert StepBackend.kernels == {} and not hasattr(StepBackend, "expert_kernel")
     monkeypatch.setattr(engine_mod, "STEP_SAMPLE_PERIOD_NS", 0)  # every cycle a ``step`` trace
     cfg = afmoe.AfmoeConfig(first_expert=4, experts_held=8)
     metrics, bus, spans = Metrics(), LoopbackBus(), []
@@ -245,18 +246,21 @@ async def test_the_counter_says_how_the_products_engage(monkeypatch):
             trace_id=f"tr-{job}", parent_span_id=f"ex-{job}"), timeout=240)
 
     await generate("a")
-    assert be.expert_kernel == "" and eng.stats.moe_kernel_items == 0 and sum(seen) > 0
+    assert be.kernels["expert"] == "" and eng.stats.model["moe_kernel_items"] == 0 and sum(seen) > 0
+    assert "moe_kernel_items" not in be.last_counters  # the key is the kernel's: absent without it
     assert metrics.serving_compiles.value(
         entry="ragged", walk_kernel="none", expert_kernel="none") == 1
-    phase = [p for p in startup.phases() if p.name == "startup.expert_kernel"]
-    assert len(phase) == 1 and phase[0].attrs["expert_kernel"] == "none"
+    phase = [p for p in startup.phases() if p.name == "startup.kernels"]
+    assert len(phase) == 1 and phase[0].attrs == {"walk": "none", "expert": "none"}
     assert [p.name for p in startup.phases() if p.id == phase[0].parent] == ["startup.state"]
     n_cpu = len(seen)
-    be.expert_kernel = expert_mlp.KERNEL_NAME  # as a backend whose arenas live on the TPU reports
+    # as a backend whose arenas live on the TPU reports, by the specification's own rule
+    be.kernels = {**be.kernels, "expert": be.spec.kernels(expert_mlp.PLATFORM, 1)["expert"]}
+    assert be.kernels["expert"] == expert_mlp.KERNEL_NAME
     await generate("b")
     await eng.stop()
     await bus.drain()
-    assert eng.stats.moe_kernel_items == sum(seen[n_cpu:]) > 0
+    assert eng.stats.model["moe_kernel_items"] == sum(seen[n_cpu:]) > 0
     steps = sorted((s for s in spans if s.name == "step"), key=lambda s: s.start_us)
     assert steps and all({"expert_kernel", "moe_items", "moe_touched"} <= set(s.attrs) for s in steps)
     cpu = [s for s in steps if s.attrs["expert_kernel"] == "none"]

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from benchmarks.families import falcon_h1_reference as ref_mod
-from cordum_tpu.models import falcon_h1, kda, llama, ssd
+from cordum_tpu.models import attention, falcon_h1, kda, llama, ssd
 from cordum_tpu.serving.backend import ServingBackend, StepEntry
 from cordum_tpu.serving.engine import GenRequest, ServingEngine
 from tests.test_bailing_serving import (ROW_TABLES, SLOTS, T_BUF, entry, holds_the_jnp_form,
@@ -148,7 +148,7 @@ def test_chunked_prefill_and_decode_through_slots_and_pages_equal_the_reference(
         5, False, True, True)
     assert be.state_bytes == 2 * (16 * 8 * 8 * 4 + 3 * cfg.conv_dim * 4)
     assert be.page_bytes == 2 * 2 * PS * 16 * 4
-    assert be.state_kernel == ""  # the CPU holds the jax.numpy form
+    assert be.kernels == {"walk": "", "state": ""}  # the CPU holds the jax.numpy forms
     for seq, p in zip(seqs, preds):
         assert len(p) == len(seq)
         g = gaps(cfg, params, seq, p)
@@ -308,7 +308,7 @@ def test_the_recurrence_is_the_references_scan():
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)],
                          ids=["float32", "bfloat16"])
 def test_the_walk_at_five_query_heads_a_key_head_is_plain_attention(dtype, tol):
-    """``llama.paged_attention`` at 20 / 4 heads (the first odd ratio: a tile's
+    """``attention.paged_attention`` at 20 / 4 heads (the first odd ratio: a tile's
     8 slots x 5 heads fill no whole multiple of 16 sublanes) against
     ``llama._attention`` over the whole row: a chunk of 21 slots at depth 30
     and two decode rows, K and V read through the pages."""
@@ -333,7 +333,7 @@ def test_the_walk_at_five_query_heads_a_key_head_is_plain_attention(dtype, tol):
     q = jnp.concatenate([qa[n - f:] for qa, n, f in zip(q_all, lens, fed)])
     token_seq = np.repeat(np.arange(3), fed).astype(np.int32)
     positions = np.concatenate([np.arange(n - f, n) for n, f in zip(lens, fed)]).astype(np.int32)
-    got = llama.paged_attention(q, k_pages, v_pages, 0, jnp.asarray(tables),
+    got = attention.paged_attention(q, k_pages, v_pages, 0, jnp.asarray(tables),
                                 jnp.asarray(token_seq), jnp.asarray(positions), 2)
     want = jnp.concatenate([
         llama._attention(qa[None], ka[None], va[None], cfg)[0, n - f:]
@@ -383,16 +383,19 @@ def test_eighty_sessions_turn_over_sixteen_slots_without_a_stale_state():
     eng.allocator.check_consistency()
     # the program's own counters (a layer's): every fed token went through the
     # scan, every session started from zeros exactly once
-    assert st.state_tokens_scanned == st.prefill_tokens + st.decoded_tokens - 80
-    assert st.state_tokens_scanned == st.kda_chunk_tokens + st.kda_decode_rows
-    assert st.state_rows_fresh == 80 and st.state_rows_advanced == st.occupancy_sum
-    assert st.moe_assignments == 0 and st.prefix_hits == 0 and st.drafted_tokens == 0
+    counted = st.model  # under the family's own names (``falcon_h1.step_counters``)
+    assert counted["state_tokens_scanned"] == st.prefill_tokens + st.decoded_tokens - 80
+    assert counted["state_tokens_scanned"] == st.state_chunk_tokens + st.state_decode_rows
+    assert counted["state_rows_fresh"] == 80 and counted["state_rows_advanced"] == st.occupancy_sum
+    assert set(counted) == {"state_rows_advanced", "state_tokens_scanned", "state_rows_fresh",
+                            "state_rows_prefetched"}  # no other family's counter
+    assert counted["moe_assignments"] == 0 and st.prefix_hits == 0 and st.drafted_tokens == 0
 
 
 async def test_the_step_span_and_the_startup_record_say_how_the_recurrence_engages(monkeypatch):
-    """``StepBackend.state_kernel``, the ``step`` span's ``state_kernel`` /
-    ``state_fresh`` / ``state_prefetched`` / ``state_rows`` / ``kda_tokens``
-    and the ``startup.ssd_kernel`` phase: ``none`` on the CPU (the arenas'
+    """``StepBackend.kernels``' ``state`` role, the ``step`` span's
+    ``state_kernel`` / ``state_fresh`` / ``state_prefetched`` / ``state_rows``
+    / ``state_tokens`` and the ``startup.kernels`` phase: ``none`` on the CPU (the arenas'
     platform holds the ``jax.numpy`` form), the kernel's name under a backend
     that reports it (as one on the TPU does); no expert layer's attribute.
     ``state_prefetched`` counts the rows the kernel's pipeline reads ahead:
@@ -406,7 +409,7 @@ async def test_the_step_span_and_the_startup_record_say_how_the_recurrence_engag
     from cordum_tpu.serving import engine as engine_mod
     from cordum_tpu.serving.backend import StepBackend
 
-    assert StepBackend.state_kernel == ""
+    assert StepBackend.kernels == {} and not hasattr(StepBackend, "state_kernel")
     monkeypatch.setattr(engine_mod, "STEP_SAMPLE_PERIOD_NS", 0)  # every cycle a ``step`` trace
     cfg = tiny(n_layers=1)
     metrics, bus, spans = Metrics(), LoopbackBus(), []
@@ -427,28 +430,27 @@ async def test_the_step_span_and_the_startup_record_say_how_the_recurrence_engag
             trace_id=f"tr-{job}", parent_span_id=f"ex-{job}"), timeout=240)
 
     await generate("a")
-    assert be.state_kernel == "" and be.expert_kernel == "" and be.walk_kernel == ""
-    phase = [p for p in startup.phases() if p.name == "startup.ssd_kernel"]
-    assert len(phase) == 1 and phase[0].attrs["ssd_kernel"] == "none"
+    assert be.kernels == {"walk": "", "state": ""}  # no expert layer: no such role
+    phase = [p for p in startup.phases() if p.name == "startup.kernels"]
+    # K and V by head: the walk's kernel is asked for too, and the CPU holds neither
+    assert len(phase) == 1 and phase[0].attrs == {"walk": "none", "state": "none"}
     assert [p.name for p in startup.phases() if p.id == phase[0].parent] == ["startup.state"]
-    # K and V by head: the walk's kernel is asked for too, and the CPU holds none
-    walk = [p for p in startup.phases() if p.name == "startup.walk_kernel"]
-    assert len(walk) == 1 and walk[0].attrs["walk_kernel"] == "none"
-    assert not [p for p in startup.phases() if p.name == "startup.expert_kernel"]
-    be.state_kernel = ssd.KERNEL_NAME  # as a backend whose arenas live on the TPU reports
+    assert not [p for p in startup.phases() if p.name.endswith("_kernel")]
+    # as a backend whose arenas live on the TPU reports, by the specification's own rule
+    be.kernels = {**be.kernels, "state": be.spec.kernels(ssd.PLATFORM, 1)["state"]}
     await generate("b")
-    assert eng.stats.state_rows_prefetched == 0  # a step of one fed row reads nothing ahead
+    assert eng.stats.model["state_rows_prefetched"] == 0  # a step of one fed row reads nothing ahead
     await asyncio.gather(generate("c"), generate("d"))
     await eng.stop()
     await bus.drain()
     steps = sorted((s for s in spans if s.name == "step"), key=lambda s: s.start_us)
     assert steps and all({"state_kernel", "state_fresh", "state_prefetched", "state_rows",
-                          "kda_tokens"} <= set(s.attrs)
+                          "state_tokens"} <= set(s.attrs)
                          and "moe_here" not in s.attrs for s in steps)
     assert {s.attrs["state_kernel"] for s in steps} == {"none", ssd.KERNEL_NAME}
-    assert sum(int(s.attrs["state_fresh"]) for s in steps) == 4 == eng.stats.state_rows_fresh
+    assert sum(int(s.attrs["state_fresh"]) for s in steps) == 4 == eng.stats.model["state_rows_fresh"]
     ahead = [int(s.attrs["state_prefetched"]) for s in steps]
-    assert sum(ahead) == eng.stats.state_rows_prefetched > 0
+    assert sum(ahead) == eng.stats.model["state_rows_prefetched"] > 0
     for s, n in zip(steps, ahead):  # the carried rows, less the first fed row where it is one
         carried = int(s.attrs["state_rows"]) - int(s.attrs["state_fresh"])
         assert max(carried - 1, 0) <= n <= carried and n < int(s.attrs["state_rows"])

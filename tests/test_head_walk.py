@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 from jax.experimental import pallas as pl
 
-from cordum_tpu.models import afmoe, head_walk, latent_walk, llama
+from cordum_tpu.models import afmoe, attention, head_walk, latent_walk
 from cordum_tpu.serving.backend import ServingBackend, StepEntry
 
 HD, PS, BP = 32, 4, 2  # blocks of 8 positions
@@ -42,9 +42,9 @@ def kernel_walk(monkeypatch):
 
     monkeypatch.setattr(jax.lax, "platform_dependent", choose)
     monkeypatch.setattr(pl, "pallas_call", functools.partial(pl.pallas_call, interpret=True))
-    llama.paged_attention.clear_cache()
+    attention.paged_attention.clear_cache()
     yield take
-    llama.paged_attention.clear_cache()
+    attention.paged_attention.clear_cache()
 
 
 def feed_of(rows, rep, t_buf, s_rows, p_width, n_pages, dtype=jnp.float32, seed=0):
@@ -126,7 +126,7 @@ def test_the_kernel_equals_the_jnp_walk_and_a_plain_reference(rep, mix, dtype, k
     t_buf, s_rows, row = 40, 8, 1
     k, v, tables, token_seq, positions, q = feed_of(
         spec["rows"], rep, t_buf, s_rows, spec.get("p_width", 14), 120, jnp.dtype(dtype))
-    walk = llama.paged_attention.__wrapped__
+    walk = attention.paged_attention.__wrapped__
     args = (row, tables, token_seq, positions, BP)
     bad_k, bad_v = (jnp.asarray(poisoned(a, tables, token_seq, positions), a.dtype) for a in (k, v))
     got = np.asarray(walk(q, bad_k, bad_v, *args), np.float32)
@@ -153,8 +153,8 @@ def test_a_tile_reads_nothing_past_its_own_last_block(dtype, live, buffers, kern
     g, kvh, rep, w, n_pages, p_width = 8, 4, 5, 8, 40, 16
     newest = np.array([7 * BT + 5, 4 * BT, 4 * BT - 1, BT, 3, 0, 2 * BT, 9])
     live = np.array(live, bool)
-    trips = head_walk.tile_trips(newest, live, BT)
-    assert head_walk.tile_trips is latent_walk.tile_trips
+    trips = attention.tile_trips(newest, live, BT)  # the one trips rule: neither kernel's own
+    assert not hasattr(head_walk, "tile_trips") and not hasattr(latent_walk, "tile_trips")
     assert list(trips) == [t if on else 0 for t, on in zip([8, 5, 4, 2, 1, 1, 3, 2], live)]
     tab = np.zeros((g, p_width), np.int32)
     for i in range(g):  # every tile its own pages
@@ -197,10 +197,12 @@ def test_the_host_counts_each_kind_of_page_as_its_program_walks_it(kernel_walk, 
     be = ServingBackend(cfg, num_pages=300, page_size=PS, max_seqs=6, max_batch_tokens=6 + 20,
                         params=afmoe.init_params(jax.random.PRNGKey(1), cfg))
     be._ensure()
-    assert be.walk_kernel == ""  # the arenas live on the CPU
-    be.walk_kernel = head_walk.KERNEL_NAME  # as a backend on the TPU reports
+    assert be.kernels == {"walk": "", "expert": ""}  # the arenas live on the CPU
+    # as a backend on the TPU reports, by the specification's own rule
+    be.kernels = be.spec.kernels(head_walk.PLATFORM, 1)
+    assert be.kernels["walk"] == head_walk.KERNEL_NAME
     bt, wbt = be._block_tokens
-    w, g = llama.attn_tile_slots(cfg.n_heads // cfg.n_kv_heads), llama.ATTN_GROUP_TILES
+    w, g = attention.attn_tile_slots(cfg.n_heads // cfg.n_kv_heads), attention.ATTN_GROUP_TILES
     assert w == 8 and bt == wbt == 64
     admitted = []
     real = head_walk.walk_group
@@ -228,15 +230,18 @@ def test_the_host_counts_each_kind_of_page_as_its_program_walks_it(kernel_walk, 
     positions = np.concatenate([d + np.arange(n) for d, n in rows])
     lo = np.array([0, 8, 16, 20, 21, 22, 23])
     hi = np.array([8, 16, 20, 21, 22, 23, 24])
-    order = llama.walk_order(positions[hi - 1], np.ones(7, bool))
-    ringed = g * int(llama.walk_blocks(positions[lo][order], positions[hi - 1][order], wbt,
+    order = attention.walk_order(positions[hi - 1], np.ones(7, bool))
+    ringed = g * int(attention.walk_blocks(positions[lo][order], positions[hi - 1][order], wbt,
                                        cfg.window)[1])
     assert be.last_attn_rows == (own + ringed, w * (own + ringed))
     assert be.last_attn_blocks[0] == 4 and be.last_window_blocks == ringed // g
     # the group rule for both kinds where the program holds no kernel
-    be.walk_kernel = ""
-    be._count_walk(spans, positions)
-    assert be.last_attn_rows == (g * 4 + ringed, w * (g * 4 + ringed))
+    shapes = (spans, positions, w, (bt, wbt), cfg.window)
+    assert attention.count_walk(*shapes, own_ends=False)[2] == (
+        g * 4 + ringed, w * (g * 4 + ringed))
+    # and the kernel's rule is the one the step above was counted by
+    assert attention.count_walk(*shapes, own_ends=True) == (
+        4, ringed // g, be.last_attn_rows, be.last_attn_live)
 
 
 def test_the_rule_is_the_arenas_form_the_platform_and_one_device():
@@ -248,6 +253,16 @@ def test_the_rule_is_the_arenas_form_the_platform_and_one_device():
     assert latent_walk.holds_kernel("tpu", True) and not latent_walk.holds_kernel("cpu", True)
     assert head_walk.PLATFORM == latent_walk.PLATFORM == "tpu"
     assert head_walk.KERNEL_NAME != latent_walk.KERNEL_NAME
+    # the ONE rule the trace and the families' ``ModelSpec.kernels`` both ask
+    assert attention.walk_kernel("tpu", True, None, 1) is head_walk
+    assert attention.walk_kernel(None, True, None, 1) is head_walk  # the kernel's own platform
+    assert attention.walk_kernel("cpu", True, None, 1) is None
+    assert attention.walk_kernel("tpu", True, None, 4) is None
+    assert attention.walk_kernel("tpu", True, 32, 1) is None is attention.walk_kernel("tpu", False, 32, 1)
+    assert attention.walk_kernel("tpu", False, None, 4) is latent_walk
+    assert attention.walk_label("tpu", False, 1) == {"walk": "latent_walk"}
+    assert attention.walk_label("tpu", True, 1) == {"walk": "head_walk"}
+    assert attention.walk_label("cpu", True, 1) == attention.walk_label("tpu", True, 2) == {"walk": ""}
 
 
 @pytest.mark.parametrize("form", ["by-head", "window", "mesh", "latent"])
@@ -269,7 +284,7 @@ def test_the_traced_program_holds_the_walk_its_form_asks_for(form):
         k, v, q = k[:, :, :, 0], None, jnp.tile(q, (1, 16, 1))  # one shared key head, 64 query heads
     kw = dict(v_dim=HD // 2, scale=0.2) if form == "latent" else {}
     text = str(jax.make_jaxpr(
-        lambda q, k, v: llama.paged_attention(q, k, v, 0, tables, token_seq, positions, BP, window, **kw)
+        lambda q, k, v: attention.paged_attention(q, k, v, 0, tables, token_seq, positions, BP, window, **kw)
     )(q, k, v))
     assert (head_walk.KERNEL_NAME in text) == (form == "by-head")
     assert (latent_walk.KERNEL_NAME in text) == (form == "latent")

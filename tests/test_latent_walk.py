@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
-from cordum_tpu.models import axk1, latent_walk, llama
+from cordum_tpu.models import attention, axk1, latent_walk
 from cordum_tpu.serving.backend import ServingBackend, StepEntry
 
 H, WIDTH, VD, PS, BP = 64, 256, 128, 16, 2  # tiles of 4 slots x 64 heads; blocks of 32
@@ -31,10 +31,10 @@ def kernel_walk(monkeypatch):
     take = ["tpu"]
     monkeypatch.setattr(jax.lax, "platform_dependent",
                         lambda *args, default, tpu: {"tpu": tpu, "default": default}[take[0]](*args))
-    llama.paged_attention.clear_cache()
+    attention.paged_attention.clear_cache()
     with pltpu.force_tpu_interpret_mode():
         yield take
-    llama.paged_attention.clear_cache()
+    attention.paged_attention.clear_cache()
 
 
 def feed_of(rows, t_buf, s_rows, p_width, n_pages, arena_rows=1, dtype=jnp.float32, seed=0):
@@ -117,7 +117,7 @@ def test_the_kernel_equals_the_jnp_walk_and_a_plain_reference(case, kernel_walk)
     arena, tables, token_seq, positions, q = feed_of(
         rows, t_buf, s_rows, p_width, 200, spec.get("arena_rows", 1),
         spec.get("dtype", jnp.float32))
-    walk = llama.paged_attention.__wrapped__
+    walk = attention.paged_attention.__wrapped__
     args = (tables, token_seq, positions, BP)
     got = np.asarray(walk(q, jnp.asarray(poisoned(arena, tables, token_seq, positions), arena.dtype),
                           None, row, *args, v_dim=VD, scale=SCALE), np.float32)
@@ -138,7 +138,7 @@ def test_a_tile_reads_nothing_past_its_own_last_block(kernel_walk):
     arena = rng.standard_normal((1, n_pages, PS, WIDTH)).astype(np.float32)
     newest = np.array([7 * BT + 5, 4 * BT, 4 * BT - 1, BT, 3, 0, 0, 0])
     live = np.array([1, 1, 1, 1, 1, 1, 0, 0], bool)
-    trips = latent_walk.tile_trips(newest, live, BT)
+    trips = attention.tile_trips(newest, live, BT)
     assert list(trips) == [8, 5, 4, 2, 1, 1, 0, 0]
     tab = np.zeros((g, p_width), np.int32)
     for i in range(g):
@@ -171,8 +171,9 @@ def test_a_tile_reads_nothing_past_its_own_last_block(kernel_walk):
 
 
 def test_the_host_counts_the_tile_trips_the_kernel_admits(kernel_walk, monkeypatch):
-    """``backend._count_walk`` under the kernel's rule against the trips the
-    kernel's own loop bounds admit, summed over a real step's groups."""
+    """``attention.count_walk`` under the kernel's rule (``own_ends``, as the
+    backend asks for it where its ``kernels`` hold a walk) against the trips
+    the kernel's own loop bounds admit, summed over a real step's groups."""
     cfg = axk1.Axk1Config(
         vocab_size=96, d_model=64, n_heads=64, q_rank=32, kv_rank=96, nope_dim=4, rope_dim=2,
         v_dim=4, d_ff=128, d_expert=32, n_layers=2, n_dense_layers=1, n_experts=8,
@@ -182,9 +183,11 @@ def test_the_host_counts_the_tile_trips_the_kernel_admits(kernel_walk, monkeypat
     be = ServingBackend(cfg, num_pages=523, page_size=8, max_seqs=5, max_batch_tokens=5 + 14,
                         params=axk1.init_params(jax.random.PRNGKey(1), cfg))
     be._ensure()
-    assert be.walk_kernel == ""  # the arenas live on the CPU
-    be.walk_kernel = latent_walk.KERNEL_NAME  # as a backend on the TPU reports
-    bt, w = be.attn_block_tokens, llama.attn_tile_slots(cfg.n_heads)
+    assert be.kernels == {"walk": "", "expert": ""}  # the arenas live on the CPU
+    # as a backend on the TPU reports, by the specification's own rule
+    be.kernels = be.spec.kernels(latent_walk.PLATFORM, 1)
+    assert be.kernels["walk"] == latent_walk.KERNEL_NAME
+    bt, w = be.attn_block_tokens, attention.attn_tile_slots(cfg.n_heads)
     assert (bt, w) == (256, 4)
     admitted = []
     real = latent_walk.walk_group
@@ -207,10 +210,14 @@ def test_the_host_counts_the_tile_trips_the_kernel_admits(kernel_walk, monkeypat
     assert be.last_attn_rows == (want, w * want)
     assert be.last_attn_blocks[0] == 4
     # the group rule (the jax.numpy walk's) counts every tile to its group's longest
-    be.walk_kernel = ""
-    be._count_walk(np.array([[0, 14], [14, 15], [15, 16], [16, 17], [17, 18]]),
-                   np.concatenate([d + np.arange(n) for d, n in rows]))
-    assert be.last_attn_rows == (8 * 4, 8 * w * 4) and be.last_attn_live > 0
+    spans = np.array([[0, 14], [14, 15], [15, 16], [16, 17], [17, 18]])
+    positions = np.concatenate([d + np.arange(n) for d, n in rows])
+    longest, ringed, gathered, live = attention.count_walk(
+        spans, positions, w, (bt,), None, own_ends=False)
+    assert (longest, ringed, gathered) == (4, 0, (8 * 4, 8 * w * 4)) and live > 0
+    # and the kernel's rule is the one the step above was counted by
+    assert attention.count_walk(spans, positions, w, (bt,), None, own_ends=True) == (
+        be.last_attn_blocks[0], 0, be.last_attn_rows, be.last_attn_live)
 
 
 def test_the_rule_is_the_arenas_form_and_the_platform():

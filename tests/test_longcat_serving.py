@@ -515,17 +515,17 @@ async def test_the_counters_equal_a_hand_count():
     st = eng.stats
     layers, k = cfg.n_layers, cfg.top_k
     live = sum(n for n, _, _ in seen)
-    assert st.moe_assignments == live * k * layers
-    assert st.moe_assignments_here == sum(int(a[:, :6].sum()) for _, a, _ in seen)
-    assert st.moe_experts_touched == sum(int((a[:, :6] > 0).sum()) for _, a, _ in seen)
-    assert st.moe_max_expert_load == sum(int(a[:, :6].max(1).sum()) for _, a, _ in seen)
-    assert st.moe_zero_assignments == sum(int(a[:, 6].sum()) for _, a, _ in seen)
-    assert st.moe_real_picks_max == sum(int(a[:, 7].sum()) for _, a, _ in seen)
-    assert st.moe_real_picks_min == sum(int(a[:, 8].sum()) for _, a, _ in seen)
-    assert 0 < st.moe_assignments_here < st.moe_assignments
+    assert st.model["moe_assignments"] == live * k * layers
+    assert st.model["moe_assignments_here"] == sum(int(a[:, :6].sum()) for _, a, _ in seen)
+    assert st.model["moe_experts_touched"] == sum(int((a[:, :6] > 0).sum()) for _, a, _ in seen)
+    assert st.model["moe_max_expert_load"] == sum(int(a[:, :6].max(1).sum()) for _, a, _ in seen)
+    assert st.model["moe_zero_assignments"] == sum(int(a[:, 6].sum()) for _, a, _ in seen)
+    assert st.model["moe_real_picks_max"] == sum(int(a[:, 7].sum()) for _, a, _ in seen)
+    assert st.model["moe_real_picks_min"] == sum(int(a[:, 8].sum()) for _, a, _ in seen)
+    assert 0 < st.model["moe_assignments_here"] < st.model["moe_assignments"]
     # identity experts are 8 of the router's 24: about a third of the picks
-    assert 0.2 < st.moe_zero_assignments / st.moe_assignments < 0.5
-    assert st.moe_real_picks_min < st.moe_real_picks_max <= k * layers * st.steps
+    assert 0.2 < st.model["moe_zero_assignments"] / st.model["moe_assignments"] < 0.5
+    assert st.model["moe_real_picks_min"] < st.model["moe_real_picks_max"] <= k * layers * st.steps
     # one step's three by hand: route the program's own m0 of a fed chunk
     del routed[:]
     seq = draw(9)

@@ -1,4 +1,4 @@
-"""``llama.paged_attention``: the ragged step's attention (docs/SERVING.md
+"""``attention.paged_attention``: the ragged step's attention (docs/SERVING.md
 §The ragged entry point) — one gather of pages a table row and block, a
 row's slots as the rows of grouped-query products, an online softmax, and a
 walk that ends at the step's longest live row — held to a plain dense
@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cordum_tpu.models import llama
+from cordum_tpu.models import attention, llama
 from cordum_tpu.serving.backend import LlamaServingBackend, StepEntry
 from cordum_tpu.serving.engine import GenRequest, ServingEngine
 
@@ -74,7 +74,7 @@ def arena_and_rows(rep, dtype, seed=0):
 
 def attend(q, k_pages, v_pages, layer, tables, token_seq, positions, block_pages,
            window=None):
-    return llama.paged_attention(
+    return attention.paged_attention(
         q, k_pages, v_pages, layer, jnp.asarray(tables), jnp.asarray(token_seq),
         jnp.asarray(positions), block_pages, window=window)
 
@@ -152,7 +152,7 @@ def test_every_fed_slot_of_a_tiled_step_matches_the_reference(case):
     rows = ROW_STEPS[case]
     window = 8 if case.startswith("window:") else None
     ps, kvh, rep, hd, bp = 4, 2, 3, 8, 2
-    width = llama.window_ring_pages(window, ps, ROW_T) if window else 24
+    width = attention.window_ring_pages(window, ps, ROW_T) if window else 24
     assert not window or width == 11
     rng = np.random.default_rng(len(case))
     n_pages = 1 + ROW_S * width
@@ -174,13 +174,13 @@ def test_every_fed_slot_of_a_tiled_step_matches_the_reference(case):
 def test_the_tiles_bound_every_split_of_the_buffer(t_buf, s_rows, want):
     """A tile holds up to 8 slots of ONE row, so a row wastes less than one:
     T // 8 + S tiles hold any split of the buffer over the rows."""
-    assert llama.attn_tiles(t_buf, s_rows) == want
-    assert want % llama.ATTN_GROUP_TILES == 0
+    assert attention.attn_tiles(t_buf, s_rows) == want
+    assert want % attention.ATTN_GROUP_TILES == 0
     rng = np.random.default_rng(t_buf)
     for _ in range(200):
         cuts = np.sort(rng.integers(0, t_buf + 1, size=s_rows))
         counts = np.diff(np.concatenate([[0], cuts]))
-        assert sum(-(-int(n) // llama.ATTN_TILE_SLOTS) for n in counts) <= want
+        assert sum(-(-int(n) // attention.ATTN_TILE_SLOTS) for n in counts) <= want
 
 
 @pytest.mark.parametrize("window", [None, 20])
@@ -188,8 +188,8 @@ def test_one_rule_for_the_traced_bound_and_the_host_count(window):
     """``walk_blocks`` on numpy and on jax arrays alike; under a window a
     tile walks from its OLDEST slot's oldest visible key."""
     oldest, newest = np.array([100, 0, 37, 0]), np.array([130, 0, 37, 0])
-    first, trips = llama.walk_blocks(oldest, newest, 16, window)
-    jfirst, jtrips = jax.jit(lambda a, b: llama.walk_blocks(a, b, 16, window))(oldest, newest)
+    first, trips = attention.walk_blocks(oldest, newest, 16, window)
+    jfirst, jtrips = jax.jit(lambda a, b: attention.walk_blocks(a, b, 16, window))(oldest, newest)
     assert list(first) == list(np.asarray(jfirst)) and int(trips) == int(jtrips)
     if window is None:
         assert list(first) == [0, 0, 0, 0] and trips == 130 // 16 + 1
@@ -224,8 +224,8 @@ KV_BY_HEAD = ((8, 128), (8, 128))  # K and V of 8 heads of 128: 4096 B a positio
 ])
 def test_block_follows_from_the_shapes(page_size, pages_per_seq, arenas, itemsize, heads, kvh,
                                        v_dim, want):
-    pos_bytes = llama.arena_pos_bytes(arenas, itemsize)
-    assert llama.attn_block_pages(page_size, pages_per_seq, pos_bytes, heads, kvh, v_dim) == want
+    pos_bytes = attention.arena_pos_bytes(arenas, itemsize)
+    assert attention.attn_block_pages(page_size, pages_per_seq, pos_bytes, heads, kvh, v_dim) == want
 
 
 def test_a_latent_walk_at_a_grown_block_matches_the_reference():
@@ -235,7 +235,7 @@ def test_a_latent_walk_at_a_grown_block_matches_the_reference():
     against the dense float64 softmax over one shared key whose leading
     columns are the value."""
     ps, width, vd, h, per = 64, 12, 8, 64, 32
-    bp = llama.attn_block_pages(ps, per, llama.arena_pos_bytes(((width,),), 4), h, 1, vd)
+    bp = attention.attn_block_pages(ps, per, attention.arena_pos_bytes(((width,),), 4), h, 1, vd)
     assert bp == 4
     rows = [(700, 1), (37, 1), (255, 1), (256, 1), (250, 12), (0, 5)]
     rng = np.random.default_rng(31)
@@ -246,7 +246,7 @@ def test_a_latent_walk_at_a_grown_block_matches_the_reference():
     token_seq, positions = packed(rows, t_buf, len(rows))
     fed = token_seq < len(rows)
     q = rng.normal(size=(t_buf, h, width)).astype(np.float32)
-    got = np.asarray(llama.paged_attention(
+    got = np.asarray(attention.paged_attention(
         jnp.asarray(q), jnp.asarray(arena), None, 0, jnp.asarray(tables),
         jnp.asarray(token_seq), jnp.asarray(positions), bp, v_dim=vd, scale=0.3))
     assert got.shape == (t_buf, h, vd) and np.isfinite(got).all()
@@ -313,6 +313,7 @@ def whole_row_step(params, k_pages, v_pages, tokens, positions, page_tables,
         return llama._attention(
             q[:, None], kc, vc, WALK_CFG, q_offset=positions[:, None])[:, 0]
 
+    # the name ``llama.ragged_step`` calls the walk by
     real, llama.paged_attention = llama.paged_attention, whole_row
     try:
         return llama.ragged_step(params, k_pages, v_pages, tokens, positions,
@@ -372,7 +373,7 @@ def test_the_walk_gathers_a_block_a_table_row_in_every_configuration(name, block
     spec, pool = spec_for(cfg), doc["pool"]
     s_rows, ps = pool["max_sessions"], pool["page_size"]
     t_buf = s_rows + pool["prefill_budget"]
-    ring = llama.window_ring_pages(spec.window, ps, t_buf) if spec.window else 0
+    ring = attention.window_ring_pages(spec.window, ps, t_buf) if spec.window else 0
     widths = (cfg.max_seq_len // ps,) + ((ring,) if ring else ())
     layout = FeedLayout(t_buf, s_rows, widths)
     params = jax.eval_shape(spec.init_params, jax.random.PRNGKey(0))
@@ -385,9 +386,9 @@ def test_the_walk_gathers_a_block_a_table_row_in_every_configuration(name, block
     # a group of tiles' block of each arena of a kind: never a gather a
     # buffer slot, and ONE walk traced a kind of page, whatever the number
     # of layers
-    assert set(shapes) == {(llama.ATTN_GROUP_TILES, block_pages, ps, *a)
+    assert set(shapes) == {(attention.ATTN_GROUP_TILES, block_pages, ps, *a)
                            for kind in spec.arenas for a in kind}
-    assert llama.ATTN_GROUP_TILES < t_buf and len(shapes) == spec.n_arenas
+    assert attention.ATTN_GROUP_TILES < t_buf and len(shapes) == spec.n_arenas
     # and the backend counts in the unit the program walks in
     from cordum_tpu.serving.backend import ServingBackend
 
@@ -410,12 +411,12 @@ def test_the_walk_over_slots_would_be_seen(monkeypatch):
             i32(WALK_S + 1, cfg.max_seq_len // WALK_PS), i32(WALK_T), i32(WALK_S))
         return {s[0] for s in walk_gathers(closed.jaxpr)}
 
-    assert rows_gathered() == {llama.ATTN_GROUP_TILES}
-    monkeypatch.setattr(llama, "ATTN_TILE_SLOTS", 1)
-    monkeypatch.setattr(llama, "ATTN_GROUP_TILES", WALK_T + WALK_S)
+    assert rows_gathered() == {attention.ATTN_GROUP_TILES}
+    monkeypatch.setattr(attention, "ATTN_TILE_SLOTS", 1)
+    monkeypatch.setattr(attention, "ATTN_GROUP_TILES", WALK_T + WALK_S)
     # a fresh function under a fresh jit: the walk's trace is cached by the
     # function and its shapes, not by the constants
-    walk = llama.paged_attention.__wrapped__
+    walk = attention.paged_attention.__wrapped__
     monkeypatch.setattr(llama, "paged_attention",
                         jax.jit(lambda *a: walk(*a), static_argnums=(7,)))
     assert rows_gathered() == {WALK_T + WALK_S}

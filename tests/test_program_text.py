@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from cordum_tpu.models import afmoe, axk1, bailing, falcon_h1, llama, longcat
+from cordum_tpu.models import afmoe, attention, axk1, bailing, falcon_h1, llama, longcat
 from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
 from cordum_tpu.serving.modelspec import spec_for
 
@@ -49,7 +49,7 @@ CONFIGS = {"llama": llama.LlamaConfig.tiny, "afmoe": afmoe.AfmoeConfig, "axk1": 
 
 def text_of(cfg) -> str:
     spec = spec_for(cfg)
-    ring = llama.window_ring_pages(spec.window, PS, TOKENS) if spec.window else 0
+    ring = attention.window_ring_pages(spec.window, PS, TOKENS) if spec.window else 0
     widths = (CONTEXT // PS, ring) if spec.window else (CONTEXT // PS,)
     layout = FeedLayout(TOKENS, SEQS, widths, state_rows=0 if spec.kv_positional else SEQS + 1)
     program = make_ragged_program(spec, layout, sample_logits=True, donate=False)

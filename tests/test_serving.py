@@ -1442,11 +1442,11 @@ def test_ragged_step_scopes_are_metadata_only():
     import jax
     import jax.numpy as jnp
 
-    from cordum_tpu.models import llama
+    from cordum_tpu.models import attention, llama
 
     cfg = llama.LlamaConfig.tiny()
     params = jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
-    k_pages, v_pages = jax.eval_shape(lambda: llama.init_kv_pages(cfg, 8, 4))
+    k_pages, v_pages = jax.eval_shape(lambda: attention.init_kv_pages(cfg, 8, 4))
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)

@@ -22,7 +22,7 @@ from .fakes import FakeBackend
 TOP = ["startup.compute", "startup.backend", "startup.state", "startup.program",
        "startup.first_step"]
 CHILDREN = {"startup.embedder": "startup.compute", "startup.weights": "startup.state",
-            "startup.arenas": "startup.state", "startup.walk_kernel": "startup.state",
+            "startup.arenas": "startup.state", "startup.kernels": "startup.state",
             "startup.program.trace": "startup.program",
             "startup.program.lower": "startup.program", "startup.program.load": "startup.program"}
 

@@ -101,8 +101,9 @@ def test_a_mixed_step_through_the_packed_feed_equals_the_spelled_out_program(bac
     if be.spec.aux_shape:
         np.testing.assert_array_equal(
             be.last_aux, out[be.max_batch_tokens:].reshape(be.spec.aux_shape))
-        assert be.last_counters == be.spec.count_aux(be.last_aux, 13)
+        assert (be.last_counters, be.last_attrs) == be.spec.count_aux(be.last_aux, 13, be.kernels)
         assert be.last_counters["moe_assignments_here"] > 0
+        assert be.last_attrs["moe_here"] == str(be.last_counters["moe_assignments_here"])
     else:
         assert out.shape == (be.max_batch_tokens,) and be.last_aux is None
 
