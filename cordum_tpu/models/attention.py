@@ -1,6 +1,6 @@
 """The paged cache and the attention's walk over it, for every serving family.
 
-What the six families' step programs share and none of them owns
+What the serving families' step programs share and none of them owns
 (docs/SERVING.md §The ragged entry point): the page arenas of K and V by
 head and the page programs over them (copy-on-write, export, import), the
 rules of the walk (tiles, groups, blocks, a window's ring), the walk itself

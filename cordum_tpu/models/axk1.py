@@ -44,9 +44,11 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import rotary
 from .afmoe import check_routing, expert_label, expert_layer, step_report
 from .attention import arena_pos_bytes, attn_block_pages, paged_attention, walk_label
 from .llama import rms_norm
+from .rotary import rotate, yarn_mscale
 
 Params = dict
 LANES = 128  # a TPU tile's minor dimension
@@ -138,39 +140,12 @@ class Axk1Config:
 # ---------------------------------------------------------------------------
 
 
-def yarn_mscale(factor: float, mscale: float) -> float:
-    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
-
-
 def yarn_inv_freq(cfg: Axk1Config) -> jax.Array:
-    """The ``rope_dim / 2`` inverse frequencies: per frequency a blend of
-    the original ``theta^(-2i/d)`` and the interpolated one (``/ factor``) by
-    a linear ramp between the correction dimensions of ``beta_fast`` and
-    ``beta_slow`` rotations over the original context — fast dimensions keep
-    their frequency, slow ones are interpolated."""
-    d = cfg.rope_dim
-    extra = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    if cfg.rope_factor <= 1:
-        return extra
-
-    def correction_dim(rotations: float) -> float:
-        return d * math.log(cfg.rope_original_len / (rotations * 2 * math.pi)) / (
-            2 * math.log(cfg.rope_theta))
-
-    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), d - 1)
-    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0, 1)
-    return extra / cfg.rope_factor * ramp + extra * (1 - ramp)
-
-
-def rotate(x: jax.Array, ang: jax.Array, ratio: float = 1.0) -> jax.Array:
-    """x: [T, ..., rope_dim] rotated by the angles ``ang`` [T, rope_dim / 2]
-    (position x inverse frequency), half-split pairing (dimension i with i +
-    d/2), cos and sin scaled by ``ratio``."""
-    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
-    cos, sin = jnp.cos(ang) * ratio, jnp.sin(ang) * ratio
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
+    """The ``rope_dim / 2`` inverse frequencies of the rotated key part under
+    the configuration's YaRN numbers (``rotary.yarn_inv_freq``, the one
+    writing of the blend)."""
+    return rotary.yarn_inv_freq(cfg.rope_dim, cfg.rope_theta, cfg.rope_factor,
+                                cfg.rope_original_len, cfg.rope_beta_fast, cfg.rope_beta_slow)
 
 
 def rope(x: jax.Array, positions: jax.Array, cfg: Axk1Config) -> jax.Array:
@@ -411,5 +386,4 @@ def serving_spec(cfg: Axk1Config) -> Any:
 
 
 __all__ = ["Axk1Config", "WalkRows", "held_kernels", "init_params", "init_arenas", "mla_sublayer",
-           "ragged_step",
-           "rope", "rotate", "serving_spec", "walk_rows", "yarn_inv_freq", "yarn_mscale"]
+           "ragged_step", "rope", "serving_spec", "walk_rows", "yarn_inv_freq"]

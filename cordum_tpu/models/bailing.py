@@ -40,8 +40,9 @@ import jax.numpy as jnp
 
 from . import kda
 from .afmoe import check_routing, expert_layer, step_report
-from .axk1 import LANES, held_kernels, mla_sublayer, rotate, walk_rows
+from .axk1 import LANES, held_kernels, mla_sublayer, walk_rows
 from .llama import rms_norm
+from .rotary import rotate
 
 Params = dict
 KDA, MLA = "kda", "mla"

@@ -49,8 +49,9 @@ import jax
 import jax.numpy as jnp
 
 from .afmoe import IDENTITY_COUNTS, check_routing, expert_layer, step_report
-from .axk1 import LANES, held_kernels, mla_sublayer, rotate, walk_rows
+from .axk1 import LANES, held_kernels, mla_sublayer, walk_rows
 from .llama import rms_norm
+from .rotary import rotate
 
 Params = dict
 #: latent-attention sublayers (each with its dense FFN) in one layer

@@ -237,8 +237,9 @@ def test_a_by_head_program_holds_the_by_head_walks_kernel(config):
 
 
 @pytest.mark.parametrize("rows,d,fe,held", [
-    (1024, 2560, 768, 128), (512, 7168, 2048, 12), (1536, 6144, 2048, 16), (256, 3072, 3072, 32)],
-    ids=["ling", "axk1", "longcat", "trinity"])
+    (1024, 2560, 768, 128), (512, 7168, 2048, 12), (1536, 6144, 2048, 16), (256, 3072, 3072, 32),
+    (2048, 2304, 896, 64)],
+    ids=["ling", "axk1", "longcat", "trinity", "mellum"])
 def test_the_expert_kernel_compiles_at_the_sparse_cells_shapes(one_chip, rows, d, fe, held):
     """``expert_mlp`` alone at each sparse cell's committed shapes (T x top_k
     rows, the experts held): Mosaic takes the blocks ``block_width`` derives,
@@ -385,6 +386,45 @@ def test_the_state_space_step_fits_one_chip_and_keeps_state_and_pages_in_place(o
     print(f"falcon_h1 step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
           f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB, "
           f"{len(calls)} ssd_step call lines")
+
+
+def test_the_whole_expert_set_step_fits_one_chip_and_keeps_both_kinds_of_page_in_place(one_chip):
+    """The mellum cell's ragged program at the benchmark's sizes (``benchmarks/
+    configs/mellum2-12b-a2.5b-pp.json``: published widths, 8 layers of 64
+    experts each held whole, the whole vocabulary, 32 sessions over a 20480
+    context): 7.59 GB of weights, 2.68 GB of whole-row pages for the two full
+    layers and 0.51 GB of rings for the six window layers fit, donation is
+    real for all four arenas, and the program lowered for the TPU holds the
+    grouped products' kernel (``expert_mlp``) AND the by-head walk's
+    (``head_walk``, for the full kind; the rings keep ``jax.numpy``'s walk)
+    and no latent walk."""
+    from benchmarks.families import mellum as fam
+    from benchmarks.harness import cells
+    from cordum_tpu.serving.backend import ServingBackend
+
+    doc = dict(cells.load_config("mellum2-12b-a2.5b-pp"))
+    cfg, pool = fam.program_config(doc), doc["pool"]
+    params = jax.tree.map(lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip),
+                          fam.param_shapes(doc), is_leaf=lambda x: isinstance(x, tuple))
+    be = ServingBackend(cfg, num_pages=pool["pages"], page_size=pool["page_size"],
+                        max_seqs=pool["max_sessions"],
+                        max_batch_tokens=pool["max_sessions"] + pool["prefill_budget"])
+    assert (be.ring_pages, be.num_window_pages, be.pages_per_seq) == (81, 2593, 1280)
+    arenas = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in jax.eval_shape(
+        lambda: tuple(be.spec.init_arenas(be.num_pages, be.page_size, be.num_window_pages)))]
+    assert [a.shape for a in arenas] == [(2, 40960, 16, 4, 128)] * 2 + [(6, 2593, 16, 4, 128)] * 2
+    feed = jax.ShapeDtypeStruct((be.feed_layout.size,), jnp.int32, sharding=one_chip)
+    program = make_ragged_program(be.spec, be.feed_layout, sample_logits=True, donate=True)
+    compiled = program.lower(params, *arenas, feed).compile()
+    ma = compiled.memory_analysis()
+    arena_bytes = sum(a.size * a.dtype.itemsize for a in arenas)
+    assert ma.alias_size_in_bytes >= arena_bytes > 3.1e9
+    assert ma.temp_size_in_bytes < 0.5e9  # no copy of an arena among the temporaries
+    assert 10.7e9 < device_bytes(compiled) <= 0.8 * HBM_BYTES
+    text = compiled.as_text()
+    assert holds_expert_kernel(text) and holds_head_kernel(text) and not holds_walk_kernel(text)
+    print(f"mellum step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
 
 
 def test_reference_forward_fits_beside_the_serving_state(one_chip):

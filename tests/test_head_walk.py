@@ -303,7 +303,9 @@ def test_the_busy_share_reader_finds_the_kernels_events_or_nothing():
     assert reader.read({"trace": {"busy_s": 2.5, "device_ops": ops}}) == pytest.approx(6.0)
     assert reader.read({"trace": {"busy_s": 2.5, "device_ops": ops[:1]}}) is None
     assert reader.read({"trace": None}) is None and reader.read({}) is None
-    entry = cells.load_benchmark()["per_layer"][-1]
+    entry = next(m for m in cells.load_benchmark()["per_layer"] if m["name"] == reader.OP_NAME + "_busy_share")
+    # the cells whose traced slice holds the kernel among its ten heaviest operations: Falcon-H1's
+    # (ISSUE 44) and, appended by ISSUE 46, Mellum's (two full layers to 17k positions)
     assert entry == {"name": "head_walk_busy_share", "unit": reader.UNIT, "better": reader.BETTER,
                      "source": reader.SOURCE, "layer": reader.LAYER, "moves": reader.MOVES,
-                     "workloads": ["falconh1-chatbursts-open"]}
+                     "workloads": ["falconh1-chatbursts-open", "mellum2-idechat-open"]}

@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from cordum_tpu.models import afmoe, attention, axk1, bailing, falcon_h1, llama, longcat
+from cordum_tpu.models import (afmoe, attention, axk1, bailing, falcon_h1, llama, longcat,
+                               mellum)
 from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
 from cordum_tpu.serving.modelspec import spec_for
 
@@ -103,3 +104,23 @@ def test_the_state_space_familys_program_holds_pages_and_state_in_every_layer():
     assert text.count(state) >= 2 and text.count(pages) >= 4
     with_state = FeedLayout(TOKENS, SEQS, (CONTEXT // PS,), state_rows=SEQS + 1)
     assert f"i32[{with_state.size}]" in text
+
+
+def test_the_whole_expert_set_familys_program_holds_both_walks_and_both_products():
+    """ISSUE 46's family is built from the parts the others run: one trace
+    holds both forms of the grouped products and both walks over K and V by
+    head (the lowering chooses; the rings' walk is ``jax.numpy``'s alone), two
+    kinds of arena among its operands and results, a ring table beside the
+    whole-row one in its feed, and neither a latent walk nor a recurrence."""
+    cfg = mellum.MellumConfig()
+    text = text_of(cfg)
+    assert all(w in text for w in ("expert_mlp", "ragged_dot", "head_walk", "platform_index"))
+    assert not any(w in text for w in ("latent_walk", "kda_step", "ssd_step"))
+    ring = attention.window_ring_pages(cfg.window, PS, TOKENS)
+    full = f"bf16[{len(cfg.full_layers)},{PAGES},{PS},{cfg.n_kv_heads},{cfg.head_dim}]"
+    rings = f"bf16[{len(cfg.window_layers)},{SEQS * ring + 1},{PS},{cfg.n_kv_heads},{cfg.head_dim}]"
+    assert text.count(full) >= 4 and text.count(rings) >= 4
+    layout = FeedLayout(TOKENS, SEQS, (CONTEXT // PS, ring))
+    assert f"i32[{layout.size}]" in text
+    # a rotation a kind: YaRN's factor on the full kind's cos and sin is in the trace
+    assert str(round(cfg.rope_full.attention_factor, 4)) in text
