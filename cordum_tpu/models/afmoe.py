@@ -487,10 +487,10 @@ def serving_spec(cfg: AfmoeConfig) -> Any:
         arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),) * 2, value_dim=cfg.head_dim,
         aux_shape=(cfg.n_expert_layers, cfg.experts_held),
         count_aux=lambda counts, live, kernels: step_report(cfg, counts, live, kernels),
-        # the full layers' whole rows are K and V by head; the window's rings
-        # keep the ``jax.numpy`` walk
+        # K and V by head in both kinds of page: whole rows and rings
         kernels=lambda platform, mesh_devices: {
-            **walk_label(platform, True, mesh_devices), **expert_label(cfg, platform)},
+            **walk_label(platform, True, mesh_devices, cfg.window),
+            **expert_label(cfg, platform)},
     )
 
 

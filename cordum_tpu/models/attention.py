@@ -287,27 +287,34 @@ def walk_order(newest: Any, live: Any) -> Any:
     return xp.argsort(xp.where(live, -newest, 1), stable=True)
 
 
+def first_block(oldest: Any, block_tokens: int, window: Optional[int]) -> Any:
+    """The block a walk starts at (numpy or jax int arrays: the position of
+    each tile's first fed slot, or of each slot): 0, or under a window the
+    block of the oldest key that position sees."""
+    return 0 * oldest if window is None else (oldest - (window - 1)).clip(0) // block_tokens
+
+
 def walk_blocks(oldest: Any, newest: Any, block_tokens: int,
                 window: Optional[int] = None) -> tuple[Any, Any]:
-    """The rule of :func:`paged_attention`'s walk, for the program's traced
-    bound and the host's count alike (numpy or jax int arrays, one entry a
-    tile of one group): ``oldest`` / ``newest`` are the positions of the
-    tile's first and last fed slot (0 and 0 for a tile with none).  Returns
-    ``(first, trips)``: the block each tile's walk starts at — 0, or under a
-    window the block of the oldest key the tile's oldest slot sees — and the
-    trips of the group, its longest tile-walk: each ends at the block of its
-    tile's newest slot."""
-    first = 0 * oldest if window is None else (
-        (oldest - (window - 1)).clip(0) // block_tokens)
+    """The rule of the ``jax.numpy`` walk of :func:`paged_attention`, for the
+    program's traced bound and the host's count alike (numpy or jax int
+    arrays, one entry a tile of one group): ``oldest`` / ``newest`` are the
+    positions of the tile's first and last fed slot (0 and 0 for a tile with
+    none).  Returns ``(first, trips)``: the block each tile's walk starts at
+    (:func:`first_block`) and the trips of the group, its longest tile-walk:
+    each ends at the block of its tile's newest slot."""
+    first = first_block(oldest, block_tokens, window)
     return first, (newest // block_tokens - first).max() + 1
 
 
-def tile_trips(newest: Any, live: Any, block_tokens: int) -> Any:
+def tile_trips(newest: Any, live: Any, block_tokens: int, first: Any = None) -> Any:
     """Blocks each tile of a walk's kernel walks (numpy or jax int arrays, one
-    entry a tile): to the block of its own newest slot, none for an idle
-    tile — the kernel's loop bound and the host's count alike."""
+    entry a tile): from its own ``first`` block (:func:`first_block`; None:
+    block 0) to the block of its own newest slot, none for an idle tile — the
+    kernel's loop bound and the host's count alike."""
     xp = np if isinstance(newest, np.ndarray) else jnp
-    return xp.where(live, newest // block_tokens + 1, 0)
+    last = newest // block_tokens
+    return xp.where(live, (last if first is None else last - first) + 1, 0)
 
 
 def mesh_devices(arena: Any) -> int:
@@ -327,21 +334,23 @@ def walk_kernel(platform: Optional[str], by_head: bool, window: Optional[int],
     program lowered for ``platform`` holds one, None where the walk is
     ``jax.numpy``'s.  From what the code can observe alone: the arena's form
     (``by_head``: K and V by head, ``models/head_walk.py``; else ONE latent
-    array, ``models/latent_walk.py``), the ``window`` (a ring keeps the
-    ``jax.numpy`` walk), the devices of the mesh the arenas are laid out over
-    (:func:`mesh_devices`: a Pallas call is one device's) and the platform,
-    each by the kernel's own ``holds_kernel``.  ``platform`` None asks for the
+    array, ``models/latent_walk.py``), the ``window`` (the by-head kernel
+    walks a ring from each tile's own first block; a latent arena under a
+    window, which no family has, keeps the ``jax.numpy`` walk), the devices of
+    the mesh the arenas are laid out over (:func:`mesh_devices`: a Pallas call
+    is one device's) and the platform, each by the kernel's own
+    ``holds_kernel``.  ``platform`` None asks for the
     kernel's own platform: what a trace holds for the lowering to choose from
     (:func:`paged_attention`); a family's ``ModelSpec.kernels`` asks for the
     platform its arenas live on (:func:`walk_label`).  A kernel's module
     imports Pallas, a second or more: it is imported here and nowhere else, so
     a program with no such arena never pays it."""
-    if window is not None:
-        return None
     if by_head:
         from . import head_walk as kernel
 
-        held = kernel.holds_kernel(platform or kernel.PLATFORM, True, window, mesh_devices)
+        held = kernel.holds_kernel(platform or kernel.PLATFORM, True, mesh_devices)
+    elif window is not None:
+        return None
     else:
         from . import latent_walk as kernel
 
@@ -349,11 +358,21 @@ def walk_kernel(platform: Optional[str], by_head: bool, window: Optional[int],
     return kernel if held else None
 
 
-def walk_label(platform: str, by_head: bool, mesh_devices: int) -> dict[str, str]:
-    """The ``walk`` role of a family's ``ModelSpec.kernels``: the name of the
-    kernel its whole-row kind of page is walked with, "" for ``jax.numpy``'s."""
-    kernel = walk_kernel(platform, by_head, None, mesh_devices)
-    return {"walk": kernel.KERNEL_NAME if kernel is not None else ""}
+#: the walk's role in a family's ``ModelSpec.kernels`` a kind of page, in the
+#: kinds' order: the whole-row kind's, the window kind's rings'
+WALK_ROLES = ("walk", "ring")
+
+
+def walk_label(platform: str, by_head: bool, mesh_devices: int,
+               window: Optional[int] = None) -> dict[str, str]:
+    """The walks' roles of a family's ``ModelSpec.kernels`` (:data:`WALK_ROLES`):
+    the name of the kernel its whole-row kind of page is walked with and, for
+    a family with a ``window``, the one its rings are — "" for ``jax.numpy``'s.
+    The host counts each kind by its role (:func:`count_walk`)."""
+    kinds = (None,) if window is None else (None, window)
+    held = {role: walk_kernel(platform, by_head, win, mesh_devices)
+            for role, win in zip(WALK_ROLES, kinds)}
+    return {role: kernel.KERNEL_NAME if kernel is not None else "" for role, kernel in held.items()}
 
 
 # jitted, with the layer a traced operand: the layers of a step program trace
@@ -407,7 +426,8 @@ def paged_attention(
       from ``positions``;
     * **a group's walk ends at its longest tile** (:func:`walk_blocks`) — a
       traced trip count on static shapes: one program, and blocks past it
-      are never read (the walks' kernels end each TILE at its own: below).
+      are never read (the walks' kernels start and end each TILE at its own
+      blocks: below).
 
     A masked key scores ``-1e30``, not ``-inf``.  A slot whose first walked
     blocks hold none of its visible keys (under a window the TILE starts the
@@ -426,12 +446,13 @@ def paged_attention(
     (:func:`window_ring_pages` wide): logical page ``n`` of the row sits in
     ring slot ``n % ring``, so a row holds a bounded number of pages however
     long it grows.  Each tile starts its walk at the block that holds the
-    oldest key its oldest slot sees and ends it at its newest slot's block;
-    a group's trip count is its longest such walk — at most ``W /
-    block_tokens + 2`` blocks, whatever the row's length.  The ring is the
-    window, one step's buffer and a page wide, so no key a slot sees has
-    been overwritten by its row's newest write; a ring slot the walk reads
-    twice is masked by its logical position.
+    oldest key its oldest slot sees (:func:`first_block`) and ends it at its
+    newest slot's block — at most ``W / block_tokens + 2`` blocks, whatever
+    the row's length; as ``jax.numpy`` a group's trip count is its longest
+    such walk, under the by-head kernel each tile makes its own.  The ring
+    is the window, one step's buffer and a page wide, so no key a slot sees
+    has been overwritten by its row's newest write; a ring slot the walk
+    reads twice is masked by its logical position.
 
     **A latent cache** (the absorbed form of latent attention,
     ``models/axk1.py``): ``k_pages`` is the ONE array ``[L, N, ps, hd]`` a
@@ -448,20 +469,23 @@ def paged_attention(
     the mask and the float32 softmax state live in VMEM for the tile's whole
     walk, a block's pages are copied page by page from the arena (both
     arenas by head; they stay in HBM) into a VMEM block of a few buffers, and
-    **each tile ends at its OWN newest block** (:func:`tile_trips`),
+    **each tile ends at its OWN newest block** (:func:`tile_trips`; a ring
+    is walked by the by-head kernel from the tile's OWN first block too, its
+    pages found round the ring where their copies are started),
     where the ``jax.numpy`` walk drags every tile of a group to the longest.
     Everything round it is shared: the tiles, their order, the groups, the
     loop over the groups that hold a live tile, the gather of the tiles'
     queries, the scatter back to buffer slots, the block as the counted unit.
     ``jax.lax.platform_dependent`` chooses, by what the code can observe
     alone: the arena's form (one latent array, ``v_pages is None``: the
-    latent kernel; K and V by head: the by-head kernel; either with no
-    window), the lowering platform, and for K and V by head that the program
+    latent kernel, with no window; K and V by head: the by-head kernel, whole
+    rows and a window's rings alike, the ``window`` a static parameter of
+    it), the lowering platform, and for K and V by head that the program
     is not partitioned over a mesh (:func:`mesh_devices` of the traced
     arena: the tensor-parallel gang shards the arenas by head, and a Pallas
     call is one device's).  Every other platform (the CPU's tests and float32
-    references), the window's ring and a program over a mesh keep the
-    ``jax.numpy`` walk below, byte for byte the program it was.  Same numerics
+    references), a latent arena under a window and a program over a mesh keep
+    the ``jax.numpy`` walk below, byte for byte the program it was.  Same numerics
     in all: operands in the arena's dtype, float32 scores and state,
     probabilities cast to the arena's dtype, a masked key ``-1e30``."""
     t, h, hd = q.shape
@@ -570,14 +594,18 @@ def paged_attention(
 
     def walk_heads(lo, out):
         """The same group through ``head_walk``'s kernel: every tile to its
-        OWN end, its queries read from the step's whole array in place; the
+        OWN end (under a window: from its own first block too, round its
+        ring), its queries read from the step's whole array in place; the
         group's outputs come back a K/V head's rows together and are laid
         out by slot as ``walk_jnp`` lays its own."""
         tab_c, new_c, live_c, pslot_c = (
             jax.lax.dynamic_slice_in_dim(x, lo, g) for x in (tab, newest, live, pslot))
+        ring = {} if window is None else dict(window=window, first_blocks=first_block(
+            jax.lax.dynamic_slice_in_dim(oldest, lo, g), bt, window))
         done = kernel.walk_group(
-            qt, pslot_c, k_pages, v_pages, layer, tab_c, tile_trips(new_c, live_c, bt),
-            lo, block_pages=bp, scale=scale).reshape(g, kvh, w, rep, vd)
+            qt, pslot_c, k_pages, v_pages, layer, tab_c,
+            tile_trips(new_c, live_c, bt, ring.get("first_blocks")),
+            lo, block_pages=bp, scale=scale, **ring).reshape(g, kvh, w, rep, vd)
         return jax.lax.dynamic_update_slice_in_dim(
             out, done.transpose(0, 2, 1, 3, 4).reshape(g * w, h, vd), lo * w, axis=0)
 
@@ -601,42 +629,41 @@ def paged_attention(
 
 
 def count_walk(spans: Any, positions: Any, tile_slots: int, block_tokens: tuple[int, ...],
-               window: Optional[int], own_ends: bool) -> tuple[int, int, tuple[int, int], int]:
+               window: Optional[int],
+               own_ends: tuple[bool, ...]) -> tuple[int, int, tuple[int, int], int]:
     """One step's walk as :func:`paged_attention` makes it, counted on the
     host (plain numpy, no kernel's module) from ``spans`` (int [rows, 2]: each
     fed row's buffer slots, packed one behind the other from slot 0) and the
     packed ``positions``: the rows cut into tiles of ``tile_slots``, the tiles
     in the program's order, and a KIND of page after the other
     (``block_tokens``: a block's positions, the whole-row kind's, then under
-    ``window`` the ring kind's) by the rule of that kind's walk: each tile to
-    its OWN end (:func:`tile_trips`) where the kind's walk is a kernel
-    (``own_ends``: the whole-row kind's, :func:`walk_kernel`), each group of
-    tiles to its longest (:func:`walk_blocks`) where it is ``jax.numpy``'s
-    (the window kind's rings always).  Returns the step's report: the longest
-    walk over whole rows and over rings, in blocks; ``(table rows gathered,
-    query slots computed)``, a block each, by one layer of each kind
-    together; and the FED slots among those that needed their block."""
+    ``window`` the ring kind's) by the rule of that kind's walk (``own_ends``,
+    one flag a kind): each tile from its OWN first block to its OWN end
+    (:func:`first_block`, :func:`tile_trips`) where the kind's walk is a
+    kernel (:func:`walk_kernel`; a family's ``ModelSpec.kernels`` says so a
+    kind: :func:`walk_label`), each group of tiles to its longest
+    (:func:`walk_blocks`) where it is ``jax.numpy``'s.  Returns the step's
+    report: the longest walk over whole rows and over rings, in blocks;
+    ``(table rows gathered, query slots computed)``, a block each, by one
+    layer of each kind together; and the FED slots among those that needed
+    their block."""
     w, g = tile_slots, ATTN_GROUP_TILES
     lo = np.concatenate([np.arange(a, b, w) for a, b in spans])  # a tile's first slot
     hi = np.minimum(lo + w, np.repeat(spans[:, 1], -(-(spans[:, 1] - spans[:, 0]) // w)))
     order = walk_order(positions[hi - 1], np.ones(len(lo), bool))
     oldest, newest = positions[lo][order], positions[hi - 1][order]
-    rows = slots = live = 0
+    walked = live = 0  # tile-trips: a table row gathered each, ``w`` query slots computed
     fed = positions[:spans[-1, 1]]
     longest = [0, 0]
-    for kind, (bt, win) in enumerate(zip(block_tokens, (None, window))):
+    for kind, (bt, win, own) in enumerate(zip(block_tokens, (None, window), own_ends)):
         # a fed slot needs the blocks from its oldest visible key's to its own
-        first = 0 if win is None else (fed - (win - 1)).clip(0) // bt
-        live += int((fed // bt - first + 1).sum())
-        if own_ends and win is None:
-            own = tile_trips(newest, np.ones(len(newest), bool), bt)
-            longest[kind] = int(own.max())
-            rows += int(own.sum())
-            slots += w * int(own.sum())
+        live += int((fed // bt - first_block(fed, bt, win) + 1).sum())
+        if own:
+            trips = tile_trips(newest, np.ones(len(newest), bool), bt,
+                               first_block(oldest, bt, win))
         else:
-            for a in range(0, len(order), g):
-                trips = int(walk_blocks(oldest[a:a + g], newest[a:a + g], bt, win)[1])
-                longest[kind] = max(longest[kind], trips)
-                rows += g * trips
-                slots += g * w * trips
-    return longest[0], longest[1], (rows, slots), live
+            trips = np.repeat([walk_blocks(oldest[a:a + g], newest[a:a + g], bt, win)[1]
+                               for a in range(0, len(order), g)], g)
+        longest[kind] = int(trips.max())
+        walked += int(trips.sum())
+    return longest[0], longest[1], (walked, w * walked), live

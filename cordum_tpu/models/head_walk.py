@@ -2,15 +2,16 @@
 kernel (docs/SERVING.md §The ragged entry point; ROADMAP S2 step 1).
 
 ``attention.paged_attention`` over two arenas ``[rows, N, ps, kvh, hd]`` (K and V
-by head, no window) walks a group of ``ATTN_GROUP_TILES`` tiles block by
-block.  As ``jax.numpy`` that walk gathers a trip's pages into a new HBM
-array and reads them back, makes five passes over the float32 scores through
-HBM, reads and rewrites the group's accumulator whatever the block's length
-and drags all eight tiles to the group's longest row: 3.8 ms of a 36 ms
-step for 0.36 ms of bytes in the one cell the device binds, 1.4 ms under
-this kernel (PERF.md section 6, PR 44).  This kernel is ``models/latent_walk.py``'s for the other form of
-arena, and shares by import what the two have in common in the kernel body
-(the platform, the page loop, the VMEM budget):
+by head: whole rows, or under a window each row's ring) walks a group of
+``ATTN_GROUP_TILES`` tiles block by block.  As ``jax.numpy`` that walk gathers
+a trip's pages into a new HBM array and reads them back, makes five passes
+over the float32 scores through HBM, reads and rewrites the group's
+accumulator whatever the block's length and drags all eight tiles to the
+group's longest row: 3.8 ms of a 36 ms step for 0.36 ms of bytes in the one
+cell the device binds, 1.4 ms under this kernel (PERF.md section 6, PR 44).
+This kernel is ``models/latent_walk.py``'s for the other form of arena, and
+shares by import what the two have in common in the kernel body (the
+platform, the page loop, the VMEM budget):
 
 * **grid = the tiles of the group**; a tile's queries ``[kvh, slots x rep,
   hd]`` are resident for its whole walk, its slots' positions and its table
@@ -33,7 +34,18 @@ arena, and shares by import what the two have in common in the kernel body
   then every head's value product; the tile's output is written once;
 * **each tile ends at ITS OWN newest block**: the block axis is a loop in
   the kernel bounded by the tile's own prefetched trip count, and no copy is
-  started for a block no tile walks.  An idle tile writes zeros nobody reads.
+  started for a block no tile walks.  An idle tile writes zeros nobody reads;
+* **a window's ring is the same walk from the tile's OWN first block** (a
+  static ``window``: with none the traced kernel is text for text the one it
+  was).  One more prefetched array gives each tile the block of the oldest
+  key its oldest slot sees; page ``p`` of its ``j``-th block is slot
+  ``((first + j) x block_pages + p) % ring`` of its table row (scalar
+  arithmetic where the copy is started: the ring need be no whole number of
+  blocks wide); the block's key positions count from ``first``, and the mask
+  gains the window's lower bound, ``pos - W < k_pos <= pos``, so a ring slot
+  read twice is masked by its logical position.  As ``jax.numpy`` the rings
+  were the heaviest device operation of both cells with window layers, about
+  ten times a block what this kernel costs (PERF.md section 6, PR 47).
 
 Same numerics as the ``jax.numpy`` walk: operands in the arena's dtype,
 float32 scores and state, probabilities cast to the arena's dtype for the
@@ -44,9 +56,9 @@ Which walk a program holds is decided where it is LOWERED
 :func:`holds_kernel`: the arena's form, the lowering platform, and that the
 program is not partitioned over a mesh (a Pallas call is one device's; the
 tensor-parallel gang shards the arenas by head and keeps the ``jax.numpy``
-walk).  ``attention.walk_kernel`` asks it for the trace and for the host
-alike, and the host counts the walk by the same rule
-(``attention.count_walk``).  The module imports Pallas, so nothing imports it
+walk) — for whole rows and rings alike.  ``attention.walk_kernel`` asks it
+for the trace and for the host alike, and the host counts the walk by the
+same rule (``attention.count_walk``).  The module imports Pallas, so nothing imports it
 at its own import (``models/latent_walk.py`` says why).
 """
 from __future__ import annotations
@@ -72,13 +84,13 @@ KERNEL_NAME = "head_walk"
 BUFFERS = 4
 
 
-def holds_kernel(platform: str, by_head: bool, window: Optional[int], mesh_devices: int) -> bool:
+def holds_kernel(platform: str, by_head: bool, mesh_devices: int) -> bool:
     """Whether a step program lowered for ``platform`` walks one kind of page
-    with this kernel: K and V by head (``by_head``), no ``window`` (a ring
-    keeps the ``jax.numpy`` walk), the platform, and a program that is one
-    device's (``mesh_devices``: the devices of the mesh the arenas are laid
-    out over, 0 or 1 where there is none) — nothing else."""
-    return by_head and window is None and mesh_devices <= 1 and platform == PLATFORM
+    with this kernel, whole rows and a window's rings alike: K and V by head
+    (``by_head``), the platform, and a program that is one device's
+    (``mesh_devices``: the devices of the mesh the arenas are laid out over,
+    0 or 1 where there is none) — nothing else."""
+    return by_head and mesh_devices <= 1 and platform == PLATFORM
 
 
 def vmem_bytes(kvh: int, rows: int, hd: int, block_tokens: int, itemsize: int) -> int:
@@ -114,9 +126,12 @@ def _heads(ref: Any, kvh: int, block_tokens: int) -> list:
     return [jax.lax.convert_element_type(x, ref.dtype) for x in heads]
 
 
-def _kernel(at_ref, trips_ref, src_ref, pos_ref, tab_ref, q_ref, k_ref, v_ref, out_ref,
-            kbuf, vbuf, sems, ahead_ref, m_ref, l_ref, acc_ref, *,
-            block_pages: int, page_size: int, slots: int, scale: float, tab_width: int):
+def _kernel(at_ref, trips_ref, src_ref, pos_ref, tab_ref, *refs,
+            block_pages: int, page_size: int, slots: int, scale: float, tab_width: int,
+            window: Optional[int]):
+    # under a window one more prefetched array: each tile's first block
+    first_ref, refs = (None, refs) if window is None else (refs[0], refs[1:])
+    q_ref, k_ref, v_ref, out_ref, kbuf, vbuf, sems, ahead_ref, m_ref, l_ref, acc_ref = refs
     del src_ref  # the queries' index map reads it
     i, g = pl.program_id(0), pl.num_programs(0)
     n = trips_ref[i]
@@ -145,10 +160,14 @@ def _kernel(at_ref, trips_ref, src_ref, pos_ref, tab_ref, q_ref, k_ref, v_ref, o
 
         @pl.when(tile < g)
         def _():
-            base = tile * tab_width + blk * bp
+            if window is None:
+                base = tile * tab_width + blk * bp
+            else:  # the tile's table row is a ring ``tab_width`` pages wide
+                base, lap = tile * tab_width, (first_ref[tile] + blk) * bp
 
             def copy(p):  # a page of each arena, one copy each
-                page = tab_ref[base + p]
+                page = tab_ref[base + p if window is None
+                               else base + jax.lax.rem(lap + p, tab_width)]
                 pltpu.make_async_copy(k_ref.at[row, page], kbuf.at[buf, p], sems.at[buf]).start()
                 pltpu.make_async_copy(v_ref.at[row, page], vbuf.at[buf, p], sems.at[buf]).start()
 
@@ -184,7 +203,11 @@ def _kernel(at_ref, trips_ref, src_ref, pos_ref, tab_ref, q_ref, k_ref, v_ref, o
         buf = (seq0 + j) % depth
         wait(buf)
         start_next((seq0 + j + depth - 1) % depth)  # the buffer the block before this one left
-        seen = j * bt + jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 1) <= pos
+        if window is None:
+            seen = j * bt + jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 1) <= pos
+        else:  # the tile's own block of this trip, and the window's lower bound
+            k_pos = (first_ref[i] + j) * bt + jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 1)
+            seen = (k_pos <= pos) & (k_pos > pos - window)
         ks = _heads(kbuf.at[buf].reshape(bt * kvh, hd), kvh, bt)
         vs = _heads(vbuf.at[buf].reshape(bt * kvh, hd), kvh, bt)
         # every head's scores first, ONE softmax over [kvh x rows, bt], then
@@ -222,7 +245,8 @@ def _kernel(at_ref, trips_ref, src_ref, pos_ref, tab_ref, q_ref, k_ref, v_ref, o
 
 def walk_group(q: jax.Array, q_pos: jax.Array, k_arena: jax.Array, v_arena: jax.Array, row: Any,
                tab: jax.Array, trips: jax.Array, first: Any = 0, *,
-               block_pages: int, scale: float) -> jax.Array:
+               block_pages: int, scale: float, window: Optional[int] = None,
+               first_blocks: Optional[jax.Array] = None) -> jax.Array:
     """The walk of one group of ``G`` tiles, tiles ``first`` to ``first + G``
     of a step's.  q: ``[tiles, kvh, rows, hd]``, every tile's queries (a K/V
     head's ``slots x rep`` product rows, in the arenas' dtype: the group's
@@ -230,14 +254,19 @@ def walk_group(q: jax.Array, q_pos: jax.Array, k_arena: jax.Array, v_arena: jax.
     k_arena / v_arena: ``[arena rows, N, ps, kvh, hd]``; row: the arena row (a
     traced int); tab: int32 ``[G, P]``, each tile's table row, ``P`` a whole
     number of blocks; trips: int32 ``[G]`` (``attention.tile_trips``).
+    Under a ``window`` (static) ``tab`` is each tile's RING, any number of
+    pages wide, ``first_blocks`` int32 ``[G]`` the block each tile's walk
+    starts at (``attention.first_block``) and ``trips`` counts from there.
     Returns the group's outputs ``[G, kvh, rows, hd]`` in q's dtype, an idle
     tile's zeros."""
     _, kvh, rows, hd = q.shape
     (g, slots), ps = q_pos.shape, k_arena.shape[2]
     bt = block_pages * ps
-    if tab.shape[1] % block_pages or rows % slots:
+    if (window is None and tab.shape[1] % block_pages) or rows % slots:
         raise ValueError(f"a table {tab.shape[1]} pages wide in blocks of {block_pages}, tiles "
                          f"of {rows} rows for {slots} slots: neither may leave a rest")
+    if (window is None) != (first_blocks is None):
+        raise ValueError("a window's walk takes each tile's first block, and no other does")
     if k_arena.shape != v_arena.shape or k_arena.shape[3:] != (kvh, hd) or (
             k_arena.dtype != v_arena.dtype):
         raise ValueError(f"arenas {k_arena.shape} {k_arena.dtype} and {v_arena.shape} "
@@ -257,12 +286,16 @@ def walk_group(q: jax.Array, q_pos: jax.Array, k_arena: jax.Array, v_arena: jax.
     src = jax.lax.cummax(jnp.where(trips > 0, jnp.arange(g, dtype=jnp.int32), 0))
     at = jnp.stack([jnp.asarray(row, jnp.int32), jnp.asarray(first, jnp.int32)])
     block = (BUFFERS, block_pages, ps, kvh, hd)
+    scalars = (at, trips, src, q_pos.astype(jnp.int32).reshape(-1),
+               tab.astype(jnp.int32).reshape(-1))
+    if window is not None:
+        scalars += (first_blocks.astype(jnp.int32),)
     return pl.pallas_call(
         partial(_kernel, block_pages=block_pages, page_size=ps, slots=slots, scale=scale,
-                tab_width=tab.shape[1]),
+                tab_width=tab.shape[1], window=window),
         out_shape=jax.ShapeDtypeStruct((g, kvh, rows, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=len(scalars),
             grid=(g,),
             in_specs=[pl.BlockSpec((1, kvh, rows, hd),
                                    lambda i, at, trips, src, *_: (at[1] + src[i], 0, 0, 0)),
@@ -279,5 +312,4 @@ def walk_group(q: jax.Array, q_pos: jax.Array, k_arena: jax.Array, v_arena: jax.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=VMEM_BUDGET_BYTES),
         name=KERNEL_NAME,
-    )(at, trips, src, q_pos.astype(jnp.int32).reshape(-1), tab.astype(jnp.int32).reshape(-1),
-      q, k_arena, v_arena)
+    )(*scalars, q, k_arena, v_arena)

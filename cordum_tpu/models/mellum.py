@@ -15,8 +15,8 @@ holding a copy of none of them:
   * **two kinds of page as ``models/afmoe.py`` has them** — the full kind's
     page tables reach the whole context, the window kind's are rings
     (``attention.window_ring_pages``), both walked by
-    ``attention.paged_attention`` (the by-head kernel over whole rows where
-    the program is lowered for the TPU, the ``jax.numpy`` walk over rings);
+    ``attention.paged_attention`` (the by-head kernel over whole rows and
+    over rings where the program is lowered for the TPU);
   * **every layer sparse, the expert set whole** — ``afmoe.expert_layer``
     told ``first_expert`` 0 and ``experts_held`` = ``n_experts``, routed by
     ``afmoe.route`` under a softmax over the router's width with no bias, no
@@ -259,9 +259,10 @@ def serving_spec(cfg: MellumConfig) -> Any:
         arenas=(kv_pair(cfg.n_kv_heads, cfg.head_dim),) * 2, value_dim=cfg.head_dim,
         aux_shape=(cfg.n_layers, cfg.experts_held),
         count_aux=lambda counts, live, kernels: step_report(cfg, counts, live, kernels),
-        # whole rows walk with the by-head kernel, rings with ``jax.numpy``
+        # K and V by head in both kinds of page: whole rows and rings
         kernels=lambda platform, mesh_devices: {
-            **walk_label(platform, True, mesh_devices), **expert_label(cfg, platform)},
+            **walk_label(platform, True, mesh_devices, cfg.window),
+            **expert_label(cfg, platform)},
     )
 
 

@@ -156,7 +156,8 @@ class StepBackend:
     state_slots: int = 0
     state_bytes: int = 0
     # which kernels the lowered step program holds, by role (``walk``: the
-    # attention's walk over the whole-row kind of page; ``expert``; ``state``),
+    # attention's walk over the whole-row kind of page; ``ring``: over the
+    # window kind's rings; ``expert``; ``state``),
     # as the model's specification states them for the platform the arenas
     # live on (``ModelSpec.kernels``): a role that is absent or "" is the
     # ``jax.numpy`` form.  Known once the state is on its device
@@ -659,14 +660,15 @@ class ServingBackend(StepBackend):
                 if self.spec.count_aux is not None:
                     self.last_counters, self.last_attrs = self.spec.count_aux(
                         self.last_aux, ti, self.kernels)
-            # the walk as the program made it: each tile to its own end where
-            # the whole-row kind's walk is a kernel
+            # the walk as the program made it: a kind of page's tiles each to
+            # their own ends where that kind's walk is a kernel
             from ..models import attention
 
             walked, self.last_window_blocks, self.last_attn_rows, self.last_attn_live = (
-                attention.count_walk(np.array(spans), positions, self._tile_slots,
-                                     self._block_tokens, self.window,
-                                     bool(self.kernels.get("walk"))))
+                attention.count_walk(
+                    np.array(spans), positions, self._tile_slots, self._block_tokens,
+                    self.window,
+                    tuple(bool(self.kernels.get(role)) for role in attention.WALK_ROLES)))
             self.last_attn_blocks = (walked, self._attn_blocks_total)
             if self.on_step is not None:
                 self.on_step(entries)

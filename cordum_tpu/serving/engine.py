@@ -1606,6 +1606,7 @@ class ServingEngine:
             attrs["walk_kernel"] = self.backend.kernels.get("walk") or "none"
         if self.ring_pages:
             attrs["window_blocks"] = str(self._count_window(rows, pos_before))
+            attrs["ring_kernel"] = self.backend.kernels.get("ring") or "none"
         if self.state_allocator is not None:
             # every fed row advanced its state slot: by one token (a state
             # read and written for it) or by a chunk

@@ -331,6 +331,11 @@ async def test_what_the_family_cannot_do_is_refused():
     cfg = tiny()
     spec = spec_for(cfg)
     assert spec.window == 32 and not spec.kv_whole_row and spec.count_aux is not None
+    # the rings' walk has a role of its own through the seam: the by-head kernel on one device
+    assert spec.kernels("cpu", 1) == {"walk": "", "ring": "", "expert": ""}
+    assert {r: spec.kernels("tpu", 1)[r] for r in attention.WALK_ROLES} == {
+        "walk": "head_walk", "ring": "head_walk"}
+    assert {spec.kernels("tpu", 4)[r] for r in attention.WALK_ROLES} == {""}
     with pytest.raises(TypeError):
         spec_for(object())  # a config that exports no specification
     be = backend_for(cfg, None)

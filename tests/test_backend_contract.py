@@ -276,13 +276,19 @@ WALK_ROWS = [(37, 20), (5, 1), (63, 1), (90, 1), (48, 4)]
     # what the backend's own method reported for this step before the count
     # left it for ``attention.count_walk`` (run on the tree of PR 44 with these shapes):
     # (longest walk over whole rows, over rings, (table rows gathered, query
-    # slots computed), fed slots that needed their block)
-    (8, (16,), None, False, (6, 0, (48, 384), 96)),
-    (8, (16,), None, True, (6, 0, (26, 208), 96)),
-    (4, (32,), None, False, (3, 0, (32, 128), 54)),
-    (4, (32,), None, True, (3, 0, (18, 72), 54)),
-    (8, (16, 8), 24, False, (6, 5, (88, 704), 197)),
-    (8, (16, 8), 24, True, (6, 5, (66, 528), 197)),
+    # slots computed), fed slots that needed their block); ``own_ends`` is one flag a
+    # kind of page since ISSUE 47 (PR 44's one flag was the whole-row kind's)
+    (8, (16,), None, (False,), (6, 0, (48, 384), 96)),
+    (8, (16,), None, (True,), (6, 0, (26, 208), 96)),
+    (4, (32,), None, (False,), (3, 0, (32, 128), 54)),
+    (4, (32,), None, (True,), (3, 0, (18, 72), 54)),
+    (8, (16, 8), 24, (False, False), (6, 5, (88, 704), 197)),
+    (8, (16, 8), 24, (True, False), (6, 5, (66, 528), 197)),
+    # the rings by each tile's own first and last block (ISSUE 47: their walk is the kernel),
+    # counted by hand: the chunk's tiles 5 + 5 + 5 blocks of 8, the decode rows 1, 3 and 4,
+    # the draft row 4: 27 beside the whole rows' 26; in tiles of four 35 beside 18
+    (8, (16, 8), 24, (True, True), (6, 5, (53, 424), 197)),
+    (4, (32, 8), 24, (True, True), (3, 5, (53, 212), 155)),
 ])
 def test_the_hosts_count_of_a_mixed_step_is_what_it_was(tile_slots, block_tokens, window,
                                                         own_ends, want):

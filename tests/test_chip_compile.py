@@ -202,7 +202,7 @@ def test_a_by_head_program_holds_the_by_head_walks_kernel(config):
     kernel where the program is lowered for the TPU (ISSUE 44), never with
     the latent form's: the benchmark's three by-head programs, LOWERED at
     their cells' shapes (nothing compiled); Trinity's holds it for its full
-    layers beside the ``jax.numpy`` walk of its rings.  Lowered for the CPU
+    layers and, since ISSUE 47, in its second form for its rings.  Lowered for the CPU
     they call no kernel; the latent program holds its own kernel on the TPU
     and none on the CPU."""
     from benchmarks.harness import cells
@@ -234,6 +234,49 @@ def test_a_by_head_program_holds_the_by_head_walks_kernel(config):
         assert not holds_head_kernel(latent)
         on_cpu = lowered("a.x-k1-ep16", "cpu")
         assert not holds_walk_kernel(on_cpu) and not holds_expert_kernel(on_cpu)
+
+
+@pytest.mark.parametrize("config,kvh,rep,window", [
+    ("trinity-large-preview-ep8", 8, 6, 4096), ("mellum2-12b-a2.5b-pp", 4, 8, 1024)])
+def test_the_ring_form_of_the_by_head_kernel_compiles_at_the_window_cells_shapes(
+        one_chip, config, kvh, rep, window):
+    """ISSUE 47: ``head_walk``'s second, static form (a first block a tile,
+    the ring's modulus on the scalar core where a page's copy is started, the
+    window's lower bound in the mask) COMPILED for the described v5e at the
+    two window cells' shapes, one group of tiles over the window kind's arenas
+    and ring tables as the cell's backend lays them out, inside the kernels'
+    VMEM budget; the rings are no whole number of blocks wide."""
+    from benchmarks.harness import cells
+    from cordum_tpu.models import head_walk
+    from cordum_tpu.serving.backend import ServingBackend
+
+    doc = dict(cells.load_config(config))
+    fam = __import__(f"benchmarks.families.{doc['family']}", fromlist=["x"])
+    cfg, pool = fam.program_config(doc), doc["pool"]
+    be = ServingBackend(cfg, num_pages=pool["pages"], page_size=pool["page_size"],
+                        max_seqs=pool["max_sessions"],
+                        max_batch_tokens=pool["max_sessions"] + pool["prefill_budget"])
+    assert (cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, be.window) == (kvh, rep, window)
+    assert be.kernels == {} and be.spec.kernels("tpu", 1)["ring"] == head_walk.KERNEL_NAME
+    k_arena = jax.eval_shape(lambda: be.spec.init_arenas(
+        be.num_pages, be.page_size, be.num_window_pages))[2]
+    bt = be._block_tokens[1]
+    g, w = attention.ATTN_GROUP_TILES, attention.attn_tile_slots(rep)
+    tiles = attention.attn_tiles(be.max_batch_tokens, be.max_seqs, w)
+    assert be.ring_pages % (bt // be.page_size) and bt == attention.ATTN_BLOCK_TOKENS
+    assert head_walk.vmem_bytes(kvh, w * rep, cfg.head_dim, bt, 2) <= head_walk.VMEM_BUDGET_BYTES
+
+    def shape(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda q, pos, k, v, row, tab, trips, first: head_walk.walk_group(
+        q, pos, k, v, row, tab, trips, g, block_pages=bt // be.page_size,
+        scale=cfg.head_dim ** -0.5, window=window, first_blocks=first)).lower(
+        shape((tiles, kvh, w * rep, cfg.head_dim), k_arena.dtype), shape((g, w)),
+        shape(k_arena.shape, k_arena.dtype), shape(k_arena.shape, k_arena.dtype), shape(()),
+        shape((g, be.ring_pages)), shape((g,)), shape((g,))).compile()
+    assert holds_head_kernel(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6  # no copy of an arena
 
 
 @pytest.mark.parametrize("rows,d,fe,held", [
@@ -396,7 +439,7 @@ def test_the_whole_expert_set_step_fits_one_chip_and_keeps_both_kinds_of_page_in
     layers and 0.51 GB of rings for the six window layers fit, donation is
     real for all four arenas, and the program lowered for the TPU holds the
     grouped products' kernel (``expert_mlp``) AND the by-head walk's
-    (``head_walk``, for the full kind; the rings keep ``jax.numpy``'s walk)
+    (``head_walk``, for the full kind and, since ISSUE 47, for the rings)
     and no latent walk."""
     from benchmarks.families import mellum as fam
     from benchmarks.harness import cells

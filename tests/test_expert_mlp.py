@@ -251,7 +251,7 @@ async def test_the_counter_says_how_the_products_engage(monkeypatch):
     assert metrics.serving_compiles.value(
         entry="ragged", walk_kernel="none", expert_kernel="none") == 1
     phase = [p for p in startup.phases() if p.name == "startup.kernels"]
-    assert len(phase) == 1 and phase[0].attrs == {"walk": "none", "expert": "none"}
+    assert len(phase) == 1 and phase[0].attrs == {"walk": "none", "ring": "none", "expert": "none"}
     assert [p.name for p in startup.phases() if p.id == phase[0].parent] == ["startup.state"]
     n_cpu = len(seen)
     # as a backend whose arenas live on the TPU reports, by the specification's own rule

@@ -10,7 +10,10 @@ form: ``jax.lax.platform_dependent`` is made to take the by-head walk's
 TPU one knows no reshaped or bitcast reference, which is how the kernel takes
 a block's heads apart).  Each case holds the kernel to the ``jax.numpy`` walk
 over the same feed and to a plain float32 softmax over each slot's own keys;
-pages a tile must not read are poisoned with NaN for the kernel alone."""
+pages a tile must not read are poisoned with NaN for the kernel alone.  Since
+ISSUE 47 the same kernel walks a window layer's RING (its second, static
+form: a first block a tile, the ring's modulus where a page's copy is started,
+the window's lower bound in the mask), and the same tests hold it there."""
 import functools
 
 import jax
@@ -24,8 +27,11 @@ from cordum_tpu.serving.backend import ServingBackend, StepEntry
 
 HD, PS, BP = 32, 4, 2  # blocks of 8 positions
 BT = BP * PS
-#: query heads a K/V head -> K/V heads: Falcon-H1's 5 over 4, Mistral's 4 over 8
-KVH = {2: 2, 4: 8, 5: 4, 8: 2}
+#: query heads a K/V head -> K/V heads: Falcon-H1's 5 over 4, Mistral's 4 over 8; under a window
+#: Trinity's 6 (over 8 there) and Mellum's 8 (over 4)
+KVH = {2: 2, 4: 8, 5: 4, 6: 4, 8: 2}
+#: the longest chunk of a window case: it sets the ring's width beside the window
+CHUNK = 20
 
 
 @pytest.fixture
@@ -47,23 +53,34 @@ def kernel_walk(monkeypatch):
     attention.paged_attention.clear_cache()
 
 
-def feed_of(rows, rep, t_buf, s_rows, p_width, n_pages, dtype=jnp.float32, seed=0):
+def feed_of(rows, rep, t_buf, s_rows, p_width, n_pages, dtype=jnp.float32, seed=0, window=None):
     """``rows``: ``(depth, slots)`` a table row, packed one behind the other;
     pages are dealt in a shuffled order, the unused tail of a table row is
-    the null page.  Returns K, V, the tables, ``token_seq``, ``positions`` and
-    the queries."""
+    the null page.  Under a ``window`` a table row is a RING (``p_width`` is
+    not read: ``attention.window_ring_pages`` wide): logical page ``n`` lies
+    in slot ``n % ring``, as the row's writes left it — a page that was
+    overwritten holds what overwrote it.  Returns K, V, the tables,
+    ``token_seq``, ``positions`` and the queries."""
     rng = np.random.default_rng(seed)
     kvh = KVH[rep]
     k, v = (rng.standard_normal((2, n_pages, PS, kvh, HD)).astype(np.float32) for _ in "kv")
     k[:, 0] = v[:, 0] = 0.0  # the null page
     free = list(rng.permutation(np.arange(1, n_pages)))
+    if window is not None:
+        p_width = attention.window_ring_pages(window, PS, CHUNK)
     tables = np.zeros((s_rows + 1, p_width), np.int32)
     token_seq = np.full(t_buf, s_rows, np.int32)
     positions = np.zeros(t_buf, np.int32)
     at = 0
     for r, (depth, n) in enumerate(rows):
         need = -(-(depth + n) // PS)
-        tables[r, :need] = [free.pop() for _ in range(need)]
+        held = min(need, p_width)
+        tables[r, :held] = [free.pop() for _ in range(held)]
+        for arena in (k, v) if need > held else ():
+            # a ring that has lapped: logical page n of the row in slot n % ring
+            pages = rng.standard_normal((2, need, PS, kvh, HD)).astype(np.float32)
+            for page in range(need):
+                arena[:, tables[r, page % p_width]] = pages[:, page]
         token_seq[at:at + n] = r
         positions[at:at + n] = depth + np.arange(n)
         at += n
@@ -72,15 +89,17 @@ def feed_of(rows, rep, t_buf, s_rows, p_width, n_pages, dtype=jnp.float32, seed=
             jnp.asarray(token_seq), jnp.asarray(positions), jnp.asarray(q, dtype))
 
 
-def reference(k, v, tables, token_seq, positions, q, row, rep):
-    """Plain float32: every fed slot's softmax over its own row's keys, a
-    query head over its K/V head's."""
+def reference(k, v, tables, token_seq, positions, q, row, rep, window=None):
+    """Plain float32: every fed slot's softmax over its own row's keys (under
+    a ``window``: the last ``window`` of them, each read from the ring slot
+    its logical page lies in), a query head over its K/V head's."""
     k, v, q = (np.asarray(x, np.float32) for x in (k, v, q))
     tables, token_seq, positions = (np.asarray(x) for x in (tables, token_seq, positions))
     out = np.zeros(q.shape, np.float32)
     for t in np.flatnonzero(token_seq < tables.shape[0] - 1):
-        keys = k[row, tables[token_seq[t]]].reshape(-1, *k.shape[3:])[:positions[t] + 1]
-        vals = v[row, tables[token_seq[t]]].reshape(-1, *v.shape[3:])[:positions[t] + 1]
+        seen = np.arange(0 if window is None else max(0, positions[t] - window + 1), positions[t] + 1)
+        at = tables[token_seq[t], (seen // PS) % tables.shape[1]], seen % PS
+        keys, vals = k[row][at], v[row][at]
         for head in range(q.shape[1]):
             s = keys[:, head // rep] @ q[t, head] / np.sqrt(HD)
             p = np.exp(s - s.max())
@@ -88,16 +107,19 @@ def reference(k, v, tables, token_seq, positions, q, row, rep):
     return out
 
 
-def poisoned(arena, tables, token_seq, positions):
+def poisoned(arena, tables, token_seq, positions, window=None):
     """NaN in every page no tile may read: those past the block of a ROW's
     newest position (the null page stays sound: a last block is padded with
-    it); what a TILE may not read of its own row is held in the test of one
-    group below."""
+    it) and, under a ``window``, those before the block of the oldest key the
+    row's oldest fed slot sees, the ring's slots taken round; what a TILE may
+    not read of its own row is held in the test of one group below."""
     arena = np.array(arena, np.float32)
     used = {0}
     for r in set(np.asarray(token_seq)) - {tables.shape[0] - 1}:
-        newest = int(np.asarray(positions)[np.asarray(token_seq) == r].max())
-        used |= set(np.asarray(tables)[r, :(newest // BT + 1) * BP].tolist())
+        mine = np.asarray(positions)[np.asarray(token_seq) == r]
+        first = int(attention.first_block(mine.min(), BT, window))
+        walked = np.arange(first * BP, (int(mine.max()) // BT + 1) * BP)
+        used |= set(np.asarray(tables)[r, walked % tables.shape[1]].tolist())
     for n in set(range(arena.shape[1])) - used:
         arena[:, n] = np.nan
     return arena
@@ -114,101 +136,169 @@ MIXES = {
     # 11 pages in blocks of 2: the table is padded to whole blocks with the null page
     "a-table-not-a-whole-number-of-blocks-wide": dict(rows=[(39, 3), (11, 1)], p_width=11),
 }
-CASES = [(rep, mix, "float32") for rep in sorted(KVH) for mix in sorted(MIXES)] + [
+#: the same walk over RINGS (blocks of 8 positions; a ring of W + 20 + 1 positions in whole pages)
+RING_MIXES = {
+    # every slot sees 5 keys: one block or two, wherever its position lies in its block
+    "a-window-shorter-than-a-block": dict(
+        window=5, rows=[(30, 20), (45, 1), (7, 1), (3, 1), (0, 1), (16, 1)]),
+    # 2.5 blocks: a tile walks three or four, from its own first block
+    "a-window-of-several-blocks": dict(
+        window=20, rows=[(41, 1), (27, 1), (63, 1), (19, 1), (20, 1), (5, 1), (33, 3)]),
+    # rows of 100 to 240 positions in rings of 44 (11 pages: no whole number of blocks): the ring
+    # has lapped two to five times, and the last block of a walk wraps onto slots it read before
+    "a-ring-that-has-lapped": dict(
+        window=20, rows=[(100, 20), (131, 1), (207, 1), (88, 1), (239, 1), (43, 1), (44, 1)]),
+    # a chunk whose oldest slots see position 0 and whose newest do not: beside decode rows
+    # deeper than the window, so that tiles of one group start at unlike blocks
+    "a-chunk-that-crosses-the-windows-edge": dict(
+        window=20, rows=[(10, 20), (60, 1), (22, 1), (0, 3), (37, 1)]),
+}
+MIXES.update(RING_MIXES)
+CASES = [(rep, mix, "float32") for rep in (2, 4, 5, 8) for mix in sorted(set(MIXES) - set(RING_MIXES))] + [
     (rep, mix, "bfloat16") for rep in (4, 5)
-    for mix in ("a-chunk-of-several-tiles-beside-decode-rows", "a-last-block-partly-filled")]
+    for mix in ("a-chunk-of-several-tiles-beside-decode-rows", "a-last-block-partly-filled")] + [
+    (rep, mix, dtype) for rep, dtype in ((6, "float32"), (8, "bfloat16")) for mix in sorted(RING_MIXES)] + [
+    (6, "a-ring-that-has-lapped", "bfloat16"), (8, "a-chunk-that-crosses-the-windows-edge", "float32")]
 
 
 @pytest.mark.parametrize("rep,mix,dtype", CASES, ids=[f"rep{r}-{m}-{d}" for r, m, d in CASES])
-def test_the_kernel_equals_the_jnp_walk_and_a_plain_reference(rep, mix, dtype, kernel_walk):
+def test_the_kernel_equals_the_jnp_walk_and_a_plain_reference(rep, mix, dtype, kernel_walk, monkeypatch):
     spec = MIXES[mix]
     tol = 2e-5 if dtype == "float32" else 3e-2
-    t_buf, s_rows, row = 40, 8, 1
+    t_buf, s_rows, row, window = 40, 8, 1, spec.get("window")
     k, v, tables, token_seq, positions, q = feed_of(
-        spec["rows"], rep, t_buf, s_rows, spec.get("p_width", 14), 120, jnp.dtype(dtype))
+        spec["rows"], rep, t_buf, s_rows, spec.get("p_width", 14), 120, jnp.dtype(dtype),
+        window=window)
     walk = attention.paged_attention.__wrapped__
-    args = (row, tables, token_seq, positions, BP)
-    bad_k, bad_v = (jnp.asarray(poisoned(a, tables, token_seq, positions), a.dtype) for a in (k, v))
+    args = (row, tables, token_seq, positions, BP, window)
+    bad_k, bad_v = (jnp.asarray(poisoned(a, tables, token_seq, positions, window), a.dtype)
+                    for a in (k, v))
+    forms, real = [], head_walk.walk_group
+
+    def noted(*a, **kw):
+        forms.append(kw.get("window"))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(head_walk, "walk_group", noted)
     got = np.asarray(walk(q, bad_k, bad_v, *args), np.float32)
+    assert forms == [window]  # the kernel's form of this case, traced once
     fed = np.asarray(token_seq) < s_rows
     assert got.shape == q.shape and np.isfinite(got).all()
     kernel_walk[0] = "default"  # the jax.numpy walk of the same feed, as on the CPU
     want = np.asarray(walk(q, k, v, *args), np.float32)
     np.testing.assert_allclose(got[fed], want[fed], atol=tol, rtol=tol)
-    ref = reference(k, v, tables, token_seq, positions, q, row, rep)
+    ref = reference(k, v, tables, token_seq, positions, q, row, rep, window)
     np.testing.assert_allclose(got[fed], ref[fed], atol=max(tol, 1e-4), rtol=max(tol, 1e-4))
 
 
-@pytest.mark.parametrize("dtype,live,buffers", [
-    ("float32", [1, 1, 1, 1, 1, 1, 0, 0], 2), ("bfloat16", [1, 1, 1, 1, 1, 1, 0, 0], 2),
+@pytest.mark.parametrize("block_pages", [8, 16])
+def test_a_block_wider_than_its_ring_laps_it(block_pages, kernel_walk):
+    """A tiny model's ring (7 pages here) can be narrower than a block (8 or
+    16 pages): the block's pages lap the ring once or twice (a remainder where
+    each copy is started), and every slot read again is masked by its logical
+    position."""
+    rep, window = 8, 5
+    k, v, tables, token_seq, positions, q = feed_of(
+        RING_MIXES["a-window-shorter-than-a-block"]["rows"], rep, 40, 8, 0, 120, window=window)
+    assert tables.shape[1] == 7
+    walk = attention.paged_attention.__wrapped__
+    args = (1, tables, token_seq, positions, block_pages, window)
+    got = np.asarray(walk(q, k, v, *args))
+    kernel_walk[0] = "default"
+    fed = np.asarray(token_seq) < 8
+    np.testing.assert_allclose(got[fed], np.asarray(walk(q, k, v, *args))[fed], atol=2e-5, rtol=2e-5)
+    ref = reference(k, v, tables, token_seq, positions, q, 1, rep, window)
+    np.testing.assert_allclose(got[fed], ref[fed], atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("dtype,live,buffers,window", [
+    ("float32", [1, 1, 1, 1, 1, 1, 0, 0], 2, None), ("bfloat16", [1, 1, 1, 1, 1, 1, 0, 0], 2, None),
     # the walk hands the kernel its live tiles first; the kernel itself asks for no order
-    ("float32", [1, 0, 1, 1, 0, 0, 1, 1], 2), ("float32", [0, 1, 1, 1, 1, 1, 0, 1], 3),
-    ("bfloat16", [1, 1, 1, 1, 1, 1, 0, 0], 4)])
-def test_a_tile_reads_nothing_past_its_own_last_block(dtype, live, buffers, kernel_walk, monkeypatch):
+    ("float32", [1, 0, 1, 1, 0, 0, 1, 1], 2, None), ("float32", [0, 1, 1, 1, 1, 1, 0, 1], 3, None),
+    ("bfloat16", [1, 1, 1, 1, 1, 1, 0, 0], 4, None),
+    # rings of 15 pages in blocks of 2 (no whole number of blocks), a window of 1.25 blocks
+    ("float32", [1, 1, 1, 1, 1, 1, 0, 0], 2, 10), ("bfloat16", [1, 0, 1, 1, 0, 0, 1, 1], 4, 10),
+    ("float32", [0, 1, 1, 1, 1, 1, 0, 1], 3, 5)])
+def test_a_tile_reads_nothing_past_its_own_last_block(dtype, live, buffers, window, kernel_walk,
+                                                      monkeypatch):
     """One group, tiles ending apart: the kernel is called as the walk calls
     it, with NaN in every block past each TILE's own trips, in both arenas;
-    whatever the number of blocks it keeps on their way in."""
+    whatever the number of blocks it keeps on their way in.  Under a window
+    the tiles start apart too: NaN in every ring slot that lies before a
+    tile's own first block or past its own last."""
     monkeypatch.setattr(head_walk, "BUFFERS", buffers)
     rng = np.random.default_rng(3)
-    g, kvh, rep, w, n_pages, p_width = 8, 4, 5, 8, 40, 16
+    g, kvh, rep, w, n_pages = 8, 4, 5, 8, 40
+    p_width = 16 if window is None else 15
     newest = np.array([7 * BT + 5, 4 * BT, 4 * BT - 1, BT, 3, 0, 2 * BT, 9])
     live = np.array(live, bool)
-    trips = attention.tile_trips(newest, live, BT)  # the one trips rule: neither kernel's own
+    pos = np.maximum(newest[:, None] - np.arange(w)[None, ::-1], 0)  # [tiles, slots]
+    first = attention.first_block(pos[:, 0], BT, window)
+    # the one trips rule: neither kernel's own
+    trips = attention.tile_trips(newest, live, BT, None if window is None else first)
     assert not hasattr(head_walk, "tile_trips") and not hasattr(latent_walk, "tile_trips")
-    assert list(trips) == [t if on else 0 for t, on in zip([8, 5, 4, 2, 1, 1, 3, 2], live)]
+    own = {None: [8, 5, 4, 2, 1, 1, 3, 2], 10: [3, 3, 3, 2, 1, 1, 3, 2], 5: [2, 3, 2, 2, 1, 1, 3, 2]}
+    assert list(trips) == [t if on else 0 for t, on in zip(own[window], live)]
     tab = np.zeros((g, p_width), np.int32)
     for i in range(g):  # every tile its own pages
         tab[i] = 1 + i * n_pages + (np.arange(p_width) + 7 * i) % (n_pages - 1)
     clean = [rng.standard_normal((1, g * n_pages + 1, PS, kvh, HD)).astype(np.float32) for _ in "kv"]
     bad = [a.copy() for a in clean]
     for i in range(g):
+        walked = np.arange(first[i] * BP, (first[i] + trips[i]) * BP) % p_width
         for a in bad:
-            a[0, tab[i, trips[i] * BP:]] = np.nan
+            a[0, np.delete(tab[i], walked)] = np.nan
     # the group is tiles 8..15 of a step's sixteen: its queries are read in place
     q = rng.standard_normal((2 * g, kvh, w * rep, HD)).astype(np.float32)
-    pos = np.maximum(newest[:, None] - np.arange(w)[None, ::-1], 0)  # [tiles, slots]
     dt = jnp.dtype(dtype)
+    ring = {} if window is None else dict(window=window, first_blocks=jnp.asarray(first, jnp.int32))
     call = lambda k, v: np.asarray(head_walk.walk_group(  # noqa: E731
         jnp.asarray(q, dt), jnp.asarray(pos, jnp.int32), jnp.asarray(k, dt), jnp.asarray(v, dt), 0,
         jnp.asarray(tab), jnp.asarray(trips, jnp.int32), g, block_pages=BP,
-        scale=1 / np.sqrt(HD)), np.float32)
+        scale=1 / np.sqrt(HD), **ring), np.float32)
     got, want = call(*bad), call(*clean)
     assert got.shape == (g, kvh, w * rep, HD) and np.isfinite(got).all()
     np.testing.assert_array_equal(got, want)
     assert not got[~live].any() and got[live].any(axis=(1, 2, 3)).all()  # idle tiles write zeros
-    # tile 8 + 3, K/V head 2, against a plain softmax over its own keys
-    k3, v3 = (np.asarray(jnp.asarray(a, dt), np.float32)[0, tab[3, :2 * BP]].reshape(2 * BT, kvh, HD)[:, 2]
-              for a in clean)
-    q3 = np.asarray(jnp.asarray(q, dt), np.float32)[g + 3, 2]
-    s3 = np.where(np.arange(2 * BT)[None] <= np.repeat(pos[3], rep)[:, None],
-                  q3 @ k3.T / np.sqrt(HD), -np.inf)
-    p3 = np.exp(s3 - s3.max(-1, keepdims=True))
+    # tiles 8 + 3 and 8 + 0 (the deepest: a window cuts its keys), K/V head 2, against a plain
+    # softmax over each slot's own keys
     tol = 2e-5 if dtype == "float32" else 3e-2
-    np.testing.assert_allclose(got[3, 2], (p3 / p3.sum(-1, keepdims=True)) @ v3, atol=tol, rtol=tol)
+    for i in (i for i in (3, 0) if live[i]):
+        k_pos = np.arange(first[i] * BT, (first[i] + trips[i]) * BT)
+        at = tab[i, (k_pos // PS) % p_width], k_pos % PS
+        ki, vi = (np.asarray(jnp.asarray(a, dt), np.float32)[0][at][:, 2] for a in clean)
+        qi = np.asarray(jnp.asarray(q, dt), np.float32)[g + i, 2]
+        mine = np.repeat(pos[i], rep)[:, None]
+        seen = (k_pos[None] <= mine) & (k_pos[None] > mine - (window or 10 ** 6))
+        si = np.where(seen, qi @ ki.T / np.sqrt(HD), -np.inf)
+        pi = np.exp(si - si.max(-1, keepdims=True))
+        np.testing.assert_allclose(got[i, 2], (pi / pi.sum(-1, keepdims=True)) @ vi, atol=tol, rtol=tol)
 
 
 def test_the_host_counts_each_kind_of_page_as_its_program_walks_it(kernel_walk, monkeypatch):
-    """A program with a window kind beside the whole-row kind: the full
-    layers' walk is the kernel (each tile to its own end: the host's count
-    equals the trips the kernel's own loop bounds admit), the window layers'
-    rings keep the ``jax.numpy`` walk and are counted by its group rule."""
+    """A program with a window kind beside the whole-row kind: both kinds'
+    walks are the kernel, each in its own form, and the host's count of each
+    kind equals the trips the kernel's own loop bounds admit: a full layer's
+    tiles each to their own end, a window layer's from their own first block
+    to their own end."""
     cfg = afmoe.AfmoeConfig(dtype=jnp.float32, n_heads=8, n_kv_heads=2, max_seq_len=512,
                             window=32)
     be = ServingBackend(cfg, num_pages=300, page_size=PS, max_seqs=6, max_batch_tokens=6 + 20,
                         params=afmoe.init_params(jax.random.PRNGKey(1), cfg))
     be._ensure()
-    assert be.kernels == {"walk": "", "expert": ""}  # the arenas live on the CPU
+    assert be.kernels == {"walk": "", "ring": "", "expert": ""}  # the arenas live on the CPU
     # as a backend on the TPU reports, by the specification's own rule
     be.kernels = be.spec.kernels(head_walk.PLATFORM, 1)
-    assert be.kernels["walk"] == head_walk.KERNEL_NAME
+    assert be.kernels["walk"] == be.kernels["ring"] == head_walk.KERNEL_NAME
     bt, wbt = be._block_tokens
     w, g = attention.attn_tile_slots(cfg.n_heads // cfg.n_kv_heads), attention.ATTN_GROUP_TILES
     assert w == 8 and bt == wbt == 64
-    admitted = []
+    admitted = {None: [], cfg.window: []}  # a form of the kernel each
     real = head_walk.walk_group
 
     def noted(*args, **kw):
-        jax.debug.callback(lambda t: admitted.append(int(t.sum())), args[6])
+        jax.debug.callback(lambda t, form=admitted[kw.get("window")]: form.append(int(t.sum())),
+                           args[6])
         return real(*args, **kw)
 
     monkeypatch.setattr(head_walk, "walk_group", noted)
@@ -222,34 +312,32 @@ def test_the_host_counts_each_kind_of_page_as_its_program_walks_it(kernel_walk, 
     jax.effects_barrier()
     # whole rows: the chunk's three tiles end in block 3, the decode rows in 1, 1, 0, 0
     own = 3 * 4 + 2 + 2 + 1 + 1
-    full_layers = cfg.n_layers - len(cfg.window_layers)
-    assert sum(admitted) == own * full_layers
-    # the rings: one group of seven tiles, every tile to the group's longest walk
-    # (a window of 32 positions in blocks of 64: one or two blocks a tile)
+    assert sum(admitted[None]) == own * (cfg.n_layers - len(cfg.window_layers))
+    # the rings, a window of 32 positions in blocks of 64: the chunk's three tiles walk blocks
+    # 2 and 3, the decode row at 127 block 1 alone, the one at 69 blocks 0 and 1, the others 0
+    ringed = 3 * 2 + 1 + 2 + 1 + 1
+    assert sum(admitted[cfg.window]) == ringed * len(cfg.window_layers)
+    assert be.last_attn_rows == (own + ringed, w * (own + ringed))
+    assert be.last_attn_blocks[0] == 4 and be.last_window_blocks == 2
+    # the group rule, every tile to its group's longest walk (one group of seven tiles here),
+    # for a kind whose program holds no kernel: the flags are one a kind
     spans = np.array([[0, 20], [20, 21], [21, 22], [22, 23], [23, 24]])
     positions = np.concatenate([d + np.arange(n) for d, n in rows])
-    lo = np.array([0, 8, 16, 20, 21, 22, 23])
-    hi = np.array([8, 16, 20, 21, 22, 23, 24])
-    order = attention.walk_order(positions[hi - 1], np.ones(7, bool))
-    ringed = g * int(attention.walk_blocks(positions[lo][order], positions[hi - 1][order], wbt,
-                                       cfg.window)[1])
-    assert be.last_attn_rows == (own + ringed, w * (own + ringed))
-    assert be.last_attn_blocks[0] == 4 and be.last_window_blocks == ringed // g
-    # the group rule for both kinds where the program holds no kernel
     shapes = (spans, positions, w, (bt, wbt), cfg.window)
-    assert attention.count_walk(*shapes, own_ends=False)[2] == (
-        g * 4 + ringed, w * (g * 4 + ringed))
-    # and the kernel's rule is the one the step above was counted by
-    assert attention.count_walk(*shapes, own_ends=True) == (
-        4, ringed // g, be.last_attn_rows, be.last_attn_live)
+    assert attention.count_walk(*shapes, own_ends=(False, False))[2] == (
+        g * 4 + g * 2, w * (g * 4 + g * 2))
+    assert attention.count_walk(*shapes, own_ends=(True, False))[2] == (
+        own + g * 2, w * (own + g * 2))
+    # and the kernels' rule is the one the step above was counted by
+    assert attention.count_walk(*shapes, own_ends=(True, True)) == (
+        4, 2, be.last_attn_rows, be.last_attn_live)
 
 
 def test_the_rule_is_the_arenas_form_the_platform_and_one_device():
-    assert head_walk.holds_kernel("tpu", True, None, 0) and head_walk.holds_kernel("tpu", True, None, 1)
-    assert not head_walk.holds_kernel("tpu", True, 32, 1)  # a window's ring
-    assert not head_walk.holds_kernel("cpu", True, None, 1)  # another platform
-    assert not head_walk.holds_kernel("tpu", True, None, 4)  # a mesh of more than one device
-    assert not head_walk.holds_kernel("tpu", False, None, 1)  # a latent arena: its own kernel
+    assert head_walk.holds_kernel("tpu", True, 0) and head_walk.holds_kernel("tpu", True, 1)
+    assert not head_walk.holds_kernel("cpu", True, 1)  # another platform
+    assert not head_walk.holds_kernel("tpu", True, 4)  # a mesh of more than one device
+    assert not head_walk.holds_kernel("tpu", False, 1)  # a latent arena: its own kernel
     assert latent_walk.holds_kernel("tpu", True) and not latent_walk.holds_kernel("cpu", True)
     assert head_walk.PLATFORM == latent_walk.PLATFORM == "tpu"
     assert head_walk.KERNEL_NAME != latent_walk.KERNEL_NAME
@@ -258,19 +346,27 @@ def test_the_rule_is_the_arenas_form_the_platform_and_one_device():
     assert attention.walk_kernel(None, True, None, 1) is head_walk  # the kernel's own platform
     assert attention.walk_kernel("cpu", True, None, 1) is None
     assert attention.walk_kernel("tpu", True, None, 4) is None
-    assert attention.walk_kernel("tpu", True, 32, 1) is None is attention.walk_kernel("tpu", False, 32, 1)
+    # a window's ring: the by-head kernel's second form, on one device; a latent arena has none
+    assert attention.walk_kernel("tpu", True, 32, 1) is head_walk
+    assert attention.walk_kernel("tpu", True, 32, 4) is None is attention.walk_kernel("cpu", True, 32, 1)
+    assert attention.walk_kernel("tpu", False, 32, 1) is None
     assert attention.walk_kernel("tpu", False, None, 4) is latent_walk
     assert attention.walk_label("tpu", False, 1) == {"walk": "latent_walk"}
     assert attention.walk_label("tpu", True, 1) == {"walk": "head_walk"}
     assert attention.walk_label("cpu", True, 1) == attention.walk_label("tpu", True, 2) == {"walk": ""}
+    # a role a kind of page, in the kinds' order
+    assert attention.WALK_ROLES == ("walk", "ring")
+    assert attention.walk_label("tpu", True, 1, 32) == {"walk": "head_walk", "ring": "head_walk"}
+    assert attention.walk_label("tpu", True, 4, 32) == {"walk": "", "ring": ""}
+    assert attention.walk_label("tpu", False, 1, 32) == {"walk": "latent_walk", "ring": ""}
 
 
 @pytest.mark.parametrize("form", ["by-head", "window", "mesh", "latent"])
 def test_the_traced_program_holds_the_walk_its_form_asks_for(form):
     """What ``paged_attention`` hands to the lowering: both walks for K and V
-    by head on one device (the platform chooses), the ``jax.numpy`` walk alone
-    under a window or over a mesh of more than one device, the latent form's
-    own kernel for a latent arena."""
+    by head on one device (the platform chooses), whole rows and a window's
+    rings alike, the ``jax.numpy`` walk alone over a mesh of more than one
+    device, the latent form's own kernel for a latent arena."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     rep = 2
@@ -286,9 +382,9 @@ def test_the_traced_program_holds_the_walk_its_form_asks_for(form):
     text = str(jax.make_jaxpr(
         lambda q, k, v: attention.paged_attention(q, k, v, 0, tables, token_seq, positions, BP, window, **kw)
     )(q, k, v))
-    assert (head_walk.KERNEL_NAME in text) == (form == "by-head")
+    assert (head_walk.KERNEL_NAME in text) == (form in ("by-head", "window"))
     assert (latent_walk.KERNEL_NAME in text) == (form == "latent")
-    assert ("platform_index" in text) == (form in ("by-head", "latent"))
+    assert ("platform_index" in text) == (form != "mesh")
 
 
 def test_the_busy_share_reader_finds_the_kernels_events_or_nothing():

@@ -213,10 +213,10 @@ def test_the_host_counts_the_tile_trips_the_kernel_admits(kernel_walk, monkeypat
     spans = np.array([[0, 14], [14, 15], [15, 16], [16, 17], [17, 18]])
     positions = np.concatenate([d + np.arange(n) for d, n in rows])
     longest, ringed, gathered, live = attention.count_walk(
-        spans, positions, w, (bt,), None, own_ends=False)
+        spans, positions, w, (bt,), None, own_ends=(False,))
     assert (longest, ringed, gathered) == (4, 0, (8 * 4, 8 * w * 4)) and live > 0
     # and the kernel's rule is the one the step above was counted by
-    assert attention.count_walk(spans, positions, w, (bt,), None, own_ends=True) == (
+    assert attention.count_walk(spans, positions, w, (bt,), None, own_ends=(True,)) == (
         be.last_attn_blocks[0], 0, be.last_attn_rows, be.last_attn_live)
 
 

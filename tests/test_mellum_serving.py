@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from benchmarks.families import mellum_reference as ref_mod
-from cordum_tpu.models import afmoe, axk1, expert_mlp, head_walk, mellum, rotary
+from cordum_tpu.models import afmoe, attention, axk1, expert_mlp, head_walk, mellum, rotary
 from cordum_tpu.serving.backend import StepEntry
 from cordum_tpu.serving.engine import GenRequest, ServingEngine
 from cordum_tpu.serving.modelspec import UnsupportedForModel, spec_for
@@ -99,7 +99,7 @@ def test_prefill_in_chunks_then_decode_through_both_kinds_of_page_equal_the_refe
     preds = feed(be, Rows(be, len(seqs)), seqs, chunks)
     assert be.compiled_programs() == 1
     assert [a.shape[0] for a in be._arenas] == [1, 1, 3, 3]  # full K, V; window K, V
-    assert be.kernels == {"walk": "", "expert": ""}  # the CPU holds the jax.numpy forms
+    assert be.kernels == {"walk": "", "ring": "", "expert": ""}  # the CPU holds the jax.numpy forms
     for seq, p in zip(seqs, preds):
         assert len(p) == len(seq)
         g = gaps(cfg, params, seq, p)
@@ -223,20 +223,22 @@ def test_the_shares_add_up_to_the_whole_layer(ranks):
 
 def test_the_specification_states_counters_and_kernels_through_the_seam():
     """``count_aux`` under afmoe's names (the existing readers read them) and
-    the ``kernels`` roles ``walk`` and ``expert``, by each kernel's own
-    ``holds_kernel``: none on the CPU, both on one TPU device, the by-head
-    walk's not over a mesh of more."""
+    the ``kernels`` roles ``walk``, ``ring`` (the window kind's walk: a role
+    of its own since ISSUE 47, by which the host counts that kind) and
+    ``expert``, by each kernel's own ``holds_kernel``: none on the CPU, all on
+    one TPU device, the by-head walk's two not over a mesh of more."""
     cfg = tiny()
     spec = spec_for(cfg)
     assert (spec.family, spec.n_arenas, spec.window) == ("mellum", 4, 32)
     assert spec.aux_shape == (cfg.n_layers, cfg.experts_held) and spec.kv_by_head
     assert spec.kv_positional and not spec.kv_whole_row and spec.value_dim == cfg.head_dim
-    assert spec.kernels("cpu", 1) == {"walk": "", "expert": ""}
+    assert spec.kernels("cpu", 1) == {"walk": "", "ring": "", "expert": ""}
     on_chip = spec.kernels("tpu", 1)
-    assert on_chip == {"walk": head_walk.KERNEL_NAME, "expert": expert_mlp.KERNEL_NAME}
-    assert head_walk.holds_kernel("tpu", True, None, 1) and expert_mlp.holds_kernel(
+    assert on_chip == {"walk": head_walk.KERNEL_NAME, "ring": head_walk.KERNEL_NAME,
+                       "expert": expert_mlp.KERNEL_NAME}
+    assert head_walk.holds_kernel("tpu", True, 1) and expert_mlp.holds_kernel(
         "tpu", cfg.d_model, cfg.d_expert, 4)
-    assert spec.kernels("tpu", 4) == {"walk": "", "expert": expert_mlp.KERNEL_NAME}
+    assert spec.kernels("tpu", 4) == {"walk": "", "ring": "", "expert": expert_mlp.KERNEL_NAME}
     counts = np.zeros((cfg.n_layers, cfg.experts_held), np.int32)
     counts[0, 3], counts[2, 5], counts[2, 6] = 7, 2, 1
     counters, attrs = spec.count_aux(counts, 5, on_chip)
@@ -248,15 +250,31 @@ def test_the_specification_states_counters_and_kernels_through_the_seam():
     assert "moe_kernel_items" not in counters and attrs["expert_kernel"] == "none"
 
 
-async def test_engine_serves_mixed_rows_bounded_and_counted():
+async def test_engine_serves_mixed_rows_bounded_and_counted(monkeypatch):
     """Through the engine: short and long rows share steps, the window kind's
     pages a session never pass the ring, both allocators stay consistent, the
     family's counters reach ``ServingStats.model`` under afmoe's names, and
-    with the whole expert set here every assignment is here."""
+    with the whole expert set here every assignment is here.  The ``step``
+    span names the ring kind's kernel beside the whole-row kind's (ISSUE 47),
+    and where the backend reports the TPU's kernels the host counts each
+    kind's tiles by their own ends: fewer rows gathered for the same step."""
+    from cordum_tpu.infra.bus import LoopbackBus
+    from cordum_tpu.obs.tracer import Tracer
+    from cordum_tpu.protocol import subjects as subj
+    from cordum_tpu.serving import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "STEP_SAMPLE_PERIOD_NS", 0)  # every cycle a ``step`` trace
+    bus, spans = LoopbackBus(), []
+
+    async def on_span(subject, pkt):
+        spans.append(pkt.span)
+
+    await bus.subscribe(subj.TRACE_SPAN, on_span)
     cfg = tiny()
     params = mellum.init_params(jax.random.PRNGKey(7), cfg)
     be = backend_for(cfg, params, max_seqs=3, budget=9, pages=100)
-    eng = ServingEngine(be, run_blocking=run_blocking, max_sessions=3, max_new_tokens_cap=64)
+    eng = ServingEngine(be, run_blocking=run_blocking, max_sessions=3, max_new_tokens_cap=64,
+                        tracer=Tracer("worker", bus))
     assert eng.prefix is None and eng.tiering is None  # sharing is off for this family
     seen = []
     inner = be.step
@@ -273,7 +291,28 @@ async def test_engine_serves_mixed_rows_bounded_and_counted():
     outs = await asyncio.wait_for(asyncio.gather(*(
         eng.submit(GenRequest(prompt=p, max_new_tokens=n, stream=False), job_id=f"j{i}")
         for i, (p, n) in enumerate(zip(prompts, n_new)))), timeout=240)
+    # the same first request again, counted as a backend on the TPU counts (the CPU's program
+    # still walks as ``jax.numpy``: the count is the host's, by the specification's own rule)
+    be.kernels = be.spec.kernels(head_walk.PLATFORM, 1)
+    again = await asyncio.wait_for(eng.submit(
+        GenRequest(prompt=prompts[0], max_new_tokens=n_new[0], stream=False), job_id="again"),
+        timeout=240)
     await eng.stop()
+    await bus.drain()
+    assert again["tokens"] == outs[0]["tokens"]
+    steps = sorted((s for s in spans if s.name == "step"), key=lambda s: s.start_us)
+    cpu = [s for s in steps if s.attrs["ring_kernel"] == "none"]
+    tpu = [s for s in steps if s.attrs["ring_kernel"] == head_walk.KERNEL_NAME]
+    assert cpu and tpu and len(cpu) + len(tpu) == len(steps)
+    assert all(s.attrs["walk_kernel"] == s.attrs["ring_kernel"] for s in steps)
+    # by the group rule all eight tiles of a group are counted to its longest walk; by the
+    # kernels' rule a lone decode row's one tile is counted by its own walk in each kind
+    assert all(int(s.attrs["kv_rows"]) % attention.ATTN_GROUP_TILES == 0 for s in cpu)
+    lone = [s for s in tpu if s.attrs["live_tokens"] == "1"]
+    assert len(lone) >= n_new[0] - 1 and all(
+        int(s.attrs["kv_rows"]) == int(s.attrs["kv_blocks"].split("/")[0]) + int(s.attrs["window_blocks"])
+        for s in lone)
+    prompts, n_new, outs = prompts + [prompts[0]], n_new + [n_new[0]], outs + [again]
     for p, o in zip(prompts, outs):
         seq = p + o["tokens"]
         g = gaps(cfg, params, seq[:-1], seq[1:])[len(p) - 1:]

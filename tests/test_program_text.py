@@ -8,8 +8,12 @@ function objects cut out); a change that means to alter one of these programs
 updates its hash, and says so: ``llama``'s and ``afmoe``'s changed ON
 PURPOSE with ISSUE 44, whose ``paged_attention`` hands BOTH walks over K and V
 by head to the lowering (``walk_jnp`` and the ``head_walk`` kernel,
-``models/head_walk.py``: ``afmoe``'s full layers; its window layers' rings
-keep ``walk_jnp`` alone), so both are in the trace; the three latent programs
+``models/head_walk.py``), so both are in the trace; ``afmoe``'s changed ON
+PURPOSE again with ISSUE 47, whose kernel walks a window layer's ring too
+(a first block a tile, the ring's modulus, the window's lower bound in the
+mask: a second, static form of the one kernel), so its window layers hand both
+walks to the lowering as its full layers do, while ``llama``'s (no window: the
+kernel's ``window=None`` form) is the text it was; the three latent programs
 hold ``latent_walk`` as they did and their text is the string it was.
 ``axk1``'s and ``longcat``'s are ISSUE 41's, whose
 expert layer hands BOTH forms of the grouped products to the lowering
@@ -36,11 +40,11 @@ from cordum_tpu.serving.modelspec import spec_for
 
 PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
 
-#: sha256 of the jaxpr text: PR 44's tree (llama, afmoe), PR 41's (axk1, longcat), PR 43's (bailing)
+#: sha256 of the jaxpr text: PR 44's tree (llama), PR 47's (afmoe), PR 41's (axk1, longcat), PR 43's (bailing)
 AS_IT_WAS = {
     "bailing": "61b1448bb9d1572be43f70b2e86c68325cb869b6ebc67b458d2b7a3f8841a919",
     "llama": "7c24797e0e81a1d624d7c9f78f6ee45ebc3763a0e78890394292bd291fa24ef3",
-    "afmoe": "61ba09b08bb56661244de4b70fd7727f8a0d65092f77d838808439fb90670dee",
+    "afmoe": "8d3124ba413fbccd996dc426ee4fc6580a4c00584d3cb0b95bd3ced3d1f9e5f8",
     "axk1": "dc5522dfaaf71aa57a0142aa9378bcda99ddab191aedeb6ab295b221287189b2",
     "longcat": "2b508f8fe8fefb380ba03563ea32191691167b2510d5a4ab0ad0cf28174be0ae",
 }
@@ -109,9 +113,10 @@ def test_the_state_space_familys_program_holds_pages_and_state_in_every_layer():
 def test_the_whole_expert_set_familys_program_holds_both_walks_and_both_products():
     """ISSUE 46's family is built from the parts the others run: one trace
     holds both forms of the grouped products and both walks over K and V by
-    head (the lowering chooses; the rings' walk is ``jax.numpy``'s alone), two
-    kinds of arena among its operands and results, a ring table beside the
-    whole-row one in its feed, and neither a latent walk nor a recurrence."""
+    head for BOTH kinds of page (the lowering chooses: since ISSUE 47 the
+    kernel walks the rings too, its second form), two kinds of arena among its
+    operands and results, a ring table beside the whole-row one in its feed,
+    and neither a latent walk nor a recurrence."""
     cfg = mellum.MellumConfig()
     text = text_of(cfg)
     assert all(w in text for w in ("expert_mlp", "ragged_dot", "head_walk", "platform_index"))
@@ -120,6 +125,8 @@ def test_the_whole_expert_set_familys_program_holds_both_walks_and_both_products
     full = f"bf16[{len(cfg.full_layers)},{PAGES},{PS},{cfg.n_kv_heads},{cfg.head_dim}]"
     rings = f"bf16[{len(cfg.window_layers)},{SEQS * ring + 1},{PS},{cfg.n_kv_heads},{cfg.head_dim}]"
     assert text.count(full) >= 4 and text.count(rings) >= 4
+    # the kernel is handed each kind's arenas where they lie (``pl.ANY``): the rings' too
+    assert f"Ref<any>{{{full}}}" in text and f"Ref<any>{{{rings}}}" in text
     layout = FeedLayout(TOKENS, SEQS, (CONTEXT // PS, ring))
     assert f"i32[{layout.size}]" in text
     # a rotation a kind: YaRN's factor on the full kind's cos and sin is in the trace
