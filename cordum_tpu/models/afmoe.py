@@ -201,6 +201,16 @@ def route(m: jax.Array, layer: Params, cfg: Any) -> tuple[jax.Array, jax.Array]:
     score is the sum of its two best experts', and a token picks its
     ``top_k`` among the experts of its ``topk_group`` best groups only, so
     its experts span at most that many groups (in a deployment: chips).
+    Neither step sorts (scope ``moe_group_select``).  A group's score is two
+    maximum passes: its best, then the best of what is left when the FIRST
+    position that holds the best is taken out, one position and not every
+    equal of the best, so two equal best scores sum to twice the best as
+    the descending pair of a ``top_k`` would.  A group is kept iff fewer than
+    ``topk_group`` groups beat it, where ``j`` beats ``g`` with the larger
+    score or, at equal scores, the lower index: ``jax.lax.top_k``'s own order,
+    so the kept set is the one a ``top_k`` over the groups names, ties and a
+    padding row's all-equal scores included (tests/test_axk1_serving.py holds
+    ``sel`` and ``w`` to that form element for element).
     Sigmoid scores, one group and no identity experts is the program it
     always was."""
     logits = jnp.matmul(
@@ -212,11 +222,17 @@ def route(m: jax.Array, layer: Params, cfg: Any) -> tuple[jax.Array, jax.Array]:
     if cfg.n_group > 1:
         with jax.named_scope("moe_group_select"):
             t, n = pick.shape
-            by_group = pick.reshape(t, cfg.n_group, n // cfg.n_group)
-            best2, _ = jax.lax.top_k(by_group, 2)
-            _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), cfg.topk_group)  # [T, topk_group]
-            keep = jnp.zeros((t, cfg.n_group), bool).at[
-                jnp.arange(t)[:, None], kept].set(True)
+            per = n // cfg.n_group
+            by_group = pick.reshape(t, cfg.n_group, per)
+            best = jnp.max(by_group, axis=-1)
+            first = jnp.argmax(by_group, axis=-1)  # ONE position: an equal score behind it stays
+            second = jnp.max(
+                jnp.where(jnp.arange(per) == first[..., None], -jnp.inf, by_group), axis=-1)
+            score = best + second  # [T, n_group]
+            ours, theirs = score[:, :, None], score[:, None, :]
+            g = jnp.arange(cfg.n_group)
+            beaten_by = (theirs > ours) | ((theirs == ours) & (g[None, :] < g[:, None]))
+            keep = jnp.sum(beaten_by, axis=-1) < cfg.topk_group
             pick = jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(t, n)
     _, sel = jax.lax.top_k(pick, cfg.top_k)
     w = jnp.take_along_axis(scores, sel, axis=1)

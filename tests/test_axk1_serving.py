@@ -258,6 +258,74 @@ def test_the_group_limit_holds_and_matches_the_reference():
         tiny(n_experts=16, n_group=4, topk_group=1, top_k=6)  # one kept group has four experts
 
 
+def sorted_group_route(m, layer, cfg):
+    """``afmoe.route`` under ``n_group`` > 1 as it was before ISSUE 48,
+    verbatim: a sort of every group for its two best, a ``top_k`` over the
+    groups' sums, the kept set scattered into a mask."""
+    logits = jnp.matmul(m.astype(jnp.float32), layer["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = (jax.nn.softmax(logits, axis=-1) if cfg.route_score == "softmax"
+              else jax.nn.sigmoid(logits))
+    pick = scores + layer["router_bias"] if "router_bias" in layer else scores
+    t, n = pick.shape
+    by_group = pick.reshape(t, cfg.n_group, n // cfg.n_group)
+    best2, _ = jax.lax.top_k(by_group, 2)
+    _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), cfg.topk_group)
+    keep = jnp.zeros((t, cfg.n_group), bool).at[jnp.arange(t)[:, None], kept].set(True)
+    pick = jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(t, n)
+    _, sel = jax.lax.top_k(pick, cfg.top_k)
+    w = jnp.take_along_axis(scores, sel, axis=1)
+    if cfg.route_norm:
+        w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+    return sel, w * cfg.route_scale
+
+
+def router_logits(kind, t, n, seed):
+    """``[t, n]`` float32 logits: ``random`` normal; ``ties`` from four
+    values, so equal scores inside a group and equal sums between groups are
+    the rule; ``flat`` one value a row (a padding row scores every expert
+    alike), a zero row among them."""
+    rng = np.random.default_rng(seed)
+    if kind == "random":
+        return rng.standard_normal((t, n)).astype(np.float32)
+    if kind == "ties":
+        return rng.choice(np.float32([-1.0, -0.5, 0.5, 1.0]), size=(t, n))
+    rows = rng.standard_normal((t, 1)).astype(np.float32)
+    rows[0] = 0.0
+    return np.broadcast_to(rows, (t, n)).copy()
+
+
+@pytest.mark.parametrize("bias", ["bias", "no_bias"])
+@pytest.mark.parametrize("score", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("kind", ["random", "ties", "flat"])
+@pytest.mark.parametrize("n_group,per,topk_group,top_k",
+                         [(4, 4, 2, 4), (8, 24, 4, 8), (8, 64, 4, 8)])
+def test_the_unsorted_group_choice_is_the_sorted_one_element_for_element(
+        n_group, per, topk_group, top_k, kind, score, bias):
+    """ISSUE 48: two maximum passes and a rank by comparison pick the experts
+    and weights the sort and the scatter picked, ties included (``top_k``'s
+    order: larger first, the lower index first among equals).  The router is
+    the identity, so the logits are the input's own numbers."""
+    n = n_group * per
+    cfg = dataclasses.replace(tiny(n_experts=n, experts_held=n, n_group=n_group,
+                                   topk_group=topk_group, top_k=top_k), route_score=score)
+    layer = {"router": jnp.eye(n, dtype=jnp.float32)}
+    if bias == "bias":  # a few values too, so that a bias does not break every tie
+        layer["router_bias"] = jnp.asarray(
+            np.random.default_rng(7).choice(np.float32([0.0, 0.125, 0.25]), size=n))
+    m = jnp.asarray(router_logits(kind, 160, n, seed=n + top_k))
+    sel, w = jax.jit(lambda x: afmoe.route(x, layer, cfg))(m)
+    sel0, w0 = jax.jit(lambda x: sorted_group_route(x, layer, cfg))(m)
+    assert np.array_equal(sel, sel0) and np.array_equal(w, w0)
+    if kind != "random":  # the input did make ties: in a group's two best and between sums
+        pick = np.asarray(jax.nn.sigmoid(m) if score == "sigmoid" else jax.nn.softmax(m, axis=-1))
+        pick = (pick + np.asarray(layer.get("router_bias", 0.0))).reshape(-1, n_group, per)
+        two = np.sort(pick, axis=-1)[..., -2:]
+        sums = two.sum(-1)
+        assert (two[..., 0] == two[..., 1]).any()
+        assert any(len(set(row.tolist())) < n_group for row in sums)
+
+
 def reference_expert_part(cfg, layer, m, first, held):
     """Shared expert + the held experts' weighted terms, by the reference."""
     sel, w = ref_mod.route(m, layer["router"], top_k=cfg.top_k, n_group=cfg.n_group,

@@ -234,8 +234,11 @@ def test_sigmoid_without_identity_experts_is_the_router_it_was_bit_for_bit(famil
     sel, w = afmoe.route(m, layer, cfg)
     sel0, w0 = pr31_route(m, layer, cfg)
     assert np.array_equal(sel, sel0) and np.array_equal(w, w0)
+    # one group traces to the text it had; the group-limited choice changed ON PURPOSE with
+    # ISSUE 48 (no sort, no scatter) and is held to this form's numbers, above and in
+    # tests/test_axk1_serving.py, ties included
     same = lambda f: str(jax.make_jaxpr(lambda x: f(x, layer, cfg))(m))  # noqa: E731
-    assert same(afmoe.route) == same(pr31_route)
+    assert (same(afmoe.route) == same(pr31_route)) == (cfg.n_group == 1)
     # and the expert layer round it computes a shared expert and returns bare counts
     _, counts = afmoe.expert_layer(m, layer, cfg, jnp.ones((64,), bool))
     assert counts.shape == (cfg.experts_held,)

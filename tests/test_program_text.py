@@ -25,7 +25,12 @@ expert layer holds neither.  ``bailing``'s is the text on the tree of ISSUE
 ``kda.state_rows``, two state buffers, a semaphore a buffer and direction),
 and ``kda.recurrence`` is jitted with the layer a traced operand, as
 ``ssd.recurrence`` is, so the KDA layers trace and lower one kernel; the four
-others lower no recurrence kernel and keep theirs."""
+others lower no recurrence kernel and keep theirs.  ``axk1``'s and
+``bailing``'s changed ON PURPOSE with ISSUE 48: the two families whose
+router is group-limited (``n_group`` > 1) find a group's two best and the
+kept groups without a sort and without a scatter (``afmoe.route``, scope
+``moe_group_select``); ``afmoe``, ``longcat`` and Mellum route with one
+group, never enter the scope, and keep their text."""
 import hashlib
 import re
 
@@ -40,19 +45,19 @@ from cordum_tpu.serving.modelspec import spec_for
 
 PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
 
-#: sha256 of the jaxpr text: PR 44's tree (llama), PR 47's (afmoe), PR 41's (axk1, longcat), PR 43's (bailing)
+#: sha256 of the jaxpr text: PR 44's tree (llama), PR 47's (afmoe), PR 41's (longcat), PR 48's (axk1, bailing)
 AS_IT_WAS = {
-    "bailing": "61b1448bb9d1572be43f70b2e86c68325cb869b6ebc67b458d2b7a3f8841a919",
+    "bailing": "e637f361c5b57e62d5a520bd12ef7af598e2600889641a3934d5fd73b91a144a",
     "llama": "7c24797e0e81a1d624d7c9f78f6ee45ebc3763a0e78890394292bd291fa24ef3",
     "afmoe": "8d3124ba413fbccd996dc426ee4fc6580a4c00584d3cb0b95bd3ced3d1f9e5f8",
-    "axk1": "dc5522dfaaf71aa57a0142aa9378bcda99ddab191aedeb6ab295b221287189b2",
+    "axk1": "01c33dbad4020604d77b6efa68cc8155de7b96b715e637624d7ffc7304bd98ae",
     "longcat": "2b508f8fe8fefb380ba03563ea32191691167b2510d5a4ab0ad0cf28174be0ae",
 }
 CONFIGS = {"llama": llama.LlamaConfig.tiny, "afmoe": afmoe.AfmoeConfig, "axk1": axk1.Axk1Config,
            "longcat": longcat.LongcatConfig, "bailing": bailing.BailingConfig}
 
 
-def text_of(cfg) -> str:
+def jaxpr_of(cfg):
     spec = spec_for(cfg)
     ring = attention.window_ring_pages(spec.window, PS, TOKENS) if spec.window else 0
     widths = (CONTEXT // PS, ring) if spec.window else (CONTEXT // PS,)
@@ -62,7 +67,26 @@ def text_of(cfg) -> str:
     arenas = jax.eval_shape(lambda: tuple(spec.init_arenas(PAGES, PS, SEQS * ring + 1)) + (
         () if spec.kv_positional else tuple(spec.init_state(SEQS + 1))))
     feed = jax.ShapeDtypeStruct((layout.size,), jnp.int32)
-    return re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(program)(params, *arenas, feed)))
+    return jax.make_jaxpr(program)(params, *arenas, feed)
+
+
+def text_of(cfg) -> str:
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr_of(cfg)))
+
+
+def equations_under(jaxpr, scope: str, inside: bool = False):
+    """Every equation traced under the named scope ``scope``, those of the
+    jaxprs its equations hold (a ``jit``, a loop's body) among them: an inner
+    jaxpr's name stacks start anew, so the scope is handed down."""
+    for eqn in jaxpr.eqns:
+        here = inside or scope in str(eqn.source_info.name_stack)
+        if here:
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations_under(sub, scope, here)
 
 
 @pytest.mark.parametrize("family", sorted(AS_IT_WAS))
@@ -131,3 +155,29 @@ def test_the_whole_expert_set_familys_program_holds_both_walks_and_both_products
     assert f"i32[{layout.size}]" in text
     # a rotation a kind: YaRN's factor on the full kind's cos and sin is in the trace
     assert str(round(cfg.rope_full.attention_factor, 4)) in text
+
+
+@pytest.mark.parametrize("family", ["axk1", "bailing"])
+def test_the_group_limited_choice_neither_sorts_nor_scatters(family):
+    """ISSUE 48: under ``moe_group_select`` a group's score is two maximum
+    passes and the kept set a rank by comparison: no ``sort``, no ``top_k``
+    (the parent's took the two best of every group and the best groups with
+    one each) and no ``scatter`` (the parent's built the mask with one); the
+    one ``top_k`` a layer left is the final choice's, outside the scope."""
+    cfg = CONFIGS[family]()
+    assert cfg.n_group > 1
+    jaxpr = jaxpr_of(cfg).jaxpr
+    under = [eqn.primitive.name for eqn in equations_under(jaxpr, "moe_group_select")]
+    assert {"reduce_max", "select_n", "gt", "eq", "lt"} <= set(under)
+    assert not any(name in ("sort", "top_k") or name.startswith("scatter") for name in under)
+    final = [eqn for eqn in equations_under(jaxpr, "moe_route") if eqn.primitive.name == "top_k"]
+    assert final and all(eqn.params["k"] == cfg.top_k for eqn in final)
+
+
+@pytest.mark.parametrize("config", [afmoe.AfmoeConfig, longcat.LongcatConfig, mellum.MellumConfig])
+def test_a_router_of_one_group_holds_no_such_scope(config):
+    cfg = config()
+    assert cfg.n_group == 1
+    jaxpr = jaxpr_of(cfg).jaxpr
+    assert not list(equations_under(jaxpr, "moe_group_select"))
+    assert any(eqn.primitive.name == "top_k" for eqn in equations_under(jaxpr, "moe_route"))
