@@ -307,6 +307,46 @@ def mla_sublayer(
     return o.reshape(t_buf, h * vd) @ layer["wo"], c_pages
 
 
+def attention_branch(h: jax.Array, layer: Params, c_pages: jax.Array, li: int, rows: WalkRows,
+                     cfg: Any, rope_fn: Callable[[jax.Array, jax.Array], jax.Array],
+                     dt: Any) -> tuple[jax.Array, jax.Array]:
+    """A block's first sublayer WITHOUT its residual add: ``h`` [T, d]
+    float32 (what the sublayer reads of the residual) -> ``(the latent
+    attention's output over its pre-norm, c_pages)``.  How the output joins
+    the residual is the family's: ``x + o`` here, ``models/xing.py``'s
+    hyper-connection there."""
+    a = rms_norm(h, layer["norm_in"], cfg.norm_eps).astype(dt)
+    return mla_sublayer(a, layer, c_pages, li, rows, cfg, rope_fn)
+
+
+def feed_forward_branch(h: jax.Array, layer: Params, li: int, cfg: Any, live: jax.Array,
+                        dt: Any) -> tuple[jax.Array, Any]:
+    """A block's second sublayer without its residual add: the dense SwiGLU
+    of a leading dense layer or the expert layer, over its pre-norm (float32
+    into the router) -> ``(output [T, d], the expert layer's counts or
+    None)``."""
+    m = rms_norm(h, layer["norm_post"], cfg.norm_eps)  # float32
+    if li < cfg.n_dense_layers:
+        with jax.named_scope("mlp"):
+            mb = m.astype(dt)
+            return (jax.nn.silu(mb @ layer["w_gate"]) * (mb @ layer["w_up"])) @ layer["w_down"], None
+    return expert_layer(m, layer, cfg, live)
+
+
+def sampled(x: jax.Array, params: Params, counts: list, cfg: Any,
+            sample_logits: bool) -> jax.Array:
+    """The step's ``out`` from the last residual ``x`` [T, d]: the per-slot
+    next-token argmax behind the final norm, then the expert layers' counts."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(params["embed"].dtype)
+    tail = jnp.concatenate(counts) if counts else jnp.zeros((0,), jnp.int32)
+    if not sample_logits:
+        nxt = jnp.zeros((x.shape[0],), jnp.int32)
+    else:
+        with jax.named_scope("lm_head"):
+            nxt = jnp.argmax(x @ params["lm_head"], axis=-1).astype(jnp.int32)
+    return jnp.concatenate([nxt, tail])
+
+
 def ragged_step(
     params: Params,
     c_pages: jax.Array,
@@ -324,7 +364,6 @@ def ragged_step(
     latent_width].  Returns ``(out, c_pages)``, ``out`` int32 [T + expert
     layers x experts_held]: the per-slot next-token argmax, then the
     assignments each held expert got in each expert layer."""
-    t_buf = tokens.shape[0]
     live = token_seq < page_tables.shape[0] - 1  # the last row is the padding row
     counts = []
     dt = params["embed"].dtype
@@ -333,26 +372,13 @@ def ragged_step(
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(jnp.float32)  # [T, d], float32 throughout
     for li, layer in enumerate(params["layers"]):
-        a = rms_norm(x, layer["norm_in"], cfg.norm_eps).astype(dt)
-        o, c_pages = mla_sublayer(a, layer, c_pages, li, rows, cfg, rope_fn)
+        o, c_pages = attention_branch(x, layer, c_pages, li, rows, cfg, rope_fn, dt)
         x = x + o
-        m = rms_norm(x, layer["norm_post"], cfg.norm_eps)  # float32
-        if li < cfg.n_dense_layers:
-            with jax.named_scope("mlp"):
-                mb = m.astype(dt)
-                f = (jax.nn.silu(mb @ layer["w_gate"]) * (mb @ layer["w_up"])) @ layer["w_down"]
-        else:
-            f, n = expert_layer(m, layer, cfg, live)
+        f, n = feed_forward_branch(x, layer, li, cfg, live, dt)
+        if n is not None:
             counts.append(n)
         x = x + f
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(dt)
-    tail = jnp.concatenate(counts) if counts else jnp.zeros((0,), jnp.int32)
-    if not sample_logits:
-        nxt = jnp.zeros((t_buf,), jnp.int32)
-    else:
-        with jax.named_scope("lm_head"):
-            nxt = jnp.argmax(x @ params["lm_head"], axis=-1).astype(jnp.int32)
-    return jnp.concatenate([nxt, tail]), c_pages
+    return sampled(x, params, counts, cfg, sample_logits), c_pages
 
 
 def held_kernels(cfg: Any, platform: str, mesh_devices: int) -> dict[str, str]:
@@ -385,5 +411,6 @@ def serving_spec(cfg: Axk1Config) -> Any:
     )
 
 
-__all__ = ["Axk1Config", "WalkRows", "held_kernels", "init_params", "init_arenas", "mla_sublayer",
-           "ragged_step", "rope", "serving_spec", "walk_rows", "yarn_inv_freq"]
+__all__ = ["Axk1Config", "WalkRows", "attention_branch", "feed_forward_branch", "held_kernels",
+           "init_params", "init_arenas", "mla_sublayer", "ragged_step", "rope", "sampled",
+           "serving_spec", "walk_rows", "yarn_inv_freq"]

@@ -144,7 +144,9 @@ class ModelSpec:
     #: partitioned over, the kernel the LOWERED step program holds in each
     #: role the family has, among ``walk`` (the attention's walk over the
     #: whole-row kind of page), ``expert`` (the expert layer's grouped
-    #: products), ``state`` (a recurrence over state slots).  A role that is
+    #: products), ``state`` (a recurrence over state slots), ``residual`` (the
+    #: maps that join a sublayer to a residual of several streams:
+    #: ``models/hyper.py``).  A role that is
     #: absent or "" is the ``jax.numpy`` / ``ragged_dot`` form.  Built from
     #: the kernel modules' own ``holds_kernel``, the predicate the trace-time
     #: choice uses, so the rule is written once a kernel; the kernels'

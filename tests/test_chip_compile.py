@@ -470,6 +470,76 @@ def test_the_whole_expert_set_step_fits_one_chip_and_keeps_both_kinds_of_page_in
           f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
 
 
+@pytest.mark.parametrize("slots,y_dtype", [(240, jnp.bfloat16), (240, jnp.float32),
+                                          (112, jnp.float32), (136, jnp.bfloat16)],
+                         ids=["cell-attention", "cell-experts", "budget-96", "not-whole-tiles"])
+def test_the_maps_kernels_compile_at_the_hyper_connected_cells_shapes(one_chip, slots, y_dtype):
+    """``mhc_open`` and ``mhc_close`` alone at the xing cell's committed shapes
+    (240 buffer slots of four streams 3584 wide; a sublayer's output in
+    bfloat16 from the attention, float32 from the expert layer), at the
+    smallest budget its sweep may choose (112 slots) and on a buffer that is
+    not whole tiles (136), each brought to whole tiles of the open by the
+    wrappers (``hyper.padded_slots``: no partial tile reaches Mosaic, which
+    hung the chip inside a step, PERF.md section 6, PR 49): Mosaic takes the
+    24-row bfloat16 ``phi`` against a tile contracted on the minor axis, the
+    two 128 x 128 transposes and the VMEM the module states."""
+    from cordum_tpu.models import hyper
+
+    hc, width = hyper.Hyper(), 3584
+    shape = lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)  # noqa: E731
+    assert hyper.holds_kernel("tpu", hyper.fits(hc.n, width))
+    opened = jax.jit(lambda x, phi, a, b: hyper.open_kernel(x, phi, a, b, hc)).lower(
+        shape((slots, hc.n * width), jnp.float32), shape((hc.rows, hc.n * width), jnp.bfloat16),
+        shape((3,), jnp.float32), shape((hc.rows,), jnp.float32)).compile()
+    closed = jax.jit(lambda x, y, m: hyper.close_kernel(x, y, m, hc)).lower(
+        shape((slots, hc.n * width), jnp.float32), shape((slots, width), y_dtype),
+        shape((slots, hyper.LANES), jnp.float32)).compile()
+    for compiled, name in ((opened, hyper.OPEN_KERNEL), (closed, hyper.CLOSE_KERNEL)):
+        assert any("tpu_custom_call" in line and name in line
+                   for line in compiled.as_text().splitlines())
+
+
+def test_the_hyper_connected_step_fits_one_chip_and_keeps_its_arena_in_place(one_chip):
+    """The xing cell's ragged program at the benchmark's sizes (``benchmarks/
+    configs/xing4.0-29b-a4b-pp.json``: published widths, one dense and five
+    expert layers of 64 experts each held whole, the whole vocabulary, 16
+    sessions over a 16384 context): 9.59 GB of weights and the 2.01 GB latent
+    arena fit, donation is real, the arena is neither copied nor laid out
+    anew, and the program lowered for the TPU holds all four kernels: the
+    latent walk, the grouped products, and both maps of every sublayer."""
+    from benchmarks.families import xing as fam
+    from benchmarks.harness import cells
+    from cordum_tpu.models import hyper
+
+    doc = dict(cells.load_config("xing4.0-29b-a4b-pp"))
+    cfg, pool = fam.program_config(doc), doc["pool"]
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, shape: jax.ShapeDtypeStruct(
+            shape, jnp.float32 if path[-1].key in fam.FLOAT32 else jnp.bfloat16, sharding=one_chip),
+        fam.param_shapes(doc), is_leaf=lambda x: isinstance(x, tuple))
+    arena = jax.ShapeDtypeStruct(
+        (cfg.n_layers, pool["pages"], pool["page_size"], cfg.latent_width), cfg.dtype,
+        sharding=one_chip)
+    assert cfg.latent_width == 640 and arena.shape[1] * pool["page_size"] == 16 * cfg.max_seq_len
+    layout = FeedLayout(pool["max_sessions"] + pool["prefill_budget"], pool["max_sessions"],
+                        (cfg.max_seq_len // pool["page_size"],))
+    feed = jax.ShapeDtypeStruct((layout.size,), jnp.int32, sharding=one_chip)
+    program = make_ragged_program(cfg, layout, sample_logits=True, donate=True)
+    compiled = program.lower(params, arena, feed).compile()
+    ma = compiled.memory_analysis()
+    arena_bytes = arena.size * arena.dtype.itemsize
+    assert ma.alias_size_in_bytes >= arena_bytes > 2.0e9
+    assert ma.temp_size_in_bytes < 0.5e9  # no copy of the arena among the temporaries
+    assert 11.5e9 < device_bytes(compiled) <= 0.8 * HBM_BYTES
+    text = compiled.as_text()
+    assert holds_walk_kernel(text) and holds_expert_kernel(text) and not holds_head_kernel(text)
+    calls = [line for line in text.splitlines() if "custom_call_target=\"tpu_custom_call\"" in line]
+    for name in (hyper.OPEN_KERNEL, hyper.CLOSE_KERNEL):
+        assert sum(f"%{name}" in line.split("=")[0] for line in calls) == cfg.n_sublayers
+    print(f"xing step: arguments {ma.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{ma.temp_size_in_bytes / 1e9:.3f} GB, code {ma.generated_code_size_in_bytes / 1e6:.1f} MB")
+
+
 def test_reference_forward_fits_beside_the_serving_state(one_chip):
     cfg = smoke_cfg()
     params, arena, _, _ = serving_shapes(

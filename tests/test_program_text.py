@@ -30,7 +30,12 @@ others lower no recurrence kernel and keep theirs.  ``axk1``'s and
 router is group-limited (``n_group`` > 1) find a group's two best and the
 kept groups without a sort and without a scatter (``afmoe.route``, scope
 ``moe_group_select``); ``afmoe``, ``longcat`` and Mellum route with one
-group, never enter the scope, and keep their text."""
+group, never enter the scope, and keep their text.  ISSUE 49 wrote
+``axk1.ragged_step``'s block body as two branches and a tail that
+``models/xing.py`` calls too (the residual adds are the only lines that
+differ): ``axk1``'s text is the string it was, and the new family's own
+program (``xing``: the same sublayers between the maps of a hyper-connected
+stream) joins the table with the text of ISSUE 49's tree."""
 import hashlib
 import re
 
@@ -38,23 +43,26 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from cordum_tpu.models import (afmoe, attention, axk1, bailing, falcon_h1, llama, longcat,
-                               mellum)
+from cordum_tpu.models import (afmoe, attention, axk1, bailing, falcon_h1, hyper, llama, longcat,
+                               mellum, xing)
 from cordum_tpu.serving.backend import FeedLayout, make_ragged_program
 from cordum_tpu.serving.modelspec import spec_for
 
 PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
 
-#: sha256 of the jaxpr text: PR 44's tree (llama), PR 47's (afmoe), PR 41's (longcat), PR 48's (axk1, bailing)
+#: sha256 of the jaxpr text: PR 44's tree (llama), PR 47's (afmoe), PR 41's (longcat), PR 48's
+#: (axk1, bailing), PR 49's (xing)
 AS_IT_WAS = {
     "bailing": "e637f361c5b57e62d5a520bd12ef7af598e2600889641a3934d5fd73b91a144a",
     "llama": "7c24797e0e81a1d624d7c9f78f6ee45ebc3763a0e78890394292bd291fa24ef3",
     "afmoe": "8d3124ba413fbccd996dc426ee4fc6580a4c00584d3cb0b95bd3ced3d1f9e5f8",
     "axk1": "01c33dbad4020604d77b6efa68cc8155de7b96b715e637624d7ffc7304bd98ae",
     "longcat": "2b508f8fe8fefb380ba03563ea32191691167b2510d5a4ab0ad0cf28174be0ae",
+    "xing": "b8bd37f17cd4b35469c294a2634374ac73848188fa41d1f18f30ba03ac71a230",
 }
 CONFIGS = {"llama": llama.LlamaConfig.tiny, "afmoe": afmoe.AfmoeConfig, "axk1": axk1.Axk1Config,
-           "longcat": longcat.LongcatConfig, "bailing": bailing.BailingConfig}
+           "longcat": longcat.LongcatConfig, "bailing": bailing.BailingConfig,
+           "xing": xing.XingConfig}
 
 
 def jaxpr_of(cfg):
@@ -99,6 +107,8 @@ def test_the_program_traces_to_the_text_it_had(family):
     # the walk's kernel of the arena's form: K and V by head, or one latent array
     assert ("head_walk" in text) == (family in ("llama", "afmoe"))
     assert ("latent_walk" in text) == (family not in ("llama", "afmoe"))
+    # the hyper-connected stream's maps, in the one family whose residual is streams
+    assert ("mhc_open" in text) == ("mhc_close" in text) == (family == "xing")
 
 
 def test_the_new_familys_program_holds_what_the_others_lack():
@@ -174,7 +184,36 @@ def test_the_group_limited_choice_neither_sorts_nor_scatters(family):
     assert final and all(eqn.params["k"] == cfg.top_k for eqn in final)
 
 
-@pytest.mark.parametrize("config", [afmoe.AfmoeConfig, longcat.LongcatConfig, mellum.MellumConfig])
+def test_the_hyper_connected_familys_program_holds_the_maps_round_every_sublayer():
+    """ISSUE 49's family calls A.X-K1's sublayers and brackets each with the
+    two maps: one trace holds each map ONCE as a jitted function that every
+    sublayer calls (``name=mhc_open`` / ``name=mhc_close``: 2 x layers calls
+    each), the stream float32 ``[T, n x d]`` between them, the latent walk
+    and both forms of the grouped products as A.X-K1's program does, the
+    router's selection bias among the operands, and one more row of counters
+    behind the expert layers'.  At a width the kernels fit, both forms of each
+    map are handed to the lowering."""
+    cfg = xing.XingConfig()
+    text = text_of(cfg)
+    assert all(w in text for w in ("latent_walk", "expert_mlp", "ragged_dot", "platform_index"))
+    assert not any(w in text for w in ("kda_step", "ssd_step", "head_walk", "moe_group_select"))
+    calls = 2 * cfg.n_layers
+    assert text.count("name=mhc_open") == text.count("name=mhc_close") == calls
+    stream = f"f32[{TOKENS},{cfg.hc_mult * cfg.d_model}]"
+    assert text.count(stream) >= 2 * calls and f"f32[{TOKENS},{hyper.LANES}]" in text
+    assert f"f32[{cfg.n_experts}]" in text  # the selection bias
+    out = TOKENS + (cfg.n_expert_layers + 1) * cfg.experts_held
+    assert f"i32[{out}]" in text
+    assert "tpu_custom_call" not in text and "pallas_call" in text  # the walk's and the products'
+    assert not hyper.fits(cfg.hc_mult, cfg.d_model)  # 64 wide: the maps are jax.numpy's alone
+    wide = str(jax.make_jaxpr(lambda x, p: hyper.mhc_open(x, p, cfg.hyper))(
+        jax.ShapeDtypeStruct((TOKENS, 4 * 128), jnp.float32),
+        jax.eval_shape(lambda: hyper.init_params(jax.random.PRNGKey(0), cfg.hyper, 128, jnp.bfloat16))))
+    assert "platform_index" in wide and hyper.OPEN_KERNEL in wide and "pallas_call" in wide
+
+
+@pytest.mark.parametrize("config", [afmoe.AfmoeConfig, longcat.LongcatConfig, mellum.MellumConfig,
+                                    xing.XingConfig])
 def test_a_router_of_one_group_holds_no_such_scope(config):
     cfg = config()
     assert cfg.n_group == 1
