@@ -317,6 +317,29 @@ def tile_trips(newest: Any, live: Any, block_tokens: int, first: Any = None) -> 
     return xp.where(live, (last if first is None else last - first) + 1, 0)
 
 
+def tile_runs(rows: Any, first: Any, last: Any, live: Any) -> Any:
+    """The RUNS of the by-head kernel's walk (``models/head_walk.py``; numpy
+    or jax arrays, one entry a tile of a step's, in the walk's order): within
+    a group of ``ATTN_GROUP_TILES`` tiles, the consecutive live tiles that
+    sit on the same table row (``rows``).  A run's tiles share each block's
+    copy: its blocks go from the least of its tiles' ``first`` blocks
+    (:func:`first_block`) to the greatest of their ``last`` (the block of a
+    tile's newest slot).  Returns int ``[tiles, 3]``: at the tile that heads
+    a run the run's tiles, its blocks and the first of them, zeros at every
+    other tile — the kernel's prefetched scalars and the host's count of its
+    copies alike."""
+    xp = np if isinstance(rows, np.ndarray) else jnp
+    at = xp.arange(1, rows.shape[0])
+    joins = live[1:] & live[:-1] & (rows[1:] == rows[:-1]) & (at % ATTN_GROUP_TILES != 0)
+    head = live & ~xp.concatenate([live[:1] & False, joins])
+    run = xp.cumsum(head)
+    # [tile, tile]: the tiles of each tile's own run
+    mine = (run[:, None] == run[None, :]) & live[:, None] & live[None, :]
+    lo = xp.where(mine, first[None, :], 2 ** 30).min(-1)
+    hi = xp.where(mine, last[None, :], -1).max(-1)
+    return xp.where(head[:, None], xp.stack([mine.sum(-1), hi - lo + 1, lo], axis=-1), 0)
+
+
 def mesh_devices(arena: Any) -> int:
     """Devices of the mesh ``arena`` is laid out over, for
     :func:`walk_kernel`: of a traced operand (its type carries the mesh of
@@ -473,6 +496,11 @@ def paged_attention(
     is walked by the by-head kernel from the tile's OWN first block too, its
     pages found round the ring where their copies are started),
     where the ``jax.numpy`` walk drags every tile of a group to the longest.
+    Under the by-head kernel the consecutive tiles of a group on ONE table
+    row, a RUN (:func:`tile_runs`: a prefill chunk's tiles lie side by side in
+    the walk's order), share each block's copy and its taking apart, and a
+    tile that feeds few slots (a decode row) computes their rows alone: the
+    kernel is handed -1 for the position of a slot its tile does not feed.
     Everything round it is shared: the tiles, their order, the groups, the
     loop over the groups that hold a live tile, the gather of the tiles'
     queries, the scatter back to buffer slots, the block as the counted unit.
@@ -535,6 +563,16 @@ def paged_attention(
     pslot = positions[slots]  # [tiles, slots]
     pt = jnp.repeat(pslot, rep, axis=1)[:, :, None]  # [tiles, slots x rep, 1]
 
+    if kernel is not None and v_pages is not None:
+        # what the by-head kernel is told of every tile: its own first block
+        # and trips, the runs of tiles that share a copy, and which of its
+        # slots it feeds (the others' positions are -1)
+        firsts = first_block(oldest, bt, window)
+        trips = tile_trips(newest, live, bt, None if window is None else firsts)
+        runs = tile_runs(trow, firsts, newest // bt, live)
+        behind = jnp.arange(w, dtype=itype)[None, :] <= (last[slot0] - slot0)[:, None]
+        pfed = jnp.where(live[:, None] & behind, pslot, -1)
+
     def walk_jnp(lo, out):
         """One group's walk as ``jax.numpy``, every tile to the group's
         longest, its outputs written behind ``out``'s slot ``lo * w``."""
@@ -595,16 +633,15 @@ def paged_attention(
     def walk_heads(lo, out):
         """The same group through ``head_walk``'s kernel: every tile to its
         OWN end (under a window: from its own first block too, round its
-        ring), its queries read from the step's whole array in place; the
-        group's outputs come back a K/V head's rows together and are laid
-        out by slot as ``walk_jnp`` lays its own."""
-        tab_c, new_c, live_c, pslot_c = (
-            jax.lax.dynamic_slice_in_dim(x, lo, g) for x in (tab, newest, live, pslot))
-        ring = {} if window is None else dict(window=window, first_blocks=first_block(
-            jax.lax.dynamic_slice_in_dim(oldest, lo, g), bt, window))
+        ring), the tiles of a RUN sharing each block's copy, its queries read
+        from the step's whole array in place; the group's outputs come back a
+        K/V head's rows together and are laid out by slot as ``walk_jnp`` lays
+        its own."""
+        tab_c, pslot_c, trips_c, runs_c, firsts_c = (
+            jax.lax.dynamic_slice_in_dim(x, lo, g) for x in (tab, pfed, trips, runs, firsts))
+        ring = {} if window is None else dict(window=window, first_blocks=firsts_c)
         done = kernel.walk_group(
-            qt, pslot_c, k_pages, v_pages, layer, tab_c,
-            tile_trips(new_c, live_c, bt, ring.get("first_blocks")),
+            qt, pslot_c, k_pages, v_pages, layer, tab_c, trips_c, runs_c,
             lo, block_pages=bp, scale=scale, **ring).reshape(g, kvh, w, rep, vd)
         return jax.lax.dynamic_update_slice_in_dim(
             out, done.transpose(0, 2, 1, 3, 4).reshape(g * w, h, vd), lo * w, axis=0)
@@ -629,8 +666,8 @@ def paged_attention(
 
 
 def count_walk(spans: Any, positions: Any, tile_slots: int, block_tokens: tuple[int, ...],
-               window: Optional[int],
-               own_ends: tuple[bool, ...]) -> tuple[int, int, tuple[int, int], int]:
+               window: Optional[int], own_ends: tuple[bool, ...],
+               shared: tuple[bool, ...] = ()) -> tuple[int, int, tuple[int, int], int]:
     """One step's walk as :func:`paged_attention` makes it, counted on the
     host (plain numpy, no kernel's module) from ``spans`` (int [rows, 2]: each
     fed row's buffer slots, packed one behind the other from slot 0) and the
@@ -642,28 +679,39 @@ def count_walk(spans: Any, positions: Any, tile_slots: int, block_tokens: tuple[
     (:func:`first_block`, :func:`tile_trips`) where the kind's walk is a
     kernel (:func:`walk_kernel`; a family's ``ModelSpec.kernels`` says so a
     kind: :func:`walk_label`), each group of tiles to its longest
-    (:func:`walk_blocks`) where it is ``jax.numpy``'s.  Returns the step's
+    (:func:`walk_blocks`) where it is ``jax.numpy``'s.  ``shared`` (one flag a
+    kind, none by default) says which kinds' kernel copies a block ONCE for a
+    run of tiles (:func:`tile_runs`: the by-head kernel's; the latent kernel
+    and ``jax.numpy`` copy or gather a block a tile-trip).  Returns the step's
     report: the longest walk over whole rows and over rings, in blocks;
     ``(table rows gathered, query slots computed)``, a block each, by one
-    layer of each kind together; and the FED slots among those that needed
-    their block."""
+    layer of each kind together: the copies or gathers the program makes, and
+    ``tile_slots`` times its tile-trips, so ``1 - gathered x tile_slots /
+    computed`` is the share of tile-trips that rode another tile's copy; and
+    the FED slots among those that needed their block."""
     w, g = tile_slots, ATTN_GROUP_TILES
+    per_row = -(-(spans[:, 1] - spans[:, 0]) // w)  # tiles a row
     lo = np.concatenate([np.arange(a, b, w) for a, b in spans])  # a tile's first slot
-    hi = np.minimum(lo + w, np.repeat(spans[:, 1], -(-(spans[:, 1] - spans[:, 0]) // w)))
+    hi = np.minimum(lo + w, np.repeat(spans[:, 1], per_row))
     order = walk_order(positions[hi - 1], np.ones(len(lo), bool))
     oldest, newest = positions[lo][order], positions[hi - 1][order]
-    walked = live = 0  # tile-trips: a table row gathered each, ``w`` query slots computed
+    rows, every = np.repeat(np.arange(len(spans)), per_row)[order], np.ones(len(order), bool)
+    gathered = walked = live = 0  # block copies or gathers; tile-trips, ``w`` query slots each
     fed = positions[:spans[-1, 1]]
     longest = [0, 0]
-    for kind, (bt, win, own) in enumerate(zip(block_tokens, (None, window), own_ends)):
+    kinds = zip(block_tokens, (None, window), own_ends, tuple(shared) + (False, False))
+    for kind, (bt, win, own, runs) in enumerate(kinds):
         # a fed slot needs the blocks from its oldest visible key's to its own
         live += int((fed // bt - first_block(fed, bt, win) + 1).sum())
+        first = first_block(oldest, bt, win)
         if own:
-            trips = tile_trips(newest, np.ones(len(newest), bool), bt,
-                               first_block(oldest, bt, win))
+            trips = tile_trips(newest, every, bt, first)
         else:
             trips = np.repeat([walk_blocks(oldest[a:a + g], newest[a:a + g], bt, win)[1]
                                for a in range(0, len(order), g)], g)
         longest[kind] = int(trips.max())
         walked += int(trips.sum())
-    return longest[0], longest[1], (walked, w * walked), live
+        # a copy a tile-trip, but where a row of several tiles shares its blocks' copies
+        gathered += int(tile_runs(rows, first, newest // bt, every)[:, 1].sum()
+                        if runs and per_row.max() > 1 else trips.sum())
+    return longest[0], longest[1], (gathered, w * walked), live

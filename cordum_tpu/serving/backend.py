@@ -661,14 +661,15 @@ class ServingBackend(StepBackend):
                     self.last_counters, self.last_attrs = self.spec.count_aux(
                         self.last_aux, ti, self.kernels)
             # the walk as the program made it: a kind of page's tiles each to
-            # their own ends where that kind's walk is a kernel
+            # their own ends where that kind's walk is a kernel, and a run of
+            # one row's tiles sharing a block's copy where it is the by-head one
             from ..models import attention
 
+            own_ends = tuple(bool(self.kernels.get(role)) for role in attention.WALK_ROLES)
             walked, self.last_window_blocks, self.last_attn_rows, self.last_attn_live = (
                 attention.count_walk(
                     np.array(spans), positions, self._tile_slots, self._block_tokens,
-                    self.window,
-                    tuple(bool(self.kernels.get(role)) for role in attention.WALK_ROLES)))
+                    self.window, own_ends, tuple(own and self.kv_by_head for own in own_ends)))
             self.last_attn_blocks = (walked, self._attn_blocks_total)
             if self.on_step is not None:
                 self.on_step(entries)

@@ -156,7 +156,9 @@ class ServingStats:
     attn_blocks_total: int = 0
     # table rows the walk's tiles gathered, a block of pages each, summed
     # over steps, by one full and one window layer together: x a block's
-    # bytes x the layers of the kind, what the attention read
+    # bytes x the layers of the kind, what the attention read (the by-head
+    # kernel copies a block once a RUN of one row's tiles: 1 - gathered x
+    # tile slots / computed is the share of tile-trips that rode a copy)
     attn_rows_gathered: int = 0
     # query slots those walks computed, a block each, and the FED slots among
     # them that needed their block: the rest is what the tiles pad (a tile's

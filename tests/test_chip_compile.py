@@ -264,17 +264,17 @@ def test_the_ring_form_of_the_by_head_kernel_compiles_at_the_window_cells_shapes
     g, w = attention.ATTN_GROUP_TILES, attention.attn_tile_slots(rep)
     tiles = attention.attn_tiles(be.max_batch_tokens, be.max_seqs, w)
     assert be.ring_pages % (bt // be.page_size) and bt == attention.ATTN_BLOCK_TOKENS
-    assert head_walk.vmem_bytes(kvh, w * rep, cfg.head_dim, bt, 2) <= head_walk.VMEM_BUDGET_BYTES
+    assert head_walk.vmem_bytes(g, kvh, w * rep, cfg.head_dim, bt, 2) <= head_walk.VMEM_BUDGET_BYTES
 
     def shape(dims, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    compiled = jax.jit(lambda q, pos, k, v, row, tab, trips, first: head_walk.walk_group(
-        q, pos, k, v, row, tab, trips, g, block_pages=bt // be.page_size,
+    compiled = jax.jit(lambda q, pos, k, v, row, tab, trips, runs, first: head_walk.walk_group(
+        q, pos, k, v, row, tab, trips, runs, g, block_pages=bt // be.page_size,
         scale=cfg.head_dim ** -0.5, window=window, first_blocks=first)).lower(
         shape((tiles, kvh, w * rep, cfg.head_dim), k_arena.dtype), shape((g, w)),
         shape(k_arena.shape, k_arena.dtype), shape(k_arena.shape, k_arena.dtype), shape(()),
-        shape((g, be.ring_pages)), shape((g,)), shape((g,))).compile()
+        shape((g, be.ring_pages)), shape((g,)), shape((g, 3)), shape((g,))).compile()
     assert holds_head_kernel(compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6  # no copy of an arena
 

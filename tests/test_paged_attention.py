@@ -326,8 +326,9 @@ def test_no_array_of_the_whole_context_in_the_program():
     bad, loops = oversized(lambda *a: llama.ragged_step(*a, WALK_CFG))
     assert not bad, bad
     # the walk was looked into: a layer's loop over groups, the blocks' inside
-    # it, and (ISSUE 44: both walks are handed to the lowering) the kernel's two
-    assert loops == 4 * WALK_CFG.n_layers
+    # it, and (ISSUE 44: both walks are handed to the lowering) the kernel's
+    # four (ISSUE 50: a run's blocks, and its tiles entering, at a block and leaving)
+    assert loops == 6 * WALK_CFG.n_layers
 
 
 def test_the_walk_over_the_program_sees_a_repeat_and_a_whole_row_gather():

@@ -35,7 +35,15 @@ group, never enter the scope, and keep their text.  ISSUE 49 wrote
 ``models/xing.py`` calls too (the residual adds are the only lines that
 differ): ``axk1``'s text is the string it was, and the new family's own
 program (``xing``: the same sublayers between the maps of a hyper-connected
-stream) joins the table with the text of ISSUE 49's tree."""
+stream) joins the table with the text of ISSUE 49's tree.  ``llama``'s and
+``afmoe``'s changed ON PURPOSE with ISSUE 50: their trace holds the
+``head_walk`` kernel, which is now ONE program a group whose consecutive tiles
+on one table row (a run: ``attention.tile_runs``, three more prefetched
+scalars a tile) share each block's copy and its taking apart, and
+``paged_attention`` computes the tiles' first blocks, trips and runs once a
+call where it holds that kernel; the four latent families' programs hold
+``latent_walk`` and none of that, and their hashes are the strings they were,
+which is the proof that their programs did not move."""
 import hashlib
 import re
 
@@ -50,12 +58,12 @@ from cordum_tpu.serving.modelspec import spec_for
 
 PAGES, PS, SEQS, TOKENS, CONTEXT = 9, 4, 3, 8, 32
 
-#: sha256 of the jaxpr text: PR 44's tree (llama), PR 47's (afmoe), PR 41's (longcat), PR 48's
-#: (axk1, bailing), PR 49's (xing)
+#: sha256 of the jaxpr text: PR 50's tree (llama, afmoe), PR 41's (longcat), PR 48's (axk1,
+#: bailing), PR 49's (xing)
 AS_IT_WAS = {
     "bailing": "e637f361c5b57e62d5a520bd12ef7af598e2600889641a3934d5fd73b91a144a",
-    "llama": "7c24797e0e81a1d624d7c9f78f6ee45ebc3763a0e78890394292bd291fa24ef3",
-    "afmoe": "8d3124ba413fbccd996dc426ee4fc6580a4c00584d3cb0b95bd3ced3d1f9e5f8",
+    "llama": "37e0fe45f0a3c2265e2db836eed39e434ddf830c6e881f7039eb23fbd5bcdb56",
+    "afmoe": "4f26e910d552717d6669182614a214ec2798f844b8255e71abae9edf0f4b7f30",
     "axk1": "01c33dbad4020604d77b6efa68cc8155de7b96b715e637624d7ffc7304bd98ae",
     "longcat": "2b508f8fe8fefb380ba03563ea32191691167b2510d5a4ab0ad0cf28174be0ae",
     "xing": "b8bd37f17cd4b35469c294a2634374ac73848188fa41d1f18f30ba03ac71a230",
