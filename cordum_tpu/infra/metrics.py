@@ -463,6 +463,13 @@ class Metrics:
             "loop published, by whether the next step was already on the "
             "device (behind_step = true | false)",
         )
+        self.serving_loop_idle = Counter(
+            "cordum_serving_loop_idle_seconds_total",
+            "Seconds the serving loop ran no step cycle, since its start-up "
+            "record closed (state = parked: no session live, waiting to be "
+            "woken | poll: sessions or pending work and nothing to feed; 1 "
+            "less their share of the wall is the loop's utilisation)",
+        )
         self.serving_sessions = Gauge(
             "cordum_serving_active_sessions",
             "Sessions currently in the decode set",
@@ -789,6 +796,7 @@ class Metrics:
             self.serving_admitted,
             self.serving_retired,
             self.serving_stream_packets,
+            self.serving_loop_idle,
             self.serving_sessions,
             self.serving_kv_pages_in_use,
             self.serving_state_slots,

@@ -14,10 +14,13 @@ Three probes, all off the hot path:
   and keeps the dump on ``last_slow_tick`` so the telemetry beacon can ship
   a summary.  The trace id names the request the process was most recently
   working for when it stalled;
-* **GC-pause counters** — ``gc.callbacks`` timing each collection into
+* **GC-pause counters** — the process's ONE ``gc.callbacks`` entry
+  (:class:`GcPauses`, ``GC_PAUSES``) times each collection into
   ``cordum_gc_pauses_total{generation}`` and ``cordum_gc_pause_seconds``
   (a generation-2 pause IS event-loop lag; correlating the two histograms
-  separates GC stalls from blocking code).
+  separates GC stalls from blocking code) and keeps the last pauses with
+  their wall-clock bounds, which the serving loop lays on its step cycles
+  as ``runtime.gc`` spans (docs/OBSERVABILITY.md §Serving spans and metrics).
 
 Everything flows through the process's ``Metrics`` registry, so the
 exporter ships it fleet-wide for free.
@@ -28,6 +31,7 @@ import asyncio
 import gc
 import time
 import traceback
+from collections import deque
 from typing import Any, Optional
 
 from ..infra import logging as logx
@@ -38,6 +42,62 @@ DEFAULT_TICK_S = 0.25
 DEFAULT_SLOW_TICK_S = 0.5
 MAX_DUMP_TASKS = 12
 MAX_DUMP_FRAMES = 6
+GC_PAUSES_KEPT = 256
+
+
+class GcPauses:
+    """The process's one ``gc.callbacks`` entry, there while somebody holds
+    it.  Every collection is stamped with ``time.time_ns()`` (the step
+    spans' clock) at both ends: the last ``GC_PAUSES_KEPT`` stay here as
+    ``(ordinal, start_ns, end_ns, generation)`` for whoever lays them on a
+    timeline (``since``), and each holder that brought a ``Metrics`` has its
+    two GC series fed from the same call."""
+
+    def __init__(self) -> None:
+        self.pauses: deque[tuple[int, int, int, int]] = deque(maxlen=GC_PAUSES_KEPT)
+        self.count = 0  # collections seen while held: the last pause's ordinal
+        self._holders: dict[int, Optional[Metrics]] = {}
+        self._t0 = 0
+
+    def hold(self, holder: object, metrics: Optional[Metrics] = None) -> None:
+        if not self._holders:
+            gc.callbacks.append(self._on_gc)
+        self._holders[id(holder)] = metrics
+
+    def release(self, holder: object) -> None:
+        if id(holder) in self._holders:
+            del self._holders[id(holder)]
+            if not self._holders:
+                gc.callbacks.remove(self._on_gc)
+
+    def since(self, ordinal: int) -> list[tuple[int, int, int, int]]:
+        """The pauses past ``ordinal`` (as many of them as are still kept),
+        oldest first; the last one's ordinal is what to ask with next."""
+        # one call copies them: a collection, on this thread or another,
+        # cannot land inside it
+        kept = list(self.pauses)
+        n = 0
+        while n < len(kept) and kept[-1 - n][0] > ordinal:
+            n += 1
+        return kept[len(kept) - n:]
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # collections do not nest, and one runs start to stop on one thread
+        if phase == "start":
+            self._t0 = time.time_ns()
+        elif self._t0:
+            t0, self._t0 = self._t0, 0
+            t1 = max(t0, time.time_ns())
+            gen = int(info.get("generation", 0))
+            self.count += 1
+            self.pauses.append((self.count, t0, t1, gen))
+            for metrics in self._holders.values():
+                if metrics is not None:
+                    metrics.gc_pauses.inc(generation=str(gen))
+                    metrics.gc_pause_seconds.observe((t1 - t0) / 1e9)
+
+
+GC_PAUSES = GcPauses()
 
 
 class RuntimeProfiler:
@@ -55,23 +115,14 @@ class RuntimeProfiler:
         self.slow_tick_s = slow_tick_s
         self.last_slow_tick: Optional[dict[str, Any]] = None
         self._task: Optional[asyncio.Task] = None
-        self._gc_start: dict[int, float] = {}
-        self._gc_cb_installed = False
 
     # ------------------------------------------------------------------
     async def start(self) -> None:
         self._task = asyncio.ensure_future(self._loop())
-        if not self._gc_cb_installed:
-            gc.callbacks.append(self._on_gc)
-            self._gc_cb_installed = True
+        GC_PAUSES.hold(self, self.metrics)
 
     async def stop(self) -> None:
-        if self._gc_cb_installed:
-            try:
-                gc.callbacks.remove(self._on_gc)
-            except ValueError:
-                logx.warn("gc callback already removed", service=self.service)
-            self._gc_cb_installed = False
+        GC_PAUSES.release(self)
         if self._task is not None:
             task, self._task = self._task, None
             task.cancel()
@@ -125,18 +176,6 @@ class RuntimeProfiler:
         )
         for t in tasks:
             logx.warn("slow-tick task stack", task=t["task"], stack=t["stack"])
-
-    # ------------------------------------------------------------------
-    def _on_gc(self, phase: str, info: dict) -> None:
-        gen = int(info.get("generation", 0))
-        if phase == "start":
-            self._gc_start[gen] = time.monotonic()
-        elif phase == "stop":
-            t0 = self._gc_start.pop(gen, None)
-            if t0 is not None:
-                dur = time.monotonic() - t0
-                self.metrics.gc_pauses.inc(generation=str(gen))
-                self.metrics.gc_pause_seconds.observe(dur)
 
     # ------------------------------------------------------------------
     def health(self) -> dict[str, Any]:

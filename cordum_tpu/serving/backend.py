@@ -65,21 +65,26 @@ DEFAULT_MAX_SEQS = 16
 STEP_PHASES = ("assemble", "pack", "dispatch", "wait", "unpack", "emit")
 
 
+def annotation(name: str, **attrs: Any) -> contextlib.AbstractContextManager:
+    """The host annotation ``name`` on the profiler's host plane, for the
+    ``with`` it is entered in: the event a device trace shows under the name
+    of the span the engine builds from its own stamps.  Inert with no
+    profiler session, and a process that never imported jax has no profiler
+    to annotate for (this module stays jax-free for the fakes)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
 @contextlib.contextmanager
 def step_phase(name: str, step: int, marks: list[int]) -> Iterator[None]:
     """One phase of step cycle ``step``: appends the phase's END boundary to
     ``marks`` (``time.time_ns()``, the clock of ``now_us()`` and of the
     profiler's host plane) and meanwhile holds the host annotation
     ``cordum.step.<name>`` open, so the span the engine builds from the
-    stamps and the event in a device trace carry one name.  The annotation
-    is inert with no profiler session, and a process that never imported
-    jax has no profiler to annotate for (this module stays jax-free for
-    the fakes)."""
-    jax = sys.modules.get("jax")
-    with (
-        jax.profiler.TraceAnnotation(f"cordum.step.{name}", step=step)
-        if jax is not None else contextlib.nullcontext()
-    ):
+    stamps and the event in a device trace carry one name."""
+    with annotation(f"cordum.step.{name}", step=step):
         yield
     marks.append(time.time_ns())
 
@@ -165,7 +170,7 @@ class StepBackend:
     # the latest step's report, written by ``step`` and read by the engine
     # after the call
     REPORT = ("last_step_compiled", "last_compile_ms", "last_cache_hit", "last_phases",
-              "last_attn_blocks", "last_window_blocks", "last_attn_rows", "last_attn_live",
+              "last_ready_ns", "last_attn_blocks", "last_window_blocks", "last_attn_rows", "last_attn_live",
               "last_counters", "last_attrs")
     # did the compiler run for this backend since the step before returned
     # (in this step's dispatch, or in a page program the cycle called first:
@@ -177,6 +182,10 @@ class StepBackend:
     # its boundaries, ns: (entry, arrays packed, program dispatched, result
     # on the host, return) — the engine splits its step cycle by them
     last_phases: tuple[int, ...] = ()
+    # inside its ``wait`` (between the third and fourth boundary), ns: the
+    # result is ready on the device, its way back to the host begins; None
+    # from a backend that does not say
+    last_ready_ns: Optional[int] = None
     # the attention walk: (blocks read, blocks a page table holds); the
     # window layers' blocks read; (tiles' table rows gathered, query slots
     # computed), blocks of them, by one full and one window layer together;
@@ -204,9 +213,11 @@ class StepBackend:
     def stamp_whole_call(self, t0: int) -> None:
         """``last_phases`` of a step with no pack, dispatch or unpack of its
         own, entered at ``t0`` (``time.time_ns()``) and returning now: five
-        ordered marks that read as one ``wait``."""
+        ordered marks that read as one ``wait``, with no word on when its
+        result was ready."""
         t1 = max(t0, time.time_ns())
         self.last_phases = (t0, t0, t0, t1, t1)
+        self.last_ready_ns = None
 
     def step(self, entries: list[StepEntry]) -> list[Any]:
         """One mixed prefill+decode call.  One value per entry, aligned:
@@ -632,7 +643,13 @@ class ServingBackend(StepBackend):
             if self.on_dispatched is not None:
                 self.on_dispatched()
             with step_phase("wait", n_step, marks):
-                out = np.asarray(nxt)
+                # two waits and a stamp between them: the program has ended
+                # (hand-over to here: launch and program), then the result's
+                # way back to the host (``wait.fetch``)
+                nxt.block_until_ready()
+                ready = time.time_ns()
+                with annotation("cordum.wait.fetch", step=n_step):
+                    out = np.asarray(nxt)
             if events.spans:  # the call traced or compiled: never in a warm window
                 self._note_compiles("ragged", events)
                 startup.program("ragged", marks[1], events, ran_until_ns=marks[3])
@@ -675,6 +692,7 @@ class ServingBackend(StepBackend):
                 self.on_step(entries)
         self._steps_done = n_step + 1
         self.last_phases = tuple(marks)
+        self.last_ready_ns = ready
         return res
 
     # ------------------------------------------------------------------

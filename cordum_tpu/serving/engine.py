@@ -38,11 +38,12 @@ from typing import Any, Awaitable, Callable, Optional
 from ..infra import logging as logx
 from ..infra.metrics import Metrics
 from ..obs import startup
+from ..obs.profiler import GC_PAUSES
 from ..obs.tracer import Tracer
 from ..protocol.types import OP_SERVING_PREFILL, SPAN_ERROR, Span
 from ..utils.eager import eager, eager_gather
 from ..utils.ids import fast_id
-from .backend import STEP_PHASES, StepBackend, StepEntry, step_phase
+from .backend import STEP_PHASES, StepBackend, StepEntry, annotation, step_phase
 from .modelspec import require_page_records, require_positional
 from .pager import CacheExhausted, PageAllocator, SlotAllocator
 from .prefixcache import PrefixCache, PrefixNode
@@ -74,6 +75,10 @@ SPEC_FLEET_ALPHA = 0.2
 STEP_SAMPLE_PERIOD_NS = 250_000_000
 STEP_STALL_FACTOR = 3.0
 STEP_MEDIAN_WINDOW = 64
+# a collector pause becomes a ``runtime.gc`` span (and keeps the cycle it fell
+# in) when it is a generation-2 collection or lasted this long; shorter ones
+# only add to ``ServingStats.gc_pause_seconds``
+GC_SPAN_MIN_NS = 1_000_000
 
 
 class SessionCancelled(Exception):
@@ -193,6 +198,17 @@ class ServingStats:
     # and those of them published with the NEXT step already on the device
     stream_packets: int = 0
     stream_packets_behind_step: int = 0
+    # the loop's life outside its step cycles, since the start-up record
+    # closed: seconds it stood parked (no session live: from the start of the
+    # cycle that found nothing, through the last step's tokens told, to its
+    # wake) and seconds it polled (sessions or pending work and nothing to
+    # feed).  With the cycles' six phases they cover the loop's life
+    parked_seconds: float = 0.0
+    polled_seconds: float = 0.0
+    # the collector's pauses the process saw since then (``obs/profiler.py``
+    # ``GC_PAUSES``), every generation and length
+    gc_pauses: int = 0
+    gc_pause_seconds: float = 0.0
     # per-step wall time (seconds), capped ring for inter-token p50/p99
     step_seconds: deque = field(default_factory=lambda: deque(maxlen=4096))
     # submit → first sampled token (seconds), capped ring for TTFT p50
@@ -260,6 +276,9 @@ class _Session:
     # open until the first token; None for migrated-in, resumed and restored
     # sessions (their first token belongs to a previous worker's clock)
     ttft: Optional[_TtftClock] = None
+    # ``_first_token``'s stamp (monotonic) until the session's first stream
+    # packet has been told (``serving.first_packet``); 0.0 otherwise
+    first_token_at: float = 0.0
 
     @property
     def prefill_seq(self) -> list[int]:
@@ -451,6 +470,10 @@ class ServingEngine:
         self._cycle_ns: deque[int] = deque(maxlen=STEP_MEDIAN_WINDOW)
         self._kept_cycle_ns = 0  # start of the last cycle kept as a trace
         self._startup_told = False  # the start-up record went out (or was not ours)
+        self._poll: Optional[tuple[str, int]] = None  # the open poll: (reason, since ns)
+        # the collector's pauses (``GC_PAUSES``) are read from the moment the
+        # start-up record is told, past this ordinal at the last take
+        self._gc_seen = 0
 
     # ------------------------------------------------------------------
     def parts(self, payload: Any) -> Optional[GenRequest]:
@@ -895,6 +918,8 @@ class ServingEngine:
         clk, sess.ttft = sess.ttft, None
         if clk is None:
             return
+        if self._startup_told and sess.on_tokens is not None:
+            sess.first_token_at = now
         if self.metrics is not None:
             self.metrics.serving_prefill.observe(now - clk.admitted_at)
         self._ttft_span("serving.prefill", sess, clk.admitted_at, now, {
@@ -912,20 +937,27 @@ class ServingEngine:
         the histogram sees every cycle, the flight recorder the kept ones."""
         c0, handed, returned, c1 = marks
         bounds = (c0, *self.backend.last_phases, c1)
+        # the two stamps inside a phase: the result ready on the device
+        # (inside ``wait``; None from a backend that does not say) and the
+        # loop awake again (inside ``emit``)
+        ready = self.backend.last_ready_ns
         if any(a > b for a, b in zip(bounds, bounds[1:])):
             # the stamps are wall-clock and the wall may step backwards:
             # the whole call then reads as ``wait``, from the loop's own stamps
             bounds = (c0, handed, handed, handed, returned, returned, c1)
+            ready = None
         if self.metrics is not None:
             for name, a, b in zip(STEP_PHASES, bounds, bounds[1:]):
                 self.metrics.serving_step_phase.observe((b - a) / 1e9, phase=name)
         dur = c1 - c0
         recent = self._cycle_ns
+        pauses = self._gc_taken()
         keep = (
             c0 - self._kept_cycle_ns >= STEP_SAMPLE_PERIOD_NS
             or "compile_ms" in attrs  # the compiler ran: never in steady state
             or (len(recent) == recent.maxlen
                 and dur > STEP_STALL_FACTOR * statistics.median(recent))
+            or bool(pauses)  # the collector held the process: kept with its cause
         )
         recent.append(dur)
         if not keep:
@@ -936,18 +968,135 @@ class ServingEngine:
             return
         trace_id = f"step-{self.worker_id}-{n_step}"
         root_id = fast_id()
+        ids = {name: fast_id() for name in STEP_PHASES}
         us = [b // 1000 for b in bounds]
         # children first: the collector decides a trace's retention when
         # its root lands
         for name, a, b in zip(STEP_PHASES, us, us[1:]):
             self._spans.append(tr.record(
-                f"step.{name}", trace_id=trace_id, parent_span_id=root_id,
-                start_us=a, end_us=b,
+                f"step.{name}", trace_id=trace_id, span_id=ids[name],
+                parent_span_id=root_id, start_us=a, end_us=b,
             ))
+        if self._startup_told:
+            # what lies INSIDE a phase, under names that are no ``step*``
+            # (a reader counts a cycle's seven): the result's way back to the
+            # host inside ``wait``, the loop's wake-up inside ``emit``
+            if ready is not None and bounds[3] <= ready <= bounds[4]:
+                self._spans.append(tr.record(
+                    "wait.fetch", trace_id=trace_id, parent_span_id=ids["wait"],
+                    start_us=ready // 1000, end_us=us[4],
+                ))
+            if bounds[5] <= returned <= bounds[6]:
+                self._spans.append(tr.record(
+                    "emit.wake", trace_id=trace_id, parent_span_id=ids["emit"],
+                    start_us=us[5], end_us=returned // 1000,
+                ))
+            if pauses:
+                self._gc_spans(tr, pauses, trace_id, root_id)
+                attrs["gc_ms"] = f"{sum(b - a for _, a, b, _ in pauses) / 1e6:.3f}"
+                attrs["gc_gen"] = str(max(gen for _, _, _, gen in pauses))
         self._spans.append(tr.record(
             "step", trace_id=trace_id, span_id=root_id,
             start_us=us[0], end_us=us[-1], attrs=attrs,
         ))
+
+    def _gc_taken(self) -> list[tuple[int, int, int, int]]:
+        """The collector's pauses since the last take, counted into the
+        stats; returns those worth a span: a generation-2 collection, or one
+        of ``GC_SPAN_MIN_NS`` and more.  Nothing before the start-up record
+        was told."""
+        if not self._startup_told or GC_PAUSES.count == self._gc_seen:
+            return []
+        new = GC_PAUSES.since(self._gc_seen)
+        if not new:
+            return []
+        self._gc_seen = new[-1][0]
+        self.stats.gc_pauses += len(new)
+        self.stats.gc_pause_seconds += sum(b - a for _, a, b, _ in new) / 1e9
+        return [p for p in new if p[3] >= 2 or p[2] - p[1] >= GC_SPAN_MIN_NS]
+
+    def _gc_spans(
+        self, tr: Tracer, pauses: list[tuple[int, int, int, int]], trace_id: str,
+        parent_span_id: str,
+    ) -> None:
+        """``runtime.gc`` spans of ``pauses`` on the trace of the interval
+        that took them (a kept cycle's, a park's or a poll's)."""
+        for _, a, b, gen in pauses:
+            self._spans.append(tr.record(
+                "runtime.gc", trace_id=trace_id, parent_span_id=parent_span_id,
+                start_us=a // 1000, end_us=b // 1000, attrs={"generation": str(gen)},
+            ))
+
+    def _idle_closed(self, state: str, since_ns: int, end_ns: int) -> None:
+        """The loop ran no step cycle from ``since_ns`` to ``end_ns``:
+        ``state`` is ``parked`` (no session live: from the start of the cycle
+        that found nothing, through the last step's tokens told and the
+        flush, to its wake) or a poll's reason, ``pages`` (pending work that
+        waits for pages) or ``budget`` (live sessions and no row to feed).
+        Seconds and a counter always, a trace ``loop-<worker_id>-<steps
+        done>`` of one span while someone listens; counted from the moment
+        the start-up record was told."""
+        if not self._startup_told:
+            return
+        secs = max(0, end_ns - since_ns) / 1e9
+        parked = state == "parked"
+        if parked:
+            self.stats.parked_seconds += secs
+        else:
+            self.stats.polled_seconds += secs
+        if self.metrics is not None:
+            self.metrics.serving_loop_idle.inc(secs, state="parked" if parked else "poll")
+        pauses = self._gc_taken()
+        tr = self.tracer
+        if tr is None or not tr.listening():
+            return
+        trace_id = f"loop-{self.worker_id}-{self.stats.steps}"
+        span_id = fast_id()
+        self._gc_spans(tr, pauses, trace_id, span_id)
+        self._spans.append(tr.record(
+            "serving.parked" if parked else "serving.poll", trace_id=trace_id,
+            span_id=span_id, start_us=since_ns // 1000, end_us=end_ns // 1000,
+            attrs={} if parked else {"reason": state},
+        ))
+
+    async def _unfed(self, since_ns: int) -> int:
+        """The cycle that began at ``since_ns`` found nothing to feed: tell
+        what the last step left untold (there is no next step to hide it
+        behind), then park until woken (nothing live, nothing pending) or
+        poll (``pages``: pending work and no session live, pages freeing;
+        ``budget``: every live row parked past the budget or frozen).  The
+        interval is the park's or the poll's from ``since_ns`` on, held under
+        the host annotation ``cordum.serving.parked`` / ``.poll``; a poll
+        stays open over the cycles that feed nothing and ends where the next
+        cycle that feeds begins (or where a park or another reason does).
+        Returns the stamp the next cycle starts at: a park's wake, 0 after a
+        poll (the cycle stamps its own start, the poll's end if it feeds)."""
+        state = "budget" if self._active else "pages" if self._pending else "parked"
+        if self._poll is not None and self._poll[0] != state:
+            self._idle_closed(*self._poll, since_ns)
+            self._poll = None
+        parked = state == "parked"
+        with (annotation("cordum.serving.parked") if parked
+              else annotation("cordum.serving.poll", reason=state)):
+            await self._publish_unsent(behind_step=False)
+            if not self._active:
+                self._gauge()
+            if not parked:
+                if self._poll is None:
+                    self._poll = (state, since_ns)
+                await asyncio.sleep(0.001)  # pages freeing, rows thawing: poll soon
+                return 0
+            if self._closed:
+                return 0
+            await self._flush_spans()
+            self._wake.clear()
+            # re-check after clear: a submit may have landed between
+            # the emptiness check and the clear
+            if not (self._pending or self._active):
+                await self._wake.wait()
+        woke_ns = time.time_ns()
+        self._idle_closed("parked", since_ns, woke_ns)
+        return woke_ns
 
     def _tell_startup(self, end_ns: int) -> None:
         """The first cycle that returned a sampled token has closed at
@@ -956,6 +1105,10 @@ class ServingEngine:
         phases as trace ``startup-<worker_id>``, children first and the root
         last, to go out with the next flush, behind a step."""
         self._startup_told = True
+        # from here on the collector's pauses are read (the process's one
+        # ``gc.callbacks`` entry, shared with a ``RuntimeProfiler`` beside us)
+        GC_PAUSES.hold(self)
+        self._gc_seen = GC_PAUSES.count
         record = startup.close(end_ns, worker_id=self.worker_id)
         if not record:
             return
@@ -989,16 +1142,25 @@ class ServingEngine:
             await self.tracer.emit(sp)
 
     async def _emit(
-        self, sess: _Session, new_tokens: list[int], n_generated: int, done: bool
+        self, sess: _Session, new_tokens: list[int], n_generated: int, done: bool,
+        behind_step: bool = False,
     ) -> None:
         """One stream packet to the session's sink: ``new_tokens`` end at
-        ``n_generated`` of its output."""
+        ``n_generated`` of its output.  The session's first closes
+        ``serving.first_packet``: the first token's way out, from the stamp
+        its step's bookkeeping took (``_first_token``) to the sink's return."""
         if sess.on_tokens is None:
             return
         try:
             await sess.on_tokens(new_tokens, n_generated, done)
         except Exception as e:  # noqa: BLE001 - streaming is best-effort
             logx.warn("token stream sink failed", job_id=sess.job_id, err=str(e))
+        if sess.first_token_at:
+            sampled_at, sess.first_token_at = sess.first_token_at, 0.0
+            self._ttft_span("serving.first_packet", sess, sampled_at, time.monotonic(), {
+                "behind_step": str(behind_step).lower(),
+                "tokens": str(len(new_tokens)),
+            })
 
     async def _publish_unsent(self, behind_step: bool) -> None:
         """Tell what the last step's bookkeeping (``_scatter``) left untold:
@@ -1013,7 +1175,7 @@ class ServingEngine:
                 packets, self._unsent = self._unsent, []
                 # each sink runs to its first real suspension in row order;
                 # a bus that delivers at publish never suspends one
-                await eager_gather([self._emit(*pkt) for pkt in packets])
+                await eager_gather([self._emit(*pkt, behind_step) for pkt in packets])
                 for sess, _, _, _ in packets:
                     sess.unsent -= 1
                 n = len(packets)
@@ -1311,9 +1473,13 @@ class ServingEngine:
         loop = asyncio.get_running_loop()
         self._tell_fed = lambda: loop.call_soon_threadsafe(self._fed.set)
         self.backend.on_dispatched = self._tell_fed
+        edge_ns = 0  # where the interval before ended: a cycle's END stamp, a park's wake
         while not self._closed:
             n_step = self.stats.steps
-            marks = [time.time_ns()]
+            # a cycle starts where the one before it ended (or the park before
+            # it woke): closing a cycle's spans is the next one's ``assemble``,
+            # and cycles, parks and polls cover the loop's life
+            marks = [edge_ns or time.time_ns()]
             entries: list[StepEntry] = []
             rows: list[tuple[_Session, int, bool, list[int]]] = []
             with step_phase("assemble", n_step, marks):
@@ -1331,32 +1497,22 @@ class ServingEngine:
                     # the publish and the flush below run while the device does
                     self._fed.clear()
                     done, step_call = eager(self._run_step(entries))
-            in_backend = bool(entries) and not done
+            if not entries:
+                # nothing to feed: the last step's tokens and finishers now,
+                # then a park or a poll
+                edge_ns = await self._unfed(marks[0])
+                continue
+            in_backend = not done
             if in_backend:
                 # the executor thread packs and dispatches in Python: what
                 # follows would hold the interpreter against it, and the
                 # program would start late
                 await self._fed.wait()
-            # the last step's tokens and finishers: behind this step if there
-            # is one, else now, before the loop parks or polls
+            if self._poll is not None:  # it ends where this cycle began
+                self._idle_closed(*self._poll, marks[0])
+                self._poll = None
+            # the last step's tokens and finishers, behind this step
             await self._publish_unsent(behind_step=in_backend)
-            if not self._active:
-                self._gauge()
-                if not self._pending:
-                    if self._closed:
-                        return
-                    await self._flush_spans()
-                    self._wake.clear()
-                    # re-check after clear: a submit may have landed between
-                    # the emptiness check and the clear
-                    if not (self._pending or self._active):
-                        await self._wake.wait()
-                else:
-                    await asyncio.sleep(0.001)  # pages freeing: poll soon
-                continue
-            if not entries:  # defensive: all rows parked past the budget
-                await asyncio.sleep(0.001)
-                continue
             # yield once: the deliveries the publish queued, and intake,
             # cancel and heartbeat tasks, run even under a saturated decode
             # set (and when the step came back at once)
@@ -1381,15 +1537,19 @@ class ServingEngine:
                 for sess, _, _, _ in rows:
                     self.stats.failed += 1
                     self._retire(sess, error=step_err)
+                edge_ns = 0
                 continue
             marks.append(time.time_ns())
             dt = time.monotonic() - t0
             with step_phase("emit", n_step, marks):
                 attrs = self._scatter(rows, results, dt)
                 self._gauge()
-            self._cycle_closed(n_step, marks, attrs)
-            if not self._startup_told and any(r is not None for r in results):
-                self._tell_startup(marks[-1])
+            edge_ns = marks[-1]
+            # by its stamps what follows is already the next cycle's ``assemble``
+            with annotation("cordum.step.assemble", step=n_step + 1):
+                self._cycle_closed(n_step, marks, attrs)
+                if not self._startup_told and any(r is not None for r in results):
+                    self._tell_startup(marks[-1])
 
     async def _run_step(
         self, entries: list[StepEntry]
@@ -1991,4 +2151,5 @@ class ServingEngine:
                 logx.warn("decode loop crashed during shutdown", err=str(e))
             self._loop_task = None
         self.backend.on_dispatched = None  # the loop's; it spoke to this event loop
+        GC_PAUSES.release(self)
         await self._flush_spans()

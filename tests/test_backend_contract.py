@@ -53,6 +53,8 @@ def test_every_backend_keeps_the_contract(kind):
             value = getattr(be, name)
             if name in ("on_step", "on_dispatched"):
                 assert value is None or callable(value)
+            elif name == "last_ready_ns":
+                assert value is None or type(value) is int
             else:
                 assert isinstance(value, typing.get_origin(hint) or hint), name
 
@@ -62,6 +64,7 @@ def test_every_backend_keeps_the_contract(kind):
     # a ring and its pool come together, and only without whole rows
     assert bool(be.ring_pages) == bool(be.num_window_pages) == (not be.kv_whole_row)
     assert be.last_phases == () and be.last_attn_blocks == (0, 0)
+    assert be.last_ready_ns is None
 
     ring = be.ring_pages
     rows = [StepEntry(tokens=[5, 9, 2], start=0, pages=[1, 2], phase="prefill",
@@ -82,6 +85,13 @@ def test_every_backend_keeps_the_contract(kind):
     check_members()
     assert len(be.last_phases) == 5
     assert [before, *be.last_phases, after] == sorted([before, *be.last_phases, after])
+    # a backend that feeds a device says when its result was ready, inside its
+    # ``wait``; one whose call is a single ``wait`` (the fake, the gang: each
+    # rank waits in its turn) does not say
+    if kind in ("fake", "gang-tp2"):
+        assert be.last_ready_ns is None
+    else:
+        assert be.last_phases[2] <= be.last_ready_ns <= be.last_phases[3]
     assert type(be.last_step_compiled) is bool
     walked, of = be.last_attn_blocks
     assert type(walked) is int and type(of) is int and walked <= of
@@ -305,3 +315,39 @@ def test_the_hosts_count_of_a_mixed_step_is_what_it_was(tile_slots, block_tokens
                                own_ends)
     assert got == want
     assert all(type(n) is int for n in (got[0], got[1], *got[2], got[3]))
+
+
+@pytest.mark.parametrize("kind", ["llama", "fake"])
+async def test_a_kept_cycle_splits_its_wait_where_the_backend_says(kind):
+    """Over the backend the chip runs, a kept cycle's ``wait.fetch`` starts at
+    the backend's own ``last_ready_ns`` and ends with ``step.wait``; over the
+    fake, which does not say, there is none.  ``emit.wake`` needs no word of
+    the backend.  Either way the trace holds seven ``step*`` spans."""
+    from .test_serving import SpanSink, traced_engine
+
+    sink = await SpanSink().listen()
+    be = build(kind)
+    eng = traced_engine(sink, be, max_new_tokens_cap=16)
+    ready = []
+    closed = eng._cycle_closed
+
+    def recording(n_step, marks, attrs):
+        ready.append(be.last_ready_ns)
+        closed(n_step, marks, attrs)
+
+    eng._cycle_closed = recording
+    for job in ("a", "b"):  # the first closes the start-up record; both cycles 0 are kept
+        await eng.submit(GenRequest(prompt=[5, 9, 2], max_new_tokens=3, stream=False), job_id=job)
+        eng._kept_cycle_ns = 0
+    await eng.stop()
+    await sink.bus.drain()
+    cycle = {s.name: s for s in sink.spans if s.trace_id == "step-w-t-3"}
+    assert sum(n == "step" or n.startswith("step.") for n in cycle) == 7
+    wait, emit = cycle["step.wait"], cycle["step.emit"]
+    assert emit.start_us == cycle["emit.wake"].start_us <= cycle["emit.wake"].end_us <= emit.end_us
+    if kind == "fake":
+        assert ready == [None] * 6 and "wait.fetch" not in cycle
+    else:
+        fetch = cycle["wait.fetch"]
+        assert fetch.start_us == ready[3] // 1000 and fetch.parent_span_id == wait.span_id
+        assert wait.start_us <= fetch.start_us <= fetch.end_us == wait.end_us

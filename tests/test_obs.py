@@ -403,6 +403,101 @@ async def test_serving_spans_reach_the_collector_and_assemble():
     await eng.stop()
 
 
+def test_gc_pauses_is_one_callback_for_all_its_holders():
+    """``GcPauses``: one ``gc.callbacks`` entry while anybody holds it, every
+    holder's ``Metrics`` fed from the same call, each pause kept with its
+    bounds on the step spans' clock."""
+    import gc
+    import time
+
+    from cordum_tpu.obs.profiler import GcPauses
+
+    watch = GcPauses()
+    before = list(gc.callbacks)
+    a, b, quiet = Metrics(), Metrics(), object()
+    watch.hold(a, a)
+    watch.hold(b, b)
+    watch.hold(quiet)  # a holder with no registry of its own (the serving engine)
+    assert gc.callbacks == before + [watch._on_gc]
+    t0 = time.time_ns()
+    gc.collect()
+    t1 = time.time_ns()
+    assert watch.count == 1 and len(watch.pauses) == 1
+    ordinal, start, end, gen = watch.pauses[-1]
+    assert (ordinal, gen) == (1, 2) and t0 <= start <= end <= t1
+    for m in (a, b):
+        assert m.gc_pauses.value(generation="2") == 1
+        assert sum(m.gc_pause_seconds._totals.values()) == 1
+    watch.release(a)
+    watch.release(a)  # releasing twice is releasing once
+    gc.collect(0)
+    assert a.gc_pauses.total() == 1 and b.gc_pauses.value(generation="0") == 1
+    watch.release(b)
+    assert gc.callbacks == before + [watch._on_gc]  # the quiet holder is still there
+    watch.release(quiet)
+    assert gc.callbacks == before
+    gc.collect()
+    assert watch.count == 2
+
+
+def test_gc_pauses_since_gives_what_came_after_and_is_bounded():
+    import gc
+
+    from cordum_tpu.obs import profiler
+
+    watch = profiler.GcPauses()
+    holder = object()
+    watch.hold(holder)
+    try:
+        gc.collect(0)
+        seen = watch.count
+        assert watch.since(seen) == []
+        gc.collect(1)
+        gc.collect(2)
+        assert [(n, gen) for n, _, _, gen in watch.since(seen)] == [(2, 1), (3, 2)]
+        assert [gen for _, _, _, gen in watch.since(0)] == [0, 1, 2]
+        for _ in range(profiler.GC_PAUSES_KEPT + 5):
+            gc.collect(0)
+        assert len(watch.pauses) == profiler.GC_PAUSES_KEPT
+        assert len(watch.since(seen)) == profiler.GC_PAUSES_KEPT  # the oldest fell out
+        assert watch.count == profiler.GC_PAUSES_KEPT + 8
+    finally:
+        watch.release(holder)
+
+
+async def test_the_new_serving_spans_assemble_inside_their_phases():
+    """Through the collector: ``wait.fetch`` and ``emit.wake`` are
+    grandchildren of a kept cycle's ``step``, and a park is a stored trace of
+    its own."""
+    from cordum_tpu.serving.backend import STEP_PHASES
+    from cordum_tpu.serving.engine import GenRequest, ServingEngine
+    from tests.fakes import run_blocking
+    from tests.test_serving import ReadyFake
+
+    kv, bus = MemoryKV(), LoopbackBus()
+    collector = SpanCollector(kv, bus, metrics=Metrics())
+    await collector.start()
+    eng = ServingEngine(ReadyFake(num_pages=64, step_delay=0.002, max_context=256),
+                        run_blocking=run_blocking, tracer=Tracer("worker", bus),
+                        max_new_tokens_cap=128)
+    eng.worker_id = "w-o"
+    for job in ("a", "b"):
+        await eng.submit(GenRequest(prompt=[4, 5], max_new_tokens=70, stream=False), job_id=job)
+        await asyncio.sleep(0.01)
+    await eng.stop()
+    await bus.drain()
+    stored = await collector.recent_trace_ids(50)
+    kept = [t for t in stored if t.startswith("step-w-o-") and t != "step-w-o-0"]
+    assert kept  # 140 steps of 2 ms: one at least began 250 ms after cycle 0
+    tree = assemble(kept[0], await collector.spans(kept[0]))
+    depth = {sp["name"]: sp["depth"] for sp in tree["spans"]}
+    assert depth["step"] == 0 and {depth[f"step.{p}"] for p in STEP_PHASES} == {1}
+    assert depth["wait.fetch"] == depth["emit.wake"] == 2
+    (park,) = await collector.spans("loop-w-o-70")  # a's 70 steps done, b not yet there
+    assert park.name == "serving.parked" and park.duration_us >= 10_000
+    await collector.stop()
+
+
 def test_span_wire_roundtrip():
     sp = _mk("a", "b", "execute", 1, 2)
     sp.attrs = {"k": "v"}

@@ -5,6 +5,7 @@ equivalence, cancel-of-stateful-jobs, scheduler session affinity, gateway
 session-key stamping, and the SDK streaming helper."""
 import asyncio
 import random
+import time
 
 import pytest
 
@@ -1432,6 +1433,309 @@ async def test_failed_step_leaves_an_error_step_span():
     (err,) = [s for s in sink.spans if s.status == "ERROR"]
     assert err.name == "step" and err.attrs["error"] == "RuntimeError"
     assert [s.name for s in sink.spans if s.trace_id == err.trace_id] == ["step"]
+
+
+# ---- the loop outside its cycles, and what lies inside a phase (ISSUE 51) ----
+
+class ReadyFake(FakeBackend):
+    """A fake that says when its result was ready (``last_ready_ns``), as a
+    backend that feeds a device does, and collects in the steps of
+    ``collect_at`` (a forced generation-2 collection inside the step)."""
+
+    def __init__(self, *a, collect_at=(), **kw):
+        super().__init__(*a, **kw)
+        self.collect_at = set(collect_at)
+
+    def device(self, n_step):
+        super().device(n_step)
+        if n_step in self.collect_at:
+            import gc
+
+            gc.collect()
+        self._ready = time.time_ns()
+        time.sleep(0.0005)  # the result's way back
+
+    def step(self, entries):
+        out = super().step(entries)
+        self.last_ready_ns = self._ready
+        return out
+
+
+def record_cycles(eng):
+    """Every cycle's own ``(start, end)`` stamps, kept or not."""
+    cycles = []
+    closed = eng._cycle_closed
+
+    def recording(n_step, marks, attrs):
+        cycles.append((marks[0], marks[-1]))
+        closed(n_step, marks, attrs)
+
+    eng._cycle_closed = recording
+    return cycles
+
+
+def hold_admission(eng, cycles):
+    """Pages that free late: the next ``cycles`` cycles admit nothing."""
+    admit, left = eng._admit, [cycles]
+
+    async def late_admit():
+        if left[0]:
+            left[0] -= 1
+        else:
+            await admit()
+
+    eng._admit = late_admit
+
+
+async def freeze_for(eng, log, job, seconds):
+    """Freeze ``job`` once it streams, stand still, thaw it: a live session
+    and no row to feed."""
+    while len(log.packets.get(job, [])) < 2:
+        await asyncio.sleep(0.001)
+    assert eng.freeze_session(job)
+    await eng.wait_quiesced(job)
+    await asyncio.sleep(seconds)
+    eng.unfreeze_session(job)
+
+
+async def test_cycles_parks_and_polls_cover_the_loops_life():
+    """Every cycle starts where the interval before it ended, and the
+    cycles' stamps, ``parked_seconds`` and ``polled_seconds`` add up to the
+    loop's life from its first cycle to its last stamp, with nothing
+    between."""
+    from cordum_tpu.infra.metrics import Metrics
+
+    metrics = Metrics()
+    eng = ServingEngine(FakeBackend(num_pages=64, step_delay=0.002, max_context=256),
+                        run_blocking=run_blocking, max_sessions=4, metrics=metrics)
+    cycles = record_cycles(eng)
+    await generate(eng, 2, trace=False)  # busy, then parked
+    await asyncio.sleep(0.03)
+    hold_admission(eng, 3)  # woken, and three cycles of pending work with no pages
+    log = CycleLog()
+    task = asyncio.ensure_future(log.submit(eng, "s", [7] * 5, 12))
+    await freeze_for(eng, log, "s", 0.02)  # live and nothing to feed
+    await task
+    await asyncio.sleep(0.01)  # parked again, and woken once more
+    await eng.submit(GenRequest(prompt=[3, 1], max_new_tokens=3, stream=False), job_id="z")
+    st = eng.stats
+    assert len(cycles) == st.steps
+    life = (cycles[-1][1] - cycles[0][0]) / 1e9  # the last park is still open
+    in_cycles = sum(b - a for a, b in cycles) / 1e9
+    assert st.parked_seconds >= 0.03 + 0.01 and st.polled_seconds >= 0.02 + 0.003
+    assert abs(life - (in_cycles + st.parked_seconds + st.polled_seconds)) < 1e-6
+    # no cycle overlaps the one before, and most follow it at once
+    assert all(a[1] <= b[0] for a, b in zip(cycles, cycles[1:]))
+    assert sum(a[1] == b[0] for a, b in zip(cycles, cycles[1:])) >= len(cycles) - 8
+    idle = metrics.serving_loop_idle
+    assert idle.value(state="parked") == pytest.approx(st.parked_seconds)
+    assert idle.value(state="poll") == pytest.approx(st.polled_seconds)
+    assert "cordum_serving_loop_idle_seconds_total" in metrics.render()
+    await eng.stop()
+
+
+@pytest.mark.parametrize("state", ["parked", "pages", "budget"])
+async def test_a_park_covers_its_wait_and_a_poll_names_its_reason(state):
+    """``serving.parked`` covers the time the loop stood with nothing live;
+    ``serving.poll`` is ONE span over the cycles that fed nothing, with the
+    reason; both on a trace ``loop-<worker>-<steps done>``, and neither
+    overlaps a kept cycle."""
+    sink = await SpanSink().listen()
+    eng = traced_engine(sink, FakeBackend(num_pages=64, step_delay=0.002, max_context=256),
+                        max_sessions=4)
+    await generate(eng, 1)  # the start-up record is told, then the loop parks
+    steps_before = eng.stats.steps
+    t0 = time.time_ns() // 1000
+    await asyncio.sleep(0.03)
+    t1 = time.time_ns() // 1000
+    log = CycleLog()
+    if state == "pages":
+        hold_admission(eng, 5)
+    task = asyncio.ensure_future(log.submit(eng, "s", [7] * 5, 8))
+    if state == "budget":
+        await freeze_for(eng, log, "s", 0.02)
+    await task
+    await eng.stop()
+    await sink.bus.drain()
+    parks, polls = sink.named("serving.parked"), sink.named("serving.poll")
+    (park,) = [p for p in parks if p.start_us <= t0]
+    assert park.end_us >= t1 and park.trace_id == f"loop-w-t-{steps_before}"
+    assert park.attrs == {} and not park.parent_span_id
+    if state == "parked":
+        assert polls == []
+    else:
+        (poll,) = polls  # one span however many cycles polled
+        assert poll.attrs == {"reason": state} and poll.trace_id.startswith("loop-w-t-")
+        assert poll.duration_us >= (5_000 if state == "pages" else 20_000)
+        assert eng.stats.polled_seconds == pytest.approx(poll.duration_us / 1e6, abs=1e-5)
+    roots = [spans[-1] for spans in sink.step_traces().values()]
+    for idle in parks + polls:
+        assert all(r.end_us <= idle.start_us or idle.end_us <= r.start_us for r in roots)
+
+
+@pytest.mark.parametrize("says", [True, False])
+async def test_a_kept_cycle_holds_seven_step_spans_and_what_lies_inside_them(says):
+    """``wait.fetch``, ``emit.wake`` and ``runtime.gc`` join a kept cycle's
+    trace under names that are no ``step*``: the trace still holds exactly
+    seven of those and the benchmark's ``cycles`` still returns it;
+    ``wait.fetch`` lies inside ``step.wait`` and is absent for a backend
+    whose ``last_ready_ns`` is None, ``emit.wake`` inside ``step.emit``."""
+    from benchmarks.layer_metrics import step_cycle_ms
+
+    sink = await SpanSink().listen()
+    cls = ReadyFake if says else FakeBackend
+    be = cls(num_pages=64, step_delay=0.002, max_context=256, slow_at={70: 0.05})
+    if says:
+        be.collect_at = {70}
+    eng = traced_engine(sink, be, max_sessions=2, max_new_tokens_cap=128)
+    await generate(eng, 1, new=90)
+    await eng.stop()
+    await sink.bus.drain()
+    traces = {}
+    for s in sink.spans:
+        if s.trace_id.startswith("step-"):
+            traces.setdefault(s.trace_id, []).append(s)
+    assert len(traces) >= 2 and "step-w-t-70" in traces
+    run = {"spans": [{"name": s.name, "trace": s.trace_id, "start_us": s.start_us,
+                      "end_us": s.end_us} for s in sink.spans]}
+    assert len(step_cycle_ms.cycles(run)) == len(traces)
+    for trace_id, spans in traces.items():
+        by = {s.name: s for s in spans}
+        assert sum(n == "step" or n.startswith("step.") for n in by) == 7
+        assert spans[-1].name == "step"  # the root still lands last
+        if trace_id == "step-w-t-0":
+            # closed before the start-up record was told: the parent's seven
+            assert len(spans) == 7
+            continue
+        wait, emit, wake = by["step.wait"], by["step.emit"], by["emit.wake"]
+        assert wake.parent_span_id == emit.span_id
+        assert emit.start_us == wake.start_us <= wake.end_us <= emit.end_us
+        assert ("wait.fetch" in by) == says
+        if says:
+            fetch = by["wait.fetch"]
+            assert fetch.parent_span_id == wait.span_id
+            assert wait.start_us <= fetch.start_us <= fetch.end_us == wait.end_us
+            assert 400 <= fetch.duration_us < wait.duration_us  # the fake's way back
+    if says:
+        # the forced collection kept its cycle, with the cause on it
+        root = traces["step-w-t-70"][-1]
+        gcs = [s for s in traces["step-w-t-70"] if s.name == "runtime.gc"]
+        assert gcs and all(s.parent_span_id == root.span_id for s in gcs)
+        assert all(root.start_us <= s.start_us <= s.end_us <= root.end_us for s in gcs)
+        assert "2" in {s.attrs["generation"] for s in gcs} and root.attrs["gc_gen"] == "2"
+        assert float(root.attrs["gc_ms"]) == pytest.approx(
+            sum(s.duration_us for s in gcs) / 1e3, abs=0.01)
+
+
+async def test_a_forced_collection_inside_a_step_keeps_its_cycle():
+    """A generation-2 collection inside a fake step: the cycle is kept though
+    the rate cap would drop it and it is no stall, the pause lies inside the
+    cycle's ``step.wait``, the root carries ``gc_ms``, and the stats count
+    every pause; with nobody listening the stats alone."""
+    sink = await SpanSink().listen()
+    be = ReadyFake(num_pages=64, step_delay=0.001, max_context=256, collect_at={20, 22})
+    eng = traced_engine(sink, be, max_sessions=2, max_new_tokens_cap=128)
+    await generate(eng, 1, new=40)
+    assert eng.stats.gc_pauses >= 2 and eng.stats.gc_pause_seconds > 0
+    await eng.stop()
+    await sink.bus.drain()
+    traces = sink.step_traces()
+    for n in (20, 22):  # 22 began well inside 250 ms of 20
+        root = traces[f"step-w-t-{n}"][-1]
+        wait = next(s for s in traces[f"step-w-t-{n}"] if s.name == "step.wait")
+        pause = max((s for s in sink.named("runtime.gc") if s.trace_id == root.trace_id),
+                    key=lambda s: s.duration_us)
+        assert wait.start_us <= pause.start_us and pause.end_us <= wait.end_us
+        assert float(root.attrs["gc_ms"]) > 0
+    assert "gc_ms" not in traces["step-w-t-0"][-1].attrs
+    quiet = ServingEngine(ReadyFake(num_pages=64, collect_at={3}), run_blocking=run_blocking)
+    await generate(quiet, 1, new=8, trace=False)
+    assert quiet.stats.gc_pauses >= 1 and not quiet._spans
+    await quiet.stop()
+
+
+async def test_the_collector_is_watched_from_the_startup_record_to_stop(monkeypatch):
+    """``gc.callbacks`` is as it was until ``_tell_startup`` and again after
+    ``stop()``; between them it holds ONE more entry, shared with a
+    ``RuntimeProfiler`` started beside the engine."""
+    import gc
+
+    from cordum_tpu.infra.metrics import Metrics
+    from cordum_tpu.obs import profiler
+    from cordum_tpu.obs.profiler import RuntimeProfiler
+    from cordum_tpu.serving import engine as engine_mod
+
+    # the process's watch may still be held by an engine some test never
+    # stopped: this test gets one of its own
+    GC_PAUSES = profiler.GcPauses()
+    monkeypatch.setattr(profiler, "GC_PAUSES", GC_PAUSES)
+    monkeypatch.setattr(engine_mod, "GC_PAUSES", GC_PAUSES)
+    before = list(gc.callbacks)
+    be = FakeBackend(num_pages=64, max_batch_tokens=4)
+    eng = ServingEngine(be, run_blocking=run_blocking, max_sessions=2)
+    seen = []
+    tell = eng._tell_startup
+
+    def telling(end_ns):
+        seen.append(list(gc.callbacks))
+        tell(end_ns)
+        seen.append(list(gc.callbacks))
+
+    eng._tell_startup = telling
+    # a prompt of three chunks: two cycles run before a token is sampled
+    out = await eng.submit(GenRequest(prompt=[5] * 10, max_new_tokens=4, stream=False),
+                           job_id="a")
+    assert len(out["tokens"]) == 4 and be.steps >= 6
+    assert seen[0] == before and seen[1] == before + [GC_PAUSES._on_gc]
+    metrics = Metrics()
+    prof = RuntimeProfiler(metrics, service="test", tick_s=5.0)
+    await prof.start()
+    assert gc.callbacks == before + [GC_PAUSES._on_gc]  # one entry, two holders
+    gc.collect()
+    assert metrics.gc_pauses.value(generation="2") == 1
+    await prof.stop()
+    assert gc.callbacks == before + [GC_PAUSES._on_gc]  # the engine still holds it
+    await eng.stop()
+    assert gc.callbacks == before
+
+
+async def test_first_packet_span_ends_after_the_sink_returned():
+    """``serving.first_packet`` lies on the request's own trace beside
+    ``serving.queue`` / ``serving.prefill``, starts where ``serving.prefill``
+    ends and ends after the session's first ``on_tokens`` call returned; a
+    session without a sink, and the one whose first token came before the
+    start-up record was told, have none."""
+    sink = await SpanSink().listen()
+    eng = traced_engine(sink, FakeBackend(num_pages=64, step_delay=0.002), max_sessions=4)
+    returned = {}
+
+    def streaming(job):
+        async def on_tokens(tokens, n_generated, done):
+            await asyncio.sleep(0.003)
+            returned.setdefault(job, time.time_ns() // 1000)
+        return on_tokens
+
+    async def submit(job, sinked=True):
+        return await eng.submit(
+            GenRequest(prompt=[4, 2, 9], max_new_tokens=5), job_id=job, trace_id=f"tr-{job}",
+            parent_span_id=f"exec-{job}", on_tokens=streaming(job) if sinked else None)
+
+    await submit("first")  # its first token closes the start-up record
+    await asyncio.gather(submit("a"), submit("b"), submit("quiet", sinked=False))
+    await eng.stop()
+    await sink.bus.drain()
+    by_trace = {}
+    for s in sink.named("serving.first_packet"):
+        assert s.trace_id not in by_trace
+        by_trace[s.trace_id] = s
+    assert set(by_trace) == {"tr-a", "tr-b"}
+    for job in ("a", "b"):
+        sp = by_trace[f"tr-{job}"]
+        (prefill,) = [s for s in sink.named("serving.prefill") if s.trace_id == sp.trace_id]
+        assert sp.parent_span_id == prefill.parent_span_id == f"exec-{job}"
+        assert sp.start_us == prefill.end_us
+        assert sp.duration_us >= 3_000 and returned[job] - 50 <= sp.end_us <= returned[job] + 20_000
+        assert set(sp.attrs) == {"behind_step", "tokens"} and sp.attrs["tokens"] == "1"
 
 
 def test_ragged_step_scopes_are_metadata_only():
